@@ -18,57 +18,50 @@ from . import (analysis, core, data, faults, fluid, models, nn, obs, ops,
 from .core import CPUPlace, Place, SeqBatch, TPUPlace, sequence_mask
 from .trainer import Trainer
 
-#: env var naming a persistent XLA compilation-cache directory; applied at
-#: import (and by :func:`init`) so a preemption-resume under the same env
-#: restarts without re-paying its compiles
-COMPILE_CACHE_ENV = "PADDLE_TPU_COMPILE_CACHE_DIR"
+#: where the compile cache lives when ``$JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a FIXED path inside the checkout (git-ignored). The directory is
+#: part of what a later process must repeat to hit the cache, so it is
+#: never a temp name, a pid or a time.
+DEFAULT_COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_cache")
 
 
-def enable_compile_cache(path: str) -> str:
-    """Point jax's persistent XLA compilation cache at ``path``.
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache — the ONE place the repo
+    decides where it lives (``serve``, ``train``, ``bench.py``'s children,
+    ``chip_smoke.py``'s children and tests/conftest.py all call this before
+    their first compile; nothing else sets a cache directory).
 
-    Compiled executables are keyed on the serialized computation + jaxlib
-    version, so a restarted process (preemption-resume, a re-run bench, a
-    new trainer on the same pod) loads them from disk instead of
-    recompiling.  The min-compile-time/entry-size floors are dropped to 0
-    so small fluid programs cache too (the knobs are best-effort across
-    jax versions).  Returns the path.
+    ``$JAX_COMPILATION_CACHE_DIR`` set: that directory, and no other.
+    Unset: :data:`DEFAULT_COMPILE_CACHE_DIR`. Compiled executables are
+    keyed on the serialized computation + jaxlib version, so a restarted
+    process (preemption-resume, the second run of a command) loads them
+    from disk instead of recompiling. The min-compile-time/entry-size
+    floors drop to 0 so small programs cache too. Returns the directory.
     """
     import jax
+    path = (_os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or DEFAULT_COMPILE_CACHE_DIR)
     _os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:
-            pass   # older jax: knob absent; the cache still works
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
 
 
-def init(compile_cache_dir: str = None, **flags):
-    """Process-level runtime init (the ``paddle.init`` analog).
-
-    ``compile_cache_dir`` (or ``$PADDLE_TPU_COMPILE_CACHE_DIR``) enables
-    the persistent XLA compilation cache via
-    :func:`enable_compile_cache`; remaining keyword flags are recorded
-    through :func:`v2.init`. Returns the recorded flag dict.
-    """
-    path = compile_cache_dir or _os.environ.get(COMPILE_CACHE_ENV)
-    if path:
-        flags["compile_cache_dir"] = enable_compile_cache(path)
+def init(**flags):
+    """Process-level runtime init (the ``paddle.init`` analog): enables the
+    persistent compilation cache (:func:`enable_compile_cache`) and records
+    the keyword flags through :func:`v2.init`. Returns the recorded flag
+    dict, ``compile_cache_dir`` included."""
+    flags["compile_cache_dir"] = enable_compile_cache()
     return v2.init(**flags)
 
-
-if _os.environ.get(COMPILE_CACHE_ENV):
-    try:
-        enable_compile_cache(_os.environ[COMPILE_CACHE_ENV])
-    except Exception:   # an unwritable dir must not break `import paddle_tpu`
-        pass
 
 __all__ = ["analysis", "core", "data", "faults", "fluid", "nn", "obs", "ops",
            "optimizer",
            "parallel", "trainer", "utils", "models", "v2", "Trainer",
            "Place", "TPUPlace", "CPUPlace", "SeqBatch", "sequence_mask",
-           "init", "enable_compile_cache", "COMPILE_CACHE_ENV",
+           "init", "enable_compile_cache", "DEFAULT_COMPILE_CACHE_DIR",
            "__version__"]

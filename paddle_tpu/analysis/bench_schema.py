@@ -1,6 +1,6 @@
 """Bench-row schema — the contract between benchmarks/*.py rows and every
 consumer downstream (bench.py's stdout JSONL, the driver's tail parser,
-BENCH_r0x.json trend tracking, `paddle_tpu lint --bench-rows`).
+saved-row trend tracking, `paddle_tpu lint --bench-rows`).
 
 A malformed row used to fail SILENTLY: a benchmark that dropped `mfu` or
 `hbm_bw_util` from its dict still printed, the trend tooling skipped the

@@ -66,7 +66,7 @@ from .verify import (BLOCK_ATTR_KEYS, _ATTR_BIND_KEYS, _ATTR_DEFINE_KEYS,
 
 
 class Effect(str, enum.Enum):
-    """Per-op effect taxonomy (docs/design/analysis.md)."""
+    """Per-op effect classification (docs/design/analysis.md)."""
 
     PURE = "pure"
     INPLACE = "in-place"

@@ -130,12 +130,11 @@ def cmd_train(args):
     from .trainer import event
     if getattr(args, "elastic", None):
         return _cmd_train_elastic(args)
-    if getattr(args, "compile_cache", None):
-        # persistent XLA compile cache BEFORE the config builds/compiles
-        # anything: a preemption-resume of this same command re-loads its
-        # executables from disk instead of re-paying the compiles
-        from . import enable_compile_cache
-        enable_compile_cache(args.compile_cache)
+    # persistent compile cache BEFORE the config builds/compiles anything:
+    # a preemption-resume of this same command re-loads its executables
+    # from disk instead of re-paying the compiles
+    from . import enable_compile_cache
+    enable_compile_cache()
     cfg = _load_config(args.config)
     trainer = _make_trainer(cfg)
     costs = []
@@ -765,8 +764,8 @@ def _lint_bench_rows(paths, as_json: bool = False, stream=None) -> int:
             data = json.loads(text)
             if isinstance(data, dict) and "metric" not in data \
                     and isinstance(data.get("tail"), str):
-                # a driver record (BENCH_r0x.json): the rows live as JSONL
-                # inside its "tail" field
+                # a driver record (a JSON object whose "tail" field holds
+                # the run's stdout): the rows live there as JSONL
                 text = data["tail"]
                 raise ValueError("driver record: parse tail as JSONL")
             rows = data if isinstance(data, list) else [data]
@@ -1521,8 +1520,9 @@ def cmd_serve(args):
     """
     import signal
 
-    from . import obs as _obs
+    from . import enable_compile_cache, obs as _obs
     from .serving import ServingDaemon, ServingEngine
+    enable_compile_cache()              # before the first compile
     if args.config:
         cfg = _load_config(args.config)
         if "model" not in cfg or "params" not in cfg:
@@ -1929,11 +1929,6 @@ def main(argv=None) -> int:
                    help="install an observability session for the run and "
                         "write its JSONL dump here (inspect with "
                         "'paddle_tpu obs summary/export')")
-    t.add_argument("--compile_cache", default=None,
-                   help="directory for the persistent XLA compilation "
-                        "cache: a preemption-resume (or any re-run) loads "
-                        "its compiled executables from here instead of "
-                        "recompiling ($PADDLE_TPU_COMPILE_CACHE_DIR analog)")
     t.add_argument("--elastic", choices=["master", "worker"], default=None,
                    help="elastic data-parallel mode (docs/design/elastic.md): "
                         "'master' serves membership + shard dispatch and "

@@ -7,7 +7,7 @@ Re-provides the reference's data stack (SURVEY.md §2.4):
   here (no network egress); same shapes/vocab semantics as the originals.
 * DataFeeder                    (python/paddle/v2/data_feeder.py + py_paddle
   DataProviderConverter) — converts row batches into device-ready arrays under the
-  feature-type taxonomy of SURVEY §8.2 (dense / index / sparse / sequence).
+  feature-type classification of SURVEY §8.2 (dense / index / sparse / sequence).
 * DoubleBuffer prefetch         (gserver/dataproviders/DataProvider.h:249) — a
   background-thread pipeline overlapping host batch prep with device steps.
 """

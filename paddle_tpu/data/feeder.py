@@ -1,4 +1,4 @@
-"""DataFeeder: row-tuples -> device-ready arrays under the slot-type taxonomy.
+"""DataFeeder: row-tuples -> device-ready arrays under the slot-type classification.
 
 The reference's canonical feature types (SURVEY.md §8.2: proto/DataFormat.proto
 SlotType; PyDataProvider2.py input_types; LayerGradUtil.h:23-34):

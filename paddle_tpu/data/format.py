@@ -5,7 +5,7 @@ Reference (SURVEY §8.2, proto/DataFormat.proto): a stream of
 ``SlotDef.SlotType`` ∈ {VECTOR_DENSE, VECTOR_SPARSE_NON_VALUE,
 VECTOR_SPARSE_VALUE, INDEX, VAR_MDIM_DENSE, VAR_MDIM_INDEX, STRING}, with
 sequence starts flagged per sample and nested sequences via SubseqSlot.
-That slot taxonomy is the framework's canonical feature-type system (it
+That slot classification is the framework's canonical feature-type system (it
 reappears in PyDataProvider2 input_types and LayerGradUtil's InputType) and
 maps 1:1 onto :mod:`paddle_tpu.data.feeder`'s slot classes.
 

@@ -52,6 +52,15 @@ class TransformerBlock(nn.Module):
                     "sharding or run without seq_axis")
             from ..parallel.ring_attention import ring_attention
             return ring_attention(q, k, v, seq_axis, self.causal)
+        from ..parallel.mesh import current_mesh
+        mesh = current_mesh()
+        if (mesh is not None and mesh.size > 1
+                and not jax.sharding.get_abstract_mesh().manual_axes):
+            # a GSPMD-partitioned step (Trainer(mesh=...)): the kernel has
+            # to sit in a shard_map, it cannot be partitioned automatically
+            from ..parallel.ring_attention import sharded_flash_attention
+            return sharded_flash_attention(mesh, q, k, v, causal=self.causal,
+                                           kv_lens=kv_lens)
         return pk.flash_attention(q, k, v, causal=self.causal,
                                   kv_lens=kv_lens)
 
@@ -493,7 +502,15 @@ class TransformerLM(nn.Module):
         [..., :i+1]. Equivalent to S sequential decode_step calls at S-th
         of the dispatches; query i attends cache rows j <= pos+i (causal
         within the span, everything live before it). Works on int8 cells
-        (span rows quantize on append; reads dequantize)."""
+        (span rows quantize on append; reads dequantize).
+
+        Contract against the sequential path (docs/design/kernels.md;
+        tests/test_decode_fused.py): the same masked-softmax formulation
+        as ``pk._dense_decode_attention``, greedy tokens EQUAL, logits
+        within 2 ulp of the largest logit — not bit-equal, because the
+        span's p·v contraction is a matmul (M = S) where the single
+        step's is a matrix-vector product and XLA may sum the two in a
+        different order."""
         B, S = tokens.shape
         pos = cell["pos"]                                  # [B]
         L = self.max_len if cache_len is None else min(cache_len,

@@ -72,11 +72,11 @@ PEAK_HBM_GBPS: Dict[str, Optional[float]] = {
 
 
 def _device_kind() -> str:
-    try:
-        import jax
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "cpu"
+    """The default device's kind as JAX reports it ("cpu" on the CPU
+    backend, by JAX's own naming). A backend that cannot start raises: a
+    failure never reads as "cpu" and with it as "no peak known"."""
+    import jax
+    return jax.devices()[0].device_kind
 
 
 _warned_env_vars: set = set()

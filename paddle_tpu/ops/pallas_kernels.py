@@ -17,8 +17,10 @@ Pallas kernel that keeps the whole inner loop in VMEM next to the MXU/VPU
   per-row logsumexp, the building block ring attention uses to merge partial
   attention results across ring steps (parallel/ring_attention.py).
 
-Kernels run with ``interpret=True`` off-TPU so the same code is testable on the
-CPU mesh (tests/test_pallas.py); numerics match the jnp reference path.
+Kernels run with ``interpret=True`` only where the backend is the CPU, so the
+same code is testable on the CPU mesh (tests/test_pallas.py); numerics match
+the jnp reference path. tests/test_chip_compile.py compiles the main-path
+kernels for a described TPU v5e at GPT-2-small widths.
 """
 
 from __future__ import annotations
@@ -34,10 +36,19 @@ _NEG = -1e30
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """Is the default backend the TPU? A backend that fails to initialise
+    raises here — an error never turns into "not a TPU" and with it into
+    the interpreter or a dense route."""
+    return jax.default_backend() == "tpu"
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    """Resolve a kernel wrapper's ``interpret=None``: the Pallas interpreter
+    only where the backend is positively the CPU (the test mesh); any other
+    backend gets the compiled kernel and that backend's own errors."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret
 
 
 # ---------------------------------------------------------------------------
@@ -471,10 +482,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         o = _dense_attention(q, k, v, causal, scale_v, kv_lens)
     else:
         block_q, block_k = _default_blocks(block_q, block_k)
-        if interpret is None:
-            interpret = not _on_tpu()
+        interpret = _interpret(interpret)
         o = _flash(q, k, v, kv_lens, causal, scale_v, block_q, block_k,
-                   bool(interpret))
+                   interpret)
     if valid is not None:
         o = o * valid[:, None, None, None].astype(o.dtype)
     return o
@@ -496,10 +506,9 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     D = q.shape[-1]
     scale_v = scale if scale is not None else D ** -0.5
     block_q, block_k = _default_blocks(block_q, block_k)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     return _fa_fwd_call(q, k, v, causal, scale_v, block_q, block_k,
-                        bool(interpret))
+                        interpret)
 
 
 def flash_block_grads(q, k, v, o, lse, do, *, causal: bool = False,
@@ -518,10 +527,9 @@ def flash_block_grads(q, k, v, o, lse, do, *, causal: bool = False,
     D = q.shape[-1]
     scale_v = scale if scale is not None else D ** -0.5
     block_q, block_k = _default_blocks(block_q, block_k)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     return _fa_bwd_call(q, k, v, o, lse, do, causal, scale_v, block_q,
-                        block_k, bool(interpret), delta=delta)
+                        block_k, interpret, delta=delta)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -549,53 +557,119 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 # ---------------------------------------------------------------------------
 # Fused decode-step attention — the KV-cache read is the bytes term that
-# dominates incremental decode (BENCH_r05: ~18% of the v5e's 819 GB/s).
-# One program per SAMPLE streams that sample's live cache rows through VMEM
-# once, in the cache's natural [L, H, D] layout (no head transpose in HBM),
-# masks rows past the write position, and runs the f32 softmax read there.
-# The int8 path dequantizes rows in VMEM from per-(row, head) scales, so the
-# HBM cache term halves (2 bytes -> 1 + scale overhead) while the matmuls
-# stay f32 — the quantized-KV numerics contract of docs/design/kernels.md.
+# dominates incremental decode. One (sample, chunk) program streams a chunk
+# of that sample's cache rows through VMEM in the cache's natural [L, H, D]
+# layout (no head transpose in HBM), masks rows past the write position and
+# folds the chunk into a running f32 softmax (m, l, acc scratch) — the
+# flash-decode recurrence, so VMEM holds one chunk, never the whole cache
+# row. Chunks wholly past ``pos`` skip their compute. The int8 path
+# dequantizes rows in VMEM from per-(row, head) scales, so the HBM cache
+# term halves (2 bytes -> 1 + scale overhead) while the math stays f32 — the
+# quantized-KV numerics contract of docs/design/kernels.md.
+#
+# The read is written for the VPU, not the MXU: with (H, D) on the tile's
+# (sublane, lane) axes, s[l, h] = sum_d q[h, d] k[l, h, d] is a broadcast
+# multiply and a lane reduction, and o[h, d] = sum_l p[l, h] v[l, h, d] a
+# lane broadcast and adds along the untiled L axis — no operand ever changes
+# layout. (A head-batched dot_general over the MIDDLE axis of [L, H, D] with
+# no free lhs dimension is refused by Mosaic: "failed to parse
+# TPU_DotDimensionNumbersAttr parameter 'lhs_non_contracting_dims'".)
+# tests/test_chip_compile.py compiles every variant for a described v5e.
 # ---------------------------------------------------------------------------
 
-def _decode_attn_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, *, scale: float):
-    """One sample: q [1, H, D], k/v [1, L, H, D], pos [1, 1, 1] int32 ->
-    o [1, H, D] f32. Rows j <= pos are live (row pos holds THIS step's k/v,
-    appended before the read)."""
-    q = q_ref[0].astype(jnp.float32) * scale            # [H, D]
-    k = k_ref[0].astype(jnp.float32)                    # [L, H, D]
-    v = v_ref[0].astype(jnp.float32)
-    _decode_attn_body(q, k, v, pos_ref[0, 0, 0], o_ref)
+#: rows of cache one decode-attention program holds in VMEM: a [256, 12, 64]
+#: bf16 block pads to [256, 16, 128] = 1 MiB, so K + V double-buffered plus
+#: the f32 working copies stay well inside the 16 MiB scoped-VMEM default
+DECODE_CHUNK = 256
 
 
-def _decode_attn_q_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref,
-                          o_ref, *, scale: float):
-    """int8-KV variant: k/v int8 [1, L, H, D] with per-(row, head) f32
-    scales [1, L, H]; rows dequantize in VMEM, never materializing an f32
-    cache in HBM."""
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0][..., None]
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0][..., None]
-    _decode_attn_body(q, k, v, pos_ref[0, 0, 0], o_ref)
+def _decode_chunk(L: int) -> int:
+    """Chunk rows for a dense-row read of L: the largest power of two
+    <= DECODE_CHUNK that divides L (>= 8, the scale block's sublane tile),
+    else the whole read as one block."""
+    c = DECODE_CHUNK
+    while c >= 8:
+        if L % c == 0:
+            return c
+        c //= 2
+    return L
 
 
-def _decode_attn_body(q, k, v, pos, o_ref):
-    """Shared masked-softmax read: head-batched dots, softmax over live
-    rows. Identical formulation to _dense_decode_attention so the kernel
-    and reference routes agree to the ulp on the same inputs."""
-    L = k.shape[0]
-    # [H, L]: contract D, batch H
-    s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                            preferred_element_type=jnp.float32)
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1)
-    s = jnp.where(j <= pos, s, _NEG)
-    m = jnp.max(s, axis=1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=1, keepdims=True)
-    # [H, D]: contract L, batch H
-    o = jax.lax.dot_general(p, v, (((1,), (0,)), ((0,), (1,))),
-                            preferred_element_type=jnp.float32)
-    o_ref[0] = o / l
+def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool):
+    """One (sample b, chunk c) program of the decode read, dense-row or
+    paged, float or int8 — one body so every variant shares the softmax.
+
+    Scalar-prefetched (the leading refs): [tbl [B, NB] — paged only, read
+    by the index maps alone,] pos [B] int32 — rows j <= pos[b] are live (row pos holds THIS step's
+    k/v, appended before the read). Blocks: q [1, H, D]; k/v [1, chunk, H,
+    D] (+ ks/vs [1, chunk, H] f32 when int8); o [1, H, D] f32, written by
+    the last chunk. Scratch m/l [H, 1], acc [H, D] f32 carry the running
+    softmax across the chunk axis."""
+    if quantized:
+        (pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
+         m_ref, l_ref, acc_ref) = refs[-10:]
+    else:
+        pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs[-8:]
+    b, c = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(c == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    pos = pos_ref[b]
+
+    @pl.when(c * chunk <= pos)           # a dead chunk adds exactly nothing
+    def _live():
+        q = q_ref[0].astype(jnp.float32) * scale            # [H, D]
+        k = k_ref[0].astype(jnp.float32)                    # [chunk, H, D]
+        v = v_ref[0].astype(jnp.float32)
+        if quantized:
+            k = k * ks_ref[0][..., None]
+            v = v * vs_ref[0][..., None]
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True)    # [chunk, H, 1]
+        j = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
+        s = jnp.where(j <= pos, s, _NEG)
+        m_prev = m_ref[...]                                 # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=0)
+        acc_ref[...] = acc_ref[...] * corr + jnp.sum(p * v, axis=0)
+        m_ref[...] = m_new
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = acc_ref[...] / l_ref[...]
+
+
+def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, kv_spec, sc_spec,
+                      *, grid, scale, chunk, interpret):
+    """The one pallas_call behind decode_attention and
+    paged_decode_attention: ``prefetch`` scalars (pos last), then q, then
+    k/v — each followed by its scale operand when the cache is int8."""
+    from jax.experimental.pallas import tpu as pltpu
+    B, H, D = q.shape
+    if k_scale is not None:
+        kv_args, kv_specs = ((k, k_scale, v, v_scale),
+                             [kv_spec, sc_spec, kv_spec, sc_spec])
+    else:
+        kv_args, kv_specs = (k, v), [kv_spec, kv_spec]
+    qo_spec = pl.BlockSpec((1, H, D), lambda b, c, *_: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch), grid=grid,
+        in_specs=[qo_spec] + kv_specs, out_specs=qo_spec,
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, D), jnp.float32)])
+    kernel = functools.partial(_decode_attn_kernel, scale=scale, chunk=chunk,
+                               quantized=k_scale is not None)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, D), jnp.float32),
+        interpret=interpret,
+    )(*prefetch, q, *kv_args)
 
 
 def quantize_kv(x: jax.Array):
@@ -668,30 +742,13 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                        v_scale)
     if route != "kernel":
         raise ValueError(f"unknown decode_attention route {route!r}")
-    if interpret is None:
-        interpret = not _on_tpu()
-    posb = pos.astype(jnp.int32)[:, None, None]          # [B, 1, 1]
-    q_spec = pl.BlockSpec((1, H, D), lambda b: (b, 0, 0))
-    kv_spec = pl.BlockSpec((1, L, H, D), lambda b: (b, 0, 0, 0))
-    sc_spec = pl.BlockSpec((1, L, H), lambda b: (b, 0, 0))
-    pos_spec = pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0))
-    out_spec = pl.BlockSpec((1, H, D), lambda b: (b, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((B, H, D), jnp.float32)
-    if k_scale is not None:
-        kernel = functools.partial(_decode_attn_q_kernel, scale=scale_v)
-        return pl.pallas_call(
-            kernel, grid=(B,),
-            in_specs=[q_spec, kv_spec, sc_spec, kv_spec, sc_spec, pos_spec],
-            out_specs=out_spec, out_shape=out_shape,
-            interpret=bool(interpret),
-        )(q, k, k_scale, v, v_scale, posb)
-    kernel = functools.partial(_decode_attn_kernel, scale=scale_v)
-    return pl.pallas_call(
-        kernel, grid=(B,),
-        in_specs=[q_spec, kv_spec, kv_spec, pos_spec],
-        out_specs=out_spec, out_shape=out_shape,
-        interpret=bool(interpret),
-    )(q, k, v, posb)
+    chunk = _decode_chunk(L)
+    kv_spec = pl.BlockSpec((1, chunk, H, D), lambda b, c, p: (b, c, 0, 0))
+    sc_spec = pl.BlockSpec((1, chunk, H), lambda b, c, p: (b, c, 0))
+    return _decode_attn_call(
+        (pos.astype(jnp.int32),), q, k, v, k_scale, v_scale, kv_spec, sc_spec,
+        grid=(B, L // chunk), scale=scale_v, chunk=chunk,
+        interpret=_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -707,40 +764,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # decode_attention — so the paged read and the dense-row read agree to the
 # bit on the same cache contents.
 # ---------------------------------------------------------------------------
-
-def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                       k_all, v_all, *, scale: float, block: int):
-    """One (sample, page) program: k_ref/v_ref [1, bs, H, D] is the page the
-    scalar-prefetched table names for (b, j); pages accumulate into the
-    k_all/v_all [NB*bs, H, D] VMEM scratch, and the LAST page program runs
-    the shared masked-softmax body over the assembled contiguous view."""
-    b, j = pl.program_id(0), pl.program_id(1)
-    k_all[pl.ds(j * block, block)] = k_ref[0].astype(jnp.float32)
-    v_all[pl.ds(j * block, block)] = v_ref[0].astype(jnp.float32)
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        q = q_ref[0].astype(jnp.float32) * scale
-        _decode_attn_body(q, k_all[...], v_all[...], pos_ref[b], o_ref)
-
-
-def _paged_attn_q_kernel(tbl_ref, pos_ref, q_ref, k_ref, ks_ref, v_ref,
-                         vs_ref, o_ref, k_all, v_all, *, scale: float,
-                         block: int):
-    """int8 pool variant: pages dequantize in VMEM from per-(row, head)
-    scales [1, bs, H] while assembling the f32 view — the f32 cache never
-    exists in HBM."""
-    b, j = pl.program_id(0), pl.program_id(1)
-    k_all[pl.ds(j * block, block)] = (k_ref[0].astype(jnp.float32)
-                                      * ks_ref[0][..., None])
-    v_all[pl.ds(j * block, block)] = (v_ref[0].astype(jnp.float32)
-                                      * vs_ref[0][..., None])
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        q = q_ref[0].astype(jnp.float32) * scale
-        _decode_attn_body(q, k_all[...], v_all[...], pos_ref[b], o_ref)
-
 
 def gather_pages(pool: jax.Array, tables: jax.Array) -> jax.Array:
     """Materialize the dense per-sample view of a page pool: pool
@@ -790,40 +813,14 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         return _dense_decode_attention(q, k, v, pos, scale_v, ks, vs)
     if route != "kernel":
         raise ValueError(f"unknown paged_decode_attention route {route!r}")
-    if interpret is None:
-        interpret = not _on_tpu()
-    from jax.experimental.pallas import tpu as pltpu
-    q_spec = pl.BlockSpec((1, H, D), lambda b, j, tbl, p: (b, 0, 0))
     page_spec = pl.BlockSpec((1, bs, H, D),
                              lambda b, j, tbl, p: (tbl[b, j], 0, 0, 0))
     sc_spec = pl.BlockSpec((1, bs, H),
                            lambda b, j, tbl, p: (tbl[b, j], 0, 0))
-    out_spec = pl.BlockSpec((1, H, D), lambda b, j, tbl, p: (b, 0, 0))
-    scratch = [pltpu.VMEM((L, H, D), jnp.float32),
-               pltpu.VMEM((L, H, D), jnp.float32)]
-    out_shape = jax.ShapeDtypeStruct((B, H, D), jnp.float32)
-    tables32 = tables.astype(jnp.int32)
-    pos32 = pos.astype(jnp.int32)
-    if k_scale is not None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B, NB),
-            in_specs=[q_spec, page_spec, sc_spec, page_spec, sc_spec],
-            out_specs=out_spec, scratch_shapes=scratch)
-        kernel = functools.partial(_paged_attn_q_kernel, scale=scale_v,
-                                   block=bs)
-        return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=bool(interpret),
-        )(tables32, pos32, q, k_pool, k_scale, v_pool, v_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B, NB),
-        in_specs=[q_spec, page_spec, page_spec],
-        out_specs=out_spec, scratch_shapes=scratch)
-    kernel = functools.partial(_paged_attn_kernel, scale=scale_v, block=bs)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=bool(interpret),
-    )(tables32, pos32, q, k_pool, v_pool)
+    return _decode_attn_call(
+        (tables.astype(jnp.int32), pos.astype(jnp.int32)), q, k_pool, v_pool,
+        k_scale, v_scale, page_spec, sc_spec, grid=(B, NB), scale=scale_v,
+        chunk=bs, interpret=_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -953,8 +950,7 @@ def lstm_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
         if save_cell:
             return out, h, c, jnp.concatenate(cells, axis=1)
         return out, h, c
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     if b is None:
         b = jnp.zeros((G,), xw.dtype)
     if h0 is None:
@@ -999,7 +995,7 @@ def lstm_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
         out, ht, ct, cseq = pl.pallas_call(
             kernel, grid=(Bp // blk,), in_specs=in_specs,
             out_specs=out_specs, out_shape=out_shape,
-            interpret=bool(interpret))(xw_tm, lens, u, b2, h0, c0)
+            interpret=interpret)(xw_tm, lens, u, b2, h0, c0)
         return (jnp.swapaxes(out, 0, 1)[:B], ht[:B], ct[:B],
                 jnp.swapaxes(cseq, 0, 1)[:B])
 
@@ -1011,7 +1007,7 @@ def lstm_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=bool(interpret),
+        interpret=interpret,
     )(xw_tm, lens, u, b2, h0, c0)
     return jnp.swapaxes(out, 0, 1)[:B], ht[:B], ct[:B]
 
@@ -1103,8 +1099,7 @@ def lstm_sequence_fused_bwd(xw, lengths, u, b, h0, c0, out_seq, c_seq,
     """
     B, T, G = xw.shape
     H = G // 4
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     blk = min(block_b, B)
     Bp = -(-B // blk) * blk
     lens = lengths.astype(jnp.float32).reshape(B, 1)
@@ -1151,7 +1146,7 @@ def lstm_sequence_fused_bwd(xw, lengths, u, b, h0, c0, out_seq, c_seq,
             jax.ShapeDtypeStruct((Bp, H), xw.dtype),
             jax.ShapeDtypeStruct((H, G), jnp.float32),
         ],
-        interpret=bool(interpret),
+        interpret=interpret,
     )(tm(xw), lens, u, b2, h0, c0, tm(out_seq), tm(c_seq), tm(g_out),
       g_ht, g_ct)
     return jnp.swapaxes(dxw, 0, 1)[:B], dh0[:B], dc0[:B], du
@@ -1262,8 +1257,7 @@ def gru_sequence_fused_bwd(xw, lengths, u, h0, out_seq, g_out, g_ht, *,
     """
     B, T, G = xw.shape
     H = G // 3
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     blk = min(block_b, B)
     Bp = -(-B // blk) * blk
     lens = lengths.astype(jnp.float32).reshape(B, 1)
@@ -1303,7 +1297,7 @@ def gru_sequence_fused_bwd(xw, lengths, u, h0, out_seq, g_out, g_ht, *,
             jax.ShapeDtypeStruct((Bp, H), xw.dtype),
             jax.ShapeDtypeStruct((H, G), jnp.float32),
         ],
-        interpret=bool(interpret),
+        interpret=interpret,
     )(tm(xw), lens, u, h0, tm(out_seq), tm(g_out), g_ht)
     return jnp.swapaxes(dxw, 0, 1)[:B], dh0[:B], du
 
@@ -1333,8 +1327,7 @@ def gru_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
                                       block_b=block_b, interpret=interpret)
             outs.append(o)
         return jnp.concatenate(outs, axis=1), h
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     if b is not None:
         xw = xw + b                       # bias folds into the projection
     if h0 is None:
@@ -1367,7 +1360,7 @@ def gru_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
             jax.ShapeDtypeStruct((T, Bp, H), xw.dtype),
             jax.ShapeDtypeStruct((Bp, H), xw.dtype),
         ],
-        interpret=bool(interpret),
+        interpret=interpret,
     )(xw_tm, lens, u, h0)
     return jnp.swapaxes(out, 0, 1)[:B], ht[:B]
 

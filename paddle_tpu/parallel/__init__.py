@@ -21,7 +21,6 @@ TPU-native SPMD design (SURVEY.md §2.5):
   experts + tokens sharded over an ``expert`` axis, all_to_all dispatch).
 """
 
-from .compat import pcast, shard_map
 from .mesh import (MeshSpec, current_mesh, make_mesh, local_mesh,
                    mesh_axis_size, use_mesh)
 from .sharding import (replicate, shard, shard_batch, shard_params,
@@ -31,7 +30,8 @@ from .collectives import (all_reduce, all_gather, reduce_scatter, broadcast,
 from .data_parallel import DataParallel, Zero1DataParallel, Zero1State
 from .tensor_parallel import ColumnParallelLinear, RowParallelLinear, ShardedEmbedding
 from .ring_attention import (ring_attention, blockwise_attention,
-                             ring_self_attention, ulysses_attention)
+                             ring_self_attention, sharded_flash_attention,
+                             ulysses_attention)
 from .pipeline import PipelineStage, pipeline_1f1b, pipeline_spmd
 from .moe import ExpertParallelMoE, init_moe_params, moe_ffn_dense
 from . import multihost
@@ -39,7 +39,6 @@ from . import multihost
 __all__ = [
     "MeshSpec", "make_mesh", "local_mesh", "mesh_axis_size",
     "current_mesh", "use_mesh",
-    "shard_map", "pcast",
     "replicate", "shard", "shard_batch", "shard_params",
     "with_sharding_constraint", "ShardingRules", "SpecLayout",
     "all_reduce", "all_gather", "reduce_scatter", "broadcast", "all_to_all",
@@ -49,7 +48,7 @@ __all__ = [
     "Zero1State",
     "ColumnParallelLinear", "RowParallelLinear", "ShardedEmbedding",
     "ring_attention", "blockwise_attention", "ring_self_attention",
-    "ulysses_attention",
+    "sharded_flash_attention", "ulysses_attention",
     "PipelineStage", "pipeline_spmd", "pipeline_1f1b", "multihost",
     "ExpertParallelMoE", "init_moe_params", "moe_ffn_dense",
 ]

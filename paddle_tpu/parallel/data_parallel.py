@@ -24,9 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import make_mesh
+from .mesh import make_mesh, use_mesh
 from .sharding import ShardingRules, replicate, shard_batch, shard_params
-from . import compat
 
 
 class DataParallel:
@@ -55,12 +54,29 @@ class DataParallel:
                 return new_params, new_state, loss, aux
             return new_params, new_state, loss
 
-        donate_args = (0, 1) if donate else ()
+        self._raw_step = _step
+        self._donate = (0, 1) if donate else ()
+        self._step = None           # jitted at the first step()
+
+    def _build_step(self, params, opt_state):
+        """The jitted step, with the new params and optimizer state PINNED
+        to the shardings the old ones arrive under. Left to XLA, outputs can
+        come back under another spec (1-D leaves sharded over ``tp``) and
+        the next step sees new input shardings: a silent recompile under
+        jit, a refusal from an AOT executable — which is what the obs cost
+        ledger runs. Pinned, donation also aliases leaf for leaf."""
+        def keep(tree):
+            return jax.tree_util.tree_map(
+                lambda a: getattr(a, "sharding", None), tree)
+        out = (keep(params), keep(opt_state), None)
+        if self.aux_fn is not None:
+            out += (None,)
         # cost-instrumented jit (as Trainer._step): an obs session sees the
         # SPMD step's FLOPs/bytes in the roofline ledger per dispatch
         from ..obs import roofline
-        self._step = roofline.instrument(
-            jax.jit(_step, donate_argnums=donate_args), "data_parallel.step")
+        return roofline.instrument(
+            jax.jit(self._raw_step, donate_argnums=self._donate,
+                    out_shardings=out), "data_parallel.step")
 
     # -- placement ---------------------------------------------------------
     def init(self, params, opt_state=None):
@@ -86,7 +102,9 @@ class DataParallel:
     def step(self, params, opt_state, *batch) -> Tuple[Any, Any, jax.Array]:
         """One global-batch SGD step; batch leaves should already be sharded
         (use :meth:`shard_batch`) or will be sharded by XLA on first use."""
-        with self.mesh:
+        if self._step is None:
+            self._step = self._build_step(params, opt_state)
+        with use_mesh(self.mesh):       # ambient: kernels wrap themselves
             return self._step(params, opt_state, *batch)
 
 
@@ -217,7 +235,7 @@ class Zero1DataParallel:
                                                {"flat": flat_shard})
             return new_p["flat"], new_state, jax.lax.pmean(loss, axis)
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             local_step, mesh=self.mesh,
             in_specs=(flat_spec, state_spec, stats_spec) + batch_specs,
             out_specs=(flat_spec, state_spec, P()),

@@ -31,7 +31,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from . import compat
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -173,7 +172,7 @@ class ExpertParallelMoE:
             # aux is a per-shard mean over its tokens; average across shards
             return y, jax.lax.pmean(aux, axis)
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(self.axis), P(self.axis), P(self.axis, None)),
             out_specs=(P(self.axis, None), P()))

@@ -25,7 +25,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..nn.module import Module
-from . import compat
 
 
 class PipelineStage(Module):
@@ -70,8 +69,8 @@ def pipeline_spmd(stage_fn: Callable, mesh: Mesh, n_microbatches: int,
         mb = x.reshape(n_microbatches, x.shape[0] // n_microbatches, *x.shape[1:])
         # activations become device-varying over 'pipe' after the first stage_fn;
         # cast the loop carry up front so the fori_loop carry type is stable
-        state = compat.pcast(jnp.zeros_like(mb[0]), axis, to="varying")
-        out_buf = compat.pcast(jnp.zeros_like(mb), axis, to="varying")
+        state = lax.pcast(jnp.zeros_like(mb[0]), axis, to="varying")
+        out_buf = lax.pcast(jnp.zeros_like(mb), axis, to="varying")
         fwd = [(i, (i + 1) % n_stages) for i in range(n_stages)]
         total = n_microbatches + n_stages - 1
 
@@ -99,7 +98,7 @@ def pipeline_spmd(stage_fn: Callable, mesh: Mesh, n_microbatches: int,
 
     pspec = P(axis)   # prefix spec: applies to every leaf of the params pytree
     xspec = P()
-    return jax.jit(compat.shard_map(local, mesh=mesh, in_specs=(pspec, xspec),
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(pspec, xspec),
                                  out_specs=xspec))
 
 
@@ -195,11 +194,11 @@ def pipeline_1f1b(stage_fn: Callable, loss_fn: Callable, mesh: Mesh,
             bwd_msg = lax.ppermute(send_b, axis, bwd_perm)
             return fwd_msg, bwd_msg, stash, dparams, loss_acc
 
-        zero_mb = compat.pcast(jnp.zeros_like(mb_shape), axis, to="varying")
-        stash0 = compat.pcast(
+        zero_mb = lax.pcast(jnp.zeros_like(mb_shape), axis, to="varying")
+        stash0 = lax.pcast(
             jnp.zeros((S,) + mb_shape.shape, mb_shape.dtype), axis,
             to="varying")
-        dp0 = compat.pcast(jax.tree_util.tree_map(jnp.zeros_like, params),
+        dp0 = lax.pcast(jax.tree_util.tree_map(jnp.zeros_like, params),
                         axis, to="varying")
         carry = (zero_mb, zero_mb, stash0, dp0, jnp.float32(0))
         total = 2 * (M + S - 1)
@@ -209,6 +208,6 @@ def pipeline_1f1b(stage_fn: Callable, loss_fn: Callable, mesh: Mesh,
         return loss, dparams
 
     pspec = P(axis)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(pspec, P(), P()),
         out_specs=(P(), pspec), check_vma=False))

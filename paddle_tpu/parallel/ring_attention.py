@@ -30,7 +30,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import pallas_kernels as pk
-from . import compat
 
 _NEG = -1e30
 
@@ -416,7 +415,7 @@ def ring_self_attention(mesh: Mesh, q, k, v, seq_axis: str = "seq",
         order = zigzag_order(T, n)
         q, k, v = (jnp.take(x, order, axis=1) for x in (q, k, v))
     # check_vma=False: pallas_call out_shapes carry no varying-mesh-axes info
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=seq_axis, causal=causal,
                 zigzag=zigzag),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
@@ -444,6 +443,45 @@ def ulysses_attention(mesh: Mesh, q, k, v, seq_axis: str = "seq",
         o = pk.flash_attention(q, k, v, causal=causal)
         return lax.all_to_all(o, seq_axis, split_axis=1, concat_axis=2, tiled=True)
 
-    fn = compat.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     return fn(q, k, v)
+
+
+def sharded_flash_attention(mesh: Mesh, q, k, v, *, causal: bool = False,
+                            kv_lens=None):
+    """The Pallas flash kernel inside a GSPMD-partitioned program.
+
+    A Mosaic kernel cannot be partitioned automatically — on more than one
+    real chip jit refuses it ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"); the CPU mesh never
+    shows this because the interpreter lowers to plain HLO. Attention is
+    independent per (sample, head), so the wrap needs no communication:
+    the batch splits over the mesh's ``data``/``fsdp`` axes and the heads
+    over ``tp``/``model`` — each only where it divides — and every shard
+    runs the kernel on its local [B/n, T, H/m, D] block. Axes the split
+    does not use see replicated operands.
+    """
+    B, H = q.shape[0], q.shape[2]
+
+    def fit(names, extent):
+        axes = []
+        for a in names:
+            n = mesh.shape.get(a, 1)
+            if n > 1 and extent % n == 0:
+                axes.append(a)
+                extent //= n
+        return tuple(axes) or None
+
+    b_axes, h_axes = fit(("data", "fsdp"), B), fit(("tp", "model"), H)
+    spec = P(b_axes, None, h_axes, None)
+    if kv_lens is None:
+        local = partial(pk.flash_attention, causal=causal)
+        args, in_specs = (q, k, v), (spec, spec, spec)
+    else:
+        def local(q, k, v, lens):
+            return pk.flash_attention(q, k, v, causal=causal, kv_lens=lens)
+        args, in_specs = (q, k, v, kv_lens), (spec, spec, spec, P(b_axes))
+    # check_vma=False: pallas_call out_shapes carry no varying-mesh-axes info
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=spec,
+                         check_vma=False)(*args)

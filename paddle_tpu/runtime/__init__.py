@@ -6,12 +6,13 @@ C++/CUDA/Go row'):
 * :mod:`recordio` — CRC-checked chunked record files (recordio / DataFormat)
 * :mod:`arena`    — host buddy allocator (paddle/memory BuddyAllocator)
 
-The library auto-builds from source on first import when a toolchain is
-available (make -C native), mirroring how the reference builds vendored
-externals at configure time.
+The library builds from source on first use (``make -C native`` under a file
+lock, see :mod:`lib`), mirroring how the reference builds vendored externals
+at configure time; a failed build raises :class:`NativeLibraryError` with the
+compiler's output.
 """
 
-from .lib import load_library, native_available
+from .lib import NativeLibraryError, load_library
 from .master import TaskMaster
 from .recordio import RecordReader, RecordWriter
 from .arena import HostArena
@@ -24,7 +25,7 @@ from .membership import (HeartbeatKeeper, MembershipClient,
 from .host_embedding import (HostEmbedBatch, HostEmbeddingTable,
                              HostEmbedPrefetcher)
 
-__all__ = ["load_library", "native_available", "TaskMaster",
+__all__ = ["load_library", "NativeLibraryError", "TaskMaster",
            "FileLease", "LeaseKeeper",
            "CoordServer", "NetworkLease", "NetworkFencedStore",
            "MembershipService", "MembershipClient", "HeartbeatKeeper",

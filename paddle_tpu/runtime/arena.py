@@ -15,10 +15,7 @@ class HostArena:
     into ``self.buffer`` (a bytearray the feeder writes batches into)."""
 
     def __init__(self, total: int = 1 << 24, min_block: int = 256):
-        lib = load_library()
-        if lib is None:
-            raise RuntimeError("native host runtime unavailable")
-        self._lib = lib
+        lib = self._lib = load_library()
         self._h = lib.pta_create(total, min_block)
         if not self._h:
             raise ValueError("total/min_block must be powers of two")

@@ -10,24 +10,9 @@ fixed-shape calls cheap.
 
 from __future__ import annotations
 
-import os
 from typing import List, Tuple
 
 import numpy as np
-
-# The C ABI's embedded interpreter must honor an explicit JAX_PLATFORMS
-# request (e.g. a test pinning the example to CPU while another process
-# holds the accelerator). Some images install a sitecustomize that forces
-# its own platform list, silently overriding the env var — re-apply it
-# here, before the Executor first touches a backend. No-op when unset or
-# when a backend is already live (then the process owner chose already).
-if os.environ.get("JAX_PLATFORMS"):
-    try:
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:  # backend already initialized: keep its choice
-        pass
 
 _DTYPES = {0: np.float32, 1: np.int32}
 

@@ -1,8 +1,20 @@
-"""Load (building if needed) the native host-runtime library."""
+"""Load (building if needed) the native host-runtime library.
+
+The library is git-ignored and built on first use from ``native/*.cc``.
+First use is concurrent by nature — six xdist workers importing their
+test files, a launcher's children — so the build runs ONCE, under a file
+lock, and the Makefile publishes each ``.so`` by atomic rename: a process
+either sees no library and waits for the lock, or sees a whole one.
+(Unlocked, concurrent ``make -j4`` runs rewrote each other's ``.o`` files
+mid-link and the losers silently got "no library".) The library is
+REQUIRED by everything that loads it, so a failed build or load raises
+:class:`NativeLibraryError` carrying the compiler's output — never None.
+"""
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -10,7 +22,7 @@ from typing import Optional
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_tried = False
+_error: Optional["NativeLibraryError"] = None
 
 #: signature of the unknown-op fallback (master_server.cc ptms_set_fallback):
 #: (request bytes, length, opaque reply handle) -> None; the callback
@@ -26,35 +38,57 @@ _SO = os.path.join(_NATIVE_DIR, "libpaddle_tpu_host.so")
 _PKG_SO = os.path.join(os.path.dirname(os.path.dirname(__file__)), "_native",
                        "libpaddle_tpu_host.so")
 
+#: a cold build takes ~15 s idle, a minute on a loaded host; the limit only
+#: bounds a hung toolchain
+_BUILD_TIMEOUT_S = 600
 
-def load_library() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+
+class NativeLibraryError(RuntimeError):
+    """libpaddle_tpu_host.so could not be built or loaded; the message
+    carries the toolchain's own output."""
+
+
+def load_library() -> ctypes.CDLL:
+    """The native library, built first if a source is newer than it.
+    Raises :class:`NativeLibraryError` (once computed, re-raised on every
+    later call — the build is not retried within a process)."""
+    global _lib, _error
     with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        candidates = [_SO, _PKG_SO]
-        if os.path.isdir(_NATIVE_DIR) and _needs_build():
+        if _error is not None:
+            raise _error
+        if _lib is None:
             try:
-                subprocess.run(["make", "-C", _NATIVE_DIR, "-j4"],
-                               check=True, capture_output=True, timeout=120)
-            except Exception:
-                # build failed with sources newer than the repo .so: loading
-                # that stale binary against new argtypes is the old-ABI
-                # hazard — only the packaged copy is eligible now
-                candidates = [_PKG_SO]
-        lib = None
-        for so in candidates:
-            try:
-                lib = ctypes.CDLL(so)
-                break
-            except OSError:
-                continue
-        if lib is None:
-            return None
-        _configure(lib)
-        _lib = lib
+                in_checkout = os.path.isdir(_NATIVE_DIR)
+                if in_checkout and _needs_build():
+                    _build()
+                lib = ctypes.CDLL(_SO if in_checkout else _PKG_SO)
+            except (OSError, subprocess.SubprocessError) as e:
+                _error = NativeLibraryError(
+                    f"native host runtime unavailable: {e}")
+                raise _error from e
+            except NativeLibraryError as e:
+                _error = e
+                raise
+            _configure(lib)
+            _lib = lib
         return _lib
+
+
+def _build() -> None:
+    """``make -C native`` under an exclusive file lock: one build at a
+    time across processes, and a process that waited re-checks before
+    building again."""
+    with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lk:
+        fcntl.flock(lk, fcntl.LOCK_EX)
+        if not _needs_build():
+            return
+        r = subprocess.run(["make", "-C", _NATIVE_DIR, "-j4"],
+                           capture_output=True, text=True,
+                           timeout=_BUILD_TIMEOUT_S)
+        if r.returncode != 0:
+            raise NativeLibraryError(
+                f"make -C native failed (exit {r.returncode}):\n"
+                f"{r.stdout[-2000:]}{r.stderr[-4000:]}")
 
 
 def _needs_build() -> bool:
@@ -63,16 +97,9 @@ def _needs_build() -> bool:
     if not os.path.exists(_SO):
         return True
     so_mtime = os.path.getmtime(_SO)
-    try:
-        entries = os.listdir(_NATIVE_DIR)
-    except OSError:
-        return False
     return any(os.path.getmtime(os.path.join(_NATIVE_DIR, n)) > so_mtime
-               for n in entries if n.endswith((".cc", ".h")) or n == "Makefile")
-
-
-def native_available() -> bool:
-    return load_library() is not None
+               for n in os.listdir(_NATIVE_DIR)
+               if n.endswith((".cc", ".h")) or n == "Makefile")
 
 
 def _configure(lib: ctypes.CDLL):

@@ -17,10 +17,7 @@ from .lib import load_library
 
 class TaskMaster:
     def __init__(self, timeout_s: float = 60.0, failure_max: int = 3):
-        lib = load_library()
-        if lib is None:
-            raise RuntimeError("native host runtime unavailable (no toolchain?)")
-        self._lib = lib
+        lib = self._lib = load_library()
         self._h = lib.ptm_create(ctypes.c_double(timeout_s), failure_max)
 
     def __del__(self):

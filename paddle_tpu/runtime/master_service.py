@@ -220,13 +220,9 @@ class MasterServer:
         import ctypes
 
         from .lib import load_library
-        lib = load_library()
-        if lib is None:
-            if self._keeper is not None:
-                self._keeper.stop(release=True)
-                self._keeper = None
-            raise RuntimeError("native host runtime unavailable "
-                               "(libpaddle_tpu_host.so)")
+        lib = load_library()    # loaded when self.master was built: a failed
+        #                         build already raised there, compiler output
+        #                         and all (NativeLibraryError)
         out_port = ctypes.c_int(0)
         h = lib.ptms_start(self.master._h, self._host.encode(), self._port,
                            ctypes.byref(out_port))

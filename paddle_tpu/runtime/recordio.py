@@ -10,10 +10,7 @@ from .lib import load_library
 
 class RecordWriter:
     def __init__(self, path: str):
-        lib = load_library()
-        if lib is None:
-            raise RuntimeError("native host runtime unavailable")
-        self._lib = lib
+        lib = self._lib = load_library()
         self._h = lib.ptr_writer_open(path.encode())
         if not self._h:
             raise IOError(f"cannot open {path}")
@@ -39,10 +36,7 @@ class RecordWriter:
 
 class RecordReader:
     def __init__(self, path: str, max_record: int = 1 << 20):
-        lib = load_library()
-        if lib is None:
-            raise RuntimeError("native host runtime unavailable")
-        self._lib = lib
+        lib = self._lib = load_library()
         self._h = lib.ptr_reader_open(path.encode())
         if not self._h:
             raise IOError(f"cannot open {path} (missing or bad magic)")

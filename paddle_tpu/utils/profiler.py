@@ -81,8 +81,7 @@ def save_device_memory_profile(path: str, backend: Optional[str] = None):
     """Dump a pprof-format device memory profile (jax.profiler
     .save_device_memory_profile) — who holds HBM right now.
 
-    Backend-dependent: some remote PJRT plugins (e.g. tunneled dev chips)
-    do not implement the heap-profile callbacks and abort the process —
-    call on direct-attached devices / the CPU backend."""
+    ``backend`` names the platform to profile (default: the default
+    backend)."""
     jax.profiler.save_device_memory_profile(path, backend=backend)
     return path

@@ -1,6 +1,6 @@
 """Input-type declarations (paddle.v2.data_type analog).
 
-Maps the reference's canonical feature taxonomy (SURVEY.md §8.2:
+Maps the reference's canonical feature classification (SURVEY.md §8.2:
 dense_vector / integer_value / sparse_binary_vector / sparse_float_vector,
 each optionally *_sequence) onto feeder slots (data/feeder.py).
 """

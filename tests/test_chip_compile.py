@@ -1,0 +1,190 @@
+"""Compile the main-path kernels for a DESCRIBED TPU v5e, at GPT-2-small
+widths, without a chip — the one file in the repo that describes a chip.
+
+The TPU's compiler is installed beside the CPU backend and compiles for a
+topology that is described, not attached (on-chip-measurement guide §2).
+Interpret-mode tests cannot see what Mosaic refuses: a dot_general whose
+dimension numbers it cannot parse, a block that overflows VMEM, a slice
+that is not tile-aligned. These cases do — every Pallas kernel
+``chip_smoke.py``'s train and serve phases can reach, plus the fused
+RNN forwards and the sharded GPT-2-small step on a four-device mesh.
+
+Rules this file keeps (and why it is ONE file): only one process at a time
+may load the TPU's library, the process that did keeps it until it exits,
+and xdist workers each import every test file. So the topology is
+described inside a module-scoped fixture, never at import, in a ``skipif``
+or in ``parametrize``; every compile runs in the test's own process; and
+the persistent compilation cache is off round the compiles (an entry
+written for a described device cannot be read back without one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import pallas_kernels as pk
+
+# GPT-2-small heads (d_model 768 = 12 x 64) and the smoke's serving batch
+B, H, D = 8, 12, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def S(one_chip):
+    """Shape on the described chip: S(shape, dtype)."""
+    return lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+
+
+def _compile(fn, *avals):
+    """Lower + compile ``fn`` for the avals' (described) devices; returns
+    the optimized text, where a Pallas kernel shows as tpu_custom_call."""
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("T", [1024, 4096])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_attention_compiles(S, T, grad):
+    qkv = S((2, T, H, D), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    _compile(fn, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("L", [256, 512, 1024])
+@pytest.mark.parametrize("kv", ["bf16", "f32", "int8"])
+def test_decode_attention_compiles(S, L, kv):
+    """f32 is the cache ``generate_cached`` holds for f32 params — the
+    reference child of chip_smoke.py's serve phase."""
+    q, pos = S((B, H, D), jnp.bfloat16), S((B,), jnp.int32)
+    if kv == "int8":
+        cache, sc = S((B, L, H, D), jnp.int8), S((B, L, H), jnp.float32)
+        _compile(lambda q, k, ks, v, vs, pos: pk.decode_attention(
+            q, k, v, pos, k_scale=ks, v_scale=vs, route="kernel",
+            interpret=False), q, cache, sc, cache, sc, pos)
+    else:
+        cache = S((B, L, H, D),
+                  jnp.bfloat16 if kv == "bf16" else jnp.float32)
+        _compile(lambda q, k, v, pos: pk.decode_attention(
+            q, k, v, pos, route="kernel", interpret=False),
+            q, cache, cache, pos)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "f32", "int8"])
+def test_paged_decode_attention_compiles(S, kv):
+    """page 64 x 8 pages = the daemon's cache_bucket 512 read
+    (benchmarks/serving_daemon.py, chip_smoke.py serve phase); f32 is what
+    ``paddle_tpu serve`` holds (no dtype flag: pools follow the params)."""
+    bs, NB, pages = 64, 8, 8 * 8 + 1
+    q, pos = S((B, H, D), jnp.float32), S((B,), jnp.int32)
+    tables = S((B, NB), jnp.int32)
+    if kv == "int8":
+        pool = S((pages, bs, H, D), jnp.int8)
+        sc = S((pages, bs, H), jnp.float32)
+        _compile(lambda q, k, ks, v, vs, t, pos: pk.paged_decode_attention(
+            q, k, v, t, pos, k_scale=ks, v_scale=vs, route="kernel",
+            interpret=False), q, pool, sc, pool, sc, tables, pos)
+    else:
+        pool = S((pages, bs, H, D),
+                 jnp.bfloat16 if kv == "bf16" else jnp.float32)
+        _compile(lambda q, k, v, t, pos: pk.paged_decode_attention(
+            q, k, v, t, pos, route="kernel", interpret=False),
+            q, pool, pool, tables, pos)
+
+
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+def test_fused_rnn_forward_compiles(S, cell):
+    """The flagship recurrent shape: bs 64, T 100, hidden 256."""
+    bs, T, h = 64, 100, 256
+    gates = 4 if cell == "lstm" else 3
+    fused = (pk.lstm_sequence_fused if cell == "lstm"
+             else pk.gru_sequence_fused)
+    _compile(lambda xw, lens, u: fused(xw, lens, u, interpret=False),
+             S((bs, T, gates * h)), S((bs,), jnp.int32), S((h, gates * h)))
+
+
+def test_sharded_gpt2_small_step_lowers_on_four_chips(topo, monkeypatch):
+    """The step ``chip_smoke.py --chips 4`` runs — Trainer(mesh, layout)'s
+    DataParallel step with the mesh and layout benchmarks/sharded_gpt2.py
+    builds (tp 2 x fsdp 2, pos_embed pinned replicated) — compiled for the
+    four described chips. Depth is cut to 2 layers so it stays in seconds;
+    widths are GPT-2-small's."""
+    # the model asks the backend whether to interpret its kernels and here
+    # the backend is the CPU: steer it to the compiled kernel, as on a chip
+    monkeypatch.setattr(pk, "_interpret", lambda interpret: False)
+    from paddle_tpu import parallel as pp
+    from paddle_tpu.models import TransformerLM
+    from paddle_tpu.optimizer import Adam
+    from paddle_tpu.parallel.data_parallel import DataParallel
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 2, 2),
+                ("data", "fsdp", "tp"))
+    layout = pp.SpecLayout(rules=[(r"pos_embed$", P())])
+    model = TransformerLM(32768, d_model=768, n_heads=H, n_layers=2,
+                          max_len=1024)
+    opt = Adam(3e-4)
+
+    def loss_fn(params, ids):
+        p16 = jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16)
+            if a.dtype == jnp.float32 else a, params)
+        return model.loss(p16, ids)
+
+    def described(tree):
+        shapes = jax.eval_shape(lambda: tree())
+        return jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            shapes, layout.shardings(mesh, shapes))
+
+    params = described(lambda: model.init(jax.random.PRNGKey(0)))
+    state = described(lambda: opt.init(model.init(jax.random.PRNGKey(0))))
+    ids = jax.ShapeDtypeStruct(
+        (8, 1024), jnp.int32,
+        sharding=NamedSharding(mesh, P("data", None)))
+    dp = DataParallel(loss_fn, opt, mesh=mesh, param_rules=layout)
+    with pp.use_mesh(mesh):                  # as DataParallel.step does
+        compiled = dp._build_step(params, state)._jitted.lower(
+            params, state, ids).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text          # flash attention is the kernel
+    assert "all-reduce" in text or "all-gather" in text or \
+        "reduce-scatter" in text             # and the step really is SPMD
+    # every described chip holds less than the whole of the parameters
+    w = params["blocks_0"]["mlp_in"]["w"]
+    assert len({s for s in w.sharding.devices_indices_map(w.shape).values()
+                }) == 4

@@ -5,12 +5,7 @@ re-dispatch)."""
 import numpy as np
 import pytest
 
-from paddle_tpu.runtime import native_available
-
-pytestmark = pytest.mark.skipif(not native_available(),
-                                reason="native toolchain unavailable")
-
-from paddle_tpu.data.chunks import chunk_reader, cloud_reader, dump_to_chunks  # noqa: E402
+from paddle_tpu.data.chunks import chunk_reader, cloud_reader, dump_to_chunks
 from paddle_tpu.data.dataset import mnist  # noqa: E402
 from paddle_tpu.runtime.master_service import MasterClient, MasterServer  # noqa: E402
 

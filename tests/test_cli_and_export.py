@@ -35,8 +35,8 @@ def config_file(tmp_path):
     return str(p)
 
 
-def _run(*argv):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+def _run(*argv, **env_over):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **env_over)
     r = subprocess.run([sys.executable, "-m", "paddle_tpu", *argv],
                        capture_output=True, text=True, env=env, timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
@@ -147,9 +147,10 @@ def test_cli_train_test_time_dump(config_file, tmp_path):
     cc = str(tmp_path / "compile_cache")
     out = _run("train", "--config", config_file, "--num_passes", "2",
                "--save_dir", save, "--log_period", "2",
-               "--compile_cache", cc)
-    # --compile_cache wired through paddle_tpu.enable_compile_cache: the
-    # run persists its XLA executables for a preemption-resume to reload
+               JAX_COMPILATION_CACHE_DIR=cc)
+    # train enables the persistent compile cache before its first compile
+    # (paddle_tpu.enable_compile_cache; $JAX_COMPILATION_CACHE_DIR places
+    # it): the run persists its XLA executables for a resume to reload
     assert os.path.isdir(cc) and os.listdir(cc)
     assert "pass 1 done" in out
     assert os.path.exists(os.path.join(save, "pass-00001", "params.tar"))
@@ -174,9 +175,6 @@ def test_cli_train_local_master(config_file, tmp_path):
     ``--obs_out`` rides along: the run arms a flight recorder, obs_pushes
     its snapshots to the in-process master, and leaves a dump the obs CLI
     reads back (the ISSUE 4 smoke)."""
-    from paddle_tpu.runtime import native_available
-    if not native_available():
-        pytest.skip("native task master not built")
     save = str(tmp_path / "out")
     obs_out = str(tmp_path / "run.jsonl")
     out = _run("train", "--config", config_file, "--num_passes", "2",

@@ -216,7 +216,7 @@ def test_mix_reader_ratio_and_drain():
 
 def test_binary_dataformat_roundtrip(tmp_path):
     """proto DataFormat parity (SURVEY §8.2): header+samples stream with the
-    full slot taxonomy (dense / sparse ±value / index / string, each
+    full slot classification (dense / sparse ±value / index / string, each
     optionally (nested) sequence) round-trips and feeds the pipeline."""
     from paddle_tpu.data import batch, format as F
 

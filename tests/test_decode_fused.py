@@ -169,9 +169,14 @@ def test_generate_fused_topk_sampling(model_and_params):
 
 
 def test_verify_step_bit_exact_vs_sequential(model_and_params):
-    """verify_step's span logits must BIT-match running decode_step
-    sequentially over the same tokens — the exactness speculative decoding
-    inherits (serving.SpeculativeDecoder)."""
+    """verify_step's span must reproduce running decode_step sequentially
+    over the same tokens — the exactness speculative decoding inherits
+    (serving.SpeculativeDecoder). The contract (docs/design/kernels.md,
+    "verify_step"): greedy tokens are EQUAL, logits agree within 2 ulp of
+    the largest logit. Bit-equal logits are out of reach: the span's p·v
+    contraction is a matmul with M = S where the single step's is a
+    matrix-vector product, and XLA's CPU backend sums the two in a
+    different order (scores and projections do agree to the bit)."""
     model, params = model_and_params
     prompt = _prompt(seed=6)
     cell, last = model.prefill(params, prompt)
@@ -183,9 +188,11 @@ def test_verify_step_bit_exact_vs_sequential(model_and_params):
         toks.append(jnp.argmax(lg, -1).astype(prompt.dtype))
     span = jnp.stack(toks[:6], axis=1)
     vlg, c2 = model.verify_step(params, cell, span)
-    for i in range(6):
-        np.testing.assert_array_equal(np.asarray(vlg[:, i]),
-                                      np.asarray(logits[i]))
+    seq = np.stack([np.asarray(lg) for lg in logits], axis=1)
+    np.testing.assert_array_equal(np.argmax(np.asarray(vlg), -1),
+                                  np.argmax(seq, -1))
+    np.testing.assert_allclose(np.asarray(vlg), seq, rtol=0,
+                               atol=2 * np.spacing(np.abs(seq).max()))
     np.testing.assert_array_equal(np.asarray(c2["pos"]),
                                   np.asarray(cell["pos"]) + 6)
 

@@ -339,8 +339,6 @@ def test_kill9_worker_mid_pass_matches_static_run(tmp_path):
             params, _, loss = em.fit(batches, PARAMS0(), num_passes=1,
                                      progress_timeout=90.0)
             kt.join(timeout=10)
-            # the view at pass completion: resharded onto the 2 survivors
-            survivors_at_finish = len(em.membership.members())
         finally:
             logs = []
             for p in procs[1:]:
@@ -355,9 +353,13 @@ def test_kill9_worker_mid_pass_matches_static_run(tmp_path):
         assert state["killed"]
         # eviction (not graceful leave) bumped the epoch mid-pass
         assert em.membership.epoch > state["epoch_at_kill"], logs
-        assert survivors_at_finish == 2
         assert reg.counter("cluster.leaves_total").get(
             reason="evicted") >= 1
+        # the pass was finished by exactly the 2 survivors: both (and only
+        # they) left through the done path. Counted once they are reaped —
+        # len(members()) right after fit() races with their leaving.
+        assert reg.counter("cluster.leaves_total").get(
+            reason="graceful") == 2, logs
         # the dead worker's in-flight shard re-bucketed via the epoch
         # change (task_timeout_s=60 rules out the timeout path)
         assert reg.counter("cluster.rebucket_tasks_total").get() >= 1

@@ -28,7 +28,6 @@ from paddle_tpu.data.chunks import (_Starved, chunk_reader, cloud_reader,
                                     dump_to_chunks)
 from paddle_tpu.data.prefetch import DoubleBuffer
 from paddle_tpu.optimizer import SGD
-from paddle_tpu.runtime import native_available
 from paddle_tpu.runtime.coord import CoordServer, NetworkFencedStore, \
     NetworkLease, _CoordClient
 from paddle_tpu.runtime.lease import FencedFile, FileLease, LeaseKeeper
@@ -463,8 +462,6 @@ def _fast_policy(attempts=5):
                        max_delay=0.002, jitter=0.0, sleep=lambda s: None)
 
 
-@pytest.mark.skipif(not native_available(),
-                    reason="native toolchain unavailable")
 def test_master_rpc_dropped_requests_are_retried(tmp_path):
     from paddle_tpu.runtime.master_service import MasterClient, MasterServer
     srv = MasterServer(snapshot_path=str(tmp_path / "m.snap"),
@@ -492,8 +489,6 @@ def test_master_rpc_dropped_requests_are_retried(tmp_path):
         srv.stop()
 
 
-@pytest.mark.skipif(not native_available(),
-                    reason="native toolchain unavailable")
 def test_master_rpc_budget_exhaustion_surfaces_attempts(tmp_path):
     from paddle_tpu.runtime.master_service import MasterClient, MasterServer
     srv = MasterServer(snapshot_path=str(tmp_path / "m.snap"),

@@ -2,6 +2,8 @@
 fluid/tests/book/test_recognize_digits_mlp.py and fit_a_line, plus IR
 round-trip and executable-cache behavior."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -431,20 +433,33 @@ def test_bucketing_static_feed_axis_is_an_error():
 
 
 def test_compile_cache_wiring(tmp_path, monkeypatch):
-    """paddle_tpu.init points jax's persistent compilation cache at the
-    requested dir (flag wins; env var is the fallback)."""
+    """ONE rule places jax's persistent compilation cache
+    (paddle_tpu.enable_compile_cache; paddle_tpu.init goes through it):
+    $JAX_COMPILATION_CACHE_DIR wins, and unset means the fixed
+    in-checkout path — never a temp name, a pid or a time."""
     import jax
 
     import paddle_tpu
     prev = jax.config.jax_compilation_cache_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     try:
-        flags = paddle_tpu.init(compile_cache_dir=str(tmp_path / "cc"))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "cc"))
+        assert paddle_tpu.enable_compile_cache() == str(tmp_path / "cc")
         assert jax.config.jax_compilation_cache_dir == str(tmp_path / "cc")
-        assert flags["compile_cache_dir"] == str(tmp_path / "cc")
-        monkeypatch.setenv(paddle_tpu.COMPILE_CACHE_ENV,
-                           str(tmp_path / "cc2"))
-        paddle_tpu.init()
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "cc2")
+        assert os.path.isdir(tmp_path / "cc")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        flags = paddle_tpu.init()
+        assert flags["compile_cache_dir"] == os.path.join(repo, ".jax_cache")
+        assert paddle_tpu.DEFAULT_COMPILE_CACHE_DIR == \
+            os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        # the names the single rule replaced are gone, not aliased
+        assert not hasattr(paddle_tpu, "COMPILE_CACHE_ENV")
+        with pytest.raises(TypeError):
+            paddle_tpu.enable_compile_cache(str(tmp_path / "other"))
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
 

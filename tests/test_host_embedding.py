@@ -12,11 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.runtime import (HostEmbeddingTable, HostEmbedPrefetcher,
-                                native_available)
-
-pytestmark = pytest.mark.skipif(not native_available(),
-                                reason="native host runtime not built")
+from paddle_tpu.runtime import HostEmbeddingTable, HostEmbedPrefetcher
 
 VOCAB, DIM, B, T = 50, 8, 4, 6
 

@@ -15,7 +15,6 @@ import sys
 import pytest
 
 from paddle_tpu import cli, obs
-from paddle_tpu.runtime import native_available
 
 pytestmark = pytest.mark.obs
 
@@ -25,8 +24,6 @@ NODE = os.path.join(REPO, "tests", "obs_cluster_node.py")
 
 @pytest.mark.chaos
 def test_worker_crash_leaves_stitchable_cross_process_trace(tmp_path):
-    if not native_available():
-        pytest.skip("native task master not built")
     master_out = str(tmp_path / "master.jsonl")
     worker_out = str(tmp_path / "worker.jsonl")
     done = str(tmp_path / "done")

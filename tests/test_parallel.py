@@ -39,7 +39,7 @@ def test_collectives_roundtrip():
         return s, g, rs, nxt
 
     x = jnp.arange(8.0)
-    fn = pp.shard_map(f, mesh=mesh, in_specs=P("data"),
+    fn = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                        out_specs=(P("data"), P("data"), P("data"), P("data")))
     s, g, rs, nxt = fn(x)
     np.testing.assert_allclose(s, np.full(8, 28.0))          # sum 0..7 bcast

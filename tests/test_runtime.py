@@ -7,10 +7,7 @@ import threading
 import pytest
 
 from paddle_tpu.runtime import (HostArena, RecordReader, RecordWriter,
-                                TaskMaster, native_available)
-
-pytestmark = pytest.mark.skipif(not native_available(),
-                                reason="native toolchain unavailable")
+                                TaskMaster)
 
 
 def test_master_dispatch_cycle():
@@ -208,3 +205,29 @@ def test_master_large_payload_not_truncated():
     assert payload == big
     m.task_finished(tid)
     assert m.pass_finished()
+
+
+def test_failed_native_build_raises_with_compiler_output(monkeypatch):
+    """The library is required: a build that fails surfaces the toolchain's
+    own message as NativeLibraryError (and keeps raising it), instead of a
+    silent None that every caller had to remember to check."""
+    import subprocess
+
+    from paddle_tpu.runtime import NativeLibraryError, lib
+
+    monkeypatch.setattr(lib, "_lib", None)
+    monkeypatch.setattr(lib, "_error", None)
+    monkeypatch.setattr(lib, "_needs_build", lambda: True)
+    calls = []
+
+    def fake_make(cmd, **kw):
+        calls.append(cmd)
+        return subprocess.CompletedProcess(
+            cmd, 2, stdout="", stderr="task_master.cc:1: error: boom\n")
+
+    monkeypatch.setattr(lib.subprocess, "run", fake_make)
+    with pytest.raises(NativeLibraryError, match="error: boom"):
+        lib.load_library()
+    with pytest.raises(NativeLibraryError, match="exit 2"):
+        TaskMaster()
+    assert len(calls) == 1          # not retried within the process

@@ -17,11 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.runtime import native_available
 from paddle_tpu.runtime.master_service import MasterClient, MasterServer
-
-pytestmark = pytest.mark.skipif(not native_available(),
-                                reason="native host runtime unavailable")
 
 VOCAB, D, H, L, MAX_LEN = 97, 32, 4, 2, 128
 

@@ -20,11 +20,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu import faults
-from paddle_tpu.runtime import native_available
 from paddle_tpu.runtime.master_service import MasterClient
-
-pytestmark = pytest.mark.skipif(not native_available(),
-                                reason="native host runtime unavailable")
 
 VOCAB, D, H, L, MAX_LEN = 97, 32, 4, 2, 128
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

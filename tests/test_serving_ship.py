@@ -136,12 +136,6 @@ def test_chaos_srv_ship_truncate_caught():
 
 # -- two-pool end-to-end: prefill pool -> wire -> decode engine -------------
 
-from paddle_tpu.runtime import native_available  # noqa: E402
-
-needs_native = pytest.mark.skipif(not native_available(),
-                                  reason="native host runtime unavailable")
-
-
 @pytest.fixture(scope="module")
 def model_and_params():
     import jax
@@ -180,7 +174,6 @@ def _drain_engine(eng, rid, steps=4000):
     raise AssertionError("engine never finished the adopted request")
 
 
-@needs_native
 def test_shipped_decode_equals_solo_decode_f32(model_and_params):
     """The acceptance bar: tokens decoded from ADOPTED pages (prefill in
     one pool, decode in another, payload through the real chunked wire
@@ -204,7 +197,6 @@ def test_shipped_decode_equals_solo_decode_f32(model_and_params):
     np.testing.assert_array_equal(got, want)
 
 
-@needs_native
 def test_shipped_decode_equals_solo_decode_int8(model_and_params):
     """Same bar for quantized KV: the int8 rows AND their f32 scale planes
     ship; parity target is a solo int8-KV engine (int8 changes numerics,
@@ -233,7 +225,6 @@ def test_shipped_decode_equals_solo_decode_int8(model_and_params):
     np.testing.assert_array_equal(got, want)
 
 
-@needs_native
 def test_adopt_refuses_geometry_and_name_mismatch(model_and_params):
     """A shipment whose arrays disagree with the receiving pool (missing
     planes, wrong dtype) is refused before any page is touched."""

@@ -132,7 +132,7 @@ def test_seq_parallel_matches_single_device():
     def fwd(params, ids, positions):
         return model(params, ids, positions=positions, seq_axis="seq")
 
-    sharded = jax.jit(pp.shard_map(
+    sharded = jax.jit(jax.shard_map(
         fwd, mesh=mesh,
         in_specs=(P(), P(None, "seq"), P(None, "seq")),
         out_specs=P(None, "seq"), check_vma=False))
@@ -230,7 +230,7 @@ def test_seq_parallel_shifted_loss_matches_unsharded():
         return model.shifted_loss(params, ids_in, targets,
                                   positions=positions, seq_axis="seq")
 
-    sharded = jax.jit(pp.shard_map(
+    sharded = jax.jit(jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(), P(None, "seq"), P(None, "seq"), P(None, "seq")),
         out_specs=P(), check_vma=False))
