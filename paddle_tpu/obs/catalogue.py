@@ -165,6 +165,12 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
     "jax.compiles_total": ("counter", "XLA backend compiles observed "
                                       "(one per executable built)"),
     "jax.compile_seconds": ("histogram", "XLA backend-compile durations"),
+    "jax.trace_seconds": ("histogram", "jaxpr-trace durations (the part of "
+                                       "building a program no compile "
+                                       "cache saves; nested jitted calls "
+                                       "report inside their caller's)"),
+    "jax.lower_seconds": ("histogram", "jaxpr -> MLIR module lowering "
+                                       "durations"),
     # -- kernels: ops/pallas_kernels.py, ops/rnn.py entry points --------
     "kernels.bytes_total": ("counter", "modeled HBM bytes streamed by "
                                        "Pallas-kernel reads, one increment "
@@ -372,6 +378,14 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                                           "(overloaded = queue cap; "
                                           "draining = shutdown gate)",
                                ("reason",)),
+    "serving.admit_blocked_total": ("counter", "requests an admission "
+                                               "round left queued, one per "
+                                               "request and round, labels: "
+                                               "reason (slots = the free "
+                                               "slots ran out; pages = "
+                                               "evict_for refused its "
+                                               "class's head)",
+                                    ("reason",)),
     "serving.queue_depth": ("gauge", "requests waiting for a slot (the "
                                      "admission queue)"),
     "serving.slots_live": ("gauge", "slots holding an in-flight request"),
@@ -536,6 +550,17 @@ SPANS: Dict[str, str] = {
     "trainer.step": "one batch step (device dispatch + host sync)",
     "trainer.device_step": "the jitted step call (dispatch)",
     "trainer.host_sync": "host block on the loss value",
+    "trainer.input": "pulling the next batch: reader + feeder (one per "
+                     "step; the goodput ledger's host_input bucket)",
+    "trainer.dispatch": "the call of the compiled step until it returns "
+                        "(child of trainer.device_step)",
+    "trainer.device_wait": "block_until_ready on the step's results, with "
+                           "an obs session (child of trainer.device_step)",
+    "trainer.release": "rebinding params/opt_state to the step's results: "
+                       "the previous step's donated arrays are released "
+                       "here, one by one (child of trainer.step)",
+    "trainer.handler": "one event_handler call: the caller's code between "
+                       "two steps (args: event)",
     "trainer.checkpoint": "pass/preemption/halt checkpoint save "
                           "(args: pass_id, reason)",
     "fluid.run": "Executor.run",
@@ -550,6 +575,22 @@ SPANS: Dict[str, str] = {
                        "placement (args: batch)",
     "serving.segment": "one batched decode segment across live slots "
                        "(args: live)",
+    "serving.schedule": "a locked section of the scheduler: reaping "
+                        "cancels/deadlines, or deficit scheduling + "
+                        "plan_admission + evict_for (args: phase = "
+                        "reap | admit)",
+    "serving.stage": "host work before a dispatch: page bookkeeping, "
+                     "building the numpy arrays and jnp.asarray of them "
+                     "(child of serving.prefill / serving.segment; args: "
+                     "what)",
+    "serving.dispatch": "one jitted pool program's call until it returns, "
+                        "and the host accounting right behind it (args: "
+                        "program = admit | admit_prefix | segment)",
+    "serving.fetch": "np.asarray of a program's tokens: the device wait "
+                     "plus the copy back (args: program)",
+    "serving.index": "prefix-index insertion over one admission wave",
+    "serving.emit": "the locked token hand-out after a prefill or a "
+                    "segment (args: after = prefill | segment)",
     "serving.ship": "client side of one KV shipment: every srv_ship chunk "
                     "RPC for one request (args: xid, bytes, key)",
     "srv_ship": "decode-side landing of one ship chunk (args: xid, seq; "

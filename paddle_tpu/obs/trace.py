@@ -28,6 +28,26 @@ import threading
 import time
 from typing import Any, Callable, Deque, Dict, List, Optional
 
+#: jax.profiler.TraceAnnotation, looked up by the first span a process
+#: records (False = jax has no such class here): every span is also
+#: entered as an annotation, so whatever profiler session is running — the
+#: benchmark's, ``paddle_tpu profile``, an operator's — finds the program's
+#: spans on its own ``/host:CPU`` plane, on the device plane's clock. With
+#: no profiler running an annotation costs well under a microsecond, and
+#: importing it starts no backend.
+_ANNOTATION: Any = None
+
+
+def _annotation_class():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except Exception:
+            _ANNOTATION = False
+    return _ANNOTATION
+
 
 class Tracer:
     """Collects span/instant events; thread-safe; clock injectable.
@@ -135,7 +155,7 @@ class _Span:
     span that raises still lands in the trace (with ``error`` noted)."""
 
     __slots__ = ("_tracer", "name", "attrs", "id", "parent", "remote",
-                 "_t0", "_dur")
+                 "_t0", "_dur", "_note")
 
     def __init__(self, tracer: Tracer, name: str, attrs: Dict[str, Any],
                  remote: Optional[Dict[str, Any]] = None):
@@ -147,17 +167,24 @@ class _Span:
         self.remote = remote
         self._t0 = 0.0
         self._dur: Optional[float] = None
+        self._note = None
 
     def __enter__(self) -> "_Span":
         stack = self._tracer._stack()
         self.parent = stack[-1] if stack else None
         stack.append(self.id)
+        note = _ANNOTATION or _annotation_class()
+        if note:
+            self._note = note(self.name)
+            self._note.__enter__()
         self._t0 = self._tracer.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = self._tracer.clock()
         self._dur = t1 - self._t0
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         stack = self._tracer._stack()
         # tolerate a foreign unwind (a generator suspended mid-span): pop
         # our own id wherever it sits instead of corrupting siblings

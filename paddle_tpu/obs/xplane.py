@@ -362,6 +362,19 @@ def xplane_dump(space: Dict[str, Any], *, device_only: bool = True,
     # cost on real traces, so compute it once and reuse for both the
     # global t0 scan and the emit loop
     per_plane = [list(plane_events(p)) for p in planes]
+    if device_only and len(planes) < len(space.get("planes", ())):
+        # the program's own spans: obs/trace.py enters each as a profiler
+        # annotation, so a trace's host planes hold them on the device
+        # planes' clock — one more lane, and host and device share an
+        # axis with no alignment at all
+        from .catalogue import SPANS
+        for p in space.get("planes", ()):
+            if p["name"].startswith("/host:"):
+                mine = [ev for ev in plane_events(p) if ev["name"] in SPANS]
+                if mine:
+                    planes = planes + [dict(p, name=f"{p['name']} "
+                                            "(program spans)")]
+                    per_plane.append(mine)
     t0_ns = min((ev["ts_ns"] for evs in per_plane for ev in evs),
                 default=0.0)
     for pi, plane in enumerate(planes):

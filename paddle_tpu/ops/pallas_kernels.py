@@ -319,6 +319,7 @@ def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((B * H, Tp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qb, kb, vb, lensb)
     o = _from_bh(out, B, T, H, D)
     lse = jnp.moveaxis(lse[:, :T, 0].reshape(B, H, T), 1, 2)
@@ -361,6 +362,7 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Tp, D), q.dtype),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qb, kb, vb, dob, lseb, deltab, lensb)
 
     # dk/dv: grid over kv blocks, stream q tiles (loop bound Tp; padded q
@@ -377,6 +379,7 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         out_shape=[jax.ShapeDtypeStruct((B * H, Sp, D), k.dtype),
                    jax.ShapeDtypeStruct((B * H, Sp, D), v.dtype)],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qb, kb, vb, dob, lseb, deltab, lensb)
 
     return (_from_bh(dq, B, T, H, D), _from_bh(dk, B, S, H, D),
@@ -645,10 +648,11 @@ def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool):
 
 
 def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, kv_spec, sc_spec,
-                      *, grid, scale, chunk, interpret):
+                      *, grid, scale, chunk, interpret, name):
     """The one pallas_call behind decode_attention and
     paged_decode_attention: ``prefetch`` scalars (pos last), then q, then
-    k/v — each followed by its scale operand when the cache is int8."""
+    k/v — each followed by its scale operand when the cache is int8.
+    ``name`` is the caller's: what a device trace shows the kernel as."""
     from jax.experimental.pallas import tpu as pltpu
     B, H, D = q.shape
     if k_scale is not None:
@@ -668,7 +672,7 @@ def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, kv_spec, sc_spec,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(*prefetch, q, *kv_args)
 
 
@@ -748,7 +752,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return _decode_attn_call(
         (pos.astype(jnp.int32),), q, k, v, k_scale, v_scale, kv_spec, sc_spec,
         grid=(B, L // chunk), scale=scale_v, chunk=chunk,
-        interpret=_interpret(interpret))
+        interpret=_interpret(interpret), name="decode_attention")
 
 
 # ---------------------------------------------------------------------------
@@ -820,7 +824,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     return _decode_attn_call(
         (tables.astype(jnp.int32), pos.astype(jnp.int32)), q, k_pool, v_pool,
         k_scale, v_scale, page_spec, sc_spec, grid=(B, NB), scale=scale_v,
-        chunk=bs, interpret=_interpret(interpret))
+        chunk=bs, interpret=_interpret(interpret),
+        name="paged_decode_attention")
 
 
 # ---------------------------------------------------------------------------
@@ -995,7 +1000,8 @@ def lstm_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
         out, ht, ct, cseq = pl.pallas_call(
             kernel, grid=(Bp // blk,), in_specs=in_specs,
             out_specs=out_specs, out_shape=out_shape,
-            interpret=interpret)(xw_tm, lens, u, b2, h0, c0)
+            interpret=interpret, name="lstm_sequence_fwd_train",
+        )(xw_tm, lens, u, b2, h0, c0)
         return (jnp.swapaxes(out, 0, 1)[:B], ht[:B], ct[:B],
                 jnp.swapaxes(cseq, 0, 1)[:B])
 
@@ -1008,6 +1014,7 @@ def lstm_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="lstm_sequence_fwd",
     )(xw_tm, lens, u, b2, h0, c0)
     return jnp.swapaxes(out, 0, 1)[:B], ht[:B], ct[:B]
 
@@ -1147,6 +1154,7 @@ def lstm_sequence_fused_bwd(xw, lengths, u, b, h0, c0, out_seq, c_seq,
             jax.ShapeDtypeStruct((H, G), jnp.float32),
         ],
         interpret=interpret,
+        name="lstm_sequence_bwd",
     )(tm(xw), lens, u, b2, h0, c0, tm(out_seq), tm(c_seq), tm(g_out),
       g_ht, g_ct)
     return jnp.swapaxes(dxw, 0, 1)[:B], dh0[:B], dc0[:B], du
@@ -1298,6 +1306,7 @@ def gru_sequence_fused_bwd(xw, lengths, u, h0, out_seq, g_out, g_ht, *,
             jax.ShapeDtypeStruct((H, G), jnp.float32),
         ],
         interpret=interpret,
+        name="gru_sequence_bwd",
     )(tm(xw), lens, u, h0, tm(out_seq), tm(g_out), g_ht)
     return jnp.swapaxes(dxw, 0, 1)[:B], dh0[:B], du
 
@@ -1361,6 +1370,7 @@ def gru_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
             jax.ShapeDtypeStruct((Bp, H), xw.dtype),
         ],
         interpret=interpret,
+        name="gru_sequence_fwd",
     )(xw_tm, lens, u, h0)
     return jnp.swapaxes(out, 0, 1)[:B], ht[:B]
 

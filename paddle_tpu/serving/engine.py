@@ -59,7 +59,7 @@ class _Rec:
     __slots__ = ("rid", "prompt", "eos_id", "left", "deadline", "t_submit",
                  "t_first", "t_done", "tokens", "done", "reason", "slot",
                  "skip", "cancelled", "collected", "tenant", "slo",
-                 "prefix_len", "ship", "key")
+                 "prefix_len", "ship", "key", "blocked")
 
     def __init__(self, rid, prompt, left, eos_id, deadline, t_submit,
                  tenant="default", slo="interactive", prefix_len=None):
@@ -81,6 +81,21 @@ class _Rec:
         #: the fabric-wide submit_key this request's timeline records
         #: under (obs/requests.py); None = no timeline (embedded use)
         self.key: Optional[str] = None
+        #: (engine-clock time, "slots" | "pages") of the FIRST admission
+        #: round that left this request queued, and why; None while no
+        #: round has passed it over — what splits its queue wait into
+        #: waiting for a segment boundary and waiting for capacity
+        self.blocked: Optional[tuple] = None
+
+
+def _blocked_extra(rec: _Rec, now: float) -> dict:
+    """The admission record's account of the capacity wait: seconds since
+    the first round that passed this request over (0 when it was taken at
+    the first boundary after it arrived), and what that round lacked."""
+    if rec.blocked is None:
+        return {"blocked_s": 0.0}
+    return {"blocked_s": round(max(0.0, now - rec.blocked[0]), 6),
+            "blocked_by": rec.blocked[1]}
 
 
 class ServingEngine:
@@ -425,7 +440,7 @@ class ServingEngine:
         victims just finalize; live victims free their slot AND pages
         immediately — mid-flight cancel is a first-class path."""
         now = self._clock()
-        with self._lock:
+        with obs.span("serving.schedule", phase="reap"), self._lock:
             for queue in self._queues.values():
                 for rec in list(queue):
                     if rec.cancelled or (rec.deadline is not None
@@ -460,8 +475,10 @@ class ServingEngine:
         CoW + suffix-only prefill for prefix-cache hits — and emit each
         admission's first token (TTFT stops here). Returns the number
         admitted."""
-        with maybe_bucket(self._gp, "host_input"), self._lock:
+        with obs.span("serving.schedule", phase="admit"), \
+                maybe_bucket(self._gp, "host_input"), self._lock:
             group, adopts, members, pending = [], [], [], 0
+            now = self._clock()
             busy = set(self._live)
             free_slots = [s for s in range(self.pool.n_slots)
                           if s not in busy]
@@ -499,7 +516,8 @@ class ServingEngine:
                     members.append(rec)
                     if rec.key is not None:
                         # queue wait of a shipped admission ends here
-                        obs.req_phase(rec.key, "scheduled", slot=slot)
+                        obs.req_phase(rec.key, "scheduled", slot=slot,
+                                      **_blocked_extra(rec, now))
                     continue
                 plan = self.pool.plan_admission(
                     rec.prompt, rec.left, tenant=rec.tenant,
@@ -518,7 +536,19 @@ class ServingEngine:
                 group.append((slot, plan))
                 members.append(rec)
                 if rec.key is not None:
-                    obs.req_phase(rec.key, "queued", slot=slot)
+                    obs.req_phase(rec.key, "queued", slot=slot,
+                                  **_blocked_extra(rec, now))
+            # why this round left what it left: a class whose head
+            # evict_for refused waits for pages, every other for a slot
+            for c in SLO_CLASSES:
+                if not self._queues[c]:
+                    continue
+                reason = "pages" if c in blocked else "slots"
+                for rec in self._queues[c]:
+                    if rec.blocked is None:
+                        rec.blocked = (now, reason)
+                obs.count("serving.admit_blocked_total",
+                          len(self._queues[c]), reason=reason)
         if not group and not adopts:
             return 0
         adopted = {rec.rid for _, rec in adopts}
@@ -532,7 +562,8 @@ class ServingEngine:
                 first[slot] = s["first"]
                 rec.ship = None                 # payload consumed
         now = self._clock()
-        with maybe_bucket(self._gp, "host_sync"), self._lock:
+        with obs.span("serving.emit", after="prefill"), \
+                maybe_bucket(self._gp, "host_sync"), self._lock:
             for rec in members:
                 # a cancel landing during the prefill only sets the flag
                 # (this thread owns finalization); the next _reap honors it
@@ -570,8 +601,8 @@ class ServingEngine:
         with obs.span("serving.segment", live=len(live)), \
                 maybe_bucket(self._gp, "device"):
             block = self.pool.run_segment(live)  # device work, lock released
-        now = self._clock()
-        with maybe_bucket(self._gp, "host_sync"), self._lock:
+        with obs.span("serving.emit", after="segment"), \
+                maybe_bucket(self._gp, "host_sync"), self._lock:
             for slot in live:
                 rec = self._live.get(slot)
                 if rec is None or rec.done:

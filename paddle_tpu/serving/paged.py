@@ -465,6 +465,8 @@ class PagePool:
         key = ("admit", self.kv_dtype, self.bs, tpad, nbp)
         fn = self._fns.get(key)
         if fn is None:
+            # a compile inside a serving window names its shape bucket
+            obs.instant("serving.program_build", kind="admit", tpad=tpad)
             model, kv_dtype, bs = self.model, self.kv_dtype, self.bs
             tpp = nbp * bs
 
@@ -507,6 +509,8 @@ class PagePool:
         key = ("hit", self.kv_dtype, self.bs, tpad, nbr)
         fn = self._fns.get(key)
         if fn is None:
+            obs.instant("serving.program_build", kind="admit_prefix",
+                        tpad=tpad)
             model = self.model
 
             def admit_sfx(params, pools, suffix, offsets, lens, tables,
@@ -530,6 +534,7 @@ class PagePool:
         key = ("seg", self.kv_dtype, self.bs, self.segment, nb)
         fn = self._fns.get(key)
         if fn is None:
+            obs.instant("serving.program_build", kind="segment", nb=nb)
             model, segment = self.model, self.segment
 
             def seg(params, pools, tables, pos, cur):
@@ -567,6 +572,29 @@ class PagePool:
         miss: List[Tuple[int, _AdmitPlan]] = []
         hits: List[Tuple[int, _AdmitPlan]] = []
         cow: Dict[int, Tuple[int, int]] = {}      # slot -> (src, dst)
+        with obs.span("serving.stage", what="pages"):
+            self._place(group, miss, hits, cow)
+
+        first = np.zeros((self.n_slots,), np.int32)
+        if miss:
+            self._dispatch_miss(miss, first)
+        if hits:
+            self._dispatch_hits(hits, cow, first)
+        if self.index is not None:
+            with obs.span("serving.index"):
+                for slot, plan in group:
+                    self._insert_after(slot, plan)
+        out = {}
+        for slot, plan in group:
+            self.pos[slot] = plan.plen
+            self.cur[slot] = int(first[slot])
+            out[slot] = int(first[slot])
+        return out
+
+    def _place(self, group, miss, hits, cow) -> None:
+        """admit()'s host bookkeeping before any dispatch: reserve, pin
+        matched prefix paths, allocate tail pages, and sort the group into
+        ``miss`` / ``hits`` (and the copy-on-write pairs into ``cow``)."""
         for slot, plan in group:
             self.slot_reserve[slot] = plan.need_pages
             self.reserved += plan.need_pages
@@ -596,83 +624,74 @@ class PagePool:
             self.prefill_tokens_total += plan.plen - plan.offset
             (hits if plan.offset else miss).append((slot, plan))
 
-        first = np.zeros((self.n_slots,), np.int32)
-        if miss:
-            self._dispatch_miss(miss, first)
-        if hits:
-            self._dispatch_hits(hits, cow, first)
-        if self.index is not None:
-            for slot, plan in group:
-                self._insert_after(slot, plan)
-        out = {}
-        for slot, plan in group:
-            self.pos[slot] = plan.plen
-            self.cur[slot] = int(first[slot])
-            out[slot] = int(first[slot])
-        return out
-
     def _dispatch_miss(self, miss, first) -> None:
         """The cold path: ONE full-pool-width jitted prefill-and-scatter,
         numerically identical to the pre-prefix-cache admission."""
-        tpad = bucket_length(max(p.plen for _, p in miss),
-                             self.prompt_buckets)
-        tpad = min(tpad, self.model.max_len - 1)
-        nbp = -(-tpad // self.bs)
-        prompts = np.zeros((self.n_slots, tpad), np.int32)
-        lens = np.zeros((self.n_slots,), np.int32)
-        pages = np.zeros((self.n_slots, nbp), np.int32)
-        for slot, plan in miss:
-            prompts[slot, :plan.plen] = plan.prompt
-            lens[slot] = plan.plen
-            n = min(nbp, len(self.slot_pages[slot]))
-            pages[slot, :n] = self.slot_pages[slot][:n]
-        fn = self._admit_fn(tpad, nbp)
-        args = (self.params, self.pools, jnp.asarray(prompts),
-                jnp.asarray(lens), jnp.asarray(pages))
-        self.pools, f = fn(*args)
-        self._note_admit_cost(fn, args)
-        f = np.asarray(f)
+        with obs.span("serving.stage", what="prompts"):
+            tpad = bucket_length(max(p.plen for _, p in miss),
+                                 self.prompt_buckets)
+            tpad = min(tpad, self.model.max_len - 1)
+            nbp = -(-tpad // self.bs)
+            prompts = np.zeros((self.n_slots, tpad), np.int32)
+            lens = np.zeros((self.n_slots,), np.int32)
+            pages = np.zeros((self.n_slots, nbp), np.int32)
+            for slot, plan in miss:
+                prompts[slot, :plan.plen] = plan.prompt
+                lens[slot] = plan.plen
+                n = min(nbp, len(self.slot_pages[slot]))
+                pages[slot, :n] = self.slot_pages[slot][:n]
+            fn = self._admit_fn(tpad, nbp)
+            args = (self.params, self.pools, jnp.asarray(prompts),
+                    jnp.asarray(lens), jnp.asarray(pages))
+        with obs.span("serving.dispatch", program="admit"):
+            self.pools, f = fn(*args)
+            self._note_admit_cost(fn, args)
+        with obs.span("serving.fetch", program="admit"):
+            f = np.asarray(f)
         for slot, _ in miss:
             first[slot] = f[slot]
 
     def _dispatch_hits(self, hits, cow, first) -> None:
         """The warm path: CoW copies + suffix prefill from each slot's
         offset, reading the shared prefix pages through the block table."""
-        max_sfx = max(p.plen - p.offset for _, p in hits)
-        tpad = min(bucket_length(max_sfx, self.prompt_buckets),
-                   self.model.max_len - 1)
-        nbr = -(-min(bucket_length(max(p.plen for _, p in hits),
-                                   self.prompt_buckets),
-                     self.model.max_len) // self.bs)
-        suffix = np.zeros((self.n_slots, tpad), np.int32)
-        offsets = np.zeros((self.n_slots,), np.int32)
-        lens = np.zeros((self.n_slots,), np.int32)
-        src = np.zeros((self.n_slots,), np.int32)
-        dst = np.zeros((self.n_slots,), np.int32)
-        for slot, plan in hits:
-            sfx = plan.prompt[plan.offset:]
-            suffix[slot, :sfx.size] = sfx
-            offsets[slot] = plan.offset
-            lens[slot] = sfx.size
-            if slot in cow:
-                src[slot], dst[slot] = cow[slot]
-        fn = self._hit_fn(tpad, nbr)
-        args = (self.params, self.pools, jnp.asarray(suffix),
-                jnp.asarray(offsets), jnp.asarray(lens),
-                jnp.asarray(self.tables[:, :nbr]), jnp.asarray(src),
-                jnp.asarray(dst))
-        self.pools, f = fn(*args)
-        self._note_admit_cost(fn, args)
-        # modeled HBM bytes of the gathered prefix read (the hit path's
-        # bytes term), through the ONE registered model
-        read = obs.roofline.kernel_cost(
-            "paged_prefill_attention", batch=self.n_slots, pages=nbr,
-            page_block=self.bs, n_heads=self._H, d_head=self._Dh,
-            layers=len(self.model.blocks), kv_dtype=self.kv_dtype,
-            itemsize=self._itemsize) or 0.0
-        obs.count("kernels.bytes_total", read,
-                  kernel="paged_prefill_attention")
-        f = np.asarray(f)
+        with obs.span("serving.stage", what="suffixes"):
+            max_sfx = max(p.plen - p.offset for _, p in hits)
+            tpad = min(bucket_length(max_sfx, self.prompt_buckets),
+                       self.model.max_len - 1)
+            nbr = -(-min(bucket_length(max(p.plen for _, p in hits),
+                                       self.prompt_buckets),
+                         self.model.max_len) // self.bs)
+            suffix = np.zeros((self.n_slots, tpad), np.int32)
+            offsets = np.zeros((self.n_slots,), np.int32)
+            lens = np.zeros((self.n_slots,), np.int32)
+            src = np.zeros((self.n_slots,), np.int32)
+            dst = np.zeros((self.n_slots,), np.int32)
+            for slot, plan in hits:
+                sfx = plan.prompt[plan.offset:]
+                suffix[slot, :sfx.size] = sfx
+                offsets[slot] = plan.offset
+                lens[slot] = sfx.size
+                if slot in cow:
+                    src[slot], dst[slot] = cow[slot]
+            fn = self._hit_fn(tpad, nbr)
+            args = (self.params, self.pools, jnp.asarray(suffix),
+                    jnp.asarray(offsets), jnp.asarray(lens),
+                    jnp.asarray(self.tables[:, :nbr]), jnp.asarray(src),
+                    jnp.asarray(dst))
+        with obs.span("serving.dispatch", program="admit_prefix"):
+            self.pools, f = fn(*args)
+            self._note_admit_cost(fn, args)
+            # modeled HBM bytes of the gathered prefix read (the hit path's
+            # bytes term), through the ONE registered model
+            read = obs.roofline.kernel_cost(
+                "paged_prefill_attention", batch=self.n_slots, pages=nbr,
+                page_block=self.bs, n_heads=self._H, d_head=self._Dh,
+                layers=len(self.model.blocks), kv_dtype=self.kv_dtype,
+                itemsize=self._itemsize) or 0.0
+            obs.count("kernels.bytes_total", read,
+                      kernel="paged_prefill_attention")
+        with obs.span("serving.fetch", program="admit_prefix"):
+            f = np.asarray(f)
         for slot, _ in hits:
             first[slot] = f[slot]
 
@@ -732,35 +751,41 @@ class PagePool:
         """One decode segment across the whole pool; returns the emitted
         token block [slots, segment] (drained slots' rows are garbage).
         Grows live slots' tables first, so no mid-scan allocation exists."""
-        for i in live:
-            self._ensure(i, int(self.pos[i]) + self.segment)
-        max_pos = max((int(self.pos[i]) for i in live), default=0)
-        cache_len = min(
-            -(-(max_pos + self.segment + 1) // self.cache_bucket)
-            * self.cache_bucket, self.model.max_len)
-        nb = cache_len // self.bs
-        self.pools, cur, toks = self._seg_fn(nb)(
-            self.params, self.pools, jnp.asarray(self.tables[:, :nb]),
-            jnp.asarray(self.pos, jnp.int32).clip(0, self.model.max_len - 1),
-            jnp.asarray(self.cur))
-        obs.count("decode.dispatches_total", route="serve_segment")
-        # modeled cache-read bytes through the ONE registered model
-        # (ops/pallas_kernels._paged_decode_attention_bytes) — the same
-        # resolution the bench rows and the roofline ledger use
-        read = obs.roofline.kernel_cost(
-            "paged_decode_attention", batch=self.n_slots, pages=nb,
-            page_block=self.bs, n_heads=self._H, d_head=self._Dh,
-            layers=len(self.model.blocks), kv_dtype=self.kv_dtype,
-            itemsize=self._itemsize, steps=self.segment) or 0.0
-        obs.count("kernels.bytes_total", read,
-                  kernel="paged_decode_attention")
-        self.segments_total += 1
-        self.read_bytes_total += read
-        self.occupancy_num += self.live_tokens(live)
-        self.occupancy_den += max(self.pages_used, 1) * self.bs
-        self.pos += self.segment
-        self.cur = np.array(cur)    # writable copy: admit() merges into it
-        return np.asarray(toks)                       # [slots, segment]
+        with obs.span("serving.stage", what="tables"):
+            for i in live:
+                self._ensure(i, int(self.pos[i]) + self.segment)
+            max_pos = max((int(self.pos[i]) for i in live), default=0)
+            cache_len = min(
+                -(-(max_pos + self.segment + 1) // self.cache_bucket)
+                * self.cache_bucket, self.model.max_len)
+            nb = cache_len // self.bs
+            fn = self._seg_fn(nb)
+            args = (self.params, self.pools,
+                    jnp.asarray(self.tables[:, :nb]),
+                    jnp.asarray(self.pos, jnp.int32).clip(
+                        0, self.model.max_len - 1),
+                    jnp.asarray(self.cur))
+        with obs.span("serving.dispatch", program="segment"):
+            self.pools, cur, toks = fn(*args)
+            obs.count("decode.dispatches_total", route="serve_segment")
+            # modeled cache-read bytes through the ONE registered model
+            # (ops/pallas_kernels._paged_decode_attention_bytes) — the same
+            # resolution the bench rows and the roofline ledger use
+            read = obs.roofline.kernel_cost(
+                "paged_decode_attention", batch=self.n_slots, pages=nb,
+                page_block=self.bs, n_heads=self._H, d_head=self._Dh,
+                layers=len(self.model.blocks), kv_dtype=self.kv_dtype,
+                itemsize=self._itemsize, steps=self.segment) or 0.0
+            obs.count("kernels.bytes_total", read,
+                      kernel="paged_decode_attention")
+            self.segments_total += 1
+            self.read_bytes_total += read
+            self.occupancy_num += self.live_tokens(live)
+            self.occupancy_den += max(self.pages_used, 1) * self.bs
+            self.pos += self.segment
+        with obs.span("serving.fetch", program="segment"):
+            self.cur = np.array(cur)  # writable copy: admit() merges into it
+            return np.asarray(toks)                   # [slots, segment]
 
     def live_tokens(self, live: Sequence[int]) -> int:
         """Cache rows written across ``live`` slots (occupancy numerator).

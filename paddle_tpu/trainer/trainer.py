@@ -45,11 +45,12 @@ _NONFINITE_POLICIES = ("raise", "skip", "halt", "off")
 
 def _timed_input(batches, gp):
     """Yield from ``batches`` timing each pull into the goodput ledger's
-    ``host_input`` bucket — the reader/feeder wait as the driver loop
-    experiences it (prefetch overlap shows up as near-zero pulls)."""
+    ``host_input`` bucket and a ``trainer.input`` span — the reader/feeder
+    wait as the driver loop experiences it (prefetch overlap shows up as
+    near-zero pulls)."""
     it = iter(batches)
     while True:
-        with gp.bucket("host_input"):
+        with obs.span("trainer.input"), gp.bucket("host_input"):
             try:
                 batch = next(it)
             except StopIteration:
@@ -348,7 +349,12 @@ class Trainer:
         ``handle_signals`` installs SIGTERM/SIGINT checkpoint-then-exit
         handlers for the duration of the call (main thread only).
         """
-        event_handler = event_handler or (lambda e: None)
+        handler = event_handler or (lambda e: None)
+
+        def event_handler(e):
+            # the caller's code, on this thread, between two steps
+            with obs.span("trainer.handler", event=type(e).__name__):
+                handler(e)
         if checkpoint_every < 1:
             raise ValueError("checkpoint_every must be >= 1")
         start_pass = 0
@@ -414,12 +420,14 @@ class Trainer:
                         with self.stats.timer("TrainBatch"), \
                                 obs.span("trainer.device_step"), \
                                 maybe_bucket(gp, "device"):
-                            if self._dp is not None:
-                                batch = self._dp.shard_batch(batch)
-                                res = self._dp.step(params, opt_state,
-                                                    *batch)
-                            else:
-                                res = self._step(params, opt_state, *batch)
+                            with obs.span("trainer.dispatch"):
+                                if self._dp is not None:
+                                    batch = self._dp.shard_batch(batch)
+                                    res = self._dp.step(params, opt_state,
+                                                        *batch)
+                                else:
+                                    res = self._step(params, opt_state,
+                                                     *batch)
                             if gp is not None:
                                 # under async dispatch (TPU) the step's wall
                                 # time surfaces at the FIRST host block — the
@@ -428,12 +436,17 @@ class Trainer:
                                 # (which would book device time as host_sync;
                                 # nothing runs between dispatch and that sync,
                                 # so this costs no overlap)
-                                jax.block_until_ready(res)
-                        if self.outputs_fn is not None:
-                            params, opt_state, cost, outs = res
-                        else:
-                            params, opt_state, cost = res
-                            outs = None
+                                with obs.span("trainer.device_wait"):
+                                    jax.block_until_ready(res)
+                        # rebinding drops the last references to the
+                        # previous step's (donated) arrays: hundreds of
+                        # releases, on this thread, before the next dispatch
+                        with obs.span("trainer.release"):
+                            if self.outputs_fn is not None:
+                                params, opt_state, cost, outs = res
+                            else:
+                                params, opt_state, cost = res
+                                outs = None
                         with obs.span("trainer.host_sync",
                                       metric="trainer.sync_seconds"), \
                                 maybe_bucket(gp, "host_sync"):
