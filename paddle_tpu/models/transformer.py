@@ -347,10 +347,12 @@ class TransformerLM(nn.Module):
         masked-softmax formulation as the dense-row path, so paged and
         pinned greedy tokens agree bit-for-bit on the same cache contents.
         ``tables`` is sliced by the CALLER to the live read bound (NB
-        pages), the paged twin of ``decode_step``'s ``cache_len``."""
+        pages), the paged twin of ``decode_step``'s ``cache_len``; the
+        kernel's work list (the live pages under ``tables`` and ``pos``)
+        is built here once and shared by every layer's read."""
         pos = cell["pos"]                                  # [B]
-        B = tokens.shape[0]
         bs = cell["k0"].shape[1]
+        work = pk.paged_work_list(tables, pos, bs)
         page = jnp.take_along_axis(tables, (pos // bs)[:, None],
                                    axis=1)[:, 0]           # [B]
         row = pos % bs
@@ -378,7 +380,7 @@ class TransformerLM(nn.Module):
             o = pk.paged_decode_attention(
                 q[:, 0], kp, vp, tables, pos,
                 scale=blk.d_head ** -0.5,
-                k_scale=ksp, v_scale=vsp, route=attn_route)
+                k_scale=ksp, v_scale=vsp, work=work, route=attn_route)
             x = blk.finish(params[f"blocks_{i}"], x, o[:, None])
         x = self.ln_f(params["ln_f"], x)
         logits = (x @ params["embed"]["w"].T.astype(x.dtype)

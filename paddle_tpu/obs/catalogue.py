@@ -407,6 +407,14 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                                         "occupancy + high prefix_pages "
                                         "is healthy retention, not a "
                                         "leak"),
+    "serving.decode_pages_walked_total": (
+        "counter", "programs the paged decode read ran: one per (layer, "
+                   "step, slot, live page) of a segment's work list, "
+                   "counted by PagePool.run_segment from the host's pos"),
+    "serving.decode_pages_table_total": (
+        "counter", "cells of the block table under the same reads "
+                   "(layers x steps x slots x table width): walked / "
+                   "table is the share of the table that was live"),
     "serving.prefix_hits_total": ("counter", "admissions that matched the "
                                              "prefix radix index and "
                                              "prefilled only their "

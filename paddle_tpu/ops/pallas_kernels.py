@@ -598,22 +598,35 @@ def _decode_chunk(L: int) -> int:
     return L
 
 
-def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool):
-    """One (sample b, chunk c) program of the decode read, dense-row or
-    paged, float or int8 — one body so every variant shares the softmax.
+def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
+                        work_list: bool):
+    """One program of the decode read, dense-row or paged, float or int8 —
+    one body so every variant shares the softmax. A program reads chunk
+    ``c`` (rows c*chunk..) of sample ``b``'s cache.
 
-    Scalar-prefetched (the leading refs): [tbl [B, NB] — paged only, read
-    by the index maps alone,] pos [B] int32 — rows j <= pos[b] are live (row pos holds THIS step's
-    k/v, appended before the read). Blocks: q [1, H, D]; k/v [1, chunk, H,
-    D] (+ ks/vs [1, chunk, H] f32 when int8); o [1, H, D] f32, written by
-    the last chunk. Scratch m/l [H, 1], acc [H, D] f32 carry the running
-    softmax across the chunk axis."""
+    Dense rows (grid (B, chunks)): scalar-prefetched pos [B]; b and c are
+    the program ids. Paged (grid (n_work,), ``work_list``): scalar-
+    prefetched slot / page / ordinal / last [B*NB] (:func:`paged_work_list`;
+    ``page`` is read by the index maps alone) then pos [B]; program i
+    reads the page of ordinal c = ordinal[i] of slot b = slot[i].
+
+    pos: rows j <= pos[b] are live (row pos holds THIS step's k/v,
+    appended before the read). Blocks: q [1, H, D]; k/v [1, chunk, H, D]
+    (+ ks/vs [1, chunk, H] f32 when int8); o [1, H, D] f32, written by
+    sample b's last chunk. Scratch m/l [H, 1], acc [H, D] f32 carry the
+    running softmax across one sample's chunks, which run back to back."""
     if quantized:
         (pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
          m_ref, l_ref, acc_ref) = refs[-10:]
     else:
         pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs[-8:]
-    b, c = pl.program_id(0), pl.program_id(1)
+    if work_list:
+        slot_ref, _, ord_ref, last_ref = refs[:4]
+        i = pl.program_id(0)
+        b, c, last = slot_ref[i], ord_ref[i], last_ref[i] == 1
+    else:
+        b, c = pl.program_id(0), pl.program_id(1)
+        last = c == pl.num_programs(1) - 1
 
     @pl.when(c == 0)
     def _init():
@@ -642,17 +655,18 @@ def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool):
         acc_ref[...] = acc_ref[...] * corr + jnp.sum(p * v, axis=0)
         m_ref[...] = m_new
 
-    @pl.when(c == pl.num_programs(1) - 1)
+    @pl.when(last)
     def _finish():
         o_ref[0] = acc_ref[...] / l_ref[...]
 
 
-def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, kv_spec, sc_spec,
-                      *, grid, scale, chunk, interpret, name):
+def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, qo_spec, kv_spec,
+                      sc_spec, *, grid, scale, chunk, interpret, name):
     """The one pallas_call behind decode_attention and
-    paged_decode_attention: ``prefetch`` scalars (pos last), then q, then
-    k/v — each followed by its scale operand when the cache is int8.
-    ``name`` is the caller's: what a device trace shows the kernel as."""
+    paged_decode_attention: ``prefetch`` scalars (pos last; five of them
+    = the paged work list), then q, then k/v — each followed by its scale
+    operand when the cache is int8. ``name`` is the caller's: what a
+    device trace shows the kernel as."""
     from jax.experimental.pallas import tpu as pltpu
     B, H, D = q.shape
     if k_scale is not None:
@@ -660,7 +674,6 @@ def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, kv_spec, sc_spec,
                              [kv_spec, sc_spec, kv_spec, sc_spec])
     else:
         kv_args, kv_specs = (k, v), [kv_spec, kv_spec]
-    qo_spec = pl.BlockSpec((1, H, D), lambda b, c, *_: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=grid,
         in_specs=[qo_spec] + kv_specs, out_specs=qo_spec,
@@ -668,7 +681,8 @@ def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, kv_spec, sc_spec,
                         pltpu.VMEM((H, 1), jnp.float32),
                         pltpu.VMEM((H, D), jnp.float32)])
     kernel = functools.partial(_decode_attn_kernel, scale=scale, chunk=chunk,
-                               quantized=k_scale is not None)
+                               quantized=k_scale is not None,
+                               work_list=len(grid) == 1)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), jnp.float32),
@@ -747,11 +761,12 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if route != "kernel":
         raise ValueError(f"unknown decode_attention route {route!r}")
     chunk = _decode_chunk(L)
+    qo_spec = pl.BlockSpec((1, H, D), lambda b, c, p: (b, 0, 0))
     kv_spec = pl.BlockSpec((1, chunk, H, D), lambda b, c, p: (b, c, 0, 0))
     sc_spec = pl.BlockSpec((1, chunk, H), lambda b, c, p: (b, c, 0))
     return _decode_attn_call(
-        (pos.astype(jnp.int32),), q, k, v, k_scale, v_scale, kv_spec, sc_spec,
-        grid=(B, L // chunk), scale=scale_v, chunk=chunk,
+        (pos.astype(jnp.int32),), q, k, v, k_scale, v_scale, qo_spec,
+        kv_spec, sc_spec, grid=(B, L // chunk), scale=scale_v, chunk=chunk,
         interpret=_interpret(interpret), name="decode_attention")
 
 
@@ -762,11 +777,13 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # j*bs..(j+1)*bs-1, and only LIVE pages move. HBM then holds tokens, not
 # padding — the serving plane's mixed-length sessions share one pool and
 # freed requests return pages immediately (paddle_tpu/serving/paged.py).
-# The kernel streams each sample's live pages through VMEM exactly once
-# (scalar-prefetched table indices drive the page DMA), assembles the
-# contiguous [L, H, D] view there, and runs the SAME masked-softmax body as
-# decode_attention — so the paged read and the dense-row read agree to the
-# bit on the same cache contents.
+# The kernel's grid is the list of LIVE (slot, page) pairs, not the table:
+# one program per page a request has started, built in the graph from the
+# table and ``pos`` (paged_work_list) and scalar-prefetched, so the index maps
+# read slot and page from it and a dead table cell costs nothing — no program,
+# no fetch. Each program streams its page through VMEM once and runs the SAME
+# masked-softmax body as decode_attention — so the paged read and the
+# dense-row read agree to the bit on the same cache contents.
 # ---------------------------------------------------------------------------
 
 def gather_pages(pool: jax.Array, tables: jax.Array) -> jax.Array:
@@ -779,11 +796,37 @@ def gather_pages(pool: jax.Array, tables: jax.Array) -> jax.Array:
     return g.reshape((B, NB * pool.shape[1]) + pool.shape[2:])
 
 
+def paged_work_list(tables: jax.Array, pos: jax.Array, page_block: int):
+    """The paged read's launch geometry: the live (slot, page) pairs of a
+    block table, slot by slot with each slot's pages in order.
+
+    tables [B, NB] int32, pos [B] int32 -> (slot, page, ordinal, last)
+    each [B * NB] int32, and n_work [1] int32. Slot b holds pages
+    ``0 .. pos[b] // page_block`` (at most NB; an empty slot — pos 0, a
+    null table — holds one, so every row of the output is written). Item
+    i < n_work reads pool page ``page[i]`` = ``tables[slot[i],
+    ordinal[i]]``; ``last[i]`` is 1 on a slot's final page. Items past
+    n_work are padding no program reads. Depends on ``tables`` and ``pos``
+    alone: one list serves every layer of a decode step."""
+    B, NB = tables.shape
+    tables, pos = tables.astype(jnp.int32), pos.astype(jnp.int32)
+    n_pages = jnp.clip(pos // page_block + 1, 1, NB)          # [B]
+    ends = jnp.cumsum(n_pages)
+    item = jnp.arange(B * NB, dtype=jnp.int32)
+    slot = jnp.minimum(
+        jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        B - 1)
+    ordinal = jnp.minimum(item - (ends - n_pages)[slot], NB - 1)
+    last = (ordinal == n_pages[slot] - 1).astype(jnp.int32)
+    return slot, tables[slot, ordinal], ordinal, last, ends[-1:]
+
+
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, tables: jax.Array,
                            pos: jax.Array, *, scale: Optional[float] = None,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
+                           work=None,
                            route: Optional[str] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Single-token attention read through a block table — the paged twin
@@ -794,11 +837,13 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     page indices covering positions 0..NB*bs-1 (entries past a request's
     live pages point at the reserved null page — rows there sit past
     ``pos`` and are masked exactly like dense padding); pos: [B] int32,
-    rows j <= pos[b] are live. Returns o [B, H, D] f32.
+    rows j <= pos[b] are live. ``work``: :func:`paged_work_list` of
+    (tables, pos, bs) when the caller already holds it (a decode step
+    builds one for all its layers). Returns o [B, H, D] f32.
 
     Routing matches decode_attention: the Pallas kernel for long on-TPU
-    reads (pages stream through VMEM once, driven by the scalar-prefetched
-    table), the dense gather + reference math for short reads / off-TPU.
+    reads (one program per live page, driven by the scalar-prefetched work
+    list), the dense gather + reference math for short reads / off-TPU.
     Both routes share one masked-softmax formulation over the SAME
     assembled row order, so route choice never changes greedy tokens."""
     B, NB = tables.shape
@@ -817,13 +862,17 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         return _dense_decode_attention(q, k, v, pos, scale_v, ks, vs)
     if route != "kernel":
         raise ValueError(f"unknown paged_decode_attention route {route!r}")
+    if work is None:
+        work = paged_work_list(tables, pos, bs)
+    *work, n_work = work
+    qo_spec = pl.BlockSpec((1, H, D), lambda i, slot, *_: (slot[i], 0, 0))
     page_spec = pl.BlockSpec((1, bs, H, D),
-                             lambda b, j, tbl, p: (tbl[b, j], 0, 0, 0))
+                             lambda i, slot, page, *_: (page[i], 0, 0, 0))
     sc_spec = pl.BlockSpec((1, bs, H),
-                           lambda b, j, tbl, p: (tbl[b, j], 0, 0))
+                           lambda i, slot, page, *_: (page[i], 0, 0))
     return _decode_attn_call(
-        (tables.astype(jnp.int32), pos.astype(jnp.int32)), q, k_pool, v_pool,
-        k_scale, v_scale, page_spec, sc_spec, grid=(B, NB), scale=scale_v,
+        (*work, pos.astype(jnp.int32)), q, k_pool, v_pool, k_scale, v_scale,
+        qo_spec, page_spec, sc_spec, grid=(n_work[0],), scale=scale_v,
         chunk=bs, interpret=_interpret(interpret),
         name="paged_decode_attention")
 
@@ -1397,11 +1446,14 @@ def _decode_attention_bytes(*, batch, read, n_heads, d_head, layers=1,
     return 2.0 * batch * read * row * layers * steps
 
 
-def _paged_decode_attention_bytes(*, batch, pages, page_block, n_heads,
-                                  d_head, layers=1, kv_dtype=None,
+def _paged_decode_attention_bytes(*, pages, page_block, n_heads, d_head,
+                                  batch=1, layers=1, kv_dtype=None,
                                   itemsize=2, steps=1):
-    """HBM bytes of ``steps`` paged reads: each sample streams its
-    ``pages`` live pages (``page_block`` rows each) once per step."""
+    """HBM bytes of paged reads: ``pages`` pages of ``page_block`` rows,
+    k and v. The decode kernel streams exactly the pages it walks (one
+    program each: ``PagePool.run_segment`` passes that count, summed over
+    the segment's steps and layers); a gathered read streams ``batch``
+    samples' ``pages`` each."""
     return _decode_attention_bytes(batch=batch, read=pages * page_block,
                                    n_heads=n_heads, d_head=d_head,
                                    layers=layers, kv_dtype=kv_dtype,

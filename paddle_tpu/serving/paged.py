@@ -750,7 +750,9 @@ class PagePool:
     def run_segment(self, live: Sequence[int]) -> np.ndarray:
         """One decode segment across the whole pool; returns the emitted
         token block [slots, segment] (drained slots' rows are garbage).
-        Grows live slots' tables first, so no mid-scan allocation exists."""
+        Grows live slots' tables first, so no mid-scan allocation exists.
+        A slot outside ``live`` decodes from pos 0 against its null table:
+        one work item of the paged read, page 0."""
         with obs.span("serving.stage", what="tables"):
             for i in live:
                 self._ensure(i, int(self.pos[i]) + self.segment)
@@ -760,29 +762,39 @@ class PagePool:
                 * self.cache_bucket, self.model.max_len)
             nb = cache_len // self.bs
             fn = self._seg_fn(nb)
+            idx = np.asarray(live, np.int64)
+            pos = np.zeros((self.n_slots,), np.int32)
+            pos[idx] = self.pos[idx].clip(0, self.model.max_len - 1)
             args = (self.params, self.pools,
-                    jnp.asarray(self.tables[:, :nb]),
-                    jnp.asarray(self.pos, jnp.int32).clip(
-                        0, self.model.max_len - 1),
+                    jnp.asarray(self.tables[:, :nb]), jnp.asarray(pos),
                     jnp.asarray(self.cur))
         with obs.span("serving.dispatch", program="segment"):
             self.pools, cur, toks = fn(*args)
             obs.count("decode.dispatches_total", route="serve_segment")
+            # the paged read's programs this segment, from the host's own
+            # pos (pk.paged_work_list's rule, step by step) against the
+            # whole table's — one per (layer, step, slot, page)
+            calls = len(self.model.blocks)
+            steps = np.arange(self.segment, dtype=np.int32)
+            walked = calls * int(np.minimum(
+                (pos[:, None] + steps[None, :]) // self.bs + 1, nb).sum())
+            obs.count("serving.decode_pages_walked_total", walked)
+            obs.count("serving.decode_pages_table_total",
+                      calls * self.segment * self.n_slots * nb)
             # modeled cache-read bytes through the ONE registered model
             # (ops/pallas_kernels._paged_decode_attention_bytes) — the same
             # resolution the bench rows and the roofline ledger use
             read = obs.roofline.kernel_cost(
-                "paged_decode_attention", batch=self.n_slots, pages=nb,
-                page_block=self.bs, n_heads=self._H, d_head=self._Dh,
-                layers=len(self.model.blocks), kv_dtype=self.kv_dtype,
-                itemsize=self._itemsize, steps=self.segment) or 0.0
+                "paged_decode_attention", pages=walked, page_block=self.bs,
+                n_heads=self._H, d_head=self._Dh, kv_dtype=self.kv_dtype,
+                itemsize=self._itemsize) or 0.0
             obs.count("kernels.bytes_total", read,
                       kernel="paged_decode_attention")
             self.segments_total += 1
             self.read_bytes_total += read
             self.occupancy_num += self.live_tokens(live)
             self.occupancy_den += max(self.pages_used, 1) * self.bs
-            self.pos += self.segment
+            self.pos[idx] += self.segment
         with obs.span("serving.fetch", program="segment"):
             self.cur = np.array(cur)  # writable copy: admit() merges into it
             return np.asarray(toks)                   # [slots, segment]

@@ -106,21 +106,27 @@ def test_decode_attention_compiles(S, L, kv):
 
 
 @pytest.mark.parametrize("kv", ["bf16", "f32", "int8"])
-def test_paged_decode_attention_compiles(S, kv):
-    """page 64 x 8 pages = the daemon's cache_bucket 512 read
-    (benchmarks/serving_daemon.py, chip_smoke.py serve phase); f32 is what
-    ``paddle_tpu serve`` holds (no dtype flag: pools follow the params)."""
-    bs, NB, pages = 64, 8, 8 * 8 + 1
-    q, pos = S((B, H, D), jnp.float32), S((B,), jnp.int32)
-    tables = S((B, NB), jnp.int32)
+@pytest.mark.parametrize("slots, heads, NB, pages", [
+    (B, H, 8, 8 * 8 + 1), (16, 20, 16, 105)], ids=["gpt2-small", "gpt2-large"])
+def test_paged_decode_attention_compiles(S, slots, heads, NB, pages, kv):
+    """gpt2-small: page 64 x 8 pages = the daemon's cache_bucket 512 read
+    (benchmarks/serving_daemon.py, chip_smoke.py serve phase). gpt2-large:
+    the benchmark's serve cells — 16 slots, 20 heads, the full table of 16
+    over a 105-page pool; a (20, 64) tile pads to (24, 128) where (12, 64)
+    pads to (16, 128), and only the compiler sees the difference. f32 is
+    what ``paddle_tpu serve`` holds (no dtype flag: pools follow the
+    params). The grid is the work list's traced length either way."""
+    bs = 64
+    q, pos = S((slots, heads, D), jnp.float32), S((slots,), jnp.int32)
+    tables = S((slots, NB), jnp.int32)
     if kv == "int8":
-        pool = S((pages, bs, H, D), jnp.int8)
-        sc = S((pages, bs, H), jnp.float32)
+        pool = S((pages, bs, heads, D), jnp.int8)
+        sc = S((pages, bs, heads), jnp.float32)
         _compile(lambda q, k, ks, v, vs, t, pos: pk.paged_decode_attention(
             q, k, v, t, pos, k_scale=ks, v_scale=vs, route="kernel",
             interpret=False), q, pool, sc, pool, sc, tables, pos)
     else:
-        pool = S((pages, bs, H, D),
+        pool = S((pages, bs, heads, D),
                  jnp.bfloat16 if kv == "bf16" else jnp.float32)
         _compile(lambda q, k, v, t, pos: pk.paged_decode_attention(
             q, k, v, t, pos, route="kernel", interpret=False),

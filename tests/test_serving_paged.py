@@ -195,6 +195,137 @@ def test_paged_attention_routes_agree(model_and_params):
                                rtol=2e-6, atol=2e-6)
 
 
+def _ragged_case(name, NB, bs):
+    """(tables [B, NB], pos [B]) of one ragged block table; page 0 is the
+    null page, entries past a slot's live pages point at it."""
+    def table(pages):
+        return pages + [0] * (NB - len(pages))
+    if name == "empty_slot":          # pos 0 under an all-null table
+        return [table([]), table([3, 4]), table([5])], [0, bs + 36, 5]
+    if name == "page_boundary":       # last row of page 0, first of page 1
+        return ([table([1]), table([2, 3]), table([4, 5])],
+                [bs - 1, bs, bs + 1])
+    if name == "whole_table_beside_one_page":
+        return ([table([1]), table(list(range(2, 2 + NB))), table([20])],
+                [7, NB * bs - 1, bs - 2])
+    if name == "shared_prefix":       # two slots read the same two pages
+        return ([table([1, 2, 3]), table([1, 2, 4]), table([5])],
+                [2 * bs + 22, 2 * bs + 42, 0])
+    if name == "every_slot_full":     # the whole table is live
+        return ([table(list(range(1 + b * NB, 1 + (b + 1) * NB)))
+                 for b in range(2)], [NB * bs - 1] * 2)
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+@pytest.mark.parametrize("NB", [4, 16])
+@pytest.mark.parametrize("case", ["empty_slot", "page_boundary",
+                                  "whole_table_beside_one_page",
+                                  "shared_prefix", "every_slot_full"])
+def test_paged_kernel_walks_ragged_tables(case, NB, kv):
+    """The kernel route's grid is the work list of live (slot, page) pairs:
+    on ragged tables it walks exactly the started pages (one item for an
+    empty slot), in slot order, and agrees with the dense gather route."""
+    Hh, Dh, bs, P = 4, 16, 64, 34
+    tables, pos = _ragged_case(case, NB, bs)
+    tables, pos = np.asarray(tables, np.int32), np.asarray(pos, np.int32)
+    B = len(pos)
+    rs = np.random.RandomState(NB)
+    k_pool = jnp.asarray(rs.randn(P, bs, Hh, Dh), jnp.float32)
+    v_pool = jnp.asarray(rs.randn(P, bs, Hh, Dh), jnp.float32)
+    q = jnp.asarray(rs.randn(B, Hh, Dh), jnp.float32)
+
+    slot, page, ordinal, last, n_work = map(
+        np.asarray, pk.paged_work_list(jnp.asarray(tables),
+                                       jnp.asarray(pos), bs))
+    n = int(n_work[0])
+    want = [(b, j) for b in range(B) for j in range(pos[b] // bs + 1)]
+    assert n == len(want) <= B * NB == slot.size
+    assert list(zip(slot[:n], ordinal[:n])) == want
+    np.testing.assert_array_equal(page[:n], [tables[b, j] for b, j in want])
+    np.testing.assert_array_equal(
+        last[:n], [int(j == pos[b] // bs) for b, j in want])
+
+    scales = {}
+    if kv == "int8":
+        k_pool, scales["k_scale"] = pk.quantize_kv(k_pool)
+        v_pool, scales["v_scale"] = pk.quantize_kv(v_pool)
+    args = (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(pos))
+    dense = pk.paged_decode_attention(*args, route="dense", **scales)
+    kern = pk.paged_decode_attention(*args, route="kernel", interpret=True,
+                                     **scales)
+    np.testing.assert_allclose(np.asarray(kern), np.asarray(dense),
+                               rtol=2e-6, atol=2e-6)
+
+
+def test_paged_batcher_kernel_route_tokens_equal_dense(model_and_params,
+                                                       monkeypatch):
+    """A mixed-length batch decoded with the paged read on the kernel route
+    (the interpreter, through the tuned-crossover hook) emits the dense
+    route's greedy tokens: idle slots, slots finishing mid-segment and
+    tables of different widths all go through the work list."""
+    from paddle_tpu import tune
+    from paddle_tpu.models import TransformerLM
+    model, params = model_and_params
+    rs = np.random.RandomState(21)
+    reqs = [Request(rid, rs.randint(0, VOCAB, plen), gen)
+            for rid, (plen, gen) in enumerate(
+                [(3, 30), (37, 9), (8, 1), (20, 26), (5, 12), (33, 17)])]
+    kw = dict(slots=4, segment=8, page_block=8, cache_bucket=32)
+    want = PagedBatcher(model, params, **kw).serve(reqs)
+    # the route is chosen while a program is traced: a model of its own
+    # keeps these programs out of the session model's shared cache
+    fresh = TransformerLM(VOCAB, d_model=D, n_heads=H, n_layers=L,
+                          max_len=MAX_LEN)
+    monkeypatch.setattr(tune, "decode_kernel_min_len", lambda: 1)
+    r = obs.MetricsRegistry()
+    with obs.ObsSession(registry=r).installed():
+        got = PagedBatcher(fresh, params, **kw).serve(reqs)
+    assert r.counter("kernels.routes_total").get(
+        kernel="paged_decode_attention", route="kernel") > 0
+    assert r.counter("kernels.routes_total").get(
+        kernel="paged_decode_attention", route="dense") == 0
+    for req in reqs:
+        np.testing.assert_array_equal(got[req.rid], want[req.rid])
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_decode_pages_walked_counters_and_bytes(model_and_params, kv_dtype):
+    """run_segment counts the paged read's programs (walked) beside the
+    table's cells, from the host's pos: walked <= table, equal when every
+    slot fills the table, and the modeled kernel bytes of a segment are
+    exactly the pages walked x one layer's (k + v) page."""
+    from paddle_tpu.serving.paged import PagePool
+    model, params = model_and_params
+    layers = len(model.blocks)
+
+    def segment(pool, plens):
+        group = [(slot, pool.plan_admission(np.arange(plen) % VOCAB, 4))
+                 for slot, plen in enumerate(plens)]
+        pool.admit(group)
+        r = obs.MetricsRegistry()
+        with obs.ObsSession(registry=r).installed():
+            pool.run_segment([slot for slot, _ in group])
+        walked = r.counter("serving.decode_pages_walked_total").get()
+        table = r.counter("serving.decode_pages_table_total").get()
+        read = r.counter("kernels.bytes_total").get(
+            kernel="paged_decode_attention")
+        assert read == pool.read_bytes_total
+        assert read == walked * pool.page_bytes / layers
+        return walked, table
+
+    kw = dict(slots=3, page_block=8, cache_bucket=32, kv_dtype=kv_dtype)
+    # ragged, a table 4 wide, 8 steps: pos 11 reads 2 pages for 5 steps and
+    # 3 from pos 16 on; pos 3 reads 1, then 2 from pos 8 on; the idle slot 1
+    walked, table = segment(PagePool(model, params, segment=8, **kw), [11, 3])
+    assert table == layers * 8 * 3 * 4
+    assert walked == layers * ((5 * 2 + 3 * 3) + (5 * 1 + 3 * 2) + 8) < table
+    # every slot on the table's last page: the whole grid is live
+    walked, table = segment(PagePool(model, params, segment=1, **kw),
+                            [30, 27, 25])
+    assert walked == table == layers * 3 * 4
+
+
 def test_validation_hardening(model_and_params):
     """Malformed requests die AT SUBMIT with precise errors (not as shape
     errors deep in prefill): max_new <= 0, empty prompt, prompt past the
