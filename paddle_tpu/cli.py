@@ -1508,10 +1508,27 @@ def cmd_serve(args):
     docs/design/serving.md and :class:`paddle_tpu.serving.ServingClient`).
 
     The model comes from ``--config`` (a Python script exposing module-
-    level ``model`` — a TransformerLM-compatible object — and ``params``)
-    or, without one, a randomly-initialized TransformerLM built from the
+    level ``model`` and ``params``) or, without one, a randomly-initialized
+    TransformerLM built from the
     ``--vocab/--d_model/...`` flags and ``--seed`` (the bring-up and e2e
     test mode: the same flags + seed reproduce the exact weights).
+
+    What a ``--config`` model must offer the page pool (``TransformerLM``
+    and ``DeepseekV3LM`` both do; serving/paged.py names no cache array
+    itself): ``max_len``; ``cache_rows(params, kv_dtype)`` — the per-layer
+    cache arrays as (name, trailing shape, dtype, fill), raising ValueError
+    for a ``kv_dtype`` it has no cache for; ``prefill(params, prompts,
+    lengths, kv_dtype=, pad_to=)`` -> (cell with one ``[B, pad_to, *shape]``
+    entry per stated array and ``pos``, last logits); ``decode_step_paged(
+    params, cell, tokens, tables, live=)`` -> (logits, new cell) over the
+    pools ``[pages, page_block, *shape]``; ``paged_read_kernel`` and
+    ``paged_read_geometry(params, kv_dtype)`` — the decode read's registered
+    cost model and the shape facts it takes; and, only for the prefix cache,
+    ``prefill_paged`` (a model without it needs ``--no_prefix_cache``; with
+    it on, ``serve`` refuses at start-up). Optional: ``program_stats_zero()``
+    / ``note_program_stats(stats, program)`` for counts a program returns
+    beside its tokens. The dtype of weights and cache follows the arrays the
+    script hands over: there is no dtype flag.
 
     The address line ``SERVING <host> <port>`` prints first and flushed
     (machine-parseable, same contract as ``obs serve``); the process then
@@ -2214,8 +2231,12 @@ def main(argv=None) -> int:
                         "(srv_submit/srv_poll/srv_cancel; "
                         "docs/design/serving.md)")
     sv.add_argument("--config", default=None,
-                    help="Python script exposing `model` and `params`; "
-                    "omitted = random-init TransformerLM from the flags")
+                    help="Python script exposing `model` and `params`: any "
+                    "model that states its cache rows and offers prefill "
+                    "and decode_step_paged (TransformerLM, DeepseekV3LM; "
+                    "the interface is in `serve`'s docstring); weights and "
+                    "cache keep the dtype of `params`; omitted = "
+                    "random-init TransformerLM from the flags")
     sv.add_argument("--host", default="127.0.0.1")
     sv.add_argument("--port", type=int, default=0)
     sv.add_argument("--vocab", type=int, default=50257)
@@ -2239,7 +2260,8 @@ def main(argv=None) -> int:
                     help="disable the copy-on-write prefix radix index "
                     "(default ON for the daemon: requests sharing a "
                     "prompt prefix share KV pages and prefill only the "
-                    "suffix; docs/design/serving.md)")
+                    "suffix; docs/design/serving.md); required for a model "
+                    "without prefill_paged (DeepseekV3LM)")
     sv.add_argument("--interactive_weight", type=float, default=4.0,
                     help="weighted-fair service share of slo=interactive "
                     "requests vs slo=batch (deficit scheduling at slot "

@@ -1,6 +1,7 @@
 """Model zoo — mirrors the reference's demo/benchmark/book model families
 (SURVEY.md §2.4 v1_api_demo + benchmark/paddle + fluid/tests/book)."""
 
+from .deepseek_v3 import DeepseekV3LM
 from .embeddings import DeepFM, Recommender, Word2Vec
 from .generative import GAN, VAE
 from .image import (AlexNet, GoogleNet, LeNet, ResNet, SmallNet,
@@ -17,5 +18,5 @@ __all__ = [
            "LSTMTextCls", "BiLSTMTextCls", "ConvTextCls",
            "AttentionSeq2Seq", "LinearCRFTagger", "BiLSTMCRFTagger",
            "Word2Vec", "Recommender", "DeepFM", "GAN", "VAE",
-           "TransformerLM", "TransformerBlock",
+           "TransformerLM", "TransformerBlock", "DeepseekV3LM",
            "TransformerSeq2Seq", "CrossAttentionBlock"]

@@ -14,7 +14,7 @@ whole-model bf16 compute with f32 master params handled by callers, and a
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +23,16 @@ from .. import nn
 from .. import obs
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
+
+
+class CacheRow(NamedTuple):
+    """One array of a model's per-layer cache as it states it to the page
+    pool (serving/paged.py), which allocates ``[pages, page_block, *shape]``
+    of ``dtype`` filled with ``fill`` and never names an array itself."""
+    name: str
+    shape: tuple
+    dtype: object
+    fill: float = 0.0
 
 
 class TransformerBlock(nn.Module):
@@ -329,8 +339,37 @@ class TransformerLM(nn.Module):
                   if self.tie_head else self.head(params["head"], x))
         return logits[:, 0], new_cell
 
+    # -- what the page pool asks of a served model ------------------------
+    def cache_rows(self, params, kv_dtype: Optional[str] = None):
+        """The per-layer cache rows: a key and a value per head
+        (``k{i}``/``v{i}``), int8 with one f32 scale per (row, head) when
+        ``kv_dtype="int8"`` (scale 1.0 everywhere, so the dequant of masked
+        null/garbage rows stays finite)."""
+        if kv_dtype not in (None, "int8"):
+            raise ValueError(f"unsupported kv_dtype {kv_dtype!r}")
+        H, Dh = self.blocks[0].n_heads, self.blocks[0].d_head
+        dt = jnp.int8 if kv_dtype == "int8" else self._compute_dtype(params)
+        rows = []
+        for i in range(len(self.blocks)):
+            rows += [CacheRow(f"k{i}", (H, Dh), dt),
+                     CacheRow(f"v{i}", (H, Dh), dt)]
+            if kv_dtype == "int8":
+                rows += [CacheRow(f"k{i}_scale", (H,), jnp.float32, 1.0),
+                         CacheRow(f"v{i}_scale", (H,), jnp.float32, 1.0)]
+        return rows
+
+    #: the decode read's registered cost model (obs/roofline.kernel_cost)
+    paged_read_kernel = "paged_decode_attention"
+
+    def paged_read_geometry(self, params, kv_dtype: Optional[str] = None):
+        """The shape facts that cost model takes beside (pages,
+        page_block)."""
+        return {"n_heads": self.blocks[0].n_heads,
+                "d_head": self.blocks[0].d_head, "kv_dtype": kv_dtype,
+                "itemsize": jnp.dtype(self._compute_dtype(params)).itemsize}
+
     def decode_step_paged(self, params, cell, tokens, tables, *,
-                          attn_route: Optional[str] = None):
+                          live=None, attn_route: Optional[str] = None):
         """One incremental step against a PAGED cache: tokens [B] ->
         (logits [B, V], new cell). The cell holds per-layer page POOLS
         (``k{i}``/``v{i}`` [P, bs, H, Dh], plus ``k{i}_scale``/``v{i}_scale``
@@ -349,7 +388,9 @@ class TransformerLM(nn.Module):
         ``tables`` is sliced by the CALLER to the live read bound (NB
         pages), the paged twin of ``decode_step``'s ``cache_len``; the
         kernel's work list (the live pages under ``tables`` and ``pos``)
-        is built here once and shared by every layer's read."""
+        is built here once and shared by every layer's read. ``live`` [B]
+        (which slots hold a request) is the pool's to pass and not needed
+        here: a drained slot reads its null page."""
         pos = cell["pos"]                                  # [B]
         bs = cell["k0"].shape[1]
         work = pk.paged_work_list(tables, pos, bs)

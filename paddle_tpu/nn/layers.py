@@ -64,11 +64,12 @@ class Embedding(Module):
     updates in optimizer.sparse)."""
 
     def __init__(self, vocab_size: int, dim: int, padding_idx: Optional[int] = None,
-                 w_init: Optional[I.Initializer] = None):
+                 w_init: Optional[I.Initializer] = None, dtype=jnp.float32):
         super().__init__()
         self.vocab_size, self.dim = vocab_size, dim
         self.padding_idx = padding_idx
-        self.param("w", (vocab_size, dim), w_init or I.normal(0.0, 0.01))
+        self.param("w", (vocab_size, dim), w_init or I.normal(0.0, 0.01),
+                   dtype=dtype)
 
     def __call__(self, params, ids, **kw):
         out = jnp.take(params["w"], ids, axis=0)
@@ -210,3 +211,43 @@ class AvgPool2D(Module):
 
     def __call__(self, params, x, **kw):
         return pool_ops.avg_pool2d(x, self.kernel, self.stride, self.padding)
+
+
+class RMSNorm(Module):
+    """Root-mean-square norm, no centring and no bias (Zhang & Sennrich
+    2019): the statistics and the scaling are computed in float32 whatever
+    dtype comes in, and the result is returned in float32 — the caller casts
+    it to its matmul operand dtype."""
+
+    def __init__(self, dim: int, eps: float = 1e-6, dtype=jnp.float32):
+        super().__init__()
+        self.eps = eps
+        self.param("gamma", (dim,), I.ones, dtype=dtype)
+
+    def __call__(self, params, x, **kw):
+        x = x.astype(jnp.float32)
+        var = jnp.mean(x * x, axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + self.eps) * params["gamma"].astype(
+            jnp.float32)
+
+
+class SwiGLU(Module):
+    """Gated feed-forward ``(silu(x W_g) * x W_u) W_d`` without biases.
+    Operands in the weights' dtype, every product accumulated in float32;
+    returns float32."""
+
+    def __init__(self, dim: int, width: int,
+                 w_init: Optional[I.Initializer] = None, dtype=jnp.float32):
+        super().__init__()
+        init = w_init or I.normal(0.0, 0.02)
+        self.param("w_gate", (dim, width), init, dtype=dtype)
+        self.param("w_up", (dim, width), init, dtype=dtype)
+        self.param("w_down", (width, dim), init, dtype=dtype)
+
+    def __call__(self, params, x, **kw):
+        dt = params["w_gate"].dtype
+        x = x.astype(dt)
+        g = jnp.dot(x, params["w_gate"], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, params["w_up"], preferred_element_type=jnp.float32)
+        return jnp.dot((jax.nn.silu(g) * u).astype(dt), params["w_down"],
+                       preferred_element_type=jnp.float32)

@@ -415,6 +415,20 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
         "counter", "cells of the block table under the same reads "
                    "(layers x steps x slots x table width): walked / "
                    "table is the share of the table that was live"),
+    "moe.assignments_total": (
+        "counter", "live (token, choice) pairs the expert layers of an "
+                   "admit or segment program routed, over ALL experts "
+                   "(live tokens x top_k x expert layers), labels: program",
+        ("program",)),
+    "moe.assignments_here_total": (
+        "counter", "those of the pairs that landed on an expert this chip "
+                   "holds, and were computed, labels: program",
+        ("program",)),
+    "moe.experts_touched_total": (
+        "counter", "held experts that had a live token, summed over the "
+                   "program's steps and expert layers: the expert matrices "
+                   "the grouped products read, labels: program",
+        ("program",)),
     "serving.prefix_hits_total": ("counter", "admissions that matched the "
                                              "prefix radix index and "
                                              "prefilled only their "
@@ -597,6 +611,10 @@ SPANS: Dict[str, str] = {
     "serving.fetch": "np.asarray of a program's tokens: the device wait "
                      "plus the copy back (args: program)",
     "serving.index": "prefix-index insertion over one admission wave",
+    "moe.program": "instant: what the expert layers of one program routed "
+                   "(args: program = admit | segment, routed_here, "
+                   "experts_touched, load_max); serving.segment carries "
+                   "the same three",
     "serving.emit": "the locked token hand-out after a prefill or a "
                     "segment (args: after = prefill | segment)",
     "serving.ship": "client side of one KV shipment: every srv_ship chunk "

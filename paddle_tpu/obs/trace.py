@@ -205,6 +205,11 @@ class _Span:
         self._tracer._record(ev)
         return False
 
+    def note(self, **attrs) -> None:
+        """Attributes learned while the span is open (what a program
+        returned); recorded with it at exit."""
+        self.attrs.update(attrs)
+
     @property
     def duration(self) -> float:
         """Elapsed seconds so far; the recorded duration once exited."""
@@ -222,6 +227,9 @@ class NullSpan:
 
     def __enter__(self) -> "NullSpan":
         return self
+
+    def note(self, **attrs) -> None:
+        pass
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         return False

@@ -878,6 +878,214 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# Paged LATENT attention — the absorbed read of multi-head latent attention
+# (DeepSeek-V2/V3): the cache holds ONE row a token a layer, the normed
+# latent (d_value wide) followed by the shared rotary key, and every query
+# head reads that same row: keys are the whole row, values its first d_value
+# entries. A sibling of paged_decode_attention, not a widening of it: that
+# kernel forms q * k elementwise per (row, head) on the VPU, which for 64
+# heads over one 576-wide row would be a [rows, 64, 576] f32 temporary; here
+# the two products are matrix products on the MXU ([H, Dk] x [rows, Dk]^T and
+# [H, rows] x [rows, d_value]). They share everything around the body: the
+# work list (paged_work_list), one program per live (slot, page), the
+# scalar-prefetched index maps, the running softmax in m/l/acc scratch, the
+# null page and the ``j <= pos`` mask, and the dense route off-TPU.
+# ---------------------------------------------------------------------------
+
+def _latent_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref, q_ref,
+                        kv_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                        scale: float, chunk: int, d_value: int):
+    """Program i reads page ordinal c = ordinal[i] of slot b = slot[i].
+    Blocks: q [1, H, Dk]; kv [1, chunk, Dk]; o [1, H, d_value] f32, written
+    by slot b's last page. Operands stay in the cache's dtype, both products
+    accumulate in f32, the softmax is f32."""
+    i = pl.program_id(0)
+    b, c, last = slot_ref[i], ord_ref[i], last_ref[i] == 1
+
+    @pl.when(c == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    pos = pos_ref[b]
+
+    @pl.when(c * chunk <= pos)
+    def _live():
+        rows = kv_ref[0]                                    # [chunk, Dk]
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # [H, chunk]
+        j = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+        s = jnp.where(j <= pos, s, _NEG)
+        m_prev = m_ref[...]                                 # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p.astype(rows.dtype), rows[:, :d_value],
+            preferred_element_type=jnp.float32)             # [H, d_value]
+        m_ref[...] = m_new
+
+    @pl.when(last)
+    def _finish():
+        o_ref[0] = acc_ref[...] / l_ref[...]
+
+
+def _dense_latent_attention(q, rows, pos, scale, d_value):
+    """Reference-math route: q [B, H, Dk], rows [B, L, Dk] (the gathered
+    per-sample view), rows j <= pos[b] live."""
+    L = rows.shape[1]
+    rows = rows.astype(jnp.float32)
+    s = jnp.einsum("bhd,bjd->bhj", q.astype(jnp.float32), rows) * scale
+    valid = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, :]
+    s = jnp.where(valid, s, _NEG)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    return jnp.einsum("bhj,bjd->bhd", p / l, rows[..., :d_value])
+
+
+def paged_latent_attention(q: jax.Array, pool: jax.Array, tables: jax.Array,
+                           pos: jax.Array, *, d_value: int, scale: float,
+                           work=None, route: Optional[str] = None,
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """Single-token absorbed latent-attention read through a block table.
+
+    q: [B, H, Dk] — each head's query already carried into the latent space
+    (``[q_nope W_UK^T | q_rope]``); pool: [P, bs, Dk] latent rows (``Dk`` =
+    d_value + the rotary key's width), ONE row a token shared by all heads;
+    tables [B, NB], pos [B] and ``work`` as for
+    :func:`paged_decode_attention`. Keys are whole rows, values their first
+    ``d_value`` entries. Returns o [B, H, d_value] f32 — the caller applies
+    W_UV. Routing follows decode_attention's rule (kernel for long on-TPU
+    reads, gathered dense math otherwise)."""
+    B, NB = tables.shape
+    P, bs, Dk = pool.shape
+    H = q.shape[1]
+    route = decode_route(NB * bs, route)
+    from .. import obs
+    obs.count("kernels.routes_total", kernel="paged_latent_attention",
+              route=route)
+    if route == "dense":
+        return _dense_latent_attention(q, gather_pages(pool, tables), pos,
+                                       scale, d_value)
+    if route != "kernel":
+        raise ValueError(f"unknown paged_latent_attention route {route!r}")
+    from jax.experimental.pallas import tpu as pltpu
+    if work is None:
+        work = paged_work_list(tables, pos, bs)
+    *work, n_work = work
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5, grid=(n_work[0],),
+        in_specs=[pl.BlockSpec((1, H, Dk),
+                               lambda i, slot, *_: (slot[i], 0, 0)),
+                  pl.BlockSpec((1, bs, Dk),
+                               lambda i, slot, page, *_: (page[i], 0, 0))],
+        out_specs=pl.BlockSpec((1, H, d_value),
+                               lambda i, slot, *_: (slot[i], 0, 0)),
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, d_value), jnp.float32)])
+    kernel = functools.partial(_latent_attn_kernel, scale=scale, chunk=bs,
+                               d_value=d_value)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, d_value), jnp.float32),
+        interpret=_interpret(interpret), name="paged_latent_attention",
+    )(*work, pos.astype(jnp.int32), q.astype(pool.dtype), pool)
+
+
+# ---------------------------------------------------------------------------
+# Grouped matrix product — the expert layer's three products. The rows of
+# ``lhs`` are laid out in TILES of ``tm`` rows, every tile belonging to ONE
+# group (parallel/expert_share.py builds that layout: each held expert's
+# tokens, padded up to a whole tile), and ``tile_group`` names each tile's
+# group. The grid walks only the ``n_tiles`` tiles in use, so an expert no
+# token chose costs nothing: no program, no weight fetch. (The technique is
+# megablox's — group metadata scalar-prefetched into the index maps; tiles
+# aligned to groups make the store mask unnecessary.)
+# ---------------------------------------------------------------------------
+
+def _grouped_matmul_kernel(group_ref, lhs_ref, rhs_ref, o_ref, acc_ref, *,
+                           n_k: int):
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[0],
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(k == n_k - 1)
+    def _store():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _dense_grouped_matmul(lhs, rhs, tile_group, tm, out_dtype):
+    """Reference-math route: every tile against its own group's matrix."""
+    M, K = lhs.shape
+    w = rhs[tile_group]                                     # [tiles, K, N]
+    out = jnp.einsum("tmk,tkn->tmn", lhs.reshape(M // tm, tm, K), w,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(M, -1).astype(out_dtype)
+
+
+def grouped_matmul_blocks(K: int, N: int) -> Tuple[int, int]:
+    """(tk, tn): the weight block a program streams. Whole rows of the
+    matrix where they fit (one contiguous fetch), about 2 MiB of bf16."""
+    tn = N if N <= 2048 else next(t for t in (3584, 2048, 1024, 512, 256,
+                                              128, N) if N % t == 0)
+    tk = next((t for t in (512, 256, 128) if K % t == 0
+               and t * tn <= (1 << 20)), K)
+    return tk, tn
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
+                   n_tiles: jax.Array, *, tm: int,
+                   out_dtype=jnp.float32, route: Optional[str] = None,
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """lhs [M, K] (M a multiple of ``tm``) x rhs [G, K, N] -> [M, N]: tile
+    t (rows t*tm ..) is multiplied by ``rhs[tile_group[t]]``. Only tiles
+    t < n_tiles[0] are computed; the rows of later tiles are UNDEFINED on
+    the kernel route (the caller masks them), so a group without rows costs
+    nothing. Operands in their own dtype, f32 accumulation. ``route``:
+    "kernel" on the TPU, the dense einsum elsewhere (``None`` decides)."""
+    M, K = lhs.shape
+    G, _, N = rhs.shape
+    if M % tm:
+        raise ValueError(f"grouped_matmul: {M} rows are not whole tiles "
+                         f"of {tm}")
+    if route is None:
+        route = "kernel" if _on_tpu() else "dense"
+    from .. import obs
+    obs.count("kernels.routes_total", kernel="expert_grouped_matmul",
+              route=route)
+    if route == "dense":
+        return _dense_grouped_matmul(lhs, rhs, tile_group, tm, out_dtype)
+    if route != "kernel":
+        raise ValueError(f"unknown grouped_matmul route {route!r}")
+    from jax.experimental.pallas import tpu as pltpu
+    tk, tn = grouped_matmul_blocks(K, N)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(N // tn, n_tiles[0], K // tk),
+        in_specs=[pl.BlockSpec((tm, tk), lambda n, t, k, g: (t, k)),
+                  pl.BlockSpec((1, tk, tn), lambda n, t, k, g: (g[t], k, n))],
+        out_specs=pl.BlockSpec((tm, tn), lambda n, t, k, g: (t, n)),
+        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_grouped_matmul_kernel, n_k=K // tk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=_interpret(interpret), name="expert_grouped_matmul",
+    )(tile_group.astype(jnp.int32), lhs, rhs)
+
+
+# ---------------------------------------------------------------------------
 # Fused LSTM sequence kernel — the hl_cuda_lstm.cu analog: the entire T-step
 # recurrence runs inside ONE kernel with the recurrent weights and the h/c
 # state resident in VMEM, so the per-step state never round-trips HBM the way
@@ -1477,6 +1685,14 @@ def _paged_prefill_attention_bytes(*, batch, pages, page_block, n_heads,
                                          itemsize=itemsize, steps=1)
 
 
+def _paged_latent_attention_bytes(*, pages, page_block, row, itemsize=2):
+    """HBM bytes of paged latent reads: ``pages`` pages of ``page_block``
+    rows of ``row`` values, each read ONCE (keys and values are the same
+    row) — the count PagePool.run_segment passes, summed over steps and
+    layers."""
+    return float(pages) * page_block * row * itemsize
+
+
 def _lstm_sequence_fused_bytes(*, batch, seq_len, hidden, itemsize=4,
                                gates=4):
     """HBM bytes of one fused-RNN forward launch: the [B, T, G*H] gate
@@ -1496,6 +1712,8 @@ def _register_cost_models():
                                   _paged_decode_attention_bytes)
     roofline.register_kernel_cost("paged_prefill_attention",
                                   _paged_prefill_attention_bytes)
+    roofline.register_kernel_cost("paged_latent_attention",
+                                  _paged_latent_attention_bytes)
     roofline.register_kernel_cost("lstm_sequence_fused",
                                   _lstm_sequence_fused_bytes)
     roofline.register_kernel_cost(

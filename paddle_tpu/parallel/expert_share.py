@@ -1,0 +1,213 @@
+"""One chip's share of a routed-expert layer (DeepSeek-V3's router).
+
+``parallel/moe.py`` is the GShard layer: softmax gate, fixed capacity,
+over-capacity tokens dropped, all experts on the mesh. This layer is what
+expert parallelism asks of ONE chip of a wide deployment: it is TOLD which
+experts it holds (``experts_held``), routes every token over ALL
+``n_experts`` (the router keeps its published width), and computes the part
+of the result its own experts give, plus the shared expert that every chip
+computes alike. A (token, choice) that lands on a held expert is always
+computed — there is no capacity — and one that lands elsewhere is left out:
+that partial result is what goes on. No code stands in for the absent chips
+or their exchange; summed over all shares (the shared expert counted once)
+the parts add up to the whole layer (tests/test_deepseek_v3.py).
+
+Routing, in float32: ``s = sigmoid(y W_r)``; SELECTION uses ``s + bias``:
+the experts form ``n_group`` groups, a group scores the sum of its top 2,
+the best ``topk_group`` groups stay, the top ``top_k`` experts inside them
+are chosen; WEIGHTS are the original ``s`` at the chosen experts, divided by
+their sum, times ``routed_scale``.
+
+The held experts' products are GROUPED: the (token, choice) pairs that
+landed here are sorted by expert and laid out in tiles of ``tm`` rows, each
+tile one expert's (ops/pallas_kernels.grouped_matmul). Work follows the
+pairs that landed here, not the pairs routed: in a program with more pairs
+than ``CHUNK`` the sorted pairs are walked ``CHUNK`` at a time for as long
+as held pairs remain, so the worst case (every pair lands here) is computed
+exactly and the usual case costs its own size.
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import nn
+from ..nn.initializer import normal, zeros
+from ..ops import pallas_kernels as pk
+
+#: (token, choice) pairs gathered at once: bounds the gathered activations
+#: ([CHUNK + held * tm, d_model]) whatever the program's token count
+CHUNK = 8192
+
+
+def route(scores_logits, bias, *, n_group: int, topk_group: int, top_k: int,
+          routed_scale: float):
+    """scores_logits [T, E] f32 (``y W_r``), bias [E] -> (experts [T, top_k]
+    int32, weights [T, top_k] f32)."""
+    T, E = scores_logits.shape
+    s = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
+    pick = s + bias.astype(jnp.float32)
+    grouped = pick.reshape(T, n_group, E // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, keep = jax.lax.top_k(group_score, topk_group)            # [T, kg]
+    kept = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], keep].set(True)
+    pick = jnp.where(jnp.repeat(kept, E // n_group, axis=1), pick, -jnp.inf)
+    _, experts = jax.lax.top_k(pick, top_k)
+    w = jnp.take_along_axis(s, experts, axis=1)
+    w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) * routed_scale
+    return experts.astype(jnp.int32), w
+
+
+def tile_layout(group_of, n_groups: int, tm: int):
+    """Lay ``A`` rows out in tiles of ``tm``, every tile one group's.
+
+    group_of [A] int32 in 0..n_groups, where ``n_groups`` itself means
+    "not here". Returns (src [M] int32 — the row each padded position
+    reads, ``A`` where it is padding; tile_group [M // tm] int32; n_tiles
+    [1] int32; counts [n_groups] int32) with the static
+    ``M = ceil(A / tm) * tm + n_groups * tm``."""
+    A = group_of.shape[0]
+    M = -(-A // tm) * tm + n_groups * tm
+    counts = jnp.zeros((n_groups + 1,), jnp.int32).at[group_of].add(1)
+    counts = counts[:n_groups]
+    tiles = (counts + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    row_start = (tile_end - tiles) * tm                        # padded
+    first = jnp.cumsum(counts) - counts                        # in sorted
+    order = jnp.argsort(group_of, stable=True).astype(jnp.int32)
+    g = group_of[order]
+    here = g < n_groups
+    gc = jnp.minimum(g, n_groups - 1)
+    dest = row_start[gc] + jnp.arange(A, dtype=jnp.int32) - first[gc]
+    src = jnp.full((M,), A, jnp.int32).at[
+        jnp.where(here, dest, M)].set(order, mode="drop")
+    tile_group = jnp.minimum(
+        jnp.sum(jnp.arange(M // tm, dtype=jnp.int32)[:, None]
+                >= tile_end[None, :], axis=1, dtype=jnp.int32),
+        n_groups - 1)
+    return src, tile_group, tile_end[-1:], counts
+
+
+class ExpertShare(nn.Module):
+    """The routed experts this chip holds + the shared expert.
+
+    ``experts_held``: the global ids of the experts whose weights live here
+    (``w_gate``/``w_up`` [held, d, f], ``w_down`` [held, f, d], in that
+    order). ``shared=False`` leaves the shared expert out (a share that is
+    summed with another's)."""
+
+    def __init__(self, d_model: int, d_expert: int, *, n_experts: int,
+                 experts_held: Sequence[int], top_k: int, n_group: int,
+                 topk_group: int, routed_scale: float, n_shared: int = 1,
+                 shared: bool = True, dtype=jnp.float32,
+                 init_std: float = 0.02):
+        super().__init__()
+        held = [int(e) for e in experts_held]
+        if not held or len(set(held)) != len(held) or \
+                min(held) < 0 or max(held) >= n_experts:
+            raise ValueError(f"experts_held {held} must be distinct ids in "
+                             f"0..{n_experts - 1}")
+        self.n_experts, self.held = n_experts, held
+        self.route_kw = dict(n_group=n_group, topk_group=topk_group,
+                             top_k=top_k, routed_scale=routed_scale)
+        # global expert id -> local index; n_held where it is not here
+        table = np.full((n_experts,), len(held), np.int32)
+        table[held] = np.arange(len(held), dtype=np.int32)
+        self._local = table
+        init = normal(0.0, init_std)
+        self.param("w_router", (d_model, n_experts), init, dtype=dtype)
+        self.param("e_bias", (n_experts,), zeros, dtype=jnp.float32)
+        self.param("w_gate", (len(held), d_model, d_expert), init,
+                   dtype=dtype)
+        self.param("w_up", (len(held), d_model, d_expert), init, dtype=dtype)
+        self.param("w_down", (len(held), d_expert, d_model), init,
+                   dtype=dtype)
+        if shared and n_shared:
+            self.shared = nn.SwiGLU(d_model, n_shared * d_expert,
+                                    w_init=init, dtype=dtype)
+        else:
+            self.shared = None
+
+    def routing(self, params, y):
+        """(experts [T, k] global ids, weights [T, k]) — f32 throughout,
+        the router product at full precision."""
+        logits = jnp.dot(y.astype(jnp.float32),
+                         params["w_router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        return route(logits, params["e_bias"], **self.route_kw)
+
+    def _held_part(self, params, y, tok, local, w, tm, route_):
+        """Σ over the pairs (tok, local expert, weight) of w * SwiGLU_e(y):
+        one pass of the three grouped products over a tile layout."""
+        T, d = y.shape
+        A = tok.shape[0]
+        n_held = len(self.held)
+        src, tile_group, n_tiles, _ = tile_layout(local, n_held, tm)
+        ok = src < A
+        srcc = jnp.minimum(src, A - 1)
+        rows = y[tok[srcc]]                                    # [M, d]
+        kw = dict(tm=tm, route=route_)
+        g = pk.grouped_matmul(rows, params["w_gate"], tile_group, n_tiles,
+                              **kw)
+        u = pk.grouped_matmul(rows, params["w_up"], tile_group, n_tiles,
+                              **kw)
+        a = (jax.nn.silu(g) * u).astype(y.dtype)
+        out = pk.grouped_matmul(a, params["w_down"], tile_group, n_tiles,
+                                **kw)
+        # rows past the tiles in use are undefined on the kernel route
+        out = jnp.where(ok[:, None], out * w[srcc][:, None], 0.0)
+        return jnp.zeros((T, d), jnp.float32).at[
+            jnp.where(ok, tok[srcc], T)].add(out, mode="drop")
+
+    def __call__(self, params, y, live=None, *, route_: Optional[str] = None,
+                 **kw):
+        """y [T, d] (normed, f32) -> (out [T, d] f32, counts [held] int32:
+        the live (token, choice) pairs that landed on each held expert).
+        ``live`` [T] bool leaves dead rows (drained slots, prompt padding)
+        out of the experts' work and of the counts."""
+        T, d = y.shape
+        dt = params["w_gate"].dtype
+        experts, weights = self.routing(params, y)
+        k = experts.shape[1]
+        local = jnp.asarray(self._local)[experts]              # [T, k]
+        if live is not None:
+            local = jnp.where(live[:, None], local, len(self.held))
+        local = local.reshape(-1)
+        w = weights.reshape(-1)
+        tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+        n_held = len(self.held)
+        counts = jnp.zeros((n_held + 1,), jnp.int32).at[local].add(
+            1)[:n_held]
+        yb = y.astype(dt)
+        A = T * k
+        if A <= CHUNK:
+            tm = 16 if A <= 1024 else 128
+            out = self._held_part(params, yb, tok, local, w, tm, route_)
+        else:
+            # held pairs first, expert by expert; walk them CHUNK at a time
+            order = jnp.argsort(local, stable=True).astype(jnp.int32)
+            here = jnp.sum(counts)
+            pad = -A % CHUNK
+            order = jnp.concatenate([order, jnp.full((pad,), A, jnp.int32)])
+            local_x = jnp.concatenate([local, jnp.full((1,), n_held,
+                                                       jnp.int32)])
+            tok_x = jnp.concatenate([tok, jnp.zeros((1,), jnp.int32)])
+            w_x = jnp.concatenate([w, jnp.zeros((1,), w.dtype)])
+
+            def body(carry):
+                i, acc = carry
+                idx = jax.lax.dynamic_slice(order, (i * CHUNK,), (CHUNK,))
+                return i + 1, acc + self._held_part(
+                    params, yb, tok_x[idx], local_x[idx], w_x[idx], 256,
+                    route_)
+            _, out = jax.lax.while_loop(
+                lambda c: c[0] * CHUNK < here, body,
+                (jnp.int32(0), jnp.zeros((T, d), jnp.float32)))
+        if self.shared is not None:
+            out = out + self.shared(params["shared"], yb)
+        return out, counts

@@ -598,9 +598,10 @@ class ServingEngine:
             live = sorted(self._live)
         if not live:
             return
-        with obs.span("serving.segment", live=len(live)), \
+        with obs.span("serving.segment", live=len(live)) as span, \
                 maybe_bucket(self._gp, "device"):
             block = self.pool.run_segment(live)  # device work, lock released
+            span.note(**self.pool.last_stats)
         with obs.span("serving.emit", after="segment"), \
                 maybe_bucket(self._gp, "host_sync"), self._lock:
             for slot in live:

@@ -135,8 +135,12 @@ class PagePool:
         if cache_bucket % page_block:
             raise ValueError(f"cache_bucket {cache_bucket} must be a "
                              f"multiple of page_block {page_block}")
-        if kv_dtype not in (None, "int8"):
-            raise ValueError(f"unsupported kv_dtype {kv_dtype!r}")
+        if prefix_cache and not hasattr(model, "prefill_paged"):
+            raise ValueError(
+                f"prefix_cache needs the model's suffix admission "
+                f"(prefill_paged), which {type(model).__name__} does not "
+                "have: build the pool with prefix_cache=False "
+                "(serve --no_prefix_cache)")
         self.model, self.params = model, params
         self.n_slots, self.segment = slots, segment
         self.bs = page_block
@@ -153,27 +157,25 @@ class PagePool:
         self.capacity_pages = self.pages - 1
         self.capacity_tokens = self.capacity_pages * self.bs
 
-        H = model.blocks[0].n_heads
-        Dh = model.blocks[0].d_head
-        dt = jnp.int8 if kv_dtype == "int8" else model._compute_dtype(params)
-        pools = {}
-        for i in range(len(model.blocks)):
-            pools[f"k{i}"] = jnp.zeros((self.pages, self.bs, H, Dh), dt)
-            pools[f"v{i}"] = jnp.zeros((self.pages, self.bs, H, Dh), dt)
-            if kv_dtype == "int8":
-                # scale 1.0 everywhere so dequant of (masked) null/garbage
-                # rows stays finite — the prefill padded-scale convention
-                pools[f"k{i}_scale"] = jnp.ones((self.pages, self.bs, H),
-                                                jnp.float32)
-                pools[f"v{i}_scale"] = jnp.ones((self.pages, self.bs, H),
-                                                jnp.float32)
-        self.pools = pools
-        self._H, self._Dh = H, Dh
-        self._itemsize = jnp.dtype(dt).itemsize
-        # one (k + v) page in HBM bytes — the prefix index's reuse-ledger
-        # credit unit (int8 rows carry a 4-byte scale per (row, head))
-        row_b = H * (Dh + 4 if kv_dtype == "int8" else Dh * self._itemsize)
-        self.page_bytes = 2.0 * self.bs * row_b * len(model.blocks)
+        # the MODEL states its per-layer cache rows (name, trailing shape,
+        # dtype, fill): k/v per head for TransformerLM, one latent row for
+        # DeepseekV3LM. Everything below — allocation, donation, the
+        # admission scatter, CoW copies, shipping, byte counts — follows
+        # that statement; the pool names no array itself.
+        rows = model.cache_rows(params, kv_dtype)
+        self.pools = {r.name: jnp.full((self.pages, self.bs) + tuple(r.shape),
+                                       r.fill, r.dtype) for r in rows}
+        # the decode read's registered cost model and the shape facts it
+        # takes beside (pages, page_block)
+        self._read_kernel = model.paged_read_kernel
+        self._read_geom = model.paged_read_geometry(params, kv_dtype)
+        # one page of every stated array in HBM bytes — the prefix index's
+        # reuse-ledger credit unit
+        self.page_bytes = float(self.bs * sum(
+            int(np.prod(r.shape, dtype=np.int64)) * jnp.dtype(r.dtype).itemsize
+            for r in rows))
+        #: what the model made of the last program's stats (span attrs)
+        self.last_stats: Dict[str, float] = {}
         self.index: Optional[PrefixIndex] = (
             PrefixIndex(self.bs, self.page_bytes,
                         half_life=prefix_half_life)
@@ -479,18 +481,11 @@ class PagePool:
                                            pad_to=tpp)
                 first = jnp.argmax(last, axis=-1).astype(prompts.dtype)
                 out = {}
-                for i in range(len(model.blocks)):
-                    for nm in (f"k{i}", f"v{i}"):
-                        rows = cell[nm][:, :tpp].reshape(
-                            (prompts.shape[0], nbp, bs) + cell[nm].shape[2:])
-                        out[nm] = pools[nm].at[pages].set(
-                            rows.astype(pools[nm].dtype))
-                    if kv_dtype == "int8":
-                        for nm in (f"k{i}_scale", f"v{i}_scale"):
-                            rows = cell[nm][:, :tpp].reshape(
-                                prompts.shape[0], nbp, bs, -1)
-                            out[nm] = pools[nm].at[pages].set(rows)
-                return out, first
+                for nm, pool in pools.items():
+                    rows = cell[nm][:, :tpp].reshape(
+                        (prompts.shape[0], nbp, bs) + cell[nm].shape[2:])
+                    out[nm] = pool.at[pages].set(rows.astype(pool.dtype))
+                return out, first, cell.get("stats", {})
             # cost-instrumented (PR 9 ledger): under an obs session the
             # dispatch feeds fluid.device_flops_total and admit() reads
             # the per-executable FLOPs into admit_flops_total — the
@@ -537,19 +532,22 @@ class PagePool:
             obs.instant("serving.program_build", kind="segment", nb=nb)
             model, segment = self.model, self.segment
 
-            def seg(params, pools, tables, pos, cur):
+            def seg(params, pools, tables, pos, cur, live):
                 cell = dict(pools, pos=pos)
+                if hasattr(model, "program_stats_zero"):
+                    cell["stats"] = model.program_stats_zero()
 
                 def body(carry, _):
                     cell, cur = carry
-                    logits, cell = model.decode_step_paged(params, cell,
-                                                           cur, tables)
+                    logits, cell = model.decode_step_paged(
+                        params, cell, cur, tables, live=live)
                     nxt = jnp.argmax(logits, axis=-1).astype(cur.dtype)
                     return (cell, nxt), cur
                 (cell, cur), toks = jax.lax.scan(body, (cell, cur), None,
                                                  length=segment)
-                pools_out = {k: v for k, v in cell.items() if k != "pos"}
-                return pools_out, cur, jnp.moveaxis(toks, 0, 1)
+                pools_out = {k: cell[k] for k in pools}
+                return (pools_out, cur, jnp.moveaxis(toks, 0, 1),
+                        cell.get("stats", {}))
             fn = obs.roofline.instrument(
                 jax.jit(seg, donate_argnums=(1,)), "serving.segment")
             self._fns[key] = fn
@@ -644,10 +642,11 @@ class PagePool:
             args = (self.params, self.pools, jnp.asarray(prompts),
                     jnp.asarray(lens), jnp.asarray(pages))
         with obs.span("serving.dispatch", program="admit"):
-            self.pools, f = fn(*args)
+            self.pools, f, stats = fn(*args)
             self._note_admit_cost(fn, args)
         with obs.span("serving.fetch", program="admit"):
             f = np.asarray(f)
+            self._note_stats(stats, "admit")
         for slot, _ in miss:
             first[slot] = f[slot]
 
@@ -685,9 +684,8 @@ class PagePool:
             # bytes term), through the ONE registered model
             read = obs.roofline.kernel_cost(
                 "paged_prefill_attention", batch=self.n_slots, pages=nbr,
-                page_block=self.bs, n_heads=self._H, d_head=self._Dh,
-                layers=len(self.model.blocks), kv_dtype=self.kv_dtype,
-                itemsize=self._itemsize) or 0.0
+                page_block=self.bs, layers=len(self.model.blocks),
+                **self._read_geom) or 0.0
             obs.count("kernels.bytes_total", read,
                       kernel="paged_prefill_attention")
         with obs.span("serving.fetch", program="admit_prefix"):
@@ -765,11 +763,13 @@ class PagePool:
             idx = np.asarray(live, np.int64)
             pos = np.zeros((self.n_slots,), np.int32)
             pos[idx] = self.pos[idx].clip(0, self.model.max_len - 1)
+            alive = np.zeros((self.n_slots,), bool)
+            alive[idx] = True
             args = (self.params, self.pools,
                     jnp.asarray(self.tables[:, :nb]), jnp.asarray(pos),
-                    jnp.asarray(self.cur))
+                    jnp.asarray(self.cur), jnp.asarray(alive))
         with obs.span("serving.dispatch", program="segment"):
-            self.pools, cur, toks = fn(*args)
+            self.pools, cur, toks, stats = fn(*args)
             obs.count("decode.dispatches_total", route="serve_segment")
             # the paged read's programs this segment, from the host's own
             # pos (pk.paged_work_list's rule, step by step) against the
@@ -781,15 +781,15 @@ class PagePool:
             obs.count("serving.decode_pages_walked_total", walked)
             obs.count("serving.decode_pages_table_total",
                       calls * self.segment * self.n_slots * nb)
-            # modeled cache-read bytes through the ONE registered model
-            # (ops/pallas_kernels._paged_decode_attention_bytes) — the same
-            # resolution the bench rows and the roofline ledger use
+            # modeled cache-read bytes through the ONE registered model of
+            # the model's own decode read (ops/pallas_kernels
+            # ._paged_decode_attention_bytes / _paged_latent_attention_bytes)
+            # — the same resolution the bench rows and the roofline ledger
+            # use
             read = obs.roofline.kernel_cost(
-                "paged_decode_attention", pages=walked, page_block=self.bs,
-                n_heads=self._H, d_head=self._Dh, kv_dtype=self.kv_dtype,
-                itemsize=self._itemsize) or 0.0
-            obs.count("kernels.bytes_total", read,
-                      kernel="paged_decode_attention")
+                self._read_kernel, pages=walked, page_block=self.bs,
+                **self._read_geom) or 0.0
+            obs.count("kernels.bytes_total", read, kernel=self._read_kernel)
             self.segments_total += 1
             self.read_bytes_total += read
             self.occupancy_num += self.live_tokens(live)
@@ -797,7 +797,17 @@ class PagePool:
             self.pos[idx] += self.segment
         with obs.span("serving.fetch", program="segment"):
             self.cur = np.array(cur)  # writable copy: admit() merges into it
+            self._note_stats(stats, "segment")
             return np.asarray(toks)                   # [slots, segment]
+
+    def _note_stats(self, stats, program: str) -> None:
+        """What a program returned beside its tokens (a model with
+        ``program_stats_zero``; nothing otherwise), fetched here and handed
+        to the model, which counts it and names what the enclosing span
+        should carry (``last_stats``)."""
+        self.last_stats = (self.model.note_program_stats(
+            {k: np.asarray(v) for k, v in stats.items()}, program)
+            if stats else {})
 
     def live_tokens(self, live: Sequence[int]) -> int:
         """Cache rows written across ``live`` slots (occupancy numerator).

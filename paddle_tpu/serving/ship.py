@@ -5,8 +5,9 @@ A prefill worker admits a prompt into its own :class:`~.paged.PagePool`
 contents to a decode worker where the request finishes its life.  This
 module owns the serialization contract both ends agree on:
 
-* :func:`pack` — the slot's per-layer ``k{i}``/``v{i}`` page rows (and the
-  int8 ``*_scale`` planes when the pool is quantized) concatenate into one
+* :func:`pack` — the slot's page rows of every array the model states for
+  its cache (``k{i}``/``v{i}`` and the int8 ``*_scale`` planes for
+  TransformerLM, one latent ``kv{i}`` for DeepseekV3LM) concatenate into one
   payload in sorted-name order, described by a manifest carrying every
   array's name/shape/dtype, the pool geometry (``page_block``,
   ``kv_dtype``), the request state (``plen``, ``first``) and a CRC32 over

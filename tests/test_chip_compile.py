@@ -133,6 +133,48 @@ def test_paged_decode_attention_compiles(S, slots, heads, NB, pages, kv):
             q, pool, pool, tables, pos)
 
 
+@pytest.mark.parametrize("NB", [4, 32], ids=["cache-256", "cache-2048"])
+def test_paged_latent_attention_compiles(S, NB):
+    """The absorbed latent read at the gigachat-ep16 cell's real shapes: 32
+    slots x 64 query heads of width 576 (512 latent + 64 rotary: 4.5 lane
+    tiles) over ONE bf16 row a token, values its first 512; a 1025-page
+    pool of 64-row pages under the narrowest and the widest table."""
+    bf = jnp.bfloat16
+    text = _compile(lambda q, pool, t, pos: pk.paged_latent_attention(
+        q, pool, t, pos, d_value=512, scale=0.145, route="kernel",
+        interpret=False),
+        S((32, 64, 576), bf), S((1025, 64, 576), bf), S((32, NB), jnp.int32),
+        S((32,), jnp.int32))
+    assert "paged_latent_attention" in text
+
+
+@pytest.mark.parametrize("rows, tm, K, N", [
+    (512, 16, 7168, 2048), (512, 16, 2048, 7168),
+    (12288, 256, 7168, 2048), (12288, 256, 2048, 7168)],
+    ids=["decode-gate", "decode-down", "prefill-gate", "prefill-down"])
+def test_expert_grouped_matmul_compiles(S, rows, tm, K, N):
+    """The held experts' grouped products at the cell's real shapes: 16
+    experts of 7168 x 2048 (gate, up) and 2048 x 7168 (down) in bf16; a
+    decode step's 256 pairs in tiles of 16 rows, an admission chunk's 8192
+    in tiles of 256 (parallel/expert_share.py's layouts)."""
+    bf = jnp.bfloat16
+    text = _compile(lambda lhs, rhs, group, n: pk.grouped_matmul(
+        lhs, rhs, group, n, tm=tm, route="kernel", interpret=False),
+        S((rows, K), bf), S((16, K, N), bf), S((rows // tm,), jnp.int32),
+        S((1,), jnp.int32))
+    assert "expert_grouped_matmul" in text
+
+
+def test_flash_attention_compiles_at_latent_head_width(S):
+    """Prefill of the latent-attention model expands k and v and runs the
+    flash kernel at head width 192 (128 + 64 rotary; v is 192 as well): 1.5
+    lane tiles, which GPT-2's 64 never showed the compiler. One prefill
+    chunk of the cell: 8 rows x 512 x 64 heads."""
+    qkv = S((8, 512, 64, 192), jnp.bfloat16)
+    _compile(lambda q, k, v: pk.flash_attention(
+        q, k, v, causal=True, scale=0.145, interpret=False), qkv, qkv, qkv)
+
+
 @pytest.mark.parametrize("cell", ["lstm", "gru"])
 def test_fused_rnn_forward_compiles(S, cell):
     """The flagship recurrent shape: bs 64, T 100, hidden 256."""
