@@ -270,10 +270,6 @@ def main(full: bool = False):
     # warm-vs-cold — TTFT p50 and prefill FLOPs/token vs hit rate
     rows.append(("__import__('benchmarks.serving_prefix', fromlist=['x'])"
                  ".run()", ROW_TIMEOUT))
-    # the autotune rows (ROADMAP item 3): tuned-vs-heuristic plan deltas
-    # for the fused-RNN families + the measured decode-route crossover
-    rows.append(("__import__('benchmarks.autotune_delta', fromlist=['x'])"
-                 ".run()", ROW_TIMEOUT))
     # the fleet-actor row (ROADMAP item 2): kill half the decode pool,
     # count alert windows until the actor restores membership + SLO
     rows.append(("__import__('benchmarks.fleet_autoscale', fromlist=['x'])"

@@ -39,14 +39,6 @@ peak_hbm_bytes_per_sec = roofline.peak_hbm_bytes_per_sec
 _PEAK_TFLOPS = roofline.PEAK_TFLOPS
 
 
-def _plan_source() -> str:
-    """The row's ``plan_source`` stamp (bench-row schema): "tuned" when
-    the process's kernel-plan consults can resolve against a loaded
-    autotune cache, else "heuristic" (paddle_tpu.tune owns the check)."""
-    from paddle_tpu import tune
-    return tune.plan_source()
-
-
 def step_flops(fn, *args, **kwargs) -> Optional[float]:
     """FLOPs of one call of ``fn(*args)`` per XLA cost analysis — None is
     an honest unknown (the failure warned once and was counted, see
@@ -79,13 +71,9 @@ def attach_mfu(result: dict, flops_per_step: Optional[float],
     ``methodology`` defaults to "measured" — attach_mfu's FLOPs come from
     XLA's cost analysis of the real compiled step over a real timing;
     pre-set the key to "modeled" before calling when the FLOPs are a hand
-    projection. ``plan_source`` defaults to
-    ``paddle_tpu.tune.plan_source()`` — "tuned" when an autotune cache
-    with current-hash entries for this device_kind was consultable during
-    the row, "heuristic" otherwise; pre-set the key to pin it."""
+    projection."""
     result.setdefault("mfu", None)
     result.setdefault("methodology", "measured")
-    result.setdefault("plan_source", _plan_source())
     if flops_per_step:
         result["gflops_per_step"] = round(flops_per_step / 1e9, 2)
         peak = peak_flops_per_sec()
@@ -114,7 +102,6 @@ def attach_hbm_bw(result: dict, bytes_per_step: Optional[float],
     bench-row schema requires the field on rows carrying roofline
     columns."""
     result.setdefault("hbm_bw_util", None)
-    result.setdefault("plan_source", _plan_source())
     if methodology is not None:
         result["methodology"] = methodology
     if bytes_per_step:

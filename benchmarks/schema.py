@@ -5,6 +5,6 @@ time."""
 
 from paddle_tpu.analysis.bench_schema import (FAMILY_EXEMPT,  # noqa: F401
                                               FAMILY_REQUIRED,
-                                              METHODOLOGIES, PLAN_SOURCES,
+                                              METHODOLOGIES,
                                               REQUIRED_KEYS, validate_row,
                                               validate_rows)

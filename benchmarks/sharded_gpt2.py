@@ -111,8 +111,7 @@ def _layout_note(mesh, params):
 
 def run(iters: int = 12, repeats: int = 2, batch: int = BATCH,
         seq: int = SEQ):
-    from benchmarks.mfu import (_plan_source, peak_flops_per_sec,
-                                step_flops)
+    from benchmarks.mfu import peak_flops_per_sec, step_flops
     from benchmarks.timing import chained_ms_per_step
 
     mesh, layout, run_n, step_fn, params, state, idss = build(batch, seq)
@@ -129,7 +128,6 @@ def run(iters: int = 12, repeats: int = 2, batch: int = BATCH,
            "vs_baseline": None,
            "mfu": None,           # overwritten below when peak is known
            "methodology": "measured",   # XLA-analyzed FLOPs, real timing
-           "plan_source": _plan_source(),
            "note": note}
     peak = peak_flops_per_sec()
     if flops and peak:

@@ -24,15 +24,13 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .dataflow import (Dataflow, DonationHazard, Effect, FusionGroup,
-                       analyze_dataflow, certificate_matches,
-                       classify_effect, donation_hazards, explain_var,
-                       fusable_groups, region_schedulable)
+from .dataflow import (Dataflow, DonationHazard, Effect, analyze_dataflow,
+                       classify_effect, donation_hazards, explain_var)
 from .diagnostics import (Diagnostic, ProgramVerificationError, Severity,
                           block_paths, errors, format_diagnostics,
                           max_severity, op_site)
-from .lints import (LINT_CATALOGUE, lint_alert_rules, lint_autotune_cache,
-                    lint_catalogue_drift, lint_metric_names, lint_program)
+from .lints import (LINT_CATALOGUE, lint_alert_rules, lint_catalogue_drift,
+                    lint_metric_names, lint_program)
 from .shape_infer import (UNKNOWN, ShapeInferRegistry, infer_program_shapes,
                           register_shape_infer)
 from .verify import verify_program
@@ -42,12 +40,10 @@ __all__ = [
     "errors", "format_diagnostics", "max_severity", "op_site", "block_paths",
     "verify_program", "infer_program_shapes", "register_shape_infer",
     "ShapeInferRegistry", "UNKNOWN", "lint_program", "lint_metric_names",
-    "lint_catalogue_drift", "lint_autotune_cache", "lint_alert_rules",
-    "LINT_CATALOGUE",
-    "Dataflow", "DonationHazard", "Effect", "FusionGroup",
+    "lint_catalogue_drift", "lint_alert_rules", "LINT_CATALOGUE",
+    "Dataflow", "DonationHazard", "Effect",
     "analyze_dataflow", "classify_effect", "donation_hazards",
-    "explain_var", "fusable_groups", "region_schedulable",
-    "certificate_matches",
+    "explain_var",
     "analyze_program", "check_or_raise",
 ]
 

@@ -15,12 +15,9 @@ Family rules key on the metric NAME, which is itself part of the contract
 * every row: ``metric`` (str), ``value`` (number or null), ``unit`` (str),
   ``vs_baseline`` (number or null);
 * ``*_train_*`` rows: ``mfu`` — the roofline campaign's target column
-  (no training row below 15% MFU, ROADMAP item 3) — plus ``plan_source``
-  ("tuned" | "heuristic": did this row's kernel-plan consults resolve
-  against measured autotune winners, ``paddle_tpu.tune.plan_source()``);
+  (no training row below 15% MFU, ROADMAP item 3);
 * ``*_decode_*`` rows: ``hbm_bw_util`` — decode is bytes-bound, so its
-  roofline column is bandwidth, not FLOPs (target >= 0.30) — plus
-  ``plan_source`` as above;
+  roofline column is bandwidth, not FLOPs (target >= 0.30);
 * ``*_serve_*`` rows: ``ttft_p50_ms`` + ``tpot_p50_ms`` — a serving row
   without its SLO pair is throughput theater (time-to-first-token and
   time-per-output-token are what callers experience; PR 8's daemon rows);
@@ -56,8 +53,8 @@ REQUIRED_KEYS = ("metric", "value", "unit", "vs_baseline")
 #: the trajectory (attach_mfu defaults it to "measured"; the decode
 #: rows' hand byte models stamp "modeled")
 FAMILY_REQUIRED = {
-    "_train_": ("mfu", "methodology", "plan_source"),
-    "_decode_": ("hbm_bw_util", "methodology", "plan_source"),
+    "_train_": ("mfu", "methodology"),
+    "_decode_": ("hbm_bw_util", "methodology"),
     "_serve_": ("ttft_p50_ms", "tpot_p50_ms", "methodology"),
     "_prefix_": ("hit_rate",),
     "_route_": ("ttft_p50_ms", "tpot_p50_ms", "n_decode_workers",
@@ -67,14 +64,6 @@ FAMILY_REQUIRED = {
 
 #: the only legal methodology stamps
 METHODOLOGIES = ("measured", "modeled")
-
-#: the only legal plan_source stamps: whether the row's kernel-plan
-#: consults could resolve against MEASURED autotune winners
-#: (paddle_tpu.tune.plan_source()) or the built-in heuristics owned every
-#: plan — required on the _train_/_decode_ families so tuned-vs-heuristic
-#: deltas are machine-checkable across BENCH files
-#: (benchmarks/autotune_delta.py emits the paired rows)
-PLAN_SOURCES = ("tuned", "heuristic")
 
 #: substrings exempting a row from family rules (comparative/meta rows
 #: that are not themselves roofline measurements)
@@ -99,9 +88,6 @@ def validate_row(row) -> List[str]:
     if "methodology" in row and row["methodology"] not in METHODOLOGIES:
         problems.append(f"'methodology' must be one of {METHODOLOGIES}, "
                         f"got {row['methodology']!r}")
-    if "plan_source" in row and row["plan_source"] not in PLAN_SOURCES:
-        problems.append(f"'plan_source' must be one of {PLAN_SOURCES}, "
-                        f"got {row['plan_source']!r}")
     if isinstance(metric, str) and not any(t in metric
                                            for t in FAMILY_EXEMPT):
         for tag, extra in FAMILY_REQUIRED.items():

@@ -3,9 +3,9 @@ effects.
 
 The structural verifier (verify.py) answers "is this desc well-formed"; this
 module answers "who defines what, who reads it, and what may alias what" —
-the dependency facts a fusion/layout pass (ROADMAP item 3(c)) and the
-executor's donation fast path need to be *provably* safe rather than
-dynamically lucky.  It is pure desc-level analysis: no jax import, no trace.
+the dependency facts the executor's donation fast path needs to be
+*provably* safe rather than dynamically lucky.  It is pure desc-level
+analysis: no jax import, no trace.
 
 Model
 -----
@@ -44,10 +44,6 @@ Consumers
   donated persistable ``p``, no Use may read a Def rooted at ``p``'s entry
   value after ``p``'s first overwrite (or share a loop with one — loops
   re-execute).  Backs lint **L011** and the executor's donate downgrade.
-- :func:`fusable_groups` — the fusion-legality oracle: elementwise chains
-  and single-consumer producer→consumer pairs in the global block, each
-  with a dependence certificate (every internal edge's def/use site and
-  consumer count).  Backs the ROADMAP 3(c) pass.
 - :func:`explain_var` — the ``lint --explain`` chain text
   ("defined at block B, op #I; last read at block B', op #J").
 - lints **L010** (dead write across blocks) and **L012** (alias escape from
@@ -58,7 +54,7 @@ from __future__ import annotations
 
 import enum
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Set, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Set, Tuple
 
 from .diagnostics import block_paths, op_site
 from .verify import (BLOCK_ATTR_KEYS, _ATTR_BIND_KEYS, _ATTR_DEFINE_KEYS,
@@ -91,18 +87,6 @@ SIDE_EFFECT_OPS = frozenset(("gaussian_random", "uniform_random", "dropout",
 #: buffer; in the traced semantics they share the jax value.
 VIEW_OPS = frozenset(("assign", "reshape", "squeeze", "unsqueeze",
                       "seq_reshape", "lod_reset"))
-
-#: elementwise value functions: one output element per input element, no
-#: cross-element reads — the always-fusable set (TVM's injective class)
-ELEMENTWISE_OPS = frozenset((
-    "elementwise_add", "elementwise_sub", "elementwise_mul",
-    "elementwise_div", "scale", "cast", "clip", "sign", "minus", "pow",
-    "power", "logical_not", "slope_intercept", "fill_zeros_like",
-    "sigmoid", "tanh", "relu", "gelu", "leaky_relu", "elu", "softsign",
-    "square", "sqrt", "abs_act", "exponential", "brelu", "soft_shrink",
-    "hard_shrink", "thresholded_relu", "stanh", "softrelu", "hard_sigmoid",
-    "swish", "reciprocal", "log",
-))
 
 #: attr keys naming sub-block results the executor reads when lowering a
 #: control op (lints._EXTRA_READ_KEYS minus the keys verify already owns)
@@ -210,27 +194,6 @@ class DonationHazard:
         return (f"donated persistable '{self.name}' (defined on entry) is "
                 f"overwritten at {ow} but its pre-update value may still be "
                 f"read at {reads}")
-
-
-@dataclass
-class FusionGroup:
-    """One legality-certified fusion candidate in the global block.
-
-    ``edges`` is the dependence certificate the 3(c) pass consumes: every
-    intra-group producer→consumer edge with its def site, use site, and
-    consumer count (always 1 — the single-consumer proof)."""
-
-    kind: str                   # "elementwise_chain" | "producer_consumer"
-    block_idx: int
-    op_idxs: List[int]
-    inputs: List[str]
-    outputs: List[str]
-    edges: List[dict]
-
-    def to_dict(self) -> dict:
-        return {"kind": self.kind, "block_idx": self.block_idx,
-                "op_idxs": list(self.op_idxs), "inputs": list(self.inputs),
-                "outputs": list(self.outputs), "edges": list(self.edges)}
 
 
 # --------------------------------------------------------------------------
@@ -544,178 +507,7 @@ def donation_hazards(program, feed: Iterable[str] = (),
 
 
 # --------------------------------------------------------------------------
-# consumer 2: fusion-legality oracle
-# --------------------------------------------------------------------------
-
-def _single_consumer_edges(df: Dataflow, block) -> Dict[tuple, dict]:
-    """(producer op idx, consumer op idx, name) -> certificate dict for
-    every global-block edge that is provably single-consumer: the value is
-    produced by exactly one reaching Def, read at exactly one op site, and
-    escapes nowhere (not fetched, not persistable, not read from another
-    block, not live-out as a data var)."""
-    edges: Dict[tuple, dict] = {}
-    for d in df.defs:
-        if d.kind != "op" or d.block_idx != block.idx:
-            continue
-        v = block.vars.get(d.name)
-        if v is not None and (v.persistable or v.is_data):
-            continue
-        if d.name in df.fetch:
-            continue
-        sites = {(u.block_idx, u.op_idx) for u in d.uses}
-        if len(sites) != 1:
-            continue
-        (ub, uo), = sites
-        if ub != block.idx:
-            continue
-        use = next(u for u in d.uses if u.op_idx == uo)
-        if use.defs != {d}:
-            continue          # the consumer may read a different Def too
-        edges[(d.op_idx, uo, d.name)] = {
-            "var": d.name, "def": df.site(d), "use": df.site(use),
-            "n_consumers": 1}
-    return edges
-
-
-def fusable_groups(program, fetch: Iterable[str] = (),
-                   feed: Iterable[str] = (),
-                   df: Optional[Dataflow] = None) -> List[FusionGroup]:
-    """The fusion-legality oracle over the global block.
-
-    Emits two group kinds, each carrying a dependence certificate:
-
-    - ``elementwise_chain`` — maximal components of pure elementwise ops
-      linked by single-consumer intermediates.  Always legal to fuse: the
-      composition is a pure per-element function of the group inputs.
-    - ``producer_consumer`` — a pure non-elementwise producer (matmul,
-      conv, reduce) whose single consumer is a pure elementwise op: the
-      epilogue-fusion shape (TVM's complex-out-fusable class).
-
-    A value read by two ops is *never* inside a group (the shared-consumer
-    rejection): fusing one consumer would either recompute the producer or
-    force a materialization — exactly the cases the 3(c) pass must prove
-    about, so the oracle refuses to certify them.  Groups only ever
-    contain ``pure`` ops: in-place, side-effecting, and control ops have
-    ordering obligations a fused region cannot honor."""
-    if df is None:
-        df = analyze_dataflow(program, feed=feed, fetch=fetch)
-    block = program.blocks[0]
-    eff = df.effects
-    ops = block.ops
-
-    def pure(i):
-        return eff.get((block.idx, i)) == Effect.PURE
-
-    def ew(i):
-        return pure(i) and ops[i].type in ELEMENTWISE_OPS
-
-    edges = _single_consumer_edges(df, block)
-
-    # union-find over elementwise ops linked by single-consumer edges
-    parent = list(range(len(ops)))
-
-    def find(i):
-        while parent[i] != i:
-            parent[i] = parent[parent[i]]
-            i = parent[i]
-        return i
-
-    def union(i, j):
-        ri, rj = find(i), find(j)
-        if ri != rj:
-            parent[rj] = ri
-
-    for (i, j, _name) in edges:
-        if ew(i) and ew(j):
-            union(i, j)
-    comps: Dict[int, List[int]] = {}
-    for i in range(len(ops)):
-        if ew(i):
-            comps.setdefault(find(i), []).append(i)
-
-    groups: List[FusionGroup] = []
-    chained: Set[int] = set()
-    for comp in comps.values():
-        if len(comp) < 2:
-            continue
-        comp = sorted(comp)
-        chained.update(comp)
-        groups.append(_certify(df, block, comp, "elementwise_chain", edges))
-
-    # producer -> consumer epilogues: pure non-elementwise producer whose
-    # sole consumer is an elementwise op not already inside a chain
-    for (i, j, name) in sorted(edges):
-        if pure(i) and not ew(i) and ew(j) and j not in chained:
-            groups.append(_certify(df, block, [i, j], "producer_consumer",
-                                   edges))
-    groups.sort(key=lambda g: g.op_idxs[0])
-    return groups
-
-
-def _certify(df: Dataflow, block, comp: List[int], kind: str,
-             edges: Dict[tuple, dict]) -> FusionGroup:
-    inside = set(comp)
-    cert = [c for (i, j, _n), c in sorted(edges.items())
-            if i in inside and j in inside]
-    internal = {c["var"] for c in cert}
-    inputs: List[str] = []
-    for i in comp:
-        for n in block.ops[i].input_vars():
-            if n not in internal and n not in inputs:
-                inputs.append(n)
-    outputs: List[str] = []
-    for i in comp:
-        for n in block.ops[i].output_vars():
-            if n not in internal and n not in outputs:
-                outputs.append(n)
-    return FusionGroup(kind, block.idx, sorted(comp), inputs, outputs, cert)
-
-
-def region_schedulable(block, group: FusionGroup) -> bool:
-    """Can ``group`` legally execute as ONE region at its first member's
-    position?  The dependence certificate proves the intra-group edges;
-    this proves the *rewrite*: hoisting every member up to the first
-    member's slot must not cross a non-member op that (re)defines a group
-    input or touches a group output name.  Conservative — a False here
-    forgoes a fusion, never risks one (the executor counts it as
-    ``reason="not_schedulable"``)."""
-    s, e = group.op_idxs[0], group.op_idxs[-1]
-    members = set(group.op_idxs)
-    ins, outs = set(group.inputs), set(group.outputs)
-    for k in range(s + 1, e):
-        if k in members:
-            continue
-        op = block.ops[k]
-        if set(op.output_vars()) & (ins | outs):
-            return False
-        if set(op.input_vars()) & outs:
-            return False
-    return True
-
-
-def certificate_matches(cert: dict, group: FusionGroup,
-                        op_types: Sequence[str]) -> bool:
-    """Does a *persisted* certificate (an autotune-cache ``fusion`` entry)
-    still describe ``group`` as the oracle certifies it TODAY?  Exact
-    match on kind, member indices, member op types, boundary vars, and
-    edge vars — any drift means the entry was measured on a different
-    graph and is refused at consult time (and flagged by L008)."""
-    if not isinstance(cert, dict):
-        return False
-    try:
-        return (cert.get("kind") == group.kind
-                and list(cert.get("op_idxs") or []) == list(group.op_idxs)
-                and list(cert.get("op_types") or []) == list(op_types)
-                and list(cert.get("inputs") or []) == list(group.inputs)
-                and list(cert.get("outputs") or []) == list(group.outputs)
-                and [e.get("var") for e in (cert.get("edges") or [])]
-                == [e["var"] for e in group.edges])
-    except (TypeError, AttributeError):
-        return False
-
-
-# --------------------------------------------------------------------------
-# consumer 4: --explain chains
+# consumer 2: --explain chains
 # --------------------------------------------------------------------------
 
 def explain_var(df: Dataflow, name: str) -> Optional[str]:

@@ -410,19 +410,12 @@ def cmd_lint(args):
     data."""
     from . import analysis, fluid
     if args.bench_rows and args.config is None:
-        rc = _lint_bench_rows(args.bench_rows,
-                              as_json=args.json or
-                              getattr(args, "format", "text") == "json")
-        if getattr(args, "autotune_cache", None):
-            rc = max(rc, _lint_autotune_only(args))
-        return rc
-    if args.config is None and getattr(args, "autotune_cache", None):
-        # autotune staleness can lint standalone — CI checks the cache
-        # file without needing a model config on hand
-        return _lint_autotune_only(args)
+        return _lint_bench_rows(args.bench_rows,
+                                as_json=args.json or
+                                getattr(args, "format", "text") == "json")
     if args.config is None:
-        print("lint: --config is required (or pass --bench-rows and/or "
-              "--autotune-cache alone)", file=sys.stderr)
+        print("lint: --config is required (or pass --bench-rows alone)",
+              file=sys.stderr)
         return 2
     try:
         cfg = _load_config(args.config)
@@ -474,11 +467,6 @@ def cmd_lint(args):
     # both directions (an undeclared emit or an orphaned entry fails CI)
     for d in analysis.lint_catalogue_drift():
         d.program = "obs"
-        all_diags.append(d)
-    # L008: autotune-cache staleness — stale entries silently fall back
-    # to heuristics at consult time; the lint is where that surfaces
-    for d in analysis.lint_autotune_cache(args.autotune_cache):
-        d.program = "autotune"
         all_diags.append(d)
     # L009: the shipped alert rules must reference catalogued metrics —
     # a rule naming a typo'd metric silently never fires
@@ -544,194 +532,6 @@ def _lint_json_payload(diags, n_err: int, n_warn: int) -> dict:
                     "info": len(diags) - n_err - n_warn,
                     "total": len(diags)},
     }
-
-
-def _lint_autotune_only(args) -> int:
-    """The config-less `lint --autotune-cache FILE` path: L008 findings
-    only. 0 clean, 1 findings at or above --fail-on."""
-    from . import analysis
-    diags = analysis.lint_autotune_cache(args.autotune_cache)
-    threshold = {"error": analysis.Severity.ERROR,
-                 "warning": analysis.Severity.WARNING,
-                 "info": analysis.Severity.INFO}[args.fail_on]
-    if args.json:
-        print(json.dumps([d.to_dict() for d in diags], indent=1))
-    elif diags:
-        print(analysis.format_diagnostics(diags))
-    print(f"lint: autotune cache — {len(diags)} finding(s)",
-          file=sys.stderr if args.json else sys.stdout)
-    return 1 if any(d.severity >= threshold for d in diags) else 0
-
-
-def cmd_tune(args):
-    """Measured autotuning (ROADMAP item 3): enumerate candidate plans per
-    (kernel, shape family, device_kind), measure each on the CURRENT
-    backend through the roofline-plane timing discipline (warmup outside
-    the window, best-of-reps, methodology="measured"), and persist
-    winners in the versioned autotune cache the routing entries consult
-    (ops/rnn.py fused plans, ops/pallas_kernels.py decode routing,
-    serving paged block size). Off-TPU the sweep runs the same kernels
-    through the Pallas interpreter at proxy dims — the whole loop is
-    CI-exercisable; an on-chip run only changes the numbers.
-
-    ``--check`` is the CI smoke: a seconds-long sweep into --cache (or a
-    temp file), then proof the loop closes — the written entries reload
-    and the consult functions resolve them. Exit 0 healthy, 1 broken."""
-    import os
-    import tempfile
-
-    from . import tune
-    spaces = (tuple(s for s in args.spaces.split(",") if s)
-              if args.spaces else None)
-    profile = args.profile
-    cache_path = args.cache
-    if args.check:
-        if args.dry_run:
-            # --check's whole point is proving the written cache reloads
-            # and consults; with nothing written there is nothing to check
-            print("tune: --check writes a cache to verify the loop; drop "
-                  "--dry-run (or point --cache at a scratch file)",
-                  file=sys.stderr)
-            return 2
-        profile = profile or "smoke"
-        if cache_path is None:
-            cache_path = os.path.join(tempfile.mkdtemp(prefix="pt_tune_"),
-                                      "autotune.json")
-    try:
-        report = tune.run_tune(spaces=spaces, profile=profile,
-                               cache_path=cache_path, reps=args.reps,
-                               save=not args.dry_run,
-                               from_ledger=args.from_ledger,
-                               ledger_topk=args.ledger_topk)
-    except (OSError, ValueError, KeyError) as e:
-        print(f"tune: {e}", file=sys.stderr)
-        return 2
-    if not args.json and report.get("ledger"):
-        led = report["ledger"]
-        names = [s["op"] for s in led["sites"] if s.get("space")]
-        print(f"tune: ledger {led['path']}: top-{led['topk']} sites "
-              f"implicate spaces {led['seeded_spaces'] or 'none'} "
-              f"(hot ops: {names[:4] or 'no matches'}); sweeping "
-              f"{led['swept_spaces']}")
-    if args.json:
-        print(json.dumps(report, indent=1))
-    elif args.markdown:
-        print(tune.results_markdown(report))
-    else:
-        for r in report["results"]:
-            if r.get("plan") is None and "skipped" in r:
-                print(f"tune: {r['space']}/{r['kernel']} {r['family']}: "
-                      f"{r['skipped']}")
-                continue
-            extra = ""
-            if r.get("speedup") is not None:
-                extra = (f"  ({r['tuned_ms']} ms vs heuristic "
-                         f"{r['heuristic_ms']} ms, {r['speedup']}x)")
-            print(f"tune: {r['space']}/{r['kernel']} {r['family']}: "
-                  f"plan {r['plan']}{extra}")
-        print(f"tune: device_kind={report['device_kind']} "
-              f"backend={report['backend']} profile={report['profile']}"
-              + (f" -> {report['cache_path']}" if report["cache_path"]
-                 else " (dry run, nothing written)"))
-    if not args.check:
-        return 0
-    # --check: prove the loop closes — reload the file, then consult it
-    # through the SAME entry points the routers use
-    problems = []
-    path = report["cache_path"]
-    try:
-        cache = tune.load_cache(path)
-    except (OSError, ValueError) as e:
-        print(f"tune: --check FAILED: written cache does not reload: {e}",
-              file=sys.stderr)
-        return 1
-    prev_env = os.environ.get(tune.CACHE_ENV)
-    os.environ[tune.CACHE_ENV] = path
-    tune.reset()
-    try:
-        for r in report["results"]:
-            if r.get("plan") is None and "skipped" in r:
-                continue
-            if cache.get(r["space"], r["kernel"], report["device_kind"],
-                         r["family"]) is None:
-                problems.append(f"{r['space']}/{r['family']}: entry "
-                                "missing after reload")
-            if r["space"] == "fused_rnn":
-                fam = next(
-                    f for f in tune.PROFILES[report["profile"]]
-                    ["fused_families"]
-                    if f["kernel"] == r["kernel"]
-                    and tune.fused_family(gates=f["gates"], T=f["T"],
-                                          H=f["H"], batch=f["batch"])
-                    == r["family"])
-                got = tune.fused_plan(
-                    r["kernel"], T=fam["T"], H=fam["H"],
-                    gates=fam["gates"],
-                    seq_h_units=fam.get("seq_h_units",
-                                        fam["gates"] + 1),
-                    batch=fam["batch"])
-                if got != tuple(r["plan"]):
-                    problems.append(f"fused_rnn/{r['family']}: consult "
-                                    f"returned {got}, tuned {r['plan']}")
-            elif r["space"] == "decode_route":
-                if tune.decode_kernel_min_len() is tune.MISS:
-                    problems.append("decode_route: consult missed the "
-                                    "tuned entry")
-            elif r["space"] == "page_block":
-                bs = r["plan"]["page_block"]
-                if tune.page_block(bs * 8, bs * 4) != bs:
-                    problems.append("page_block: consult missed the "
-                                    "tuned entry")
-            elif r["space"] == "bucket_grid":
-                got = tune.bucket_grid(r["family"])
-                if got != tuple(r["plan"]["buckets"]):
-                    problems.append(f"bucket_grid/{r['family']}: consult "
-                                    f"returned {got}, tuned "
-                                    f"{r['plan']['buckets']}")
-        # fusion: rebuild the proxy program the sweep measured and prove
-        # plan_for resolves every persisted family through the full
-        # consult chain (cert re-validation included) — a winner must
-        # activate, a measured loser must refuse with measured_slower
-        fusion_rows = [r for r in report["results"]
-                       if r["space"] == "fusion" and r.get("plan")]
-        if fusion_rows:
-            from .tune import fusion as _fusion
-            fcfg = tune.PROFILES[report["profile"]]["fusion"]
-            main, _startup, feed, fetch = _fusion.build_proxy_program(
-                batch=fcfg["batch"], width=fcfg["width"],
-                depth=fcfg["depth"])
-            plan = _fusion.plan_for(
-                main, {k: v.shape for k, v in feed.items()},
-                fetch=fetch, feed=list(feed))
-            refused = dict(plan.rejected)
-            for r in fusion_rows:
-                fam = r["family"]
-                if r["plan"]["fuse"]:
-                    if fam not in plan.families:
-                        problems.append(
-                            f"fusion/{fam}: measured winner did not "
-                            f"activate (rejected: "
-                            f"{refused.get(fam, 'missing')})")
-                elif refused.get(fam) != "measured_slower":
-                    problems.append(
-                        f"fusion/{fam}: measured loser should refuse "
-                        f"with measured_slower, got "
-                        f"{refused.get(fam, 'activated')}")
-        if tune.plan_source() != "tuned":
-            problems.append("plan_source() != 'tuned' with a fresh cache")
-    finally:
-        if prev_env is None:
-            os.environ.pop(tune.CACHE_ENV, None)
-        else:
-            os.environ[tune.CACHE_ENV] = prev_env
-        tune.reset()
-    if problems:
-        for p in problems:
-            print(f"tune: --check FAILED: {p}", file=sys.stderr)
-        return 1
-    print(f"tune: --check OK ({len(report['results'])} plan-space "
-          f"sweeps measured, persisted, reloaded, and consulted)")
-    return 0
 
 
 def _lint_bench_rows(paths, as_json: bool = False, stream=None) -> int:
@@ -2018,59 +1818,7 @@ def main(argv=None) -> int:
                     help="comma-separated valid sharding axis names "
                          "(default: parallel.mesh.CANONICAL_ORDER, with "
                          "unknown axes reported as warnings)")
-    lt.add_argument("--autotune-cache", default=None, dest="autotune_cache",
-                    metavar="FILE",
-                    help="autotune cache to check for staleness (L008; "
-                         "default: $PADDLE_TPU_AUTOTUNE_CACHE / "
-                         "~/.paddle_tpu/autotune.json — a missing file "
-                         "is clean). Works standalone without --config.")
     lt.set_defaults(fn=cmd_lint)
-
-    tu = sub.add_parser("tune", help="measure candidate kernel plans "
-                                     "(fused-RNN tiles, decode routing, "
-                                     "paged block size, graph fusion, "
-                                     "serving bucket grids) and persist "
-                                     "winners in the autotune cache the "
-                                     "routers consult")
-    tu.add_argument("--spaces", default=None,
-                    help="comma-separated plan spaces (default: all of "
-                         "bucket_grid,decode_route,fused_rnn,fusion,"
-                         "page_block)")
-    tu.add_argument("--from-ledger", default=None, dest="from_ledger",
-                    metavar="FILE",
-                    help="seed the sweep from a profile ledger (xplane "
-                         ".pb or JSON op rows): the hottest op sites "
-                         "pick which plan spaces get swept — tuning "
-                         "effort follows the measured time (an explicit "
-                         "--spaces list overrides the seeding)")
-    tu.add_argument("--ledger-topk", type=int, default=8,
-                    dest="ledger_topk", metavar="N",
-                    help="how many top self-time op sites seed the "
-                         "sweep (default 8)")
-    tu.add_argument("--profile", choices=["smoke", "cpu", "bench"],
-                    default=None,
-                    help="measurement profile (default: bench on TPU, "
-                         "cpu elsewhere; --check defaults to smoke)")
-    tu.add_argument("--cache", default=None, metavar="FILE",
-                    help="cache file to merge winners into (default: "
-                         "$PADDLE_TPU_AUTOTUNE_CACHE / "
-                         "~/.paddle_tpu/autotune.json)")
-    tu.add_argument("--reps", type=int, default=None,
-                    help="timing repetitions per candidate (default: "
-                         "the profile's)")
-    tu.add_argument("--check", action="store_true",
-                    help="CI smoke: tiny sweep, then verify the written "
-                         "cache reloads and the routing consults resolve "
-                         "it (exit 1 on any break)")
-    tu.add_argument("--dry-run", action="store_true", dest="dry_run",
-                    help="measure and report, write nothing")
-    tu.add_argument("--markdown", action="store_true",
-                    help="print the winners as the markdown crossover "
-                         "table docs/design/kernels.md embeds")
-    tu.add_argument("--json", action="store_true",
-                    help="print the full report (sweeps included) as "
-                         "JSON")
-    tu.set_defaults(fn=cmd_tune)
 
     mm = sub.add_parser("merge_model")
     common(mm)
@@ -2247,9 +1995,9 @@ def main(argv=None) -> int:
     sv.add_argument("--seed", type=int, default=0)
     sv.add_argument("--slots", type=int, default=8)
     sv.add_argument("--segment", type=int, default=32)
-    sv.add_argument("--page_block", type=int, default=None,
-                    help="KV page size; default consults the autotune "
-                         "cache (paddle_tpu tune) and falls back to 64")
+    sv.add_argument("--page_block", type=int, default=64,
+                    help="KV page size in tokens; must divide --max_len "
+                         "and --cache_bucket")
     sv.add_argument("--pages", type=int, default=None,
                     help="pool pages incl. the null page (default: worst "
                     "case slots*max_len/page_block + 1)")

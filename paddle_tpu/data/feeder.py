@@ -64,7 +64,6 @@ class BucketSpec:
 
         BucketSpec({"words": (32, 64, 128)})                  # axis inferred
         BucketSpec({"words": {"axis": 2, "buckets": (8, 16)}})  # pinned axis
-        BucketSpec({"words": "tuned"})          # tune.bucket_grid("prompt")
 
     A feed axis is padded up to the next listed bucket (falling back to the
     next power of two past the largest), the true length is fed alongside
@@ -85,12 +84,6 @@ class BucketSpec:
                 buckets = v.get("buckets", ())
             else:
                 buckets = v
-            if buckets == "tuned":
-                # the measured ``bucket_grid`` winner (validated by the
-                # consult); without one, the serving-default grid
-                from .. import tune
-                buckets = (tune.bucket_grid("prompt")
-                           or (32, 64, 128, 256, 512))
             self.spec[name] = (axis, tuple(sorted(int(b) for b in buckets)))
 
     def names(self):

@@ -105,55 +105,8 @@ class TraceContext:
         self.entry_env = entry_env
 
 
-class _FusedRegion:
-    """One activated fusion group, prepared for tracing: the member ops in
-    ascending (= topological, straight-line SSA) order, the certificate's
-    boundary vars, and the single named-scope tag the whole region lowers
-    under — the profiler then attributes the region as ONE op site
-    (``block B, op #first (fused_<kind>)``) instead of N."""
-
-    __slots__ = ("start", "member_idxs", "ops", "inputs", "outputs", "tag")
-
-    def __init__(self, group, block: Block):
-        self.start = group.op_idxs[0]
-        self.member_idxs = frozenset(group.op_idxs)
-        self.ops = [(i, block.ops[i]) for i in group.op_idxs]
-        self.inputs = tuple(group.inputs)
-        self.outputs = tuple(group.outputs)
-        self.tag = (f"b{group.block_idx}_op{self.start}_fused_"
-                    f"{_SCOPE_SAFE.sub('_', group.kind)}")
-
-
-def _trace_fused_region(region: _FusedRegion, env: Dict[str, Any]):
-    """Trace one certified group as a single dispatch region: all member
-    computes under ONE named scope, intermediates confined to a region-
-    local env (the single-consumer certificate guarantees nothing outside
-    reads them), only the certificate's outputs exported."""
-    sub: Dict[str, Any] = {n: env[n] for n in region.inputs if n in env}
-    with jax.named_scope(region.tag):
-        for _idx, op in region.ops:
-            compute = OpRegistry.get(op.type)
-            ins = {k: [sub[n] if n in sub else env[n] for n in vs]
-                   for k, vs in op.inputs.items()}
-            outs = compute(ins, op.attrs)
-            for k, names in op.outputs.items():
-                for n, v in zip(names, outs[k]):
-                    sub[n] = v
-    for n in region.outputs:
-        if n in sub:
-            env[n] = sub[n]
-
-
-def _trace_ops(ops, env: Dict[str, Any], ctx: TraceContext,
-               fused: Optional[Dict[int, _FusedRegion]] = None):
+def _trace_ops(ops, env: Dict[str, Any], ctx: TraceContext):
     """Symbolically run an op list over env (name -> traced array).
-
-    ``fused`` (global-block traces only) maps member op indices to their
-    activated :class:`_FusedRegion`: the whole region traces at its first
-    member's slot, later members are skipped.  Sub-block and autodiff-
-    replay traces never pass it (their local op indices would collide),
-    so a replayed forward re-traces unfused — same ops, same order, same
-    values; XLA CSE merges the two as usual.
 
     A failing op re-raises with the op's position, type, and io names plus
     the chain of ops leading up to it — the fluid-level analog of the
@@ -162,11 +115,6 @@ def _trace_ops(ops, env: Dict[str, Any], ctx: TraceContext,
     """
     for idx, op in enumerate(ops):
         try:
-            if fused is not None and idx in fused:
-                region = fused[idx]
-                if idx == region.start:
-                    _trace_fused_region(region, env)
-                continue
             if op.type == "autodiff_grad":
                 _trace_autodiff(op, ops, env, ctx)
                 continue
@@ -533,21 +481,10 @@ class Executor:
                  buckets: Optional[Any] = None,
                  mesh: Optional[Any] = None,
                  layout: Optional[Any] = None,
-                 fuse: Optional[Any] = None,
                  cache_capacity: int = DEFAULT_CACHE_CAPACITY):
         self.place = place
         self.scope = scope if scope is not None else global_scope()
         self.donate = donate
-        # graph fusion over certified groups (tune/fusion.py, ROADMAP 3c):
-        # None = MEASURED-ONLY (consult the autotune cache's `fusion`
-        # space; no entries for this device -> run unfused, zero analysis
-        # cost), False = off, True = force-fuse every schedulable
-        # certified group, a set of first-op indices = force exactly those
-        # groups (the measurement harness's per-group knob). Forcing can
-        # cost speed, never correctness: certification + schedulability
-        # still gate every region.
-        self.fuse = fuse
-        self._fusion_memo: Dict[Tuple, Any] = {}
         if mesh is None:
             from ..parallel.mesh import current_mesh
             mesh = current_mesh()
@@ -898,11 +835,9 @@ class Executor:
         else:
             mesh_key = None
 
-        fusion_plan = self._fusion_plan(program, block, feed, fetch_names)
         bflag = "true" if bucketed else "false"
         key = (program._serial, program.version, block.idx, tuple(fetch_names),
                tuple(persist_in), bool(donate), mesh_key,
-               fusion_plan.key() if fusion_plan is not None else None,
                tuple((k, v.shape, str(v.dtype),
                       bool(getattr(v, "weak_type", False)))
                      for k, v in sorted(feed.items())))
@@ -925,7 +860,7 @@ class Executor:
                 self._miss_streaks[churn_key] = streak
                 self._maybe_warn_churn(streak)
             fn = self._build(program, block, list(feed), kept_in, donated_in,
-                             fetch_names, written, shardings, fusion_plan)
+                             fetch_names, written, shardings)
             if use_cache:
                 self._cache[key] = fn
                 while len(self._cache) > self.cache_capacity:
@@ -980,43 +915,6 @@ class Executor:
         return list(fetches)
 
     # ------------------------------------------------------------------
-    def _fusion_plan(self, program, block, feed, fetch_names):
-        """The (memoized) fusion decision for this run's compile key.
-
-        The measured-only default costs nothing until an autotune cache
-        with ``fusion`` entries for this device is active: without one,
-        every certified group's answer is already known to be "unfused",
-        so the dataflow analysis is skipped entirely. Plans memoize per
-        (program version, fetch, feed shapes, fuse mode) — the counters
-        inside ``plan_for`` therefore count plan DECISIONS, not runs."""
-        if self.fuse is False or block.idx != 0:
-            return None
-        from ..tune import fusion as _fusion
-        if self.fuse is None and not _fusion.cache_has_fusion_entries():
-            return None
-        mode = (True if self.fuse is True else
-                tuple(sorted(self.fuse)) if self.fuse is not None else None)
-        ctoken = None
-        if self.fuse is None:
-            # consults must not survive a cache swap: the active cache's
-            # identity + entry count ride the memo key
-            from ..tune.cache import get_cache
-            c = get_cache()
-            ctoken = (id(c), len(c.entries) if c is not None else 0)
-        fkey = (program._serial, program.version, tuple(fetch_names), mode,
-                ctoken,
-                tuple((k, v.shape) for k, v in sorted(feed.items())))
-        plan = self._fusion_memo.get(fkey)
-        if plan is None:
-            plan = _fusion.plan_for(
-                program, {k: v.shape for k, v in feed.items()},
-                fetch=fetch_names, feed=list(feed), force=self.fuse)
-            if len(self._fusion_memo) > 256:     # unbounded-churn cap
-                self._fusion_memo.clear()
-            self._fusion_memo[fkey] = plan
-        return plan if plan.groups else None
-
-    # ------------------------------------------------------------------
     @staticmethod
     def _written_vars(program: Program, block: Block) -> List[str]:
         out: List[str] = []
@@ -1031,16 +929,8 @@ class Executor:
 
     # ------------------------------------------------------------------
     def _build(self, program: Program, block: Block, feed_names, kept_in,
-               donated_in, fetch_names, written, shardings=None,
-               fusion_plan=None):
+               donated_in, fetch_names, written, shardings=None):
         has_host_ops = any(op.type == "fill_init" for op in block.ops)
-        fused: Optional[Dict[int, _FusedRegion]] = None
-        if fusion_plan is not None and fusion_plan.groups and not has_host_ops:
-            fused = {}
-            for g in fusion_plan.groups:
-                region = _FusedRegion(g, block)
-                for i in g.op_idxs:
-                    fused[i] = region
 
         def raw(feed: Dict[str, Any], kept_vals: List[Any],
                 donated_vals: List[Any]):
@@ -1049,7 +939,7 @@ class Executor:
             env.update(dict(zip(kept_in, kept_vals)))
             env.update(dict(zip(donated_in, donated_vals)))
             ctx = TraceContext(program, dict(env))
-            _trace_ops(block.ops, env, ctx, fused)
+            _trace_ops(block.ops, env, ctx)
             fetches = [env[n] for n in fetch_names]
             new_persist = [env.get(n) for n in written]
             return fetches, new_persist

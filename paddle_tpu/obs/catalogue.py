@@ -99,21 +99,6 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
     "fluid.param_bytes_global": ("gauge", "total persistable bytes the "
                                           "mesh executor holds (the "
                                           "replicated footprint)"),
-    "fluid.fused_regions_total": ("counter", "certified fusion groups "
-                                             "activated into single fused "
-                                             "dispatch regions (counted "
-                                             "per plan decision, not per "
-                                             "run), labels: source (tuned "
-                                             "| forced)", ("source",)),
-    "fluid.fusion_rejected_total": ("counter", "certified fusion groups "
-                                               "REFUSED by the measured-"
-                                               "only consult chain "
-                                               "(tune/fusion.py), labels: "
-                                               "reason (no_entry | stale | "
-                                               "invalid_plan | cert_invalid"
-                                               " | measured_slower | "
-                                               "not_schedulable)",
-                                   ("reason",)),
     "fluid.run_seconds": ("histogram", "whole Executor.run duration"),
     "fluid.verify_seconds": ("histogram", "static pre-flight "
                                           "(analysis.check_or_raise)"),
@@ -539,17 +524,6 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
     "router.workers": ("gauge", "serving workers live in the router's "
                                 "membership table, labels: role (decode "
                                 "| prefill)", ("role",)),
-    # -- tune: tune/driver.py (`paddle_tpu tune`) -----------------------
-    "tune.measurements_total": ("counter", "candidate-plan timings taken "
-                                           "by the autotune driver (one "
-                                           "per timed dispatch), labels: "
-                                           "space",
-                                ("space",)),
-    "tune.ledger_seeded_families_total": ("counter",
-                                          "plan families swept because a "
-                                          "profile ledger implicated "
-                                          "their space (`paddle_tpu tune "
-                                          "--from-ledger`)"),
     # -- trainer: trainer/trainer.py ------------------------------------
     "trainer.steps_total": ("counter", "train batches executed"),
     "trainer.examples_total": ("counter", "samples consumed (leading dim "

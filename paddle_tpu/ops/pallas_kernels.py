@@ -414,18 +414,12 @@ def decode_route(L: int, route: Optional[str] = None) -> str:
     kernel bytes apply only on the kernel route; the dense route's bytes
     are already visible to XLA's own cost analysis.
 
-    A MEASURED crossover from the autotune cache (``paddle_tpu tune``,
-    paddle_tpu.tune) replaces the ``SHORT_SEQ_DENSE`` heuristic when one
-    exists for this device_kind: the tuned ``kernel_min_len`` (null =
-    the dense route won at every measured length) decides, and off-TPU
-    hosts then honor it through the interpreter — both routes share one
-    masked-softmax formulation, so the swap never changes tokens."""
+    A forced ``route`` wins (the tests' way to pick the dense reference,
+    or the kernel through the interpreter); otherwise the kernel runs for
+    on-TPU reads of at least ``SHORT_SEQ_DENSE`` rows. Both routes share
+    one masked-softmax formulation, so the choice never changes tokens."""
     if route is not None:
         return route
-    from .. import tune
-    thr = tune.decode_kernel_min_len()
-    if thr is not tune.MISS:
-        return "kernel" if thr is not None and L >= thr else "dense"
     return "kernel" if _on_tpu() and L >= SHORT_SEQ_DENSE else "dense"
 
 

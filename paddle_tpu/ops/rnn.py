@@ -103,8 +103,7 @@ def lstm(x: jax.Array, lengths: Optional[jax.Array], w: jax.Array, u: jax.Array,
     if fused:
         from . import pallas_kernels as _pk
         from .. import obs
-        plan = _fused_plan(T, H, seq_h_units=6, batch=B,
-                           kernel="lstm_sequence_fused")
+        plan = _fused_plan(T, H, seq_h_units=6, batch=B)
         obs.count("kernels.routes_total", kernel="lstm_sequence_fused",
                   route=("fused" if _pk._on_tpu() and plan is not None
                          else "scan"))
@@ -139,63 +138,11 @@ def lstm(x: jax.Array, lengths: Optional[jax.Array], w: jax.Array, u: jax.Array,
 _CHUNK_MIN_WIDE = 16
 
 
-def plan_is_legal(T: int, H: int, gates: int, seq_h_units: int,
-                  batch: int, block_b: int, chunk_t: int,
-                  budget_bytes: int = 15_500_000,
-                  double_buffer_always: bool = False) -> bool:
-    """Can (block_b, chunk_t) launch the fused kernel for this family?
-
-    The ONE owner of launch legality — :func:`_fused_plan`'s heuristic
-    preference and the autotune plane's candidate enumeration /
-    cached-plan validation (paddle_tpu.tune) both resolve through it, so
-    a tuned cache can never name a plan the heuristic's VMEM cost model
-    would reject. Constraints: Mosaic's batch-tile rule (a multiple of 8,
-    or one single-program grid covering the whole batch), and the
-    resident [chunk, blk, seq_h_units*H] tile + u (+ du accumulator)
-    fitting the scoped-VMEM budget — double-buffered whenever the grid
-    has more than one program (see :func:`_fused_plan`)."""
-    if block_b < 1 or chunk_t < 1 or batch < 1:
-        return False
-    blk = min(block_b, batch)
-    grid_is_1 = blk >= batch            # one program covers the batch
-    if blk % 8 and not grid_is_1:
-        return False                    # Mosaic batch-tile rule
-    u_bytes = H * gates * H * 4
-    avail = budget_bytes - 2 * u_bytes
-    if avail <= 0:
-        return False
-    per_step = blk * seq_h_units * H * 4
-    if double_buffer_always or not grid_is_1:
-        per_step *= 2
-    return min(chunk_t, T) * per_step <= avail
-
-
-def _tuned_plan(kernel: Optional[str], T: int, H: int, gates: int,
-                seq_h_units: int, batch: Optional[int],
-                budget_bytes: int,
-                double_buffer_always: bool) -> Optional[Tuple[int, int]]:
-    """Consult the autotune cache for this launch's family; None on any
-    miss (no cache, stale hash, illegal plan) — the heuristic then owns
-    the decision, so a cache changes speed, never numerics."""
-    if kernel is None or batch is None:
-        return None
-    from .. import tune
-    plan = tune.fused_plan(kernel, T=T, H=H, gates=gates,
-                           seq_h_units=seq_h_units, batch=batch,
-                           budget_bytes=budget_bytes,
-                           double_buffer_always=double_buffer_always)
-    if plan is None:
-        return None
-    blk, chunk = plan
-    return blk, min(chunk, T)
-
-
 def _fused_plan(T: int, H: int, gates: int = 4,
                 seq_h_units: Optional[int] = None,
                 batch: Optional[int] = None,
                 budget_bytes: int = 15_500_000,
-                double_buffer_always: bool = False,
-                kernel: Optional[str] = None
+                double_buffer_always: bool = False
                 ) -> Optional[Tuple[int, int]]:
     """(block_b, chunk_t) for the fused whole-sequence kernels, or None
     for the scan. ``gates``: 4 for LSTM, 3 for GRU (sizes the [H, gates*H]
@@ -203,14 +150,6 @@ def _fused_plan(T: int, H: int, gates: int = 4,
     of the per-step sequence buffers in multiples of H (default xw + out =
     gates + 1; the train forward adds the saved cell sequence, the
     backward roughly doubles it).
-
-    ``kernel`` names the launch site ("lstm_sequence_fused", ...): when
-    given, a MEASURED plan from the autotune cache (paddle_tpu.tune,
-    ``paddle_tpu tune``) is consulted first and, when one exists for this
-    exact (kernel, shape family, device_kind) and passes
-    :func:`plan_is_legal`, it replaces the heuristic preference below —
-    both plans run the same kernel math, so the swap changes launch
-    geometry (speed) only, never outputs.
 
     Preference order: the WIDEST batch tile whose resident time-chunk
     still fits VMEM — the recurrent matmul is [blk, H] @ [H, gates*H] per
@@ -230,10 +169,6 @@ def _fused_plan(T: int, H: int, gates: int = 4,
     at full batch)."""
     if seq_h_units is None:
         seq_h_units = gates + 1
-    tuned = _tuned_plan(kernel, T, H, gates, seq_h_units, batch,
-                        budget_bytes, double_buffer_always)
-    if tuned is not None:
-        return tuned
     u_bytes = H * gates * H * 4          # u resident + du accumulator
     avail = budget_bytes - 2 * u_bytes
     if avail <= 0:
@@ -262,18 +197,16 @@ def _fused_plan(T: int, H: int, gates: int = 4,
 
 def _fused_bwd_plan(T: int, H: int, gates: int, seq_h_units: int,
                     batch: int,
-                    budget_bytes: int = 15_500_000,
-                    kernel: Optional[str] = None
+                    budget_bytes: int = 15_500_000
                     ) -> Optional[Tuple[int, int]]:
     """(block_b, chunk_t) for the hand-written backward kernels — the SAME
     planner as :func:`_fused_plan` (one place owns the VMEM cost model and
     tile preference), always double-buffer-costed. The reverse recurrence
     splits cleanly at chunk boundaries: the saved (out, c) sequences
     provide each chunk's initial state, so the wrapper runs a few kernel
-    launches instead of one. ``kernel`` (e.g. "lstm_sequence_fused_bwd")
-    keys the autotune consult separately from the forward plan."""
+    launches instead of one."""
     return _fused_plan(T, H, gates, seq_h_units, batch, budget_bytes,
-                       double_buffer_always=True, kernel=kernel)
+                       double_buffer_always=True)
 
 
 def _reverse_within_length(x: jax.Array, lengths: jax.Array) -> jax.Array:
@@ -351,8 +284,7 @@ def _lstm_fused_bwd(forget_bias, block_b, chunk_t, res, g):
     zero_lens = np.zeros(lens.shape, jax.dtypes.float0)
     B, T, D = x.shape
     H = u.shape[0]
-    plan = _fused_bwd_plan(T, H, 4, 11, B,   # 2*(xw+dxw) + 3 H-wide seqs
-                           kernel="lstm_sequence_fused_bwd")
+    plan = _fused_bwd_plan(T, H, 4, 11, B)   # 2*(xw+dxw) + 3 H-wide seqs
     if plan is None:
         # VMEM won't hold even an 8-step backward tile: replay the
         # (bit-identical) scan under autodiff instead
@@ -419,8 +351,7 @@ def gru(x: jax.Array, lengths: Optional[jax.Array], w: jax.Array, u: jax.Array,
     if fused:
         from . import pallas_kernels as _pk
         from .. import obs
-        plan = _fused_plan(T, H, gates=3, batch=B,
-                           kernel="gru_sequence_fused")
+        plan = _fused_plan(T, H, gates=3, batch=B)
         obs.count("kernels.routes_total", kernel="gru_sequence_fused",
                   route=("fused" if _pk._on_tpu() and plan is not None
                          else "scan"))
@@ -482,8 +413,7 @@ def _gru_fused_bwd(block_b, chunk_t, res, g):
     zero_lens = np.zeros(lens.shape, jax.dtypes.float0)
     B, T, D = x.shape
     H = u.shape[0]
-    plan = _fused_bwd_plan(T, H, 3, 8, B,    # 2*(xw+dxw) + 2 H-wide seqs
-                           kernel="gru_sequence_fused_bwd")
+    plan = _fused_bwd_plan(T, H, 3, 8, B)    # 2*(xw+dxw) + 2 H-wide seqs
     if plan is None:
         def replay(x, w, u, b, h0):
             return gru(x, lens, w, u, b, h0, fused=False)

@@ -104,10 +104,10 @@ class ServingEngine:
     tests may instead drive :meth:`step` directly (deterministic)."""
 
     def __init__(self, model, params, *, slots: int = 8, segment: int = 32,
-                 page_block: Optional[int] = None,
+                 page_block: int = 64,
                  pages: Optional[int] = None,
-                 cache_bucket: Optional[int] = None,
-                 prompt_buckets: Optional[Sequence[int]] = None,
+                 cache_bucket: int = 256,
+                 prompt_buckets: Sequence[int] = (32, 64, 128, 256, 512),
                  kv_dtype: Optional[str] = None, queue_cap: int = 64,
                  default_timeout_s: Optional[float] = None,
                  prefix_cache: bool = False,

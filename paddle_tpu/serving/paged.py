@@ -95,40 +95,23 @@ class PagePool:
     programs. Compile surface is bounded exactly like the pinned batcher:
     one admission program per prompt-pad bucket, one suffix-admission
     program per (suffix-pad, read-pages) bucket pair, one segment program
-    per cache-read bucket (in pages)."""
+    per cache-read bucket (in pages).
+
+    The geometry defaults (``page_block`` 64, ``cache_bucket`` 256,
+    ``prompt_buckets`` 32..512) are the values every serve cell of the
+    chip benchmark runs and warms up (chipbench/workloads/*.json, PERF.md
+    section 4). Page size changes read geometry only: the assembled row
+    order is the same at any block, so tokens never change
+    (tests/test_serving_paged.py holds paged == solo over page sizes)."""
 
     def __init__(self, model, params, *, slots: int, segment: int = 32,
-                 page_block: Optional[int] = None,
+                 page_block: int = 64,
                  pages: Optional[int] = None,
-                 cache_bucket: Optional[int] = None,
-                 prompt_buckets: Optional[Sequence[int]] = None,
+                 cache_bucket: int = 256,
+                 prompt_buckets: Sequence[int] = (32, 64, 128, 256, 512),
                  kv_dtype: Optional[str] = None,
                  prefix_cache: bool = False,
                  prefix_half_life: int = 64):
-        if cache_bucket is None or prompt_buckets is None:
-            # bucket_grid consult: the measured compile-count-vs-padding
-            # winner for this backend, legality-validated by the consult
-            # (ascending, ≤ max_len, divisible by an explicit page_block);
-            # heuristic grids otherwise. Resolved BEFORE the page_block
-            # consult below — its validation needs the real cache_bucket.
-            from .. import tune
-            if cache_bucket is None:
-                grid = tune.bucket_grid("cache", max_len=model.max_len,
-                                        divisor=page_block)
-                cache_bucket = grid[-1] if grid else 256
-            if prompt_buckets is None:
-                prompt_buckets = (
-                    tune.bucket_grid("prompt", max_len=model.max_len)
-                    or (32, 64, 128, 256, 512))
-        if page_block is None:
-            # autotune consult (paddle_tpu.tune, `paddle_tpu tune`): a
-            # measured winner validated against THIS pool's grid
-            # (divides max_len and cache_bucket), else the 64 heuristic.
-            # Page size changes read geometry only — the assembled row
-            # order is identical at any block, so tokens never change
-            # (test_serving_paged.py holds paged==solo at page_block=8).
-            from .. import tune
-            page_block = tune.page_block(model.max_len, cache_bucket) or 64
         if model.max_len % page_block:
             raise ValueError(f"page_block {page_block} must divide "
                              f"max_len {model.max_len}")
@@ -840,10 +823,10 @@ class PagedBatcher:
     sharing (copy-on-write radix index; see :class:`PagePool`)."""
 
     def __init__(self, model, params, *, slots: int = 8, segment: int = 32,
-                 page_block: Optional[int] = None,
+                 page_block: int = 64,
                  pages: Optional[int] = None,
-                 cache_bucket: Optional[int] = None,
-                 prompt_buckets: Optional[Sequence[int]] = None,
+                 cache_bucket: int = 256,
+                 prompt_buckets: Sequence[int] = (32, 64, 128, 256, 512),
                  schedule: str = "longest_first",
                  kv_dtype: Optional[str] = None,
                  prefix_cache: bool = False):

@@ -40,23 +40,6 @@ def _session_compile_cache():
     yield
 
 
-@pytest.fixture(scope="session", autouse=True)
-def _hermetic_autotune(tmp_path_factory):
-    """Point the autotune consult at a session-local (absent) cache file:
-    a developer's real ~/.paddle_tpu/autotune.json must never steer test
-    plans (tuned plans are parity-safe by construction, but the suite's
-    route/plan assertions pin exact heuristic decisions). Tests that
-    exercise the consult install their own caches via
-    paddle_tpu.tune.set_cache / $PADDLE_TPU_AUTOTUNE_CACHE; the env var
-    is exported so subprocess tests inherit the hermetic path."""
-    from paddle_tpu import tune
-    if not os.environ.get(tune.CACHE_ENV):
-        os.environ[tune.CACHE_ENV] = str(
-            tmp_path_factory.mktemp("autotune") / "autotune.json")
-        tune.reset()
-    yield
-
-
 @pytest.fixture(scope="session")
 def paged_model_and_params():
     """ONE TransformerLM (the shared serving dims: VOCAB=97, D=32, H=4,

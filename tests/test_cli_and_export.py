@@ -91,8 +91,7 @@ def test_lint_bench_rows_schema(tmp_path):
     good.write_text(
         json.dumps({"metric": "x_train_ms_per_batch", "value": 1.0,
                     "unit": "ms", "vs_baseline": None, "mfu": 0.2,
-                    "methodology": "measured",
-                    "plan_source": "heuristic"}) + "\n"
+                    "methodology": "measured"}) + "\n"
         + json.dumps({"metric": "z_serve_daemon_tokens_per_sec",
                       "value": 9.0, "unit": "tok/s", "vs_baseline": None,
                       "ttft_p50_ms": 12.0, "tpot_p50_ms": 3.0,
@@ -113,8 +112,7 @@ def test_lint_bench_rows_schema(tmp_path):
                       "vs_baseline": None}) + "\n"
         + json.dumps({"metric": "w_train_ms_per_batch", "value": 1.0,
                       "unit": "ms", "vs_baseline": None, "mfu": 0.2,
-                      "methodology": "guessed",
-                      "plan_source": "vibes"}) + "\n"
+                      "methodology": "guessed"}) + "\n"
         + json.dumps({"metric": "r_route_disagg_tokens_per_sec",
                       "value": 7.0, "unit": "tok/s", "vs_baseline": None,
                       "ttft_p50_ms": 20.0, "tpot_p50_ms": 4.0}) + "\n")
@@ -131,9 +129,6 @@ def test_lint_bench_rows_schema(tmp_path):
     # methodology is required on roofline/SLO rows and must be one of
     # measured|modeled — on-chip vs projected stays distinguishable
     assert "methodology" in r.stdout and "guessed" in r.stdout
-    # plan_source is required on _train_/_decode_ rows (tuned-vs-heuristic
-    # deltas stay machine-checkable) and must be tuned|heuristic
-    assert "plan_source" in r.stdout and "vibes" in r.stdout
     # the _route_ family rule (disaggregated serving): a routed row
     # without the fleet size it was spread over is not comparable, and
     # without its phase-decomposed TTFT (request-timeline ledger) a

@@ -1,7 +1,7 @@
 """paddle_tpu.analysis.dataflow — def-use chains, liveness, aliasing,
-effects, and the three planes built on them: the donation-safety proof
-(L011 + Executor auto-downgrade), the fusion-legality oracle (bit-parity
-certified), and lints L010/L012 with full nested-block-path citations.
+effects, and the two planes built on them: the donation-safety proof
+(L011 + Executor auto-downgrade) and lints L010/L012 with full
+nested-block-path citations.
 
 Tier-1 (JAX_PLATFORMS=cpu safe).  Also the home of the satellite gates:
 the tree-clean sweep over every in-repo example/benchmark Program, the
@@ -231,191 +231,6 @@ def test_safe_training_program_keeps_donation():
                       donate=True)
     assert not [w for w in rec if "L011" in str(w.message)]
     assert float(np.asarray(l1)) != float(np.asarray(l0))  # params moved
-
-
-# ------------------------------------------------- fusion-legality oracle --
-
-def _run_group(block, group, feeds, fused):
-    """Execute one certified group the way the executor would: inside ONE
-    jitted trace (the executor compiles a whole Program into one jit).
-
-    ``fused=False`` is the standard sequential trace — every group op runs
-    through its registered compute, every intermediate is a named binding.
-    ``fused=True`` replaces the group with a single fused callable built
-    STRICTLY from the certificate: it may touch only ``group.inputs`` and
-    must yield exactly ``group.outputs``.  A certificate missing an input,
-    leaking an intermediate, or mis-ordering the region fails loudly here.
-    """
-    def step(env, i):
-        op = block.ops[i]
-        compute = OpRegistry.get(op.type)
-        ins = {k: [env[n] for n in vs] for k, vs in op.inputs.items()}
-        outs = compute(ins, op.attrs)
-        for k, names in op.outputs.items():
-            for n, v in zip(names, outs[k]):
-                env[n] = v
-
-    def run_unfused(env):
-        env = dict(env)
-        for i in group.op_idxs:
-            step(env, i)
-        return [env[n] for n in group.outputs]
-
-    def fused_fn(*args):
-        # the fused region: sees ONLY the certified inputs
-        env = dict(zip(group.inputs, args))
-        for i in group.op_idxs:
-            step(env, i)
-        return tuple(env[n] for n in group.outputs)
-
-    def run_fused(env):
-        outs = fused_fn(*[env[n] for n in group.inputs])
-        return list(outs)
-
-    fn = jax.jit(run_fused if fused else run_unfused)
-    return [np.asarray(v) for v in fn(feeds)]
-
-
-def _assert_groups_bit_identical(prog, groups, shapes, seed=0):
-    rs = np.random.RandomState(seed)
-    block = prog.blocks[0]
-    for g in groups:
-        feeds = {n: rs.randn(*shapes[n]).astype(np.float32)
-                 for n in g.inputs}
-        fused = _run_group(block, g, feeds, fused=True)
-        unfused = _run_group(block, g, feeds, fused=False)
-        for a, b_ in zip(fused, unfused):
-            assert a.dtype == b_.dtype and np.array_equal(a, b_), g.to_dict()
-
-
-def test_elementwise_chain_certified_and_bit_identical():
-    x = layers.data(name="x", shape=[8], dtype="float32")
-    y = layers.data(name="y", shape=[8], dtype="float32")
-    b = fluid.default_main_program().global_block()
-    t1 = b.create_var(shape=[-1, 8], dtype="float32")
-    b.append_op("elementwise_add", {"X": [x.name], "Y": [y.name]},
-                {"Out": [t1.name]}, {})
-    t2 = b.create_var(shape=[-1, 8], dtype="float32")
-    b.append_op("elementwise_mul", {"X": [t1.name], "Y": [x.name]},
-                {"Out": [t2.name]}, {})
-    t3 = b.create_var(shape=[-1, 8], dtype="float32")
-    b.append_op("relu", {"X": [t2.name]}, {"Out": [t3.name]}, {})
-    w = b.create_var(name="wm", shape=[8, 4], dtype="float32",
-                     persistable=True)
-    out = b.create_var(shape=[-1, 4], dtype="float32")
-    b.append_op("matmul", {"X": [t3.name], "Y": [w.name]},
-                {"Out": [out.name]}, {})
-    prog = fluid.default_main_program()
-    groups = A.fusable_groups(prog, fetch=[out.name])
-    chains = [g for g in groups if g.kind == "elementwise_chain"]
-    assert len(chains) == 1
-    g = chains[0]
-    assert g.op_idxs == [0, 1, 2]
-    assert set(g.inputs) == {x.name, y.name}
-    assert g.outputs == [t3.name]
-    # the dependence certificate: every internal edge is single-consumer
-    assert {(e["var"], e["n_consumers"]) for e in g.edges} == {
-        (t1.name, 1), (t2.name, 1)}
-    _assert_groups_bit_identical(prog, chains,
-                                 {x.name: (3, 8), y.name: (3, 8)})
-
-
-def test_producer_consumer_epilogue_certified_and_bit_identical():
-    x = layers.data(name="x", shape=[8], dtype="float32")
-    b = fluid.default_main_program().global_block()
-    w = b.create_var(name="wm", shape=[8, 4], dtype="float32",
-                     persistable=True)
-    m = b.create_var(shape=[-1, 4], dtype="float32")
-    b.append_op("matmul", {"X": [x.name], "Y": [w.name]},
-                {"Out": [m.name]}, {})
-    r = b.create_var(shape=[-1, 4], dtype="float32")
-    b.append_op("relu", {"X": [m.name]}, {"Out": [r.name]}, {})
-    prog = fluid.default_main_program()
-    groups = A.fusable_groups(prog, fetch=[r.name])
-    assert [g.kind for g in groups] == ["producer_consumer"]
-    g = groups[0]
-    assert g.op_idxs == [0, 1]
-    assert [e["var"] for e in g.edges] == [m.name]
-    _assert_groups_bit_identical(prog, groups,
-                                 {x.name: (3, 8), w.name: (8, 4)})
-
-
-def test_shared_consumer_rejected():
-    """The counterexample the oracle must refuse: t feeds TWO consumers,
-    so op 0 can be in no group, while the single-consumer diamond join
-    downstream (u1 + u2 -> z) is still legally fusable."""
-    x = layers.data(name="x", shape=[4], dtype="float32")
-    y = layers.data(name="y", shape=[4], dtype="float32")
-    b = fluid.default_main_program().global_block()
-    t = b.create_var(shape=[-1, 4], dtype="float32")
-    b.append_op("elementwise_add", {"X": [x.name], "Y": [y.name]},
-                {"Out": [t.name]}, {})
-    u1 = b.create_var(shape=[-1, 4], dtype="float32")
-    b.append_op("elementwise_mul", {"X": [t.name], "Y": [x.name]},
-                {"Out": [u1.name]}, {})
-    u2 = b.create_var(shape=[-1, 4], dtype="float32")
-    b.append_op("elementwise_sub", {"X": [t.name], "Y": [y.name]},
-                {"Out": [u2.name]}, {})
-    z = b.create_var(shape=[-1, 4], dtype="float32")
-    b.append_op("elementwise_add", {"X": [u1.name], "Y": [u2.name]},
-                {"Out": [z.name]}, {})
-    prog = fluid.default_main_program()
-    groups = A.fusable_groups(prog, fetch=[z.name])
-    for g in groups:
-        assert 0 not in g.op_idxs, g.to_dict()
-    chains = [g for g in groups if g.kind == "elementwise_chain"]
-    assert len(chains) == 1 and chains[0].op_idxs == [1, 2, 3]
-    _assert_groups_bit_identical(
-        prog, chains, {t.name: (2, 4), x.name: (2, 4), y.name: (2, 4)})
-
-
-def test_fetched_and_impure_values_never_fused():
-    """A fetched intermediate escapes (must materialize); an in-place op
-    has ordering obligations — neither may appear inside a group."""
-    x = layers.data(name="x", shape=[4], dtype="float32")
-    b = fluid.default_main_program().global_block()
-    t = b.create_var(shape=[-1, 4], dtype="float32")
-    b.append_op("relu", {"X": [x.name]}, {"Out": [t.name]}, {})
-    u = b.create_var(shape=[-1, 4], dtype="float32")
-    b.append_op("elementwise_mul", {"X": [t.name], "Y": [t.name]},
-                {"Out": [u.name]}, {})
-    prog = fluid.default_main_program()
-    # fetching t makes the relu->mul edge escape: no group may contain it
-    assert A.fusable_groups(prog, fetch=[t.name, u.name]) == []
-    # not fetched: the chain is certified
-    assert [g.op_idxs for g in A.fusable_groups(prog, fetch=[u.name])] \
-        == [[0, 1]]
-
-
-def test_elementwise_chain_sweep_bit_parity():
-    """Sweep randomized elementwise chains: every certified group must be
-    bit-identical fused vs unfused (the oracle's soundness contract)."""
-    rs = np.random.RandomState(7)
-    unary = ["relu", "tanh", "sigmoid", "square", "abs_act", "exponential"]
-    binary = ["elementwise_add", "elementwise_mul", "elementwise_sub"]
-    for trial in range(6):
-        fluid.reset_default_programs()
-        x = layers.data(name="x", shape=[5], dtype="float32")
-        y = layers.data(name="y", shape=[5], dtype="float32")
-        b = fluid.default_main_program().global_block()
-        cur = x.name
-        for _ in range(int(rs.randint(2, 6))):
-            out = b.create_var(shape=[-1, 5], dtype="float32")
-            if rs.rand() < 0.5:
-                b.append_op(unary[rs.randint(len(unary))],
-                            {"X": [cur]}, {"Out": [out.name]}, {})
-            else:
-                b.append_op(binary[rs.randint(len(binary))],
-                            {"X": [cur], "Y": [y.name]},
-                            {"Out": [out.name]}, {})
-            cur = out.name
-        prog = fluid.default_main_program()
-        groups = A.fusable_groups(prog, fetch=[cur])
-        assert groups and groups[0].kind == "elementwise_chain"
-        assert groups[0].op_idxs == list(range(len(b.ops)))
-        _assert_groups_bit_identical(
-            prog, groups, {x.name: (2, 5), y.name: (2, 5)},
-            seed=100 + trial)
 
 
 # ------------------------------------------------------- lints L010 / L012 --
@@ -800,9 +615,7 @@ def test_verify_preflight_fits_wall_budget():
     t1 = time.perf_counter()
     df = A.analyze_dataflow(prog, fetch=[loss.name])
     hz = A.donation_hazards(prog, df=df)
-    grp = A.fusable_groups(prog, fetch=[loss.name], df=df)
     dflow = time.perf_counter() - t1
     assert hz == []
-    assert grp      # a transformer block is full of fusable epilogues
     budget = float(os.environ.get("PADDLE_TPU_VERIFY_BUDGET_S", "20"))
     assert elapsed + dflow < budget, (elapsed, dflow)

@@ -43,10 +43,12 @@ def _solo(model, params, prompt, steps, _bucket=12):
     return np.asarray(out)[0, len(prompt):len(prompt) + steps]
 
 
-def test_paged_matches_solo_decode(model_and_params):
+@pytest.mark.parametrize("page_block", [8, 16, 32])
+def test_paged_matches_solo_decode(model_and_params, page_block):
     """The tentpole contract: mixed prompt/gen lengths through the paged
     pool, every request's greedy continuation token-for-token equal to
-    decoding it alone — and every page back in the free list after."""
+    decoding it alone, whatever the page size — and every page back in
+    the free list after."""
     model, params = model_and_params
     rs = np.random.RandomState(3)
     reqs = []
@@ -54,8 +56,8 @@ def test_paged_matches_solo_decode(model_and_params):
         plen = int(rs.randint(3, 40))
         gen = int(rs.randint(1, 37))
         reqs.append(Request(rid, rs.randint(0, VOCAB, plen), gen))
-    b = PagedBatcher(model, params, slots=4, segment=8, page_block=8,
-                     cache_bucket=32)
+    b = PagedBatcher(model, params, slots=4, segment=8,
+                     page_block=page_block, cache_bucket=32)
     got = b.serve(reqs)
     assert sorted(got) == [r.rid for r in reqs]
     for r in reqs:
@@ -66,6 +68,40 @@ def test_paged_matches_solo_decode(model_and_params):
                     f"{r.max_new}) diverged under the paged cache")
     assert b.pool.pages_used == 0 and b.pool.reserved == 0
     assert 0 < b.pool.peak_pages_used <= b.pool.capacity_pages
+
+
+@pytest.mark.parametrize("case", ["defaults", "refusals"])
+def test_pool_defaults_and_grid_refusals(model_and_params, case):
+    """The pool's geometry comes from its signature: page_block 64,
+    cache_bucket 256, prompt buckets 32..512 with nothing passed, and the
+    batcher and the engine hand the same values on. A geometry off the
+    page grid is refused with the ValueError that ``serve`` prints as
+    ``serve: <message>`` before it exits 2 (page_block against max_len
+    through the CLI: test_cli_serve_bad_flags_structured_error) — the
+    defaults too, on a model whose max_len they do not divide."""
+    import inspect
+    from paddle_tpu.models import TransformerLM
+    from paddle_tpu.serving.paged import PagePool
+    model, params = model_and_params
+    grid = (64, 256, (32, 64, 128, 256, 512))
+    if case == "defaults":
+        pool = PagePool(model, params, slots=2)
+        assert (pool.bs, pool.cache_bucket,
+                tuple(pool.prompt_buckets)) == grid
+        for owner in (PagePool, PagedBatcher, ServingEngine):
+            sig = inspect.signature(owner.__init__).parameters
+            assert tuple(sig[k].default for k in (
+                "page_block", "cache_bucket", "prompt_buckets")) == grid
+        return
+    with pytest.raises(ValueError, match="cache_bucket 40 must be a "
+                                         "multiple of page_block 16"):
+        ServingEngine(model, params, slots=2, page_block=16,
+                      cache_bucket=40)
+    short = TransformerLM(VOCAB, d_model=D, n_heads=H, n_layers=1,
+                          max_len=96)
+    with pytest.raises(ValueError, match="page_block 64 must divide "
+                                         "max_len 96"):
+        PagePool(short, None, slots=2)
 
 
 def test_paged_matches_pinned_batcher(model_and_params):
@@ -261,10 +297,9 @@ def test_paged_kernel_walks_ragged_tables(case, NB, kv):
 def test_paged_batcher_kernel_route_tokens_equal_dense(model_and_params,
                                                        monkeypatch):
     """A mixed-length batch decoded with the paged read on the kernel route
-    (the interpreter, through the tuned-crossover hook) emits the dense
+    (the interpreter, with ``pk.decode_route`` patched) emits the dense
     route's greedy tokens: idle slots, slots finishing mid-segment and
     tables of different widths all go through the work list."""
-    from paddle_tpu import tune
     from paddle_tpu.models import TransformerLM
     model, params = model_and_params
     rs = np.random.RandomState(21)
@@ -277,7 +312,8 @@ def test_paged_batcher_kernel_route_tokens_equal_dense(model_and_params,
     # keeps these programs out of the session model's shared cache
     fresh = TransformerLM(VOCAB, d_model=D, n_heads=H, n_layers=L,
                           max_len=MAX_LEN)
-    monkeypatch.setattr(tune, "decode_kernel_min_len", lambda: 1)
+    monkeypatch.setattr(pk, "decode_route",
+                        lambda L, route=None: route or "kernel")
     r = obs.MetricsRegistry()
     with obs.ObsSession(registry=r).installed():
         got = PagedBatcher(fresh, params, **kw).serve(reqs)
