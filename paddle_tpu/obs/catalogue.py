@@ -166,6 +166,19 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                                        "decode: live cache rows, halved "
                                        "under int8 KV), labels: kernel",
                             ("kernel",)),
+    "kernels.flash_block_pairs_total": ("counter", "(q-block, k-block) "
+                                                   "pairs of the flash-"
+                                                   "attention kernels, over "
+                                                   "all batch x head squares "
+                                                   "of a call: state=visited "
+                                                   "is what the kernel walks "
+                                                   "(causal: the pairs at or "
+                                                   "below the diagonal), "
+                                                   "state=grid the whole "
+                                                   "square; counted once per "
+                                                   "TRACE of a call, labels: "
+                                                   "kernel, state",
+                                        ("kernel", "state")),
     "kernels.routes_total": ("counter", "auto-route decisions at the "
                                         "kernel entry points; counted when "
                                         "the routing Python runs — once "

@@ -20,7 +20,8 @@ Pallas kernel that keeps the whole inner loop in VMEM next to the MXU/VPU
 Kernels run with ``interpret=True`` only where the backend is the CPU, so the
 same code is testable on the CPU mesh (tests/test_pallas.py); numerics match
 the jnp reference path. tests/test_chip_compile.py compiles the main-path
-kernels for a described TPU v5e at GPT-2-small widths.
+kernels for a described TPU v5e at GPT-2-small widths and at the benchmark
+cells' own flash-attention calls.
 """
 
 from __future__ import annotations
@@ -56,25 +57,107 @@ def _interpret(interpret: Optional[bool]) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def _k_block_hi(n_k: int, qi, block_q: int, block_k: int, kv_len,
-                causal: bool, has_lens: bool):
-    """Upper k-block bound shared by the forward and dq kernels: skip
-    k-blocks the masks zero out ENTIRELY — causally, blocks past the
-    q-block's last row; by length, blocks at/past kv_len. Statically gated
-    on n_k > 1: a dynamic fori_loop bound lowers to a while loop whose
-    control overhead measurably LOSES when there is only one k-block
-    anyway (the T<=1024 default-block case, measured -8..20%); with
-    several blocks the diagonal walk saves up to half the streamed tiles.
-    Skipped blocks contribute p == 0 exactly, so fwd lse and the bwd
-    recomputation stay consistent by construction."""
-    hi = n_k
+# How the causal kernels walk the square. One k-block holds the rows of
+# r = ceil(block_k / block_q) q-blocks (_blocks(): several k-blocks are a
+# multiple of block_q or divide it; ONE k-block is the whole of S, its last
+# q-block ragged). For q-block ``qi`` the k-blocks that END at or before its
+# first row are CLEAR: whole, no masked pair, no iota / compare / select.
+# What is left of its row of the square is ONE tile against the diagonal,
+# from the end of the clear blocks to the q-block's last row:
+# min((qi % r + 1) * block_q, block_k) keys wide — a static width chosen by
+# a branch on qi % r, so the tile's products, its softmax and its mask cover
+# the live keys and nothing to their right. Everything past it is masked
+# ENTIRELY and not visited: it contributes p == 0 exactly, so fwd lse and
+# the bwd recomputation stay consistent by construction. The dk/dv kernel
+# walks the same tiles from the k-block's side: its r diagonal tiles in a
+# static loop and the q-blocks below them clear. Why one wide tile and not
+# a walk of small blocks: on this chip 256 x 256 blocks, K/V resident under
+# a dynamic loop or walked by the grid, ran 1.5-1.9x SLOWER than 512 x 1024
+# computing the whole square (PERF.md, PR 31) — a tile costs its area plus
+# a fixed part, and 512 streamed rows keep the MXU's weights busy.
+
+
+def _mask_tile(s, q_pos, k_start, kv_len, causal: bool, whole: bool):
+    """Scores of one tile with its masked pairs at _NEG: keys at / past
+    kv_len (padded, over-length) and, causally, keys past the row.
+    ``q_pos`` [block_q, 1]; the tile's keys start at ``k_start``. A causal
+    tile wider than it is tall ends on the diagonal, so only the columns
+    of its last q-block can hold a pair past it (and, S == T, a padded key
+    a real row sees): unless ``whole`` (kv_lens given), only those are
+    touched."""
+    block_q, width = s.shape
+    skip = (width - 1) // block_q * block_q if causal and not whole else 0
+    k_pos = k_start + skip + jax.lax.broadcasted_iota(
+        jnp.int32, (1, width - skip), 1)
+    valid = k_pos < kv_len
+    if causal:
+        valid = valid & (q_pos >= k_pos)
+    if not skip:
+        return jnp.where(valid, s, _NEG)
+    return jnp.concatenate(
+        [s[:, :skip], jnp.where(valid, s[:, skip:], _NEG)], axis=1)
+
+
+def _diag_per_block(block_q: int, block_k: int) -> int:
+    """r: q-blocks whose rows one k-block holds (1 when block_q >= block_k)."""
+    return -(-block_k // block_q)
+
+
+def _visited_units(n_q: int, n_k: int, block_q: int, block_k: int,
+                   causal: bool, by_k: bool) -> Tuple[int, int]:
+    """(visited, grid) area of one batch x head square in units of the
+    smaller block side squared (rounded up), for the forward / dq walk or
+    (``by_k``) the dk/dv walk — the arithmetic the kernels do on traced
+    indices."""
+    u = min(block_q, block_k)
+    grid = n_q * block_q * n_k * block_k
+    if not causal:
+        area = grid
+    else:
+        r = _diag_per_block(block_q, block_k)
+        widths = [block_q if r == 1 and not by_k
+                  else min(block_k, (c + 1) * block_q) for c in range(r)]
+        if by_k:
+            area = sum(max(0, n_q - (ki * block_k) // block_q - r)
+                       * block_q * block_k + block_q * sum(widths)
+                       for ki in range(n_k))
+        else:
+            area = sum(((qi * block_q) // block_k) * block_k * block_q
+                       + block_q * widths[qi % r] for qi in range(n_q))
+    return -(-area // (u * u)), -(-grid // (u * u))
+
+
+def _walk_k(tile, finish, carry, qi, block_q: int, block_k: int, n_k: int,
+            kv_len, causal: bool, has_lens: bool) -> None:
+    """The forward / dq walk over one q-block's keys (above): fold
+    ``tile(carry, start, width, masked)`` over the tiles it visits and hand
+    the result to ``finish`` (which writes the program's outputs)."""
+    def blocks(hi, masked, carry):
+        return jax.lax.fori_loop(
+            0, hi, lambda ki, c: tile(c, ki * block_k, block_k, masked), carry)
+
+    # k-blocks wholly at / past kv_len are masked entirely: not visited
+    by_len = (kv_len + block_k - 1) // block_k
+    if not causal:
+        hi = jnp.minimum(n_k, by_len) if has_lens and n_k > 1 else n_k
+        finish(blocks(hi, True, carry))
+        return
+    r = _diag_per_block(block_q, block_k)
+    start = 0
     if n_k > 1:
-        if causal:
-            hi = jnp.minimum(hi, ((qi + 1) * block_q + block_k - 1)
-                             // block_k)
-        if has_lens:
-            hi = jnp.minimum(hi, (kv_len + block_k - 1) // block_k)
-    return hi
+        clear = (qi * block_q) // block_k
+        start = clear * block_k
+        # a clear block holds no key past the diagonal; with kv_lens it may
+        # hold one past the sample's length, so those calls mask throughout
+        carry = blocks(jnp.minimum(clear, by_len) if has_lens else clear,
+                       has_lens, carry)
+    if r == 1:
+        finish(tile(carry, start, block_q, True))
+        return
+    for c in range(r):
+        @pl.when(jax.lax.rem(qi, r) == c)
+        def _diagonal(width=min((c + 1) * block_q, block_k)):
+            finish(tile(carry, start, width, True))
 
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
@@ -94,20 +177,14 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
     kv_len = jnp.minimum(len_ref[0, 0, 0], true_len)
 
-    n_k = seq_len // block_k
-
-    def body(ki, carry):
+    def tile(carry, start, width: int, masked: bool):
         acc, m, l = carry
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
+        k = k_ref[0, pl.ds(start, width), :]
+        v = v_ref[0, pl.ds(start, width), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        valid = k_pos < kv_len              # mask padded + over-length keys
-        if causal:
-            valid = valid & (q_pos >= k_pos)
-        s = jnp.where(valid, s, _NEG)
+        if masked:
+            s = _mask_tile(s, q_pos, start, kv_len, causal, has_lens)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -117,14 +194,17 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
         acc_new = acc * corr + pv
         return acc_new, m_new, l_new
 
-    hi = _k_block_hi(n_k, qi, block_q, block_k, kv_len, causal, has_lens)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q, 1), _NEG, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, hi, body, (acc0, m0, l0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l_safe)
+    def finish(carry):
+        acc, m, l = carry
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[0] = m + jnp.log(l_safe)
+
+    carry = (jnp.zeros((block_q, d), jnp.float32),
+             jnp.full((block_q, 1), _NEG, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32))
+    _walk_k(tile, finish, carry, qi, block_q, block_k, seq_len // block_k,
+            kv_len, causal, has_lens)
 
 
 # ---------------------------------------------------------------------------
@@ -148,38 +228,32 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
     kv_len = jnp.minimum(len_ref[0, 0, 0], true_len)
 
-    n_k = seq_len // block_k
-
-    def body(ki, dq):
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
+    def tile(dq, start, width: int, masked: bool):
+        k = k_ref[0, pl.ds(start, width), :]
+        v = v_ref[0, pl.ds(start, width), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        valid = k_pos < kv_len
-        if causal:
-            valid = valid & (q_pos >= k_pos)
-        s = jnp.where(valid, s, _NEG)
-        p = jnp.exp(s - lse)                        # [block_q, block_k]
+        if masked:
+            s = _mask_tile(s, q_pos, start, kv_len, causal, has_lens)
+        p = jnp.exp(s - lse)                        # [block_q, width]
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
-        dq = dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dq
+        return dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
 
-    # same skipping as the forward (see _k_block_hi: skipped blocks have
-    # p == 0 and contribute nothing to dq)
-    hi = _k_block_hi(n_k, qi, block_q, block_k, kv_len, causal, has_lens)
-    dq = jax.lax.fori_loop(0, hi, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+    def finish(dq):
+        dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+
+    # the forward's walk: what it skipped has p == 0 and adds nothing to dq
+    _walk_k(tile, finish, jnp.zeros((block_q, d), jnp.float32), qi, block_q,
+            block_k, seq_len // block_k, kv_len, causal, has_lens)
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        len_ref, dk_ref, dv_ref, *, block_q: int, scale: float,
                        causal: bool, seq_len: int, true_len: int,
-                       n_k_blocks: int):
+                       has_lens: bool):
     """dk/dv for one (batch*head, kv-block): stream Q tiles.
 
     dV = Pᵀ·dO;   dK = scale · dSᵀ·Q.
@@ -190,45 +264,63 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ki = pl.program_id(1)
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-    valid_k = k_pos < jnp.minimum(len_ref[0, 0, 0], true_len)
+    kv_len = jnp.minimum(len_ref[0, 0, 0], true_len)
 
     n_q = seq_len // block_q
 
-    def body(qi, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), :]
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    def tile(q_start, width: int, masked: bool):
+        """(dk, dv) [width, D] that q rows [q_start, + block_q) add to this
+        block's first ``width`` keys."""
+        q = q_ref[0, pl.ds(q_start, block_q), :].astype(jnp.float32) * scale
+        do = do_ref[0, pl.ds(q_start, block_q), :].astype(jnp.float32)
+        lse = lse_ref[0, pl.ds(q_start, block_q), :]
+        delta = delta_ref[0, pl.ds(q_start, block_q), :]
+        kw, vw = k[:width], v[:width]
+        s = jax.lax.dot_general(q, kw, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        valid = valid_k
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        if masked:
+            q_pos = q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, 1), 0)
-            valid = valid & (q_pos >= k_pos)
-        s = jnp.where(valid, s, _NEG)
-        p = jnp.exp(s - lse)                        # [block_q, block_k]
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+            s = _mask_tile(s, q_pos, ki * block_k, kv_len, causal, has_lens)
+        p = jnp.exp(s - lse)                        # [block_q, width]
+        dv = jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do, vw, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+        dk = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
         return dk, dv
 
-    # causal skip from the other side: q-blocks that end strictly before
-    # this k-block's first key are fully below the diagonal — p == 0 rows
-    # only, no dk/dv contribution. Statically gated on BOTH grids being
-    # multi-block: with a single k-block ki == 0 always and lo == 0, so a
-    # dynamic lower bound would be pure while-loop overhead (measured -8%)
-    lo = ((ki * block_k) // block_q
-          if (causal and n_q > 1 and n_k_blocks > 1) else 0)
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo, n_q, body, (dk0, dv0))
+    def blocks(lo, masked):
+        def body(qi, carry):
+            dk, dv = tile(qi * block_q, block_k, masked)
+            return carry[0] + dk, carry[1] + dv
+        zero = jnp.zeros((block_k, d), jnp.float32)
+        return jax.lax.fori_loop(lo, n_q, body, (zero, zero))
+
+    if not causal:
+        dk, dv = blocks(0, True)
+    else:
+        # q-blocks that end before this block's first key see none of it
+        # (p == 0 rows only, no dk/dv) and are not visited; the r the
+        # diagonal crosses see its first (c + 1) * block_q keys; those
+        # below are clear (with kv_lens they mask: see the forward)
+        r = _diag_per_block(block_q, block_k)
+        if n_q > r:
+            lo = (ki * block_k) // block_q
+            dk, dv = blocks(lo + r, has_lens)
+        else:                           # one k-block: no q-block is clear
+            lo = 0
+            dk = dv = jnp.zeros((block_k, d), jnp.float32)
+        for c in range(r):
+            w = min(block_k, (c + 1) * block_q)
+            dk_d, dv_d = tile((lo + c) * block_q, w, True)
+            if w < block_k:             # the keys past the tile get nothing
+                rest = jnp.zeros((block_k - w, d), jnp.float32)
+                dk_d = jnp.concatenate([dk_d, rest])
+                dv_d = jnp.concatenate([dv_d, rest])
+            dk, dv = dk + dk_d, dv + dv_d
     dk_ref[0] = dk.astype(dk_ref.dtype)             # scale folded into q
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -237,13 +329,27 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # layout helpers + pallas_call wrappers
 # ---------------------------------------------------------------------------
 
-def _blocks(T: int, S: int, block_q: int,
-            block_k: int) -> Tuple[int, int, int, int]:
+def _blocks(T: int, S: int, block_q: int, block_k: int,
+            causal: bool = False) -> Tuple[int, int, int, int]:
     """Block sizes + padded lengths for q (len T) and kv (len S). The two
     sides pad independently — cross-attention / half-block calls (zigzag
-    ring steps) have S != T."""
+    ring steps) have S != T. A causal call is a square; when it has several
+    k-blocks the larger block is rounded up to a multiple of the smaller
+    and both sides pad to it, the geometry the causal walk's static tile
+    widths stand on (ONE k-block is the whole of S, unpadded, as on any
+    call)."""
+    if causal and S != T:
+        raise ValueError(f"causal flash attention is a square: S {S} != T {T}")
     blk_q = min(block_q, max(8, T))
     blk_k = min(block_k, max(8, S))
+    if causal and blk_k < S:
+        if blk_k >= blk_q:
+            blk_k = -(-blk_k // blk_q) * blk_q
+        else:
+            blk_q = -(-blk_q // blk_k) * blk_k
+        big = max(blk_q, blk_k)
+        Tp = Sp = -(-T // big) * big
+        return blk_q, blk_k, Tp, Sp
     Tp = -(-T // blk_q) * blk_q
     Sp = -(-S // blk_k) * blk_k
     return blk_q, blk_k, Tp, Sp
@@ -286,6 +392,23 @@ def _lens_to_bh(kv_lens, B, H, S):
     return jnp.repeat(lens, H)[:, None, None]
 
 
+def _count_block_pairs(kernel: str, bh: int, n_q: int, n_k: int, blk_q: int,
+                       blk_k: int, causal: bool, by_k: bool = False) -> None:
+    """``kernels.flash_block_pairs_total``: the area of the square one
+    traced call's kernel walks (state=visited) against the whole square
+    (state=grid), over all batch x head squares, in block pairs of the
+    smaller block side. Counted when the wrapper's Python runs — once a
+    TRACE, as ``kernels.routes_total`` is. The causal structure alone: what
+    ``kv_lens`` skips besides depends on data and is not counted."""
+    from .. import obs
+    visited, grid = _visited_units(n_q, n_k, blk_q, blk_k, causal, by_k)
+    obs.count("kernels.flash_block_pairs_total", bh * visited,
+              kernel=kernel, state="visited")
+    obs.count("kernels.flash_block_pairs_total", bh * grid,
+              kernel=kernel, state="grid")
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
                  kv_lens=None):
     """Returns (o [B,T,H,D], lse [B,T,H] f32). k/v may be shorter or longer
@@ -294,13 +417,16 @@ def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
     (variable-length batches / cross-attention over padded sources)."""
     B, T, H, D = q.shape
     S = k.shape[1]
-    blk_q, blk_k, Tp, Sp = _blocks(T, S, block_q, block_k)
+    blk_q, blk_k, Tp, Sp = _blocks(T, S, block_q, block_k, causal)
     qb, kb, vb = _to_bh(q, Tp), _to_bh(k, Sp), _to_bh(v, Sp)
     lensb = _lens_to_bh(kv_lens, B, H, S)
     kernel = functools.partial(_fa_fwd_kernel, block_k=blk_k, scale=scale,
                                causal=causal, seq_len=Sp, true_len=S,
                                has_lens=kv_lens is not None)
-    grid = (B * H, Tp // blk_q)
+    n_q, n_k = Tp // blk_q, Sp // blk_k
+    _count_block_pairs("flash_attention_fwd", B * H, n_q, n_k, blk_q, blk_k,
+                       causal)
+    grid = (B * H, n_q)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -326,13 +452,14 @@ def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
     return o, lse
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
 def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                  interpret, delta=None, kv_lens=None):
     """Returns (dq, dk, dv); dq follows q's [B,T,H,D], dk/dv follow k/v's
     [B,S,H,D] (S != T for the zigzag half-block steps)."""
     B, T, H, D = q.shape
     S = k.shape[1]
-    blk_q, blk_k, Tp, Sp = _blocks(T, S, block_q, block_k)
+    blk_q, blk_k, Tp, Sp = _blocks(T, S, block_q, block_k, causal)
     if delta is None:
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1)
@@ -340,6 +467,11 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     kb, vb = _to_bh(k, Sp), _to_bh(v, Sp)
     lseb, deltab = _row_to_bh(lse, Tp), _row_to_bh(delta, Tp)
     lensb = _lens_to_bh(kv_lens, B, H, S)
+    n_q, n_k = Tp // blk_q, Sp // blk_k
+    _count_block_pairs("flash_attention_bwd_dq", B * H, n_q, n_k, blk_q,
+                       blk_k, causal)
+    _count_block_pairs("flash_attention_bwd_dkv", B * H, n_q, n_k, blk_q,
+                       blk_k, causal, by_k=True)
 
     q_spec = pl.BlockSpec((1, blk_q, D), lambda bh, qi: (bh, qi, 0))
     q_full_spec = pl.BlockSpec((1, Tp, D), lambda bh, i: (bh, 0, 0))
@@ -356,7 +488,7 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                                   has_lens=kv_lens is not None)
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(B * H, Tp // blk_q),
+        grid=(B * H, n_q),
         in_specs=[q_spec, kv_full_spec, kv_full_spec, q_spec, row_q_spec,
                   row_q_spec, len_spec],
         out_specs=q_spec,
@@ -369,10 +501,20 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     # rows have zero do/delta so they contribute nothing); mask keys >= S
     dkv_kernel = functools.partial(_fa_bwd_dkv_kernel, block_q=blk_q,
                                    scale=scale, causal=causal, seq_len=Tp,
-                                   true_len=S, n_k_blocks=Sp // blk_k)
+                                   true_len=S, has_lens=kv_lens is not None)
+    # q, do and the [Tp, 1] lse / delta rows (lane-padded 128x in VMEM) are
+    # resident per program, double-buffered: 10 MiB at T 4096. Past half the
+    # default scoped limit (16 MiB) the tiles' temporaries no longer fit
+    # beside them, so such a call asks for 32 of the chip's 128 MiB.
+    resident = 2 * 2 * Tp * (128 * 4 + D * q.dtype.itemsize)
+    params = {}
+    if resident > 8 * 2 ** 20:
+        from jax.experimental.pallas import tpu as pltpu
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=32 * 2 ** 20)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(B * H, Sp // blk_k),
+        grid=(B * H, n_k),
         in_specs=[q_full_spec, k_spec, k_spec, q_full_spec, row_full_spec,
                   row_full_spec, len_spec],
         out_specs=[k_spec, k_spec],
@@ -380,6 +522,7 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                    jax.ShapeDtypeStruct((B * H, Sp, D), v.dtype)],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
+        **params,
     )(qb, kb, vb, dob, lseb, deltab, lensb)
 
     return (_from_bh(dq, B, T, H, D), _from_bh(dk, B, S, H, D),
@@ -392,18 +535,25 @@ def _fa_bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
 
 def _default_blocks(block_q: Optional[int],
                     block_k: Optional[int]) -> Tuple[int, int]:
-    """Measured on v5e (GPT-2-small shapes, fwd+bwd): 128x128 tiles spend
-    ~5x the kernel's time on per-program overhead; 512/1024 sits within 10%
-    of the best sweep point while keeping the dq/dkv working sets well
-    inside the 16MB VMEM budget. _blocks() still clamps to the actual
-    sequence lengths, so short sequences are unaffected."""
+    """512 / 1024 for every call that names no blocks, causal or not.
+    Measured on this v5e at the train cell's call ([8, 1023, 16, 64] bf16,
+    causal; fwd + dq + dk/dv ms a call, PERF.md PR 31): 512 x 1024 1.47,
+    256 x 1024 1.56, 512 x 512 1.80, 256 x 512 2.04, 128 x 1024 2.14,
+    256 x 256 2.97 — and 1.96 before the causal walk, when 512 x 1024
+    computed the whole square. A tile's time is its area at ~43 % of the
+    MXU's peak (what d_head 64 allows) plus a cost a tile and a program, so
+    few wide tiles win and 512 rows keep the MXU's weights busy; the
+    diagonal is followed by narrowing the one tile that meets it, not by
+    small blocks. _blocks() still clamps to the actual lengths, so short
+    sequences are unaffected."""
     return block_q or 512, block_k or 1024
 
 
 # below this sequence length the Pallas kernels' per-program overhead beats
-# their HBM saving on this chip (128-tile flash measured 5x slower than
-# 512/1024 tiles; at S<=256 the whole [T,S] score tile fits comfortably in
-# VMEM through XLA fusion anyway) — a masked dense einsum is faster
+# their HBM saving on this chip (128 x 128 tiles measured 3x slower than
+# 512 x 1024: PERF.md, PR 31; at S<=256 the whole [T,S] score tile fits
+# comfortably in VMEM through XLA fusion anyway) — a masked dense einsum is
+# faster
 SHORT_SEQ_DENSE = 256
 
 
@@ -456,7 +606,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     cross-attention path; grads for masked keys are exactly zero. Fully
     differentiable: the VJP runs dedicated Pallas dq and dk/dv kernels that
     recompute probability tiles in VMEM from the saved logsumexp — no [T, S]
-    matrix in HBM in either direction.
+    matrix in HBM in either direction. With ``causal`` (a square: S == T)
+    all three kernels visit only what lies at or below the diagonal (the
+    walk is described above ``_mask_tile``).
 
     Short sequences (max(T, S) < SHORT_SEQ_DENSE, no explicit blocks given)
     auto-route to a masked dense einsum: below that point the kernels'

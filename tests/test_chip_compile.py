@@ -86,6 +86,41 @@ def test_flash_attention_compiles(S, T, grad):
     _compile(fn, qkv, qkv, qkv)
 
 
+@pytest.mark.parametrize("cell, shape, dtype, grad, calls", [
+    ("gpt2m-train-1k", (8, 1023, 16, 64), jnp.bfloat16, False, 1),
+    ("gpt2m-train-1k", (8, 1023, 16, 64), jnp.bfloat16, True, 3),
+    ("gpt2l-serve", (16, 512, 20, 64), jnp.float32, False, 1),
+], ids=["train-fwd", "train-fwd_bwd", "serve-512-fwd"])
+def test_flash_attention_compiles_at_the_cells_calls(S, cell, shape, dtype,
+                                                     grad, calls):
+    """The benchmark cells' own causal calls: the train step's 8 rows x 1023
+    tokens x 16 heads in bf16 (not a block multiple: Tp 1024), forward and
+    forward + both backward kernels, and the GPT-2 serve cells' widest
+    prompt bucket in f32. Every kernel must keep an operand or result of
+    ``[rows * heads, T | T + 1, d_head]``: the benchmark's reader,
+    ``chipbench/metrics/flash_attention_roofline.py``, tells the kernels in
+    a trace by that shape, and a layout it cannot find makes the metric
+    vanish and the result line malformed."""
+    import re
+    qkv = S(shape, dtype)
+    rows, T, heads, d_head = shape
+
+    def fwd(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd,
+                    qkv, qkv, qkv)
+    kernels = [ln for ln in text.splitlines()
+               if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(kernels) == calls
+    shape_re = re.compile(rf"\[{rows * heads},({T}|{T + 1}),{d_head}\]")
+    for ln in kernels:
+        assert shape_re.search(ln), ln[:300]
+
+
 @pytest.mark.parametrize("L", [256, 512, 1024])
 @pytest.mark.parametrize("kv", ["bf16", "f32", "int8"])
 def test_decode_attention_compiles(S, L, kv):

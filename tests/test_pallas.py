@@ -1,6 +1,8 @@
 """Pallas kernel numerics vs the jnp reference path (interpret mode on CPU) —
 the per-op equivalence discipline of the MKLDNN tester (SURVEY.md §8.3)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,14 +11,21 @@ import pytest
 from paddle_tpu.ops.pallas_kernels import flash_attention
 
 
-def _full_attention(q, k, v, causal=False):
-    B, T, H, D = q.shape
+def _masked_attention(q, k, v, causal, lens=None):
+    """Dense f32 reference with the kernels' two masks."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    T, S, D = q.shape[1], k.shape[1], q.shape[-1]
     s = jnp.einsum("bthd,bshd->bhts", q, k) * (D ** -0.5)
+    if lens is not None:
+        key_ok = (jnp.arange(S)[None, :] < lens[:, None])[:, None, None, :]
+        s = jnp.where(key_ok, s, -1e30)
     if causal:
-        mask = jnp.tril(jnp.ones((T, T), bool))
-        s = jnp.where(mask[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhts,bshd->bthd", p, v)
+        s = jnp.where(jnp.tril(jnp.ones((T, S), bool))[None, None], s, -1e30)
+    return jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _full_attention(q, k, v, causal=False):
+    return _masked_attention(q, k, v, causal)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -189,6 +198,147 @@ def test_flash_backward_no_dense_scores_in_jaxpr():
             shape = getattr(var.aval, "shape", ())
             assert not (len(shape) >= 2 and shape[-1] == T and
                         shape[-2] == T), f"dense [T,T] tensor in bwd: {eqn}"
+
+
+def _qkvg(seed, B, T, S, H, D, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shapes = [(B, T, H, D), (B, S, H, D), (B, S, H, D), (B, T, H, D)]
+    return [jax.random.normal(k_, sh).astype(dtype)
+            for k_, sh in zip(ks, shapes)]
+
+
+@pytest.mark.parametrize("with_lens", [False, True], ids=["full", "kv_lens"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("blocks", [(16, 16), (32, 16), (16, 32), (16, 128)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")   # 128: one k-block
+@pytest.mark.parametrize("T", [80, 75])    # 5 blocks a side; 75: ragged tail
+def test_flash_causal_walk_matches_dense(T, blocks, dtype, with_lens):
+    """The causal kernels visit only the block pairs at or below the
+    diagonal and mask only those it crosses (forward, dq, dk/dv): at >= 3
+    blocks a side, with a ragged tail, unequal blocks and per-sample
+    lengths, outputs and all three grads still match dense attention."""
+    B, H, D = 3, 2, 16
+    q, k, v, g = _qkvg(21, B, T, T, H, D, dtype)
+    lens = jnp.array([T, 37, 5], jnp.int32) if with_lens else None
+    bq, bk = blocks
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, causal=True, kv_lens=lens, block_q=bq,
+                               block_k=bk, interpret=True)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)
+                                       * g.astype(jnp.float32))
+
+    tol = 2e-4 if dtype == "float32" else 3e-2
+    ref = functools.partial(_masked_attention, causal=True, lens=lens)
+    np.testing.assert_allclose(np.asarray(f(q, k, v), np.float32),
+                               np.asarray(ref(q, k, v)), rtol=tol, atol=tol)
+    got = jax.jit(jax.grad(loss(f), (0, 1, 2)))(q, k, v)
+    want = jax.grad(loss(ref), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol)
+    if with_lens:                           # masked keys: exact zero
+        assert np.all(np.asarray(got[1], np.float32)[1, 37:] == 0)
+        assert np.all(np.asarray(got[2], np.float32)[2, 5:] == 0)
+
+
+@pytest.mark.parametrize("with_lens", [False, True], ids=["full", "kv_lens"])
+@pytest.mark.parametrize("T,S", [(80, 80), (75, 75), (48, 80), (80, 43)],
+                         ids=lambda n: str(n))
+def test_flash_noncausal_and_cross_match_dense(T, S, with_lens):
+    """What the causal walk must not touch: non-causal squares and S != T
+    calls at several blocks a side, outputs and grads against dense."""
+    B, H, D = 2, 2, 16
+    q, k, v, g = _qkvg(23, B, T, S, H, D, jnp.float32)
+    lens = jnp.array([S, 19], jnp.int32) if with_lens else None
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, kv_lens=lens, block_q=16, block_k=16,
+                               interpret=True)
+
+    ref = functools.partial(_masked_attention, causal=False, lens=lens)
+    np.testing.assert_allclose(np.asarray(f(q, k, v)),
+                               np.asarray(ref(q, k, v)), rtol=2e-4, atol=2e-4)
+    got = jax.grad(lambda *a: jnp.sum(f(*a) * g), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(ref(*a) * g), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def _flash_grids(fn, *args):
+    """{kernel name: grid} of the pallas_calls in ``fn``'s jaxpr."""
+    grids = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+@pytest.mark.parametrize("case, causal, T, S, want", [
+    # the parent's grids: (B*H, Tp / 512) and (B*H, Sp / min(1024, S))
+    ("noncausal", False, 1024, 1024, ((4, 2), (4, 2), (4, 1))),
+    ("noncausal-long", False, 2048, 2048, ((4, 4), (4, 4), (4, 2))),
+    ("cross", False, 600, 1500, ((4, 2), (4, 2), (4, 2))),
+    ("ring-half", False, 1024, 512, ((4, 2), (4, 2), (4, 1))),
+    ("one-block", True, 256, 256, ((4, 1), (4, 1), (4, 1))),
+    ("causal-train-cell", True, 1023, 1023, ((4, 2), (4, 2), (4, 1))),
+    ("causal-long", True, 4096, 4096, ((4, 8), (4, 8), (4, 4))),
+])
+def test_flash_default_grids(case, causal, T, S, want):
+    """Default blocks are 512 / 1024 for every call, so a non-causal call,
+    an S != T call, a call with one block a side — and the causal square,
+    whose walk narrows the tile on the diagonal inside a program — all
+    keep the kernel grids they had before the causal walk (PR 31)."""
+    q = jnp.zeros((2, T, 2, 64), jnp.bfloat16)
+    kv = jnp.zeros((2, S, 2, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=causal,
+                               interpret=True).astype(jnp.float32).sum()
+
+    grids = _flash_grids(jax.grad(loss, (0, 1, 2)), q, kv, kv)
+    assert (grids["flash_attention_fwd"], grids["flash_attention_bwd_dq"],
+            grids["flash_attention_bwd_dkv"]) == want
+
+
+@pytest.mark.parametrize("case, causal, T, S, blocks, visited, grid", [
+    ("causal", True, 80, 80, (16, 16), 15, 25),          # n (n + 1) / 2
+    ("causal-ragged", True, 75, 75, (16, 16), 15, 25),
+    ("causal-wide-q", True, 80, 80, (32, 16), 4 + 8 + 12, 36),   # Tp 96
+    ("causal-wide-k", True, 80, 80, (16, 32), 21, 36),
+    ("causal-one-k-block", True, 75, 75, (16, 128), 15, 24),    # ragged
+    ("causal-one-block", True, 16, 16, (16, 16), 1, 1),
+    ("noncausal", False, 80, 80, (16, 16), 25, 25),
+    ("cross", False, 48, 80, (16, 16), 15, 15),
+])
+def test_flash_block_pairs_counter(case, causal, T, S, blocks, visited, grid):
+    """kernels.flash_block_pairs_total: visited / grid is the share of the
+    square a traced call's kernels walk, per kernel, over B * H squares."""
+    from paddle_tpu import obs
+    B, H, D = 2, 3, 16
+    q, k, v, g = _qkvg(29, B, T, S, H, D, jnp.float32)
+    r = obs.MetricsRegistry()
+    jax.clear_caches()      # counted once a TRACE: a cached one counts nothing
+    with obs.ObsSession(registry=r).installed():
+        jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, causal=causal, block_q=blocks[0], block_k=blocks[1],
+            interpret=True) * g), (0, 1, 2))(q, k, v)
+    c = r.counter("kernels.flash_block_pairs_total")
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert c.get(kernel=kernel, state="visited") == B * H * visited, kernel
+        assert c.get(kernel=kernel, state="grid") == B * H * grid, kernel
 
 
 @pytest.mark.parametrize("block_b,chunk_t", [(2, None), (5, 3)])
