@@ -1313,22 +1313,35 @@ def cmd_serve(args):
     ``--vocab/--d_model/...`` flags and ``--seed`` (the bring-up and e2e
     test mode: the same flags + seed reproduce the exact weights).
 
-    What a ``--config`` model must offer the page pool (``TransformerLM``
-    and ``DeepseekV3LM`` both do; serving/paged.py names no cache array
-    itself): ``max_len``; ``cache_rows(params, kv_dtype)`` — the per-layer
-    cache arrays as (name, trailing shape, dtype, fill), raising ValueError
-    for a ``kv_dtype`` it has no cache for; ``prefill(params, prompts,
-    lengths, kv_dtype=, pad_to=)`` -> (cell with one ``[B, pad_to, *shape]``
-    entry per stated array and ``pos``, last logits); ``decode_step_paged(
-    params, cell, tokens, tables, live=)`` -> (logits, new cell) over the
-    pools ``[pages, page_block, *shape]``; ``paged_read_kernel`` and
-    ``paged_read_geometry(params, kv_dtype)`` — the decode read's registered
-    cost model and the shape facts it takes; and, only for the prefix cache,
-    ``prefill_paged`` (a model without it needs ``--no_prefix_cache``; with
-    it on, ``serve`` refuses at start-up). Optional: ``program_stats_zero()``
+    What a ``--config`` model must offer the page pool (``TransformerLM``,
+    ``DeepseekV3LM`` and ``Lfm2MoeLM`` do; serving/paged.py names no cache
+    array itself): ``max_len``; ``cache_rows(params, kv_dtype)`` — its state
+    as it states it, raising ValueError for a ``kv_dtype`` it has no cache
+    for: ``CacheRow`` (name, trailing shape, dtype, fill) for what lives in
+    PAGES, a row a token, and ``SlotRow`` (the same four) for what lives
+    PER SLOT at a fixed size whatever the context (Lfm2MoeLM's convolution
+    tails); ``prefill(params, prompts, lengths, kv_dtype=, pad_to=)`` ->
+    (cell with one ``[B, pad_to, *shape]`` entry per cache row, one ``[B,
+    *shape]`` entry per slot row — each row's state at its own length — and
+    ``pos``, last logits); ``decode_step_paged(params, cell, tokens, tables,
+    live=)`` -> (logits, new cell) over the pools ``[pages, page_block,
+    *shape]`` and the slot rows ``[slots, *shape]``; ``paged_read_kernel``
+    and ``paged_read_geometry(params, kv_dtype)`` — the decode read's
+    registered cost model and the shape facts it takes (``kv_heads`` where
+    a cache row holds fewer heads than the queries have) — and optionally
+    ``paged_read_layers`` (layers of a step that make that read; default all);
+    and, only for the prefix cache, ``prefill_paged`` (a model without it
+    needs ``--no_prefix_cache``; with it on, ``serve`` refuses at start-up;
+    slot rows are not shared by prefix). Optional: ``program_stats_zero()``
     / ``note_program_stats(stats, program)`` for counts a program returns
-    beside its tokens. The dtype of weights and cache follows the arrays the
-    script hands over: there is no dtype flag.
+    beside its tokens (they land on the ``serving.prefill`` and
+    ``serving.segment`` spans). The dtype of weights and cache follows the
+    arrays the script hands over: there is no dtype flag.
+
+    ``--prompt_buckets 512,1024,2048,4096`` names the prompt lengths
+    admissions are padded to, one compiled admit program each; the default
+    32..512 is what the GPT-2 and GigaChat cells run. A prompt past the last bucket compiles
+    its own length when it arrives.
 
     The address line ``SERVING <host> <port>`` prints first and flushed
     (machine-parseable, same contract as ``obs serve``); the process then
@@ -1376,7 +1389,7 @@ def cmd_serve(args):
             model, params, slots=args.slots, segment=args.segment,
             page_block=args.page_block, pages=args.pages,
             cache_bucket=args.cache_bucket, kv_dtype=args.kv_dtype,
-            queue_cap=args.queue_cap,
+            prompt_buckets=args.prompt_buckets, queue_cap=args.queue_cap,
             default_timeout_s=args.request_timeout,
             prefix_cache=not args.no_prefix_cache,
             class_weights={"interactive": args.interactive_weight,
@@ -1459,6 +1472,15 @@ def cmd_serve(args):
     return 0
 
 
+def _int_list(s: str):
+    """``"512,1024"`` -> (512, 1024): ascending positive ints."""
+    out = tuple(int(x) for x in str(s).split(",") if x.strip())
+    if not out or any(b <= 0 for b in out) or list(out) != sorted(set(out)):
+        raise argparse.ArgumentTypeError(
+            f"expected ascending positive integers N,N,..., got {s!r}")
+    return out
+
+
 def _parse_hostport(s: str):
     host, _, port = str(s).rpartition(":")
     if not host or not port.isdigit():
@@ -1497,6 +1519,7 @@ def _serve_prefill(args, model, params, session, flight):
                         segment=args.segment, page_block=args.page_block,
                         pages=args.pages, cache_bucket=args.cache_bucket,
                         kv_dtype=args.kv_dtype,
+                        prompt_buckets=args.prompt_buckets,
                         prefix_cache=not args.no_prefix_cache)
     except ValueError as e:
         _teardown()
@@ -1979,12 +2002,13 @@ def main(argv=None) -> int:
                         "(srv_submit/srv_poll/srv_cancel; "
                         "docs/design/serving.md)")
     sv.add_argument("--config", default=None,
-                    help="Python script exposing `model` and `params`: any "
-                    "model that states its cache rows and offers prefill "
-                    "and decode_step_paged (TransformerLM, DeepseekV3LM; "
-                    "the interface is in `serve`'s docstring); weights and "
-                    "cache keep the dtype of `params`; omitted = "
-                    "random-init TransformerLM from the flags")
+                    help="Python script exposing `model` and `params`: "
+                    "any model that states "
+                    "its cache rows and slot rows and offers prefill and "
+                    "decode_step_paged (TransformerLM, DeepseekV3LM, "
+                    "Lfm2MoeLM; the interface is in `serve`'s docstring); "
+                    "weights and cache keep the dtype of `params`; omitted "
+                    "= random-init TransformerLM from the flags")
     sv.add_argument("--host", default="127.0.0.1")
     sv.add_argument("--port", type=int, default=0)
     sv.add_argument("--vocab", type=int, default=50257)
@@ -2002,6 +2026,12 @@ def main(argv=None) -> int:
                     help="pool pages incl. the null page (default: worst "
                     "case slots*max_len/page_block + 1)")
     sv.add_argument("--cache_bucket", type=int, default=256)
+    sv.add_argument("--prompt_buckets", type=_int_list,
+                    default=(32, 64, 128, 256, 512), metavar="N,N,...",
+                    help="ascending prompt lengths an admission is padded "
+                    "to, one compiled admit program each; the longest "
+                    "bounds nothing: a longer prompt compiles its own "
+                    "length")
     sv.add_argument("--kv_dtype", choices=["int8"], default=None)
     sv.add_argument("--queue_cap", type=int, default=64)
     sv.add_argument("--no_prefix_cache", action="store_true",
@@ -2009,7 +2039,7 @@ def main(argv=None) -> int:
                     "(default ON for the daemon: requests sharing a "
                     "prompt prefix share KV pages and prefill only the "
                     "suffix; docs/design/serving.md); required for a model "
-                    "without prefill_paged (DeepseekV3LM)")
+                    "without prefill_paged (DeepseekV3LM, Lfm2MoeLM)")
     sv.add_argument("--interactive_weight", type=float, default=4.0,
                     help="weighted-fair service share of slo=interactive "
                     "requests vs slo=batch (deficit scheduling at slot "

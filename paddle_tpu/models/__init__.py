@@ -4,6 +4,7 @@
 from .deepseek_v3 import DeepseekV3LM
 from .embeddings import DeepFM, Recommender, Word2Vec
 from .generative import GAN, VAE
+from .lfm2 import Lfm2MoeLM
 from .image import (AlexNet, GoogleNet, LeNet, ResNet, SmallNet,
                     VGG, resnet50)
 from .mlp import MnistMLP
@@ -18,5 +19,5 @@ __all__ = [
            "LSTMTextCls", "BiLSTMTextCls", "ConvTextCls",
            "AttentionSeq2Seq", "LinearCRFTagger", "BiLSTMCRFTagger",
            "Word2Vec", "Recommender", "DeepFM", "GAN", "VAE",
-           "TransformerLM", "TransformerBlock", "DeepseekV3LM",
+           "TransformerLM", "TransformerBlock", "DeepseekV3LM", "Lfm2MoeLM",
            "TransformerSeq2Seq", "CrossAttentionBlock"]

@@ -41,12 +41,10 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
-from ..parallel.expert_share import ExpertShare
-from .transformer import CacheRow
-
-
-#: tokens a prefill runs through the depth at once (rows x prompt width)
-PREFILL_TOKENS = 2048
+from ..parallel.expert_share import (ExpertShare, ProgramStats,
+                                     ffn_or_experts)
+from .transformer import (PREFILL_TOKENS, CacheRow, paged_greedy,
+                          prefill_live_rows)
 
 
 def _dot(x, w):
@@ -150,16 +148,10 @@ class DeepseekV3Block(nn.Module):
 
     def feed_forward(self, params, h, live):
         """h [..., d] f32 -> (h + FFN(norm(h)), counts or None)."""
-        y = self.ffn_norm(params["ffn_norm"], h)
-        if not self.is_moe:
-            return h + self.ffn(params["ffn"], y), None
-        flat = y.reshape(-1, y.shape[-1])
-        out, counts = self.moe(params["moe"], flat,
-                               None if live is None else live.reshape(-1))
-        return h + out.reshape(h.shape), counts
+        return ffn_or_experts(self, params, h, live)
 
 
-class DeepseekV3LM(nn.Module):
+class DeepseekV3LM(ProgramStats, nn.Module):
     """``vocab`` rows of embedding and (untied) head, ``n_layers`` blocks of
     which the first ``n_dense`` carry the dense FFN and the rest the expert
     layer over ``experts_held`` of ``n_experts``."""
@@ -237,25 +229,6 @@ class DeepseekV3LM(nn.Module):
     def _compute_dtype(self, params):
         return params["embed"]["w"].dtype
 
-    def program_stats_zero(self):
-        """Accumulators a program returns beside its tokens: the live
-        (token, choice) pairs that landed on each held expert, per expert
-        layer; the held experts touched, summed over steps and layers; the
-        live tokens routed, summed over steps."""
-        return {"routed": jnp.zeros((self.n_moe, self.n_held), jnp.int32),
-                "touched": jnp.zeros((), jnp.int32),
-                "tokens": jnp.zeros((), jnp.int32)}
-
-    def _add_stats(self, stats, counts, live, n_rows):
-        if not counts:
-            return stats
-        c = jnp.stack(counts)
-        n = n_rows if live is None else jnp.sum(live, dtype=jnp.int32)
-        return {"routed": stats["routed"] + c,
-                "touched": stats["touched"] + jnp.sum(c > 0,
-                                                      dtype=jnp.int32),
-                "tokens": stats["tokens"] + n}
-
     # -- whole sequences ---------------------------------------------------
     def _sequence(self, params, ids, lengths, keep_latents):
         B, T = ids.shape
@@ -305,35 +278,13 @@ class DeepseekV3LM(nn.Module):
                              f"is narrower than the prompt ({T0})")
         pos = (jnp.full((B,), T0, jnp.int32) if lengths is None
                else jnp.asarray(lengths, jnp.int32))
-        # Rows are independent of one another, so the depth runs a few rows
-        # at a time (PREFILL_TOKENS tokens): the expanded keys and values,
-        # the flash kernel's copies and the FFN's intermediates are bounded
-        # by that, not by slots x prompt bucket. And only rows that HOLD a
-        # prompt run at all: the page pool hands every admission the whole
-        # pool's width with length 0 in the slots it is not filling, so the
-        # rows are taken live ones first and the walk stops after the last
-        # chunk that has one — an admission costs what was admitted.
-        R = next(r for r in range(max(1, min(B, PREFILL_TOKENS // T0)), 0,
-                                  -1) if B % r == 0)
-        order = jnp.argsort(pos == 0, stable=True).astype(jnp.int32)
-        n_chunks = (jnp.sum(pos > 0, dtype=jnp.int32) + R - 1) // R
-
-        def chunk(carry):
-            i, last, latents, stats = carry
-            idx = jax.lax.dynamic_slice(order, (i * R,), (R,))
-            n = pos[idx]
-            h, lat, st = self._sequence(params, prompt[idx], n, True)
-            last = last.at[idx].set(h[jnp.arange(R), n - 1])
-            latents = [buf.at[idx].set(x) for buf, x in zip(latents, lat)]
-            return (i + 1, last, latents,
-                    jax.tree_util.tree_map(jnp.add, stats, st))
-        d = params["embed"]["w"].shape[1]
+        # a few rows at a time, and only the rows that HOLD a prompt
         dt = self._compute_dtype(params)
-        _, last, latents, stats = jax.lax.while_loop(
-            lambda c: c[0] < n_chunks, chunk,
-            (jnp.int32(0), jnp.zeros((B, d), jnp.float32),
-             [jnp.zeros((B, T0, self.row), dt) for _ in self.blocks],
-             self.program_stats_zero()))
+        last, latents, stats = prefill_live_rows(
+            lambda ids, n: self._sequence(params, ids, n, True), prompt, pos,
+            params["embed"]["w"].shape[1],
+            [jnp.zeros((B, T0, self.row), dt) for _ in self.blocks],
+            self.program_stats_zero(), PREFILL_TOKENS)
         cell = {"pos": pos, "stats": stats}
         for i, lat in enumerate(latents):
             cell[f"kv{i}"] = jnp.pad(lat, ((0, 0), (0, limit - T0), (0, 0)))
@@ -382,39 +333,4 @@ class DeepseekV3LM(nn.Module):
         """Greedy continuation through prefill + the paged decode step
         (one private table a sample): prompt [B, T0] -> [B, T0 + steps].
         The solo decode a served stream is compared with."""
-        B, T0 = prompt.shape
-        nb = self.max_len // page_block
-        cell, last = self.prefill(params, prompt)
-        tables = 1 + jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
-        pools = {}
-        for r in self.cache_rows(params):
-            rows = cell[r.name].reshape((B * nb, page_block) + r.shape)
-            pools[r.name] = jnp.concatenate(
-                [jnp.zeros((1, page_block) + r.shape, r.dtype), rows])
-        cell = dict(pools, pos=cell["pos"])
-        cur = jnp.argmax(last, axis=-1).astype(prompt.dtype)
-        out = [prompt, cur[:, None]]
-        for _ in range(steps - 1):
-            logits, cell = self.decode_step_paged(params, cell, cur, tables)
-            cur = jnp.argmax(logits, axis=-1).astype(prompt.dtype)
-            out.append(cur[:, None])
-        return jnp.concatenate(out, axis=1)
-
-    def note_program_stats(self, stats, program: str):
-        """Host side of :meth:`program_stats_zero`: count what a program
-        (``admit`` or ``segment``) routed, mark it on the timeline
-        (``moe.program``), and return what the enclosing span should carry
-        — the pairs that landed here, the expert visits, and the busiest
-        (layer, held expert) cell of the program."""
-        from .. import obs
-        routed = stats["routed"]
-        here, touched = int(routed.sum()), int(stats["touched"])
-        obs.count("moe.assignments_total",
-                  int(stats["tokens"]) * self.top_k * self.n_moe,
-                  program=program)
-        obs.count("moe.assignments_here_total", here, program=program)
-        obs.count("moe.experts_touched_total", touched, program=program)
-        attrs = {"routed_here": here, "experts_touched": touched,
-                 "load_max": int(routed.max())}
-        obs.instant("moe.program", program=program, **attrs)
-        return attrs
+        return paged_greedy(self, params, prompt, steps, page_block)

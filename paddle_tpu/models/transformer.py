@@ -35,6 +35,94 @@ class CacheRow(NamedTuple):
     fill: float = 0.0
 
 
+class SlotRow(NamedTuple):
+    """Per-SLOT state a model states beside its pages (``cache_rows`` may
+    list both): state of a fixed size whatever the context — a short
+    convolution's tail, a recurrence's carry. The page pool allocates
+    ``[slots, *shape]`` of ``dtype``, writes an admitted slot's entry from
+    the ``[B, *shape]`` array of this name in ``prefill``'s cell, hands it
+    to ``decode_step_paged`` in the cell and keeps what comes back for the
+    live slots (any other slot's goes back to ``fill``: a freed slot is
+    clear after the next segment), and ships it with the slot's pages."""
+    name: str
+    shape: tuple
+    dtype: object
+    fill: float = 0.0
+
+
+#: tokens a chunked prefill runs through the depth at once (rows x width)
+PREFILL_TOKENS = 2048
+
+
+def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
+                      chunk_tokens):
+    """The admission walk ``DeepseekV3LM`` and ``Lfm2MoeLM`` share. Rows
+    are independent of one another, so the depth runs a few rows at a time
+    (``chunk_tokens``, the caller's ``PREFILL_TOKENS``): what a chunk
+    expands is bounded by that, not by slots x prompt bucket. And only rows
+    that HOLD a prompt run at
+    all: the page pool hands every admission the whole pool's width with
+    length 0 in the slots it is not filling, so the rows are taken live
+    ones first and the walk stops after the last chunk that has one — an
+    admission costs what was admitted (but for the rows that fill up the
+    last live chunk).
+
+    ``sequence(ids [R, T0], lengths [R]) -> (h [R, T0, d] f32, state,
+    stats)``; ``state0``: a pytree of ``[B, ...]`` buffers the chunks'
+    ``state`` (same tree, ``[R, ...]``) is written into; ``stats0``: the
+    tree the chunks' ``stats`` are summed into. Returns (each row's hidden
+    state at its last position [B, d], state, stats); rows of length 0
+    keep their zeros."""
+    B, T0 = prompt.shape
+    R = next(r for r in range(max(1, min(B, chunk_tokens // T0)), 0, -1)
+             if B % r == 0)
+    order = jnp.argsort(pos == 0, stable=True).astype(jnp.int32)
+    n_chunks = (jnp.sum(pos > 0, dtype=jnp.int32) + R - 1) // R
+
+    def chunk(carry):
+        i, last, state, stats = carry
+        idx = jax.lax.dynamic_slice(order, (i * R,), (R,))
+        n = pos[idx]
+        h, new, st = sequence(prompt[idx], n)
+        last = last.at[idx].set(h[jnp.arange(R), n - 1])
+        state = jax.tree_util.tree_map(lambda buf, x: buf.at[idx].set(x),
+                                       state, new)
+        return (i + 1, last, state,
+                jax.tree_util.tree_map(jnp.add, stats, st))
+    _, last, state, stats = jax.lax.while_loop(
+        lambda c: c[0] < n_chunks, chunk,
+        (jnp.int32(0), jnp.zeros((B, d_model), jnp.float32), state0,
+         stats0))
+    return last, state, stats
+
+
+def paged_greedy(model, params, prompt, steps: int, page_block: int):
+    """Greedy continuation through ``model.prefill`` + its paged decode
+    step, one private block table a sample: prompt [B, T0] -> [B, T0 +
+    steps]. The solo decode a served stream is compared with, for any
+    model that states its rows (``cache_rows``: pages are cut from the
+    prefill's cell, slot rows carried as they come)."""
+    B = prompt.shape[0]
+    nb = model.max_len // page_block
+    cell, last = model.prefill(params, prompt)
+    tables = 1 + jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
+    state = {"pos": cell["pos"]}
+    for r in model.cache_rows(params):
+        if isinstance(r, SlotRow):
+            state[r.name] = cell[r.name]
+            continue
+        rows = cell[r.name].reshape((B * nb, page_block) + r.shape)
+        state[r.name] = jnp.concatenate(
+            [jnp.zeros((1, page_block) + r.shape, r.dtype), rows])
+    cur = jnp.argmax(last, axis=-1).astype(prompt.dtype)
+    out = [prompt, cur[:, None]]
+    for _ in range(steps - 1):
+        logits, state = model.decode_step_paged(params, state, cur, tables)
+        cur = jnp.argmax(logits, axis=-1).astype(prompt.dtype)
+        out.append(cur[:, None])
+    return jnp.concatenate(out, axis=1)
+
+
 class TransformerBlock(nn.Module):
     def __init__(self, d_model: int, n_heads: int, d_ff: int,
                  init_std: float = 0.02, causal: bool = True):

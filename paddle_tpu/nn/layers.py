@@ -251,3 +251,66 @@ class SwiGLU(Module):
         u = jnp.dot(x, params["w_up"], preferred_element_type=jnp.float32)
         return jnp.dot((jax.nn.silu(g) * u).astype(dt), params["w_down"],
                        preferred_element_type=jnp.float32)
+
+
+class ShortConv(Module):
+    """Gated short convolution (the ``conv`` operator of LFM2): ``[B | C |
+    x] = u W_in``; ``z = B * x``; ``c_t = sum_j w[:, j] * z_{t - (taps - 1)
+    + j}`` — depthwise, causal, no bias; ``y = (C * c) W_out``. Its state is
+    the last ``taps - 1`` rows of ``z``, fixed in size whatever the context.
+
+    Operands of the two projections in the weights' dtype with float32
+    accumulation; ``z`` is rounded to that dtype where it is made (it is
+    what the state holds, so a step from the state and a whole sequence see
+    the same values); the gates and the taps' sum are float32."""
+
+    def __init__(self, dim: int, taps: int = 3,
+                 w_init: Optional[I.Initializer] = None, dtype=jnp.float32):
+        super().__init__()
+        self.dim, self.taps = dim, taps
+        init = w_init or I.normal(0.0, 0.02)
+        self.param("w_in", (dim, 3 * dim), init, dtype=dtype)
+        # PyTorch's Conv1d default: uniform(+-1/sqrt(fan_in)), fan_in = taps
+        self.param("w_conv", (dim, taps),
+                   I.uniform(-taps ** -0.5, taps ** -0.5), dtype=dtype)
+        self.param("w_out", (dim, dim), init, dtype=dtype)
+
+    def _gates(self, params, u):
+        dt = params["w_in"].dtype
+        bcx = jnp.dot(u.astype(dt), params["w_in"],
+                      preferred_element_type=jnp.float32)
+        b, c, x = jnp.split(bcx, 3, axis=-1)
+        return (b * x).astype(dt), c
+
+    def _out(self, params, c_gate, taps_of):
+        """``taps_of(j)``: the rows tap j multiplies, [..., dim]."""
+        w = params["w_conv"].astype(jnp.float32)
+        c = sum(w[:, j] * taps_of(j).astype(jnp.float32)
+                for j in range(self.taps))
+        dt = params["w_out"].dtype
+        return jnp.dot((c_gate * c).astype(dt), params["w_out"],
+                       preferred_element_type=jnp.float32)
+
+    def __call__(self, params, u, tail=None, lengths=None, **kw):
+        """u [B, T, dim] -> (y [B, T, dim] f32, new tail [B, taps - 1, dim]).
+        ``tail``: the state the sequence continues from (zeros: it starts
+        here); ``lengths`` [B]: each row's live length, where the new tail
+        is taken (T when None)."""
+        z, c_gate = self._gates(params, u)
+        B, T, _ = z.shape
+        keep = self.taps - 1
+        if tail is None:
+            tail = jnp.zeros((B, keep, self.dim), z.dtype)
+        zz = jnp.concatenate([tail.astype(z.dtype), z], axis=1)
+        y = self._out(params, c_gate, lambda j: zz[:, j:j + T])
+        n = jnp.full((B,), T, jnp.int32) if lengths is None \
+            else jnp.asarray(lengths, jnp.int32)
+        at = n[:, None] + jnp.arange(keep, dtype=jnp.int32)[None, :]
+        return y, jnp.take_along_axis(zz, at[:, :, None], axis=1)
+
+    def step(self, params, u, tail):
+        """One position: u [B, dim], tail [B, taps - 1, dim] -> (y [B, dim]
+        f32, the tail rolled by one)."""
+        z, c_gate = self._gates(params, u)
+        zz = jnp.concatenate([tail, z[:, None].astype(tail.dtype)], axis=1)
+        return self._out(params, c_gate, lambda j: zz[:, j]), zz[:, 1:]

@@ -7,8 +7,10 @@ their frequency, those that turn fewer than ``beta_slow`` times are divided
 by ``factor``, and a linear ramp blends the ones between. The blend is fixed
 when the model is built and applies at every position.
 
-``apply_rope`` rotates INTERLEAVED pairs (x0, x1), (x2, x3), ... — the layout
-of DeepSeek's checkpoints — by ``position * inv_freq``.
+``apply_rope`` rotates pairs of a head's entries by ``position * inv_freq``:
+INTERLEAVED pairs (x0, x1), (x2, x3), ... — the layout of DeepSeek's
+checkpoints — or, ``layout="half"``, the HALF-SPLIT pairs (x_i, x_{i + D/2})
+of the ``rotate_half`` convention (LFM2 and most other published models).
 """
 
 from __future__ import annotations
@@ -46,15 +48,22 @@ def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
     return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, inv_freq):
-    """x [..., T, (H,) D] with interleaved pairs along D; positions [..., T]
-    broadcastable to x's leading axes. Computed in float32, returned in
-    float32."""
+def apply_rope(x, positions, inv_freq, layout: str = "interleaved"):
+    """x [..., T, (H,) D] with its pairs along D laid out as ``layout`` says
+    ("interleaved" or "half"); positions [..., T] broadcastable to x's
+    leading axes. Computed in float32, returned in float32."""
     x = x.astype(jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
     if x.ndim == ang.ndim + 1:          # a heads axis between T and D
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if layout == "half":
+        half = x.shape[-1] // 2
+        x0, x1 = x[..., :half], x[..., half:]
+        return jnp.concatenate([x0 * cos - x1 * sin, x1 * cos + x0 * sin],
+                               axis=-1)
+    if layout != "interleaved":
+        raise ValueError(f"unknown rotary layout {layout!r}")
     x0, x1 = x[..., 0::2], x[..., 1::2]
     out = jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos], axis=-1)
     return out.reshape(x.shape)

@@ -413,6 +413,15 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
         "counter", "cells of the block table under the same reads "
                    "(layers x steps x slots x table width): walked / "
                    "table is the share of the table that was live"),
+    "serving.slot_state_bytes_held": (
+        "gauge", "bytes of the pool's per-slot rows (a model's SlotRow "
+                 "statements: state of a fixed size whatever the context, "
+                 "e.g. Lfm2MoeLM's convolution tails), all slots; 0 for a "
+                 "model that states none"),
+    "serving.slot_state_writes_total": (
+        "counter", "admitted slots whose per-slot rows an admit program "
+                   "wrote (one per request admitted by prefill; 0 for a "
+                   "model that states no SlotRow)"),
     "moe.assignments_total": (
         "counter", "live (token, choice) pairs the expert layers of an "
                    "admit or segment program routed, over ALL experts "
@@ -581,9 +590,12 @@ SPANS: Dict[str, str] = {
     "coord.dispatch": "server-side handling of one coord RPC (args: op; "
                       "remote = the client's rpc.call span)",
     "serving.prefill": "one admission batch: ragged prefill + page "
-                       "placement (args: batch)",
+                       "placement (args: batch; with expert layers also "
+                       "routed_here, experts_touched, load_max of the admit "
+                       "program)",
     "serving.segment": "one batched decode segment across live slots "
-                       "(args: live)",
+                       "(args: live; with expert layers also routed_here, "
+                       "experts_touched, load_max)",
     "serving.schedule": "a locked section of the scheduler: reaping "
                         "cancels/deadlines, or deficit scheduling + "
                         "plan_admission + evict_for (args: phase = "
@@ -600,8 +612,8 @@ SPANS: Dict[str, str] = {
     "serving.index": "prefix-index insertion over one admission wave",
     "moe.program": "instant: what the expert layers of one program routed "
                    "(args: program = admit | segment, routed_here, "
-                   "experts_touched, load_max); serving.segment carries "
-                   "the same three",
+                   "experts_touched, load_max); serving.prefill and "
+                   "serving.segment carry the same three",
     "serving.emit": "the locked token hand-out after a prefill or a "
                     "segment (args: after = prefill | segment)",
     "serving.ship": "client side of one KV shipment: every srv_ship chunk "

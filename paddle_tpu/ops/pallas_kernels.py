@@ -417,6 +417,12 @@ def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
     (variable-length batches / cross-attention over padded sources)."""
     B, T, H, D = q.shape
     S = k.shape[1]
+    # grouped-query heads: k/v [B, S, Hkv, D]; program bh = b * H + h reads
+    # KV row b * Hkv + h // G = bh // G, so a group's G x n_q consecutive
+    # programs name the same K/V block and it is fetched once for them
+    G = H // k.shape[2]
+    kv_at = (lambda bh, qi: (bh, 0, 0)) if G == 1 else \
+        (lambda bh, qi: (bh // G, 0, 0))
     blk_q, blk_k, Tp, Sp = _blocks(T, S, block_q, block_k, causal)
     qb, kb, vb = _to_bh(q, Tp), _to_bh(k, Sp), _to_bh(v, Sp)
     lensb = _lens_to_bh(kv_lens, B, H, S)
@@ -432,8 +438,8 @@ def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, blk_q, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, Sp, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, Sp, D), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, Sp, D), kv_at),
+            pl.BlockSpec((1, Sp, D), kv_at),
             pl.BlockSpec((1, 1, 1), lambda bh, qi: (bh, 0, 0)),
         ],
         out_specs=[
@@ -577,6 +583,9 @@ def _dense_attention(q, k, v, causal, scale, kv_lens):
     """Masked dense attention for short sequences — same semantics as the
     flash kernels (causal + per-sample kv_lens), ordinary autodiff."""
     T, S = q.shape[1], k.shape[1]
+    if k.shape[2] != q.shape[2]:        # grouped-query heads: h reads h // G
+        k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
+        v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
     s = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if kv_lens is not None:
@@ -598,7 +607,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Fused attention. q: [B, T, H, D], k/v: [B, S, H, D] -> [B, T, H, D]
-    (S != T = cross attention).
+    (S != T = cross attention). k/v may carry FEWER heads, a divisor of H
+    (grouped-query attention: query head h reads KV head ``h // (H //
+    Hkv)`` through the forward kernel's index map, no repeated copy in
+    HBM); such a call is forward-only — the backward kernels sum nothing
+    over a group — and differentiating it raises.
 
     T is padded to a block multiple internally; padded keys are masked in the
     kernel. ``kv_lens`` [B] int additionally masks each sample's keys at or
@@ -632,8 +645,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     else:
         block_q, block_k = _default_blocks(block_q, block_k)
         interpret = _interpret(interpret)
-        o = _flash(q, k, v, kv_lens, causal, scale_v, block_q, block_k,
-                   interpret)
+        if k.shape[2] != q.shape[2]:
+            if q.shape[2] % k.shape[2]:
+                raise ValueError(
+                    f"flash_attention: {q.shape[2]} query heads are not "
+                    f"whole groups over {k.shape[2]} KV heads")
+            o, _ = _fa_fwd_call(q, k, v, causal, scale_v, block_q, block_k,
+                                interpret, kv_lens=kv_lens)
+        else:
+            o = _flash(q, k, v, kv_lens, causal, scale_v, block_q, block_k,
+                       interpret)
     if valid is not None:
         o = o * valid[:, None, None, None].astype(o.dtype)
     return o
@@ -745,7 +766,7 @@ def _decode_chunk(L: int) -> int:
 
 
 def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
-                        work_list: bool):
+                        work_list: bool, groups: int = 1):
     """One program of the decode read, dense-row or paged, float or int8 —
     one body so every variant shares the softmax. A program reads chunk
     ``c`` (rows c*chunk..) of sample ``b``'s cache.
@@ -760,7 +781,13 @@ def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
     appended before the read). Blocks: q [1, H, D]; k/v [1, chunk, H, D]
     (+ ks/vs [1, chunk, H] f32 when int8); o [1, H, D] f32, written by
     sample b's last chunk. Scratch m/l [H, 1], acc [H, D] f32 carry the
-    running softmax across one sample's chunks, which run back to back."""
+    running softmax across one sample's chunks, which run back to back.
+
+    ``groups`` > 1 (grouped-query attention): H above is the KV heads';
+    q and o are [1, groups, H, D] (member g of every KV head's group of
+    query heads) and the scratch carries a leading ``groups`` axis. The
+    chunk's K and V are loaded ONCE and folded into each member's softmax
+    in turn, so a KV head's bytes serve its whole group."""
     if quantized:
         (pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
          m_ref, l_ref, acc_ref) = refs[-10:]
@@ -784,22 +811,25 @@ def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
 
     @pl.when(c * chunk <= pos)           # a dead chunk adds exactly nothing
     def _live():
-        q = q_ref[0].astype(jnp.float32) * scale            # [H, D]
         k = k_ref[0].astype(jnp.float32)                    # [chunk, H, D]
         v = v_ref[0].astype(jnp.float32)
         if quantized:
             k = k * ks_ref[0][..., None]
             v = v * vs_ref[0][..., None]
-        s = jnp.sum(k * q[None], axis=-1, keepdims=True)    # [chunk, H, 1]
         j = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
-        s = jnp.where(j <= pos, s, _NEG)
-        m_prev = m_ref[...]                                 # [H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-        p = jnp.exp(s - m_new[None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=0)
-        acc_ref[...] = acc_ref[...] * corr + jnp.sum(p * v, axis=0)
-        m_ref[...] = m_new
+        for g in range(groups):
+            # member g of a group's refs; the whole ref without groups
+            g = (g,) if groups > 1 else (...,)
+            q = q_ref[(0,) + g].astype(jnp.float32) * scale  # [H, D]
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # [chunk, H, 1]
+            s = jnp.where(j <= pos, s, _NEG)
+            m_prev = m_ref[g]                               # [H, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None])
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[g] = l_ref[g] * corr + jnp.sum(p, axis=0)
+            acc_ref[g] = acc_ref[g] * corr + jnp.sum(p * v, axis=0)
+            m_ref[g] = m_new
 
     @pl.when(last)
     def _finish():
@@ -811,10 +841,11 @@ def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, qo_spec, kv_spec,
     """The one pallas_call behind decode_attention and
     paged_decode_attention: ``prefetch`` scalars (pos last; five of them
     = the paged work list), then q, then k/v — each followed by its scale
-    operand when the cache is int8. ``name`` is the caller's: what a
-    device trace shows the kernel as."""
+    operand when the cache is int8. q [B, H, D], or [B, groups, H, D] with
+    H the KV heads' (grouped-query attention); the result has q's shape.
+    ``name`` is the caller's: what a device trace shows the kernel as."""
     from jax.experimental.pallas import tpu as pltpu
-    B, H, D = q.shape
+    *lead, H, D = q.shape[1:]
     if k_scale is not None:
         kv_args, kv_specs = ((k, k_scale, v, v_scale),
                              [kv_spec, sc_spec, kv_spec, sc_spec])
@@ -823,15 +854,16 @@ def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, qo_spec, kv_spec,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=grid,
         in_specs=[qo_spec] + kv_specs, out_specs=qo_spec,
-        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, D), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((*lead, H, 1), jnp.float32),
+                        pltpu.VMEM((*lead, H, 1), jnp.float32),
+                        pltpu.VMEM((*lead, H, D), jnp.float32)])
     kernel = functools.partial(_decode_attn_kernel, scale=scale, chunk=chunk,
                                quantized=k_scale is not None,
-                               work_list=len(grid) == 1)
+                               work_list=len(grid) == 1,
+                               groups=lead[0] if lead else 1)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         interpret=interpret, name=name,
     )(*prefetch, q, *kv_args)
 
@@ -861,6 +893,9 @@ def _dense_decode_attention(q, k, v, pos, scale, k_scale, v_scale):
         k = k.astype(jnp.float32) * k_scale[..., None]
         v = v.astype(jnp.float32) * v_scale[..., None]
     L = k.shape[1]
+    if k.shape[2] != q.shape[1]:        # grouped-query heads: h reads h // G
+        k = jnp.repeat(k, q.shape[1] // k.shape[2], axis=2)
+        v = jnp.repeat(v, q.shape[1] // v.shape[2], axis=2)
     s = jnp.einsum("bhd,bjhd->bhj", q.astype(jnp.float32) * scale,
                    k.astype(jnp.float32))
     valid = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, :]
@@ -978,8 +1013,11 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     """Single-token attention read through a block table — the paged twin
     of :func:`decode_attention`.
 
-    q: [B, H, D]; k_pool/v_pool: [P, bs, H, D] page pools (bf16/f32, or
-    int8 with k_scale/v_scale [P, bs, H] f32 pools); tables: [B, NB] int32
+    q: [B, H, D]; k_pool/v_pool: [P, bs, Hkv, D] page pools (bf16/f32, or
+    int8 with k_scale/v_scale [P, bs, Hkv] f32 pools), ``Hkv`` = H or a
+    divisor of it (grouped-query attention: query head h reads KV head
+    ``h // (H // Hkv)``, and the kernel streams a page ONCE for the whole
+    group); tables: [B, NB] int32
     page indices covering positions 0..NB*bs-1 (entries past a request's
     live pages point at the reserved null page — rows there sit past
     ``pos`` and are masked exactly like dense padding); pos: [B] int32,
@@ -994,6 +1032,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     assembled row order, so route choice never changes greedy tokens."""
     B, NB = tables.shape
     P, bs, H, D = k_pool.shape
+    Hq = q.shape[1]
+    if Hq % H:
+        raise ValueError(f"paged_decode_attention: {Hq} query heads are "
+                         f"not whole groups over {H} KV heads")
     L = NB * bs
     scale_v = scale if scale is not None else D ** -0.5
     route = decode_route(L, route)
@@ -1011,16 +1053,24 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     if work is None:
         work = paged_work_list(tables, pos, bs)
     *work, n_work = work
-    qo_spec = pl.BlockSpec((1, H, D), lambda i, slot, *_: (slot[i], 0, 0))
+    G = Hq // H
+    if G == 1:
+        qo_spec = pl.BlockSpec((1, H, D), lambda i, slot, *_: (slot[i], 0, 0))
+    else:
+        # member-major: [B, G, Hkv, D], so a member's heads are one tile
+        q = jnp.swapaxes(q.reshape(B, H, G, D), 1, 2)
+        qo_spec = pl.BlockSpec((1, G, H, D),
+                               lambda i, slot, *_: (slot[i], 0, 0, 0))
     page_spec = pl.BlockSpec((1, bs, H, D),
                              lambda i, slot, page, *_: (page[i], 0, 0, 0))
     sc_spec = pl.BlockSpec((1, bs, H),
                            lambda i, slot, page, *_: (page[i], 0, 0))
-    return _decode_attn_call(
+    o = _decode_attn_call(
         (*work, pos.astype(jnp.int32)), q, k_pool, v_pool, k_scale, v_scale,
         qo_spec, page_spec, sc_spec, grid=(n_work[0],), scale=scale_v,
         chunk=bs, interpret=_interpret(interpret),
         name="paged_decode_attention")
+    return o if G == 1 else jnp.swapaxes(o, 1, 2).reshape(B, Hq, D)
 
 
 # ---------------------------------------------------------------------------
@@ -1790,19 +1840,22 @@ def gru_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _decode_attention_bytes(*, batch, read, n_heads, d_head, layers=1,
-                            kv_dtype=None, itemsize=2, steps=1):
+                            kv_dtype=None, itemsize=2, steps=1,
+                            kv_heads=None):
     """HBM bytes of ``steps`` decode_attention dispatches: k+v live cache
     rows stream once per step (int8 rows read 1 byte/element plus one f32
     scale per (row, head) — the quantized-KV numerics contract,
-    docs/design/kernels.md)."""
-    row = n_heads * (d_head + 4 if kv_dtype == "int8"
-                     else d_head * itemsize)
+    docs/design/kernels.md). ``kv_heads``: the heads a cache row holds
+    where they are fewer than the query heads (grouped-query attention);
+    the read is charged those."""
+    row = (kv_heads or n_heads) * (d_head + 4 if kv_dtype == "int8"
+                                   else d_head * itemsize)
     return 2.0 * batch * read * row * layers * steps
 
 
 def _paged_decode_attention_bytes(*, pages, page_block, n_heads, d_head,
                                   batch=1, layers=1, kv_dtype=None,
-                                  itemsize=2, steps=1):
+                                  itemsize=2, steps=1, kv_heads=None):
     """HBM bytes of paged reads: ``pages`` pages of ``page_block`` rows,
     k and v. The decode kernel streams exactly the pages it walks (one
     program each: ``PagePool.run_segment`` passes that count, summed over
@@ -1811,7 +1864,8 @@ def _paged_decode_attention_bytes(*, pages, page_block, n_heads, d_head,
     return _decode_attention_bytes(batch=batch, read=pages * page_block,
                                    n_heads=n_heads, d_head=d_head,
                                    layers=layers, kv_dtype=kv_dtype,
-                                   itemsize=itemsize, steps=steps)
+                                   itemsize=itemsize, steps=steps,
+                                   kv_heads=kv_heads)
 
 
 def _paged_prefill_attention_bytes(*, batch, pages, page_block, n_heads,

@@ -16,7 +16,9 @@ Routing, in float32: ``s = sigmoid(y W_r)``; SELECTION uses ``s + bias``:
 the experts form ``n_group`` groups, a group scores the sum of its top 2,
 the best ``topk_group`` groups stay, the top ``top_k`` experts inside them
 are chosen; WEIGHTS are the original ``s`` at the chosen experts, divided by
-their sum, times ``routed_scale``.
+their sum (+ ``norm_eps``), times ``routed_scale``. ``n_group`` 1 is the
+router without groups (LFM2's: top ``top_k`` of ``s + bias`` over all the
+experts, ``norm_eps`` 1e-6 as published); 1e-20 is DeepSeek-V3's.
 
 The held experts' products are GROUPED: the (token, choice) pairs that
 landed here are sorted by expert and laid out in tiles of ``tm`` rows, each
@@ -45,21 +47,23 @@ CHUNK = 8192
 
 
 def route(scores_logits, bias, *, n_group: int, topk_group: int, top_k: int,
-          routed_scale: float):
+          routed_scale: float, norm_eps: float = 1e-20):
     """scores_logits [T, E] f32 (``y W_r``), bias [E] -> (experts [T, top_k]
     int32, weights [T, top_k] f32)."""
     T, E = scores_logits.shape
     s = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
     pick = s + bias.astype(jnp.float32)
-    grouped = pick.reshape(T, n_group, E // n_group)
-    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-    _, keep = jax.lax.top_k(group_score, topk_group)            # [T, kg]
-    kept = jnp.zeros((T, n_group), bool).at[
-        jnp.arange(T)[:, None], keep].set(True)
-    pick = jnp.where(jnp.repeat(kept, E // n_group, axis=1), pick, -jnp.inf)
+    if n_group > 1:
+        grouped = pick.reshape(T, n_group, E // n_group)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(group_score, topk_group)        # [T, kg]
+        kept = jnp.zeros((T, n_group), bool).at[
+            jnp.arange(T)[:, None], keep].set(True)
+        pick = jnp.where(jnp.repeat(kept, E // n_group, axis=1), pick,
+                         -jnp.inf)
     _, experts = jax.lax.top_k(pick, top_k)
     w = jnp.take_along_axis(s, experts, axis=1)
-    w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) * routed_scale
+    w = w / (jnp.sum(w, axis=1, keepdims=True) + norm_eps) * routed_scale
     return experts.astype(jnp.int32), w
 
 
@@ -103,7 +107,8 @@ class ExpertShare(nn.Module):
 
     def __init__(self, d_model: int, d_expert: int, *, n_experts: int,
                  experts_held: Sequence[int], top_k: int, n_group: int,
-                 topk_group: int, routed_scale: float, n_shared: int = 1,
+                 topk_group: int, routed_scale: float,
+                 norm_eps: float = 1e-20, n_shared: int = 1,
                  shared: bool = True, dtype=jnp.float32,
                  init_std: float = 0.02):
         super().__init__()
@@ -114,7 +119,8 @@ class ExpertShare(nn.Module):
                              f"0..{n_experts - 1}")
         self.n_experts, self.held = n_experts, held
         self.route_kw = dict(n_group=n_group, topk_group=topk_group,
-                             top_k=top_k, routed_scale=routed_scale)
+                             top_k=top_k, routed_scale=routed_scale,
+                             norm_eps=norm_eps)
         # global expert id -> local index; n_held where it is not here
         table = np.full((n_experts,), len(held), np.int32)
         table[held] = np.arange(len(held), dtype=np.int32)
@@ -211,3 +217,60 @@ class ExpertShare(nn.Module):
         if self.shared is not None:
             out = out + self.shared(params["shared"], yb)
         return out, counts
+
+
+def ffn_or_experts(blk, params, h, live):
+    """The second half of a block that carries ``ffn_norm`` and either a
+    dense ``ffn`` or a routed ``moe`` (``blk.is_moe``): h [..., d] f32 ->
+    (h + FFN(norm(h)), counts or None)."""
+    y = blk.ffn_norm(params["ffn_norm"], h)
+    if not blk.is_moe:
+        return h + blk.ffn(params["ffn"], y), None
+    flat = y.reshape(-1, y.shape[-1])
+    out, counts = blk.moe(params["moe"], flat,
+                          None if live is None else live.reshape(-1))
+    return h + out.reshape(h.shape), counts
+
+
+class ProgramStats:
+    """What a served model with expert layers returns beside a program's
+    tokens, and what the host makes of it. The model gives ``n_moe``
+    (expert layers), ``n_held`` (experts held here) and ``top_k``."""
+
+    def program_stats_zero(self):
+        """Accumulators a program returns beside its tokens: the live
+        (token, choice) pairs that landed on each held expert, per expert
+        layer; the held experts touched, summed over steps and layers; the
+        live tokens routed, summed over steps."""
+        return {"routed": jnp.zeros((self.n_moe, self.n_held), jnp.int32),
+                "touched": jnp.zeros((), jnp.int32),
+                "tokens": jnp.zeros((), jnp.int32)}
+
+    def _add_stats(self, stats, counts, live, n_rows):
+        if not counts:
+            return stats
+        c = jnp.stack(counts)
+        n = n_rows if live is None else jnp.sum(live, dtype=jnp.int32)
+        return {"routed": stats["routed"] + c,
+                "touched": stats["touched"] + jnp.sum(c > 0,
+                                                      dtype=jnp.int32),
+                "tokens": stats["tokens"] + n}
+
+    def note_program_stats(self, stats, program: str):
+        """Host side of :meth:`program_stats_zero`: count what a program
+        (``admit`` or ``segment``) routed, mark it on the timeline
+        (``moe.program``), and return what the enclosing span should carry
+        — the pairs that landed here, the expert visits, and the busiest
+        (layer, held expert) cell of the program."""
+        from .. import obs
+        routed = stats["routed"]
+        here, touched = int(routed.sum()), int(stats["touched"])
+        obs.count("moe.assignments_total",
+                  int(stats["tokens"]) * self.top_k * self.n_moe,
+                  program=program)
+        obs.count("moe.assignments_here_total", here, program=program)
+        obs.count("moe.experts_touched_total", touched, program=program)
+        attrs = {"routed_here": here, "experts_touched": touched,
+                 "load_max": int(routed.max())}
+        obs.instant("moe.program", program=program, **attrs)
+        return attrs

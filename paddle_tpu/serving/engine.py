@@ -552,9 +552,11 @@ class ServingEngine:
         if not group and not adopts:
             return 0
         adopted = {rec.rid for _, rec in adopts}
-        with obs.span("serving.prefill", batch=len(group) + len(adopts)), \
+        with obs.span("serving.prefill",
+                      batch=len(group) + len(adopts)) as span, \
                 maybe_bucket(self._gp, "device"):
             first = self.pool.admit(group)      # device work, lock released
+            span.note(**self.pool.last_stats)
             for slot, rec in adopts:            # ditto: scheduler thread
                 s = rec.ship
                 self.pool.adopt_slot(slot, s["plen"], s["first"],

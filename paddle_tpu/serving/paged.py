@@ -49,6 +49,7 @@ import numpy as np
 
 from .. import obs
 from ..core.lod import bucket_length
+from ..models.transformer import SlotRow
 from . import ship
 from .batcher import Request, clip_emission, validate_request
 from .prefix import Match, PrefixIndex
@@ -97,10 +98,26 @@ class PagePool:
     program per (suffix-pad, read-pages) bucket pair, one segment program
     per cache-read bucket (in pages).
 
+    What the pool holds is what the MODEL states (``cache_rows``): a
+    ``CacheRow`` becomes a page pool ``[pages, page_block, *shape]``, a
+    ``SlotRow`` (models/transformer.py: state of a fixed size whatever the
+    context, e.g. Lfm2MoeLM's convolution tails) an array ``[slots,
+    *shape]`` beside the pages (``slot_state``): written for the admitted
+    slots from ``prefill``'s cell, carried through the segment scan in the
+    cell — which keeps the LIVE slots' rows and puts every other slot's
+    back to its fill, so a freed slot is clear after the next segment with
+    no program of its own — shipped by :meth:`export_slot` /
+    :meth:`adopt_slot` under its own name, checked by
+    :meth:`check_shipment`. A model that states no slot row runs the
+    programs it ran before there were any. Slot rows are not shared by
+    prefix (a model with them has no ``prefill_paged``).
+
     The geometry defaults (``page_block`` 64, ``cache_bucket`` 256,
-    ``prompt_buckets`` 32..512) are the values every serve cell of the
-    chip benchmark runs and warms up (chipbench/workloads/*.json, PERF.md
-    section 4). Page size changes read geometry only: the assembled row
+    ``prompt_buckets`` 32..512) are the values the GPT-2 and GigaChat serve
+    cells of the chip benchmark run and warm up; ``lfm2-serve-rag`` passes
+    ``prompt_buckets`` 512..4096 and one ``cache_bucket`` for the whole
+    table (chipbench/workloads/*.json, PERF.md section 4; ``serve
+    --prompt_buckets``). Page size changes read geometry only: the assembled row
     order is the same at any block, so tokens never change
     (tests/test_serving_paged.py holds paged == solo over page sizes)."""
 
@@ -145,13 +162,28 @@ class PagePool:
         # DeepseekV3LM. Everything below — allocation, donation, the
         # admission scatter, CoW copies, shipping, byte counts — follows
         # that statement; the pool names no array itself.
-        rows = model.cache_rows(params, kv_dtype)
+        stated = model.cache_rows(params, kv_dtype)
+        rows = [r for r in stated if not isinstance(r, SlotRow)]
         self.pools = {r.name: jnp.full((self.pages, self.bs) + tuple(r.shape),
                                        r.fill, r.dtype) for r in rows}
-        # the decode read's registered cost model and the shape facts it
-        # takes beside (pages, page_block)
+        # ... and its per-SLOT rows (SlotRow; the class docstring says
+        # what becomes of them): [slots, *shape], none for most models
+        self._slot_rows = [r for r in stated if isinstance(r, SlotRow)]
+        self.slot_state = {
+            r.name: jnp.full((slots,) + tuple(r.shape), r.fill, r.dtype)
+            for r in self._slot_rows}
+        self.slot_state_bytes = float(sum(
+            int(np.prod(r.shape, dtype=np.int64)) * jnp.dtype(r.dtype).itemsize
+            for r in self._slot_rows))          # one slot's
+        obs.gauge_set("serving.slot_state_bytes_held",
+                      slots * self.slot_state_bytes)
+        # the decode read's registered cost model, the shape facts it
+        # takes beside (pages, page_block), and how many layers of a step
+        # make that read (every block unless the model says otherwise)
         self._read_kernel = model.paged_read_kernel
         self._read_geom = model.paged_read_geometry(params, kv_dtype)
+        self._read_layers = getattr(model, "paged_read_layers",
+                                    len(model.blocks))
         # one page of every stated array in HBM bytes — the prefix index's
         # reuse-ledger credit unit
         self.page_bytes = float(self.bs * sum(
@@ -383,6 +415,9 @@ class PagePool:
         pages = jnp.asarray(self.tables[slot, :npg])
         arrays = {nm: np.asarray(arr[pages])
                   for nm, arr in self.pools.items()}
+        # the slot's per-slot rows travel under their own names, [*shape]
+        arrays.update({nm: np.asarray(arr[slot])
+                       for nm, arr in self.slot_state.items()})
         manifest, payload = ship.pack(arrays, plen=plen, first=first,
                                       page_block=self.bs,
                                       kv_dtype=self.kv_dtype)
@@ -398,8 +433,9 @@ class PagePool:
         ValueError refusal at the wire edge, never a scheduler-thread
         death mid-adoption."""
         npg = -(-int(plen) // self.bs)
-        missing = set(self.pools) - set(arrays)
-        extra = set(arrays) - set(self.pools)
+        here = set(self.pools) | set(self.slot_state)
+        missing = here - set(arrays)
+        extra = set(arrays) - here
         if missing or extra:
             raise ValueError(
                 f"shipped arrays disagree with this pool's layout "
@@ -407,8 +443,12 @@ class PagePool:
                 "— prefill and decode pools must share model depth and "
                 "kv_dtype")
         for nm, rows in arrays.items():
-            ref = self.pools[nm]
-            want = (npg,) + tuple(ref.shape[1:])
+            if nm in self.slot_state:
+                ref = self.slot_state[nm]
+                want = tuple(ref.shape[1:])
+            else:
+                ref = self.pools[nm]
+                want = (npg,) + tuple(ref.shape[1:])
             if tuple(rows.shape) != want:
                 raise ValueError(
                     f"shipped {nm!r} shape {tuple(rows.shape)} != expected "
@@ -437,9 +477,11 @@ class PagePool:
         self._ensure(slot, plen)
         pages = jnp.asarray(self.tables[slot, :npg])
         for nm, rows in arrays.items():
-            ref = self.pools[nm]
-            self.pools[nm] = ref.at[pages].set(
-                jnp.asarray(np.ascontiguousarray(rows)))
+            rows = jnp.asarray(np.ascontiguousarray(rows))
+            if nm in self.slot_state:
+                self.slot_state[nm] = self.slot_state[nm].at[slot].set(rows)
+            else:
+                self.pools[nm] = self.pools[nm].at[pages].set(rows)
         self.pos[slot] = plen
         self.cur[slot] = int(first)
         self.prompt_tokens_total += plen
@@ -455,10 +497,11 @@ class PagePool:
             model, kv_dtype, bs = self.model, self.kv_dtype, self.bs
             tpp = nbp * bs
 
-            def admit(params, pools, prompts, lens, pages):
+            def admit(params, state, prompts, lens, pages):
                 # pad_to=tpp: the transient cell holds prompt-bucket rows,
                 # not a max_len-padded (pinned-pool-sized) cache — the
                 # admission HBM spike stays proportional to the prompts
+                pools, slot_state = state
                 cell, last = model.prefill(params, prompts, lens,
                                            kv_dtype=kv_dtype,
                                            pad_to=tpp)
@@ -468,7 +511,13 @@ class PagePool:
                     rows = cell[nm][:, :tpp].reshape(
                         (prompts.shape[0], nbp, bs) + cell[nm].shape[2:])
                     out[nm] = pool.at[pages].set(rows.astype(pool.dtype))
-                return out, first, cell.get("stats", {})
+                # per-slot rows: only the slots this admission fills
+                took = lens > 0
+                slot_out = {
+                    nm: jnp.where(took.reshape((-1,) + (1,) * (v.ndim - 1)),
+                                  cell[nm].astype(v.dtype), v)
+                    for nm, v in slot_state.items()}
+                return (out, slot_out), first, cell.get("stats", {})
             # cost-instrumented (PR 9 ledger): under an obs session the
             # dispatch feeds fluid.device_flops_total and admit() reads
             # the per-executable FLOPs into admit_flops_total — the
@@ -514,9 +563,11 @@ class PagePool:
         if fn is None:
             obs.instant("serving.program_build", kind="segment", nb=nb)
             model, segment = self.model, self.segment
+            fills = {r.name: r.fill for r in self._slot_rows}
 
-            def seg(params, pools, tables, pos, cur, live):
-                cell = dict(pools, pos=pos)
+            def seg(params, state, tables, pos, cur, live):
+                pools, slot_state = state
+                cell = dict(pools, **slot_state, pos=pos)
                 if hasattr(model, "program_stats_zero"):
                     cell["stats"] = model.program_stats_zero()
 
@@ -528,8 +579,15 @@ class PagePool:
                     return (cell, nxt), cur
                 (cell, cur), toks = jax.lax.scan(body, (cell, cur), None,
                                                  length=segment)
-                pools_out = {k: cell[k] for k in pools}
-                return (pools_out, cur, jnp.moveaxis(toks, 0, 1),
+                # a slot that is not live keeps no per-slot state: a freed
+                # slot's rows are back at their fill after the next segment
+                # (no program of its own for that; none rolls idle either)
+                state_out = ({k: cell[k] for k in pools},
+                             {k: jnp.where(
+                                 live.reshape((-1,) + (1,) * (v.ndim - 1)),
+                                 cell[k], jnp.asarray(fills[k], v.dtype))
+                              for k, v in slot_state.items()})
+                return (state_out, cur, jnp.moveaxis(toks, 0, 1),
                         cell.get("stats", {}))
             fn = obs.roofline.instrument(
                 jax.jit(seg, donate_argnums=(1,)), "serving.segment")
@@ -546,6 +604,7 @@ class PagePool:
         dispatch for hits, insert the new full prompt blocks (and the
         last partial page) into the index, and return {slot: first
         generated token}."""
+        self.last_stats = {}
         if not group:
             return {}
         if self.index is not None:
@@ -622,11 +681,14 @@ class PagePool:
                 n = min(nbp, len(self.slot_pages[slot]))
                 pages[slot, :n] = self.slot_pages[slot][:n]
             fn = self._admit_fn(tpad, nbp)
-            args = (self.params, self.pools, jnp.asarray(prompts),
-                    jnp.asarray(lens), jnp.asarray(pages))
+            args = (self.params, (self.pools, self.slot_state),
+                    jnp.asarray(prompts), jnp.asarray(lens),
+                    jnp.asarray(pages))
         with obs.span("serving.dispatch", program="admit"):
-            self.pools, f, stats = fn(*args)
+            (self.pools, self.slot_state), f, stats = fn(*args)
             self._note_admit_cost(fn, args)
+            if self.slot_state:
+                obs.count("serving.slot_state_writes_total", len(miss))
         with obs.span("serving.fetch", program="admit"):
             f = np.asarray(f)
             self._note_stats(stats, "admit")
@@ -748,16 +810,16 @@ class PagePool:
             pos[idx] = self.pos[idx].clip(0, self.model.max_len - 1)
             alive = np.zeros((self.n_slots,), bool)
             alive[idx] = True
-            args = (self.params, self.pools,
+            args = (self.params, (self.pools, self.slot_state),
                     jnp.asarray(self.tables[:, :nb]), jnp.asarray(pos),
                     jnp.asarray(self.cur), jnp.asarray(alive))
         with obs.span("serving.dispatch", program="segment"):
-            self.pools, cur, toks, stats = fn(*args)
+            (self.pools, self.slot_state), cur, toks, stats = fn(*args)
             obs.count("decode.dispatches_total", route="serve_segment")
             # the paged read's programs this segment, from the host's own
             # pos (pk.paged_work_list's rule, step by step) against the
             # whole table's — one per (layer, step, slot, page)
-            calls = len(self.model.blocks)
+            calls = self._read_layers
             steps = np.arange(self.segment, dtype=np.int32)
             walked = calls * int(np.minimum(
                 (pos[:, None] + steps[None, :]) // self.bs + 1, nb).sum())

@@ -168,6 +168,45 @@ def test_paged_decode_attention_compiles(S, slots, heads, NB, pages, kv):
             q, pool, pool, tables, pos)
 
 
+def test_grouped_query_paged_decode_attention_compiles(S):
+    """The lfm2-serve-rag cell's read: 32 slots x 32 query heads over bf16
+    pools of 8 KV heads (an (8, 64) tile: half a bf16 sublane tile, which
+    no other pool showed the compiler), the whole table of 72 over a
+    2305-page pool. The kernel keeps its own name in the program — the
+    benchmark's reader (chipbench/metrics/gqa_decode_roofline.py) finds it
+    by that — and takes the pools as they are, [pages, 64, 8, 64]."""
+    text = _compile(lambda q, k, v, t, pos: pk.paged_decode_attention(
+        q, k, v, t, pos, route="kernel", interpret=False),
+        S((32, 32, D), jnp.float32), S((2305, 64, 8, D), jnp.bfloat16),
+        S((2305, 64, 8, D), jnp.bfloat16), S((32, 72), jnp.int32),
+        S((32,), jnp.int32))
+    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    assert "paged_decode_attention" in call.split(" = ")[0]
+    assert "bf16[2305,64,8,64]" in call and "f32[32,4,8,64]" in call
+
+
+@pytest.mark.parametrize("rows, T", [(4, 512), (1, 4096)])
+def test_grouped_query_flash_forward_compiles(S, rows, T):
+    """The cell's prefill chunks: 32 query heads over 8 KV heads through
+    the forward kernel's index map (no repeated K / V in HBM: the kernel's
+    K and V operands keep 8 heads' rows), at the shortest and the longest
+    prompt bucket. Its result is ``[rows x 32, T, 64]`` under the kernel's
+    own name: what chipbench/metrics/flash_prefill_roofline.py reads."""
+    import re
+    bf = jnp.bfloat16
+    text = _compile(lambda q, k, v: pk.flash_attention(
+        q, k, v, causal=True, interpret=False),
+        S((rows, T, 32, D), bf), S((rows, T, 8, D), bf),
+        S((rows, T, 8, D), bf))
+    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    assert "flash_attention_fwd" in call.split(" = ")[0]
+    m = re.search(r"\[(\d+),(\d+),(\d+)\]", call)
+    assert tuple(int(g) for g in m.groups()) == (rows * 32, T, D)
+    assert f"bf16[{rows * 8},{T},{D}]" in call
+
+
 @pytest.mark.parametrize("NB", [4, 32], ids=["cache-256", "cache-2048"])
 def test_paged_latent_attention_compiles(S, NB):
     """The absorbed latent read at the gigachat-ep16 cell's real shapes: 32
