@@ -436,6 +436,13 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                    "program's steps and expert layers: the expert matrices "
                    "the grouped products read, labels: program",
         ("program",)),
+    "moe.row_tiles_total": (
+        "counter", "row tiles the grouped products walked for those "
+                   "visits (sum of ceil(pairs / tile rows) over held "
+                   "experts, steps and layers); over experts_touched: the "
+                   "tiles a visit, all but the first of which find the "
+                   "expert's matrix already fetched, labels: program",
+        ("program",)),
     "serving.prefix_hits_total": ("counter", "admissions that matched the "
                                              "prefix radix index and "
                                              "prefilled only their "
@@ -591,11 +598,11 @@ SPANS: Dict[str, str] = {
                       "remote = the client's rpc.call span)",
     "serving.prefill": "one admission batch: ragged prefill + page "
                        "placement (args: batch; with expert layers also "
-                       "routed_here, experts_touched, load_max of the admit "
-                       "program)",
+                       "routed_here, experts_touched, row_tiles, load_max of "
+                       "the admit program)",
     "serving.segment": "one batched decode segment across live slots "
                        "(args: live; with expert layers also routed_here, "
-                       "experts_touched, load_max)",
+                       "experts_touched, row_tiles, load_max)",
     "serving.schedule": "a locked section of the scheduler: reaping "
                         "cancels/deadlines, or deficit scheduling + "
                         "plan_admission + evict_for (args: phase = "
@@ -612,8 +619,8 @@ SPANS: Dict[str, str] = {
     "serving.index": "prefix-index insertion over one admission wave",
     "moe.program": "instant: what the expert layers of one program routed "
                    "(args: program = admit | segment, routed_here, "
-                   "experts_touched, load_max); serving.prefill and "
-                   "serving.segment carry the same three",
+                   "experts_touched, row_tiles, load_max); serving.prefill "
+                   "and serving.segment carry the same four",
     "serving.emit": "the locked token hand-out after a prefill or a "
                     "segment (args: after = prefill | segment)",
     "serving.ship": "client side of one KV shipment: every srv_ship chunk "

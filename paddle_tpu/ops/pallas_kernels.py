@@ -1202,6 +1202,21 @@ def paged_latent_attention(q: jax.Array, pool: jax.Array, tables: jax.Array,
 # token chose costs nothing: no program, no weight fetch. (The technique is
 # megablox's — group metadata scalar-prefetched into the index maps; tiles
 # aligned to groups make the store mask unnecessary.)
+#
+# The tiles of one group are ADJACENT (tile_layout sorts them by group), and
+# a group's matrix should cross HBM once a visit however many tiles the group
+# has. Two plans, one owner (grouped_matmul_blocks):
+# - K-split (_grouped_matmul_kernel): weight blocks (tk, tn) on a
+#   (N // tn, n_tiles, K // tk) grid. The block changes on every step, so a
+#   group with k tiles streams its matrix k times — right where k is 1 (a
+#   decode step's short tiles) or the whole matrix does not fit VMEM twice.
+# - resident (_grouped_matmul_resident_kernel): tall tiles, one grid step a
+#   tile, the group's WHOLE matrix in one of two VMEM buffers. The first tile
+#   of a group waits for its matrix and at once starts the fetch of the next
+#   group's into the other buffer; the group's further tiles find the
+#   matrix where it is and fetch nothing. (BlockSpec pipelining would skip
+#   the unchanged block too, but fetches only ONE step ahead: the next
+#   group's matrix would hide behind one tile's product, not behind all.)
 # ---------------------------------------------------------------------------
 
 def _grouped_matmul_kernel(group_ref, lhs_ref, rhs_ref, o_ref, acc_ref, *,
@@ -1220,6 +1235,43 @@ def _grouped_matmul_kernel(group_ref, lhs_ref, rhs_ref, o_ref, acc_ref, *,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def _grouped_matmul_resident_kernel(group_ref, lhs_ref, rhs_hbm, o_ref,
+                                    w_ref, sem, slot_ref):
+    """One tile a step; ``rhs_hbm`` [G, K, N] stays in HBM, ``w_ref``
+    [2, K, N] holds this group's matrix and receives the next group's,
+    ``slot_ref`` (SMEM) remembers which half is this group's."""
+    from jax.experimental.pallas import tpu as pltpu
+    t, n_t = pl.program_id(0), pl.num_programs(0)
+    last = group_ref.shape[0] - 1
+    g = group_ref[t]
+
+    def fetch(group, slot):
+        return pltpu.make_async_copy(rhs_hbm.at[group], w_ref.at[slot],
+                                     sem.at[slot])
+
+    @pl.when(t == 0)
+    def _prime():
+        slot_ref[0] = 1                     # _arrive flips it to 0
+        fetch(g, 0).start()
+
+    @pl.when((t == 0) | (group_ref[jnp.maximum(t - 1, 0)] != g))
+    def _arrive():
+        slot = 1 - slot_ref[0]
+        slot_ref[0] = slot
+        fetch(g, slot).wait()
+        nxt = jax.lax.while_loop(
+            lambda j: (j < n_t) & (group_ref[jnp.minimum(j, last)] == g),
+            lambda j: j + 1, t + 1)
+
+        @pl.when(nxt < n_t)
+        def _ahead():
+            fetch(group_ref[nxt], 1 - slot).start()
+
+    o_ref[...] = jnp.dot(lhs_ref[...], w_ref[slot_ref[0]],
+                         preferred_element_type=jnp.float32
+                         ).astype(o_ref.dtype)
+
+
 def _dense_grouped_matmul(lhs, rhs, tile_group, tm, out_dtype):
     """Reference-math route: every tile against its own group's matrix."""
     M, K = lhs.shape
@@ -1229,14 +1281,37 @@ def _dense_grouped_matmul(lhs, rhs, tile_group, tm, out_dtype):
     return out.reshape(M, -1).astype(out_dtype)
 
 
-def grouped_matmul_blocks(K: int, N: int) -> Tuple[int, int]:
-    """(tk, tn): the weight block a program streams. Whole rows of the
-    matrix where they fit (one contiguous fetch), about 2 MiB of bf16."""
+#: VMEM the resident plan may ask for (a v5e core has 128 MiB; Mosaic's
+#: default scoped limit is 16 MiB, so the plan states its own)
+_RESIDENT_VMEM = 40 << 20
+
+
+def _resident_vmem_bytes(tm: int, K: int, N: int, itemsize: int) -> int:
+    """The resident plan's VMEM: the matrix twice, the pipeline's two lhs
+    and two f32 out blocks, and the product before it is stored."""
+    return 2 * K * N * itemsize + 2 * tm * K * itemsize + 3 * tm * N * 4
+
+
+def grouped_matmul_blocks(tm: int, K: int, N: int,
+                          itemsize: int = 2) -> Tuple[int, int, bool]:
+    """(tk, tn, resident): the weight block a program holds and the plan
+    that holds it — the single owner of "which plan", from what it can
+    see. RESIDENT (the block is the whole matrix): tiles are tall (``tm``
+    >= 128, the admission layouts, where a group has several adjacent
+    tiles) and the matrix fits VMEM twice, so a group's matrix is fetched
+    once a visit and the fetch of the next group's hides behind all of
+    this group's tiles. Otherwise K-SPLIT: whole rows of the matrix where
+    they fit (one contiguous fetch), about 2 MiB of bf16 a block, streamed
+    once a TILE — a decode step's short tiles (one a group: nothing to
+    reuse) and matrices too large to hold twice (``K`` 7168)."""
+    if tm >= 128 and _resident_vmem_bytes(tm, K, N, itemsize) \
+            <= _RESIDENT_VMEM:
+        return K, N, True
     tn = N if N <= 2048 else next(t for t in (3584, 2048, 1024, 512, 256,
                                               128, N) if N % t == 0)
     tk = next((t for t in (512, 256, 128) if K % t == 0
                and t * tn <= (1 << 20)), K)
-    return tk, tn
+    return tk, tn, False
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
@@ -1247,10 +1322,15 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
     t (rows t*tm ..) is multiplied by ``rhs[tile_group[t]]``. Only tiles
     t < n_tiles[0] are computed; the rows of later tiles are UNDEFINED on
     the kernel route (the caller masks them), so a group without rows costs
-    nothing. Operands in their own dtype, f32 accumulation. ``route``:
-    "kernel" on the TPU, the dense einsum elsewhere (``None`` decides)."""
-    M, K = lhs.shape
-    G, _, N = rhs.shape
+    nothing. The tiles of one group must be ADJACENT in ``tile_group``
+    (tile_layout's order): the resident plan fetches a group's matrix when
+    the walk arrives at the group and never again, so a group that came
+    back later would be fetched again, and streamed once a visit only if
+    its tiles lie together. Operands in their own dtype, f32 accumulation.
+    ``route``: "kernel" on the TPU (ONE custom call name,
+    ``expert_grouped_matmul``, whichever plan :func:`grouped_matmul_blocks`
+    gives the shape), the dense einsum elsewhere (``None`` decides)."""
+    M = lhs.shape[0]
     if M % tm:
         raise ValueError(f"grouped_matmul: {M} rows are not whole tiles "
                          f"of {tm}")
@@ -1263,22 +1343,53 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
         return _dense_grouped_matmul(lhs, rhs, tile_group, tm, out_dtype)
     if route != "kernel":
         raise ValueError(f"unknown grouped_matmul route {route!r}")
+    return _grouped_matmul_call(tile_group.astype(jnp.int32), lhs, rhs,
+                                n_tiles, tm, jnp.dtype(out_dtype),
+                                _interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _grouped_matmul_call(tile_group, lhs, rhs, n_tiles, tm, out_dtype,
+                         interpret):
+    """The kernel route of :func:`grouped_matmul`. Jitted: a program calls
+    it with the same shapes a layer (12 layers x gate / up / down), and a
+    jitted wrapper is traced and lowered once a distinct call, not once a
+    call site."""
     from jax.experimental.pallas import tpu as pltpu
-    tk, tn = grouped_matmul_blocks(K, N)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(N // tn, n_tiles[0], K // tk),
-        in_specs=[pl.BlockSpec((tm, tk), lambda n, t, k, g: (t, k)),
-                  pl.BlockSpec((1, tk, tn), lambda n, t, k, g: (g[t], k, n))],
-        out_specs=pl.BlockSpec((tm, tn), lambda n, t, k, g: (t, n)),
-        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)])
+    (M, K), N = lhs.shape, rhs.shape[2]
+    itemsize = jnp.dtype(rhs.dtype).itemsize
+    tk, tn, resident = grouped_matmul_blocks(tm, K, N, itemsize)
+    if resident:
+        kernel = _grouped_matmul_resident_kernel
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_tiles[0],),
+            in_specs=[pl.BlockSpec((tm, K), lambda t, g: (t, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tm, N), lambda t, g: (t, 0)),
+            scratch_shapes=[pltpu.VMEM((2, K, N), rhs.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)])
+        params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_resident_vmem_bytes(tm, K, N, itemsize)
+            + (8 << 20))
+    else:
+        kernel = functools.partial(_grouped_matmul_kernel, n_k=K // tk)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(N // tn, n_tiles[0], K // tk),
+            in_specs=[pl.BlockSpec((tm, tk), lambda n, t, k, g: (t, k)),
+                      pl.BlockSpec((1, tk, tn),
+                                   lambda n, t, k, g: (g[t], k, n))],
+            out_specs=pl.BlockSpec((tm, tn), lambda n, t, k, g: (t, n)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)])
+        params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"))
     return pl.pallas_call(
-        functools.partial(_grouped_matmul_kernel, n_k=K // tk),
-        grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=_interpret(interpret), name="expert_grouped_matmul",
-    )(tile_group.astype(jnp.int32), lhs, rhs)
+        compiler_params=params, interpret=interpret,
+        name="expert_grouped_matmul",
+    )(tile_group, lhs, rhs)
 
 
 # ---------------------------------------------------------------------------

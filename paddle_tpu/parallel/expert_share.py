@@ -46,6 +46,29 @@ from ..ops import pallas_kernels as pk
 CHUNK = 8192
 
 
+def tile_rows(n_pairs: int) -> int:
+    """``tm``, the rows of a tile, for a program of ``n_pairs`` (token,
+    choice) pairs: short tiles for a decode step's few, tall ones for an
+    admission's, the tallest where the pairs are walked ``CHUNK`` at a
+    time."""
+    return 16 if n_pairs <= 1024 else 128 if n_pairs <= CHUNK else 256
+
+
+def row_tiles(counts, n_pairs: int):
+    """The row tiles the grouped products walk for ``counts`` [..., held]
+    held pairs an expert in a program of ``n_pairs`` pairs: Σ ceil(count /
+    tm), an expert's run cut where the walk in ``CHUNK``s cuts it (each
+    chunk lays its own tiles out). int32 scalar."""
+    tm = tile_rows(n_pairs)
+    end = jnp.cumsum(counts, axis=-1)
+    if n_pairs > CHUNK:
+        cut = jnp.arange(-(-n_pairs // CHUNK), dtype=jnp.int32) * CHUNK
+        counts = jnp.clip(jnp.minimum(end[..., None], cut + CHUNK)
+                          - jnp.maximum((end - counts)[..., None], cut),
+                          0, None)
+    return jnp.sum((counts + tm - 1) // tm, dtype=jnp.int32)
+
+
 def route(scores_logits, bias, *, n_group: int, topk_group: int, top_k: int,
           routed_scale: float, norm_eps: float = 1e-20):
     """scores_logits [T, E] f32 (``y W_r``), bias [E] -> (experts [T, top_k]
@@ -74,7 +97,10 @@ def tile_layout(group_of, n_groups: int, tm: int):
     "not here". Returns (src [M] int32 — the row each padded position
     reads, ``A`` where it is padding; tile_group [M // tm] int32; n_tiles
     [1] int32; counts [n_groups] int32) with the static
-    ``M = ceil(A / tm) * tm + n_groups * tm``."""
+    ``M = ceil(A / tm) * tm + n_groups * tm``. Tiles are SORTED BY GROUP
+    (group 0's tiles, then group 1's ...), and callers rely on it:
+    pk.grouped_matmul fetches a group's matrix once for the run of
+    adjacent tiles that name it."""
     A = group_of.shape[0]
     M = -(-A // tm) * tm + n_groups * tm
     counts = jnp.zeros((n_groups + 1,), jnp.int32).at[group_of].add(1)
@@ -191,8 +217,8 @@ class ExpertShare(nn.Module):
             1)[:n_held]
         yb = y.astype(dt)
         A = T * k
+        tm = tile_rows(A)
         if A <= CHUNK:
-            tm = 16 if A <= 1024 else 128
             out = self._held_part(params, yb, tok, local, w, tm, route_)
         else:
             # held pairs first, expert by expert; walk them CHUNK at a time
@@ -209,7 +235,7 @@ class ExpertShare(nn.Module):
                 i, acc = carry
                 idx = jax.lax.dynamic_slice(order, (i * CHUNK,), (CHUNK,))
                 return i + 1, acc + self._held_part(
-                    params, yb, tok_x[idx], local_x[idx], w_x[idx], 256,
+                    params, yb, tok_x[idx], local_x[idx], w_x[idx], tm,
                     route_)
             _, out = jax.lax.while_loop(
                 lambda c: c[0] * CHUNK < here, body,
@@ -240,10 +266,12 @@ class ProgramStats:
     def program_stats_zero(self):
         """Accumulators a program returns beside its tokens: the live
         (token, choice) pairs that landed on each held expert, per expert
-        layer; the held experts touched, summed over steps and layers; the
-        live tokens routed, summed over steps."""
+        layer; the held experts touched and the row tiles their grouped
+        products walked (:func:`row_tiles`), each summed over steps and
+        layers; the live tokens routed, summed over steps."""
         return {"routed": jnp.zeros((self.n_moe, self.n_held), jnp.int32),
                 "touched": jnp.zeros((), jnp.int32),
+                "row_tiles": jnp.zeros((), jnp.int32),
                 "tokens": jnp.zeros((), jnp.int32)}
 
     def _add_stats(self, stats, counts, live, n_rows):
@@ -254,14 +282,18 @@ class ProgramStats:
         return {"routed": stats["routed"] + c,
                 "touched": stats["touched"] + jnp.sum(c > 0,
                                                       dtype=jnp.int32),
+                "row_tiles": stats["row_tiles"]
+                + row_tiles(c, n_rows * self.top_k),
                 "tokens": stats["tokens"] + n}
 
     def note_program_stats(self, stats, program: str):
         """Host side of :meth:`program_stats_zero`: count what a program
         (``admit`` or ``segment``) routed, mark it on the timeline
         (``moe.program``), and return what the enclosing span should carry
-        — the pairs that landed here, the expert visits, and the busiest
-        (layer, held expert) cell of the program."""
+        — the pairs that landed here, the expert visits, the row tiles
+        those visits were laid out in (``row_tiles / experts_touched``: how
+        many tiles found their expert's matrix already fetched, plus one),
+        and the busiest (layer, held expert) cell of the program."""
         from .. import obs
         routed = stats["routed"]
         here, touched = int(routed.sum()), int(stats["touched"])
@@ -270,7 +302,9 @@ class ProgramStats:
                   program=program)
         obs.count("moe.assignments_here_total", here, program=program)
         obs.count("moe.experts_touched_total", touched, program=program)
+        tiles = int(stats["row_tiles"])
+        obs.count("moe.row_tiles_total", tiles, program=program)
         attrs = {"routed_here": here, "experts_touched": touched,
-                 "load_max": int(routed.max())}
+                 "row_tiles": tiles, "load_max": int(routed.max())}
         obs.instant("moe.program", program=program, **attrs)
         return attrs

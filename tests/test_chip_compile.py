@@ -222,21 +222,54 @@ def test_paged_latent_attention_compiles(S, NB):
     assert "paged_latent_attention" in text
 
 
-@pytest.mark.parametrize("rows, tm, K, N", [
-    (512, 16, 7168, 2048), (512, 16, 2048, 7168),
-    (12288, 256, 7168, 2048), (12288, 256, 2048, 7168)],
-    ids=["decode-gate", "decode-down", "prefill-gate", "prefill-down"])
-def test_expert_grouped_matmul_compiles(S, rows, tm, K, N):
-    """The held experts' grouped products at the cell's real shapes: 16
-    experts of 7168 x 2048 (gate, up) and 2048 x 7168 (down) in bf16; a
+@pytest.mark.parametrize("G, rows, tm, K, N", [
+    (16, 512, 16, 7168, 2048), (16, 512, 16, 2048, 7168),
+    (16, 12288, 256, 7168, 2048), (16, 12288, 256, 2048, 7168),
+    (32, 640, 16, 2048, 1792), (32, 12288, 128, 2048, 1792),
+    (32, 12288, 128, 1792, 2048), (32, 16384, 256, 2048, 1792),
+    (32, 16384, 256, 1792, 2048)],
+    ids=["decode-gate", "decode-down", "prefill-gate", "prefill-down",
+         "rag-decode-gate", "rag-admit-gate", "rag-admit-down",
+         "rag-chunk-gate", "rag-chunk-down"])
+def test_expert_grouped_matmul_compiles(S, G, rows, tm, K, N):
+    """The held experts' grouped products at the cells' real shapes, bf16.
+    longout: 16 experts of 7168 x 2048 (gate, up) and 2048 x 7168 (down); a
     decode step's 256 pairs in tiles of 16 rows, an admission chunk's 8192
-    in tiles of 256 (parallel/expert_share.py's layouts)."""
+    in tiles of 256 (parallel/expert_share.py's layouts). rag: 32 experts
+    of 2048 x 1792 and 1792 x 2048; a decode step's 128 pairs in tiles of
+    16, an admission's <= 8192 in tiles of 128, a 4,096-token admission's
+    chunks of 8192 in tiles of 256 — the admission shapes take the
+    RESIDENT plan (a whole matrix twice in VMEM, its own VMEM limit, a
+    hand-started fetch): Mosaic's verdict on it is this test."""
     bf = jnp.bfloat16
     text = _compile(lambda lhs, rhs, group, n: pk.grouped_matmul(
         lhs, rhs, group, n, tm=tm, route="kernel", interpret=False),
-        S((rows, K), bf), S((16, K, N), bf), S((rows // tm,), jnp.int32),
+        S((rows, K), bf), S((G, K, N), bf), S((rows // tm,), jnp.int32),
         S((1,), jnp.int32))
     assert "expert_grouped_matmul" in text
+
+
+@pytest.mark.parametrize("tm, K, N, blocks", [
+    (16, 7168, 2048, (512, 2048, False)),
+    (16, 2048, 7168, (256, 3584, False)),
+    (256, 7168, 2048, (512, 2048, False)),
+    (256, 2048, 7168, (256, 3584, False)),
+    (16, 2048, 1792, (512, 1792, False)),
+    (16, 1792, 2048, (256, 2048, False)),
+    (128, 2048, 1792, (2048, 1792, True)),
+    (128, 1792, 2048, (1792, 2048, True)),
+    (256, 2048, 1792, (2048, 1792, True)),
+    (256, 1792, 2048, (1792, 2048, True))],
+    ids=["longout-decode-gate", "longout-decode-down", "longout-admit-gate",
+         "longout-admit-down", "rag-decode-gate", "rag-decode-down",
+         "rag-admit-gate", "rag-admit-down", "rag-chunk-gate",
+         "rag-chunk-down"])
+def test_grouped_matmul_blocks_by_shape(tm, K, N, blocks):
+    """Which plan runs where (no chip, no compile): every ``tm`` 16 shape of
+    both expert cells and longout's admissions (a 7168-deep matrix does not
+    fit VMEM twice) keep the K-split blocks they had before the resident
+    plan existed; rag's admissions hold the whole matrix."""
+    assert pk.grouped_matmul_blocks(tm, K, N, 2) == blocks
 
 
 def test_flash_attention_compiles_at_latent_head_width(S):

@@ -251,19 +251,132 @@ def test_tile_layout_gives_every_tile_one_group():
     assert (src[2 * int(n_tiles[0]):] == group_of.size).all()
 
 
-def test_grouped_matmul_kernel_matches_the_dense_route():
-    lhs = jax.random.normal(jax.random.PRNGKey(0), (64, 128))
-    rhs = jax.random.normal(jax.random.PRNGKey(1), (3, 128, 256))
-    tile_group = jnp.asarray([0, 0, 2, 1, 1, 1, 1, 1], jnp.int32)
-    n_tiles = jnp.asarray([4], jnp.int32)
-    kw = dict(tm=8)
+@pytest.mark.parametrize("tm, tile_group, n_tiles", [
+    (8, [0, 0, 2, 1, 1, 1, 1, 1], 4),
+    # tall tiles, the whole matrix held: group 0 owns three adjacent tiles
+    # (its matrix is fetched once), group 1 none, group 2 one, group 3 two;
+    # the walk stops before the clamped tail
+    (128, [0, 0, 0, 2, 3, 3, 3, 3], 6)], ids=["k-split", "resident"])
+def test_grouped_matmul_kernel_matches_the_dense_route(tm, tile_group,
+                                                       n_tiles):
+    K, N = 128, 256
+    assert pk.grouped_matmul_blocks(tm, K, N, 4) == (K, N, tm == 128)
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (8 * tm, K))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (4, K, N))
+    tile_group = jnp.asarray(tile_group, jnp.int32)
+    n_tiles = jnp.asarray([n_tiles], jnp.int32)
+    kw = dict(tm=tm)
     dense = pk.grouped_matmul(lhs, rhs, tile_group, n_tiles, route="dense",
                               **kw)
     kern = pk.grouped_matmul(lhs, rhs, tile_group, n_tiles, route="kernel",
                              interpret=True, **kw)
-    np.testing.assert_allclose(kern[:32], dense[:32], rtol=2e-5, atol=2e-4)
+    rows = int(n_tiles[0]) * tm
+    np.testing.assert_allclose(kern[:rows], dense[:rows], rtol=2e-5,
+                               atol=2e-4)
     with pytest.raises(ValueError, match="whole tiles"):
-        pk.grouped_matmul(lhs[:60], rhs, tile_group, n_tiles, **kw)
+        pk.grouped_matmul(lhs[:-4], rhs, tile_group, n_tiles, **kw)
+
+
+def test_equal_grouped_products_of_a_program_are_traced_once(monkeypatch):
+    """A program calls the kernel with the same shapes a layer; the jitted
+    kernel call traces the body once a distinct call, not once a call site
+    (set-up time: 36 call sites an admit program)."""
+    runs = []
+    body = pk._grouped_matmul_kernel
+
+    def counted(*a, **k):
+        runs.append(1)
+        return body(*a, **k)
+    monkeypatch.setattr(pk, "_grouped_matmul_kernel", counted)
+    lhs = jnp.ones((24, 384))                 # shapes no other test uses
+    rhs = jnp.ones((2, 384, 128))
+    group, n = jnp.asarray([0, 1, 1], jnp.int32), jnp.asarray([3], jnp.int32)
+
+    @jax.jit
+    def program(x):
+        for _ in range(3):
+            x = x + pk.grouped_matmul(x, rhs, group, n, tm=8, route="kernel",
+                                      interpret=True)[:, :1]
+        return x
+    program(lhs)
+    assert len(runs) == 1
+
+
+def test_grouped_matmul_resident_plan_walks_no_tile_of_an_empty_layout():
+    """No held pair at all (every slot drained): the grid is empty, no
+    fetch is started, the call returns."""
+    lhs = jnp.ones((256, 128))
+    rhs = jnp.ones((2, 128, 256))
+    out = pk.grouped_matmul(lhs, rhs, jnp.asarray([1, 1], jnp.int32),
+                            jnp.asarray([0], jnp.int32), tm=128,
+                            route="kernel", interpret=True)
+    assert out.shape == (256, 256)
+
+
+@pytest.mark.parametrize("n_pairs, chunk", [(96, 8192), (2000, 8192),
+                                            (2000, 512)],
+                         ids=["decode", "admission", "chunked"])
+def test_row_tiles_counts_the_tiles_the_layouts_hold(monkeypatch, n_pairs,
+                                                     chunk):
+    """row_tiles, from the counts alone, is the number of tiles the
+    layer's tile_layout calls lay out — also where the sorted pairs are
+    walked CHUNK at a time and a chunk boundary cuts an expert's run."""
+    monkeypatch.setattr(expert_share, "CHUNK", chunk)
+    n_held = 5
+    rng = np.random.default_rng(n_pairs)
+    local = rng.choice(n_held + 1, size=n_pairs,
+                       p=[.4, .05, .2, 0, .15, .2]).astype(np.int32)
+    counts = np.bincount(local, minlength=n_held + 1)[:n_held]
+    tm = expert_share.tile_rows(n_pairs)
+    assert tm == (16 if n_pairs <= 1024 else 128 if chunk == 8192 else 256)
+    # the layer's walk: the held pairs sorted by expert, cut every ``step``
+    # pairs, each piece laid out on its own (padding adds no tile)
+    held = np.sort(local[local < n_held])
+    step = chunk if n_pairs > chunk else n_pairs
+    want = sum(int(expert_share.tile_layout(
+        jnp.asarray(held[i:i + step]), n_held, tm)[2][0])
+        for i in range(0, held.size, step))
+    got = int(expert_share.row_tiles(jnp.asarray(counts, jnp.int32), n_pairs))
+    assert got == want
+    if n_pairs <= chunk:
+        assert got == int(np.sum(-(-counts // tm)))
+    # a stack of layers' counts sums over the layers
+    both = jnp.asarray(np.stack([counts, counts[::-1]]), jnp.int32)
+    if n_pairs <= chunk:
+        assert int(expert_share.row_tiles(both, n_pairs)) == 2 * got
+
+
+@pytest.mark.parametrize("program", ["admit", "decode"])
+def test_a_program_returns_the_row_tiles_of_its_own_counts(program):
+    """``row_tiles`` beside ``routed``: Σ ceil(count / tm) over the
+    program's own (layer, held expert) counts — an admission chunk of 512
+    tokens (2,048 pairs, tiles of 128: the busiest experts own two) and one
+    decode step (tiles of 16, one an expert)."""
+    model, params = build()
+    if program == "admit":
+        ids = jax.random.randint(jax.random.PRNGKey(5), (8, 64), 0, VOCAB)
+        lengths = jnp.asarray([64, 64, 64, 64, 64, 64, 64, 3], jnp.int32)
+        _, _, stats = model._sequence(params, ids, lengths, False)
+        tm = 128
+    else:
+        B, bs = 4, 8
+        cell = {"pos": jnp.asarray([3, 0, 9, 5], jnp.int32),
+                "stats": model.program_stats_zero()}
+        for i in range(3):
+            cell[f"kv{i}"] = jnp.zeros((1 + B * 2, bs, model.row))
+        tables = 1 + jnp.arange(B * 2, dtype=jnp.int32).reshape(B, 2)
+        _, cell = model.decode_step_paged(
+            params, cell, jnp.asarray([1, 2, 3, 4], jnp.int32), tables,
+            live=jnp.asarray([True, False, True, True]))
+        stats, tm = cell["stats"], 16
+    routed = np.asarray(stats["routed"])
+    assert routed.sum() > 0
+    assert int(stats["row_tiles"]) == int(np.sum(-(-routed // tm)))
+    assert int(stats["row_tiles"]) >= int(stats["touched"])
+    if program == "admit":
+        assert int(stats["row_tiles"]) > int(stats["touched"])
+    else:
+        assert int(stats["row_tiles"]) == int(stats["touched"])
 
 
 def test_latent_read_kernel_matches_the_dense_route():
@@ -431,9 +544,17 @@ def test_engine_counts_what_the_expert_layer_routed(tiny):
         assert 0 < here <= steps * 4 * 2
         assert values[("moe.experts_touched_total", "segment")] <= here
         assert spans and all(
-            {"routed_here", "experts_touched", "load_max"} <= set(e["args"])
-            for e in spans)
+            {"routed_here", "experts_touched", "row_tiles", "load_max"}
+            <= set(e["args"]) for e in spans)
         assert sum(e["args"]["routed_here"] for e in spans) == here
+        # a decode step's expert has one short tile; the 9-token admission's
+        # 36 pairs an expert layer too
+        for prog in ("admit", "segment"):
+            assert values[("moe.row_tiles_total", prog)] == \
+                values[("moe.experts_touched_total", prog)]
+        prefills = [e for e in session.tracer.snapshot()
+                    if e.get("name") == "serving.prefill"]
+        assert prefills and all("row_tiles" in e["args"] for e in prefills)
         marks = [e for e in session.tracer.snapshot()
                  if e.get("name") == "moe.program"]
         assert {e["args"]["program"] for e in marks} == {"admit", "segment"}
