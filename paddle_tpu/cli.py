@@ -1314,13 +1314,13 @@ def cmd_serve(args):
     test mode: the same flags + seed reproduce the exact weights).
 
     What a ``--config`` model must offer the page pool (``TransformerLM``,
-    ``DeepseekV3LM`` and ``Lfm2MoeLM`` do; serving/paged.py names no cache
-    array itself): ``max_len``; ``cache_rows(params, kv_dtype)`` — its state
+    ``DeepseekV3LM``, ``Lfm2MoeLM`` and ``NemotronHLM`` do; serving/paged.py
+    names no cache array itself): ``max_len``; ``cache_rows(params, kv_dtype)`` — its state
     as it states it, raising ValueError for a ``kv_dtype`` it has no cache
     for: ``CacheRow`` (name, trailing shape, dtype, fill) for what lives in
     PAGES, a row a token, and ``SlotRow`` (the same four) for what lives
     PER SLOT at a fixed size whatever the context (Lfm2MoeLM's convolution
-    tails); ``prefill(params, prompts, lengths, kv_dtype=, pad_to=)`` ->
+    tails, NemotronHLM's recurrent carry); ``prefill(params, prompts, lengths, kv_dtype=, pad_to=)`` ->
     (cell with one ``[B, pad_to, *shape]`` entry per cache row, one ``[B,
     *shape]`` entry per slot row — each row's state at its own length — and
     ``pos``, last logits); ``decode_step_paged(params, cell, tokens, tables,
@@ -1332,7 +1332,14 @@ def cmd_serve(args):
     ``paged_read_layers`` (layers of a step that make that read; default all);
     and, only for the prefix cache, ``prefill_paged`` (a model without it
     needs ``--no_prefix_cache``; with it on, ``serve`` refuses at start-up;
-    slot rows are not shared by prefix). Optional: ``program_stats_zero()``
+    slot rows are not shared by prefix). Optional: ``slot_rows_in_place =
+    True`` — for slot rows too large to hold twice (NemotronHLM's 1.57 GB
+    at 32 slots): ``prefill`` then also takes ``slot_state=`` (the pool's
+    own ``[slots, *shape]`` arrays, donated) and returns them in the cell
+    WRITTEN at the rows that hold a prompt and untouched elsewhere, and
+    the segment program resets dead slots' rows by a scatter at those
+    slots alone, so no program holds a second copy of them. Optional:
+    ``program_stats_zero()``
     / ``note_program_stats(stats, program)`` for counts a program returns
     beside its tokens (they land on the ``serving.prefill`` and
     ``serving.segment`` spans). The dtype of weights and cache follows the

@@ -8,6 +8,7 @@ from .lfm2 import Lfm2MoeLM
 from .image import (AlexNet, GoogleNet, LeNet, ResNet, SmallNet,
                     VGG, resnet50)
 from .mlp import MnistMLP
+from .nemotron_h import NemotronHLM
 from .seq2seq import AttentionSeq2Seq
 from .transformer import TransformerBlock, TransformerLM
 from .transformer_nmt import CrossAttentionBlock, TransformerSeq2Seq
@@ -20,4 +21,5 @@ __all__ = [
            "AttentionSeq2Seq", "LinearCRFTagger", "BiLSTMCRFTagger",
            "Word2Vec", "Recommender", "DeepFM", "GAN", "VAE",
            "TransformerLM", "TransformerBlock", "DeepseekV3LM", "Lfm2MoeLM",
+           "NemotronHLM",
            "TransformerSeq2Seq", "CrossAttentionBlock"]

@@ -55,7 +55,7 @@ PREFILL_TOKENS = 2048
 
 
 def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
-                      chunk_tokens):
+                      chunk_tokens, in_place=()):
     """The admission walk ``DeepseekV3LM`` and ``Lfm2MoeLM`` share. Rows
     are independent of one another, so the depth runs a few rows at a time
     (``chunk_tokens``, the caller's ``PREFILL_TOKENS``): what a chunk
@@ -72,7 +72,11 @@ def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
     ``state`` (same tree, ``[R, ...]``) is written into; ``stats0``: the
     tree the chunks' ``stats`` are summed into. Returns (each row's hidden
     state at its last position [B, d], state, stats); rows of length 0
-    keep their zeros."""
+    keep their zeros. ``in_place``: names of ``state0`` (a dict then)
+    whose buffers are NOT fresh zeros but somebody's live arrays (the
+    pool's per-slot rows, ``NemotronHLM.prefill``): a chunk writes them
+    at the rows that hold a prompt and nowhere else — the rows of length
+    0 that fill up the last chunk keep what they hold."""
     B, T0 = prompt.shape
     R = next(r for r in range(max(1, min(B, chunk_tokens // T0)), 0, -1)
              if B % r == 0)
@@ -85,8 +89,14 @@ def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
         n = pos[idx]
         h, new, st = sequence(prompt[idx], n)
         last = last.at[idx].set(h[jnp.arange(R), n - 1])
-        state = jax.tree_util.tree_map(lambda buf, x: buf.at[idx].set(x),
-                                       state, new)
+        if in_place:
+            held = jnp.where(n > 0, idx, B)         # B: dropped
+            state = {k: (buf.at[held].set(new[k], mode="drop")
+                         if k in in_place else buf.at[idx].set(new[k]))
+                     for k, buf in state.items()}
+        else:
+            state = jax.tree_util.tree_map(
+                lambda buf, x: buf.at[idx].set(x), state, new)
         return (i + 1, last, state,
                 jax.tree_util.tree_map(jnp.add, stats, st))
     _, last, state, stats = jax.lax.while_loop(

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from ..ops import activations as A
 from ..ops import conv as conv_ops
 from ..ops import norm as norm_ops
+from ..ops import pallas_kernels as pk
 from ..ops import pool as pool_ops
 from ..ops.random import dropout as dropout_op
 from . import initializer as I
@@ -253,6 +254,26 @@ class SwiGLU(Module):
                        preferred_element_type=jnp.float32)
 
 
+class ReluSquaredMLP(Module):
+    """``down(relu(up(x)) ** 2)``: the ungated two-matrix feed-forward
+    (``relu2``) of Nemotron-H's experts. Operands in the weights' dtype,
+    float32 accumulation, the activation in float32."""
+
+    def __init__(self, dim: int, hidden: int,
+                 w_init: Optional[I.Initializer] = None, dtype=jnp.float32):
+        super().__init__()
+        init = w_init or I.normal(0.0, 0.02)
+        self.param("w_up", (dim, hidden), init, dtype=dtype)
+        self.param("w_down", (hidden, dim), init, dtype=dtype)
+
+    def __call__(self, params, x, **kw):
+        dt = params["w_up"].dtype
+        u = jnp.dot(x.astype(dt), params["w_up"],
+                    preferred_element_type=jnp.float32)
+        return jnp.dot(jnp.square(jax.nn.relu(u)).astype(dt),
+                       params["w_down"], preferred_element_type=jnp.float32)
+
+
 class ShortConv(Module):
     """Gated short convolution (the ``conv`` operator of LFM2): ``[B | C |
     x] = u W_in``; ``z = B * x``; ``c_t = sum_j w[:, j] * z_{t - (taps - 1)
@@ -314,3 +335,139 @@ class ShortConv(Module):
         z, c_gate = self._gates(params, u)
         zz = jnp.concatenate([tail, z[:, None].astype(tail.dtype)], axis=1)
         return self._out(params, c_gate, lambda j: zz[:, j]), zz[:, 1:]
+
+
+def _log_uniform_dt_bias(low: float, high: float, floor: float):
+    """``dt_bias`` as the published Mamba-2 draws it: the inverse softplus
+    of a step log-uniform in [low, high], floored at ``floor``."""
+    def init(key, shape, dtype=jnp.float32):
+        u = jax.random.uniform(key, shape, jnp.float32)
+        step = jnp.maximum(jnp.exp(u * (jnp.log(high) - jnp.log(low))
+                                   + jnp.log(low)), floor)
+        return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)
+    return init
+
+
+class Mamba2Mixer(Module):
+    """The Mamba-2 mixer (Nemotron-H's ``M`` layer): ``[z | xBC | dt] = u
+    W_in``; ``xBC = silu(conv1d(xBC))`` (depthwise, causal, ``taps`` taps,
+    with bias); ``[x | B | C] = xBC`` (``heads x head_dim`` | ``groups x
+    state`` | the same); ``dt = softplus(dt + dt_bias)``, ``A =
+    -exp(a_log)``, a head each. Head h of group ``h // (heads // groups)``
+    keeps ``S`` [head_dim, state]: ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t
+    (x) B_t``, ``y_t = S_t C_t + D x_t``. Then ``y = RMSNorm_groups(y *
+    silu(z))`` (the gate BEFORE the norm, ``groups`` groups, one gain over
+    the whole width) and ``y W_out``.
+
+    Its state, whatever the context: ``S`` of every head, float32, packed
+    as ops/pallas_kernels.ssm_pack lays it out ([heads // 2, state, 2
+    head_dim]), and the last ``taps - 1`` rows of ``xBC`` as they enter
+    the convolution, in the weights' dtype (rounded where they are made, so
+    a step from the state and a whole sequence see the same values). A
+    sequence runs the chunked scan (pk.ssd_chunk_scan), a step the
+    in-place update (pk.ssm_state_update). Products take operands in the
+    weights' dtype with float32 accumulation; ``dt``, ``exp(dt A)``, the
+    taps' sum, the gate, the norm and ``S`` are float32."""
+
+    def __init__(self, dim: int, *, heads: int, head_dim: int, groups: int,
+                 state: int, taps: int = 4, chunk: int = 128,
+                 eps: float = 1e-5, dt_min: float = 1e-3,
+                 dt_max: float = 0.1, dt_floor: float = 1e-4,
+                 w_init: Optional[I.Initializer] = None, dtype=jnp.float32):
+        super().__init__()
+        self.heads, self.head_dim, self.groups = heads, head_dim, groups
+        self.state, self.taps, self.chunk, self.eps = state, taps, chunk, eps
+        self.inner = heads * head_dim
+        self.conv_dim = self.inner + 2 * groups * state
+        init = w_init or I.normal(0.0, 0.02)
+        self.param("w_in", (dim, self.inner + self.conv_dim + heads), init,
+                   dtype=dtype)
+        # PyTorch's Conv1d default: uniform(+-1/sqrt(fan_in)), fan_in = taps
+        bound = taps ** -0.5
+        self.param("w_conv", (self.conv_dim, taps),
+                   I.uniform(-bound, bound), dtype=dtype)
+        self.param("b_conv", (self.conv_dim,), I.uniform(-bound, bound),
+                   dtype=dtype)
+        self.param("dt_bias", (heads,),
+                   _log_uniform_dt_bias(dt_min, dt_max, dt_floor))
+        self.param("a_log", (heads,), lambda key, shape, dtype=jnp.float32:
+                   jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0)))
+        self.param("d", (heads,), I.ones)
+        self.param("norm_gamma", (self.inner,), I.ones, dtype=dtype)
+        self.param("w_out", (self.inner, dim), init, dtype=dtype)
+
+    def _project(self, params, u):
+        dt_ = params["w_in"].dtype
+        zxd = jnp.dot(u.astype(dt_), params["w_in"],
+                      preferred_element_type=jnp.float32)
+        z = zxd[..., :self.inner]
+        xbc = zxd[..., self.inner:self.inner + self.conv_dim].astype(dt_)
+        dt = jax.nn.softplus(zxd[..., self.inner + self.conv_dim:]
+                             + params["dt_bias"].astype(jnp.float32))
+        return z, xbc, dt
+
+    def _conv(self, params, taps_of):
+        """``taps_of(j)``: the rows tap j multiplies -> (x [..., heads,
+        head_dim], B, C [..., groups, state]) f32, after the silu."""
+        w = params["w_conv"].astype(jnp.float32)
+        c = params["b_conv"].astype(jnp.float32) + sum(
+            w[:, j] * taps_of(j).astype(jnp.float32)
+            for j in range(self.taps))
+        c = jax.nn.silu(c)
+        lead, gn = c.shape[:-1], self.groups * self.state
+        return (c[..., :self.inner].reshape(lead + (self.heads,
+                                                    self.head_dim)),
+                c[..., self.inner:self.inner + gn].reshape(
+                    lead + (self.groups, self.state)),
+                c[..., self.inner + gn:].reshape(
+                    lead + (self.groups, self.state)))
+
+    def _out(self, params, y, x, z):
+        """(S C, x, z) -> the mixer's output: + D x, gate, grouped norm,
+        ``W_out``."""
+        y = y + params["d"].astype(jnp.float32)[:, None] * x
+        lead = z.shape[:-1]
+        y = y.reshape(lead + (self.inner,)) * jax.nn.silu(z)
+        g = y.reshape(lead + (self.groups, self.inner // self.groups))
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                              + self.eps)
+        y = g.reshape(lead + (self.inner,)) \
+            * params["norm_gamma"].astype(jnp.float32)
+        dt_ = params["w_out"].dtype
+        return jnp.dot(y.astype(dt_), params["w_out"],
+                       preferred_element_type=jnp.float32)
+
+    def __call__(self, params, u, lengths=None, *, route=None, **kw):
+        """u [B, T, dim], a sequence from its start -> (y [B, T, dim] f32,
+        S packed [B, heads // 2, state, 2 head_dim] f32, tail [B, taps -
+        1, conv_dim]) — state and tail AT each row's own length
+        (``lengths`` [B]; T when None), whatever the padding holds."""
+        z, xbc, dt = self._project(params, u)
+        B, T, _ = xbc.shape
+        keep = self.taps - 1
+        zz = jnp.concatenate(
+            [jnp.zeros((B, keep, self.conv_dim), xbc.dtype), xbc], axis=1)
+        x, bm, cm = self._conv(params, lambda j: zz[:, j:j + T])
+        y, state = pk.ssd_chunk_scan(
+            x, dt, -jnp.exp(params["a_log"].astype(jnp.float32)), bm, cm,
+            lengths, chunk=self.chunk, dtype=params["w_in"].dtype,
+            route=route)
+        n = jnp.full((B,), T, jnp.int32) if lengths is None \
+            else jnp.asarray(lengths, jnp.int32)
+        at = n[:, None] + jnp.arange(keep, dtype=jnp.int32)[None, :]
+        tail = jnp.take_along_axis(zz, at[:, :, None], axis=1)
+        return self._out(params, y, x, z), state, tail
+
+    def step(self, params, u, state, tail, live=None, *, route=None):
+        """One position: u [B, dim], the slot's ``state`` and ``tail`` ->
+        (y [B, dim] f32, the state after the position — in place on the
+        kernel route, untouched where ``live`` [B] is False — and the
+        tail rolled by one)."""
+        z, xbc, dt = self._project(params, u)
+        zz = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)],
+                             axis=1)
+        x, bm, cm = self._conv(params, lambda j: zz[:, j])
+        y, state = pk.ssm_state_update(
+            state, x, dt, -jnp.exp(params["a_log"].astype(jnp.float32)),
+            bm, cm, live, route=route)
+        return self._out(params, y, x, z), state, zz[:, 1:]

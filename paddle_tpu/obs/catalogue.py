@@ -416,12 +416,27 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
     "serving.slot_state_bytes_held": (
         "gauge", "bytes of the pool's per-slot rows (a model's SlotRow "
                  "statements: state of a fixed size whatever the context, "
-                 "e.g. Lfm2MoeLM's convolution tails), all slots; 0 for a "
-                 "model that states none"),
+                 "e.g. Lfm2MoeLM's convolution tails, NemotronHLM's "
+                 "recurrent carry: 1.57 GB at 32 slots), all slots; 0 for "
+                 "a model that states none"),
     "serving.slot_state_writes_total": (
         "counter", "admitted slots whose per-slot rows an admit program "
                    "wrote (one per request admitted by prefill; 0 for a "
                    "model that states no SlotRow)"),
+    "ssm.state_updates_total": (
+        "counter", "single-position state updates of the Mamba-2 layers "
+                   "(pk.ssm_state_update): one a LIVE slot a state-space "
+                   "layer a decode step, counted by the program and "
+                   "fetched beside its tokens (NemotronHLM), labels: "
+                   "program",
+        ("program",)),
+    "ssm.scan_tokens_total": (
+        "counter", "(position, state-space layer) pairs the chunked scan "
+                   "of an admission ran (pk.ssd_chunk_scan): state=real "
+                   "lies inside its row's own length, state=padded past "
+                   "it (bucket padding and the rows of length 0 that fill "
+                   "up a chunk), labels: state",
+        ("state",)),
     "moe.assignments_total": (
         "counter", "live (token, choice) pairs the expert layers of an "
                    "admit or segment program routed, over ALL experts "
@@ -599,7 +614,9 @@ SPANS: Dict[str, str] = {
     "serving.prefill": "one admission batch: ragged prefill + page "
                        "placement (args: batch; with expert layers also "
                        "routed_here, experts_touched, row_tiles, load_max of "
-                       "the admit program)",
+                       "the admit program; with state-space layers also "
+                       "rows and prompt_tokens: the rows the admit program "
+                       "ran and the positions inside their own length)",
     "serving.segment": "one batched decode segment across live slots "
                        "(args: live; with expert layers also routed_here, "
                        "experts_touched, row_tiles, load_max)",

@@ -1219,16 +1219,24 @@ def paged_latent_attention(q: jax.Array, pool: jax.Array, tables: jax.Array,
 #   group's matrix would hide behind one tile's product, not behind all.)
 # ---------------------------------------------------------------------------
 
+def _tile_dot(lhs, w, transposed: bool):
+    """lhs [tm, K] x w -> [tm, N] f32: w [K, N], or [N, K] where the
+    group's matrix is held with K minor (``transposed``)."""
+    if not transposed:
+        return jnp.dot(lhs, w, preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(lhs, w, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _grouped_matmul_kernel(group_ref, lhs_ref, rhs_ref, o_ref, acc_ref, *,
-                           n_k: int):
+                           n_k: int, transposed: bool):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(lhs_ref[...], rhs_ref[0],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += _tile_dot(lhs_ref[...], rhs_ref[0], transposed)
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -1236,10 +1244,12 @@ def _grouped_matmul_kernel(group_ref, lhs_ref, rhs_ref, o_ref, acc_ref, *,
 
 
 def _grouped_matmul_resident_kernel(group_ref, lhs_ref, rhs_hbm, o_ref,
-                                    w_ref, sem, slot_ref):
-    """One tile a step; ``rhs_hbm`` [G, K, N] stays in HBM, ``w_ref``
-    [2, K, N] holds this group's matrix and receives the next group's,
-    ``slot_ref`` (SMEM) remembers which half is this group's."""
+                                    w_ref, sem, slot_ref, *,
+                                    transposed: bool):
+    """One tile a step; ``rhs_hbm`` [G, K, N] ([G, N, K] ``transposed``)
+    stays in HBM, ``w_ref`` [2, ...] holds this group's matrix and receives
+    the next group's, ``slot_ref`` (SMEM) remembers which half is this
+    group's."""
     from jax.experimental.pallas import tpu as pltpu
     t, n_t = pl.program_id(0), pl.num_programs(0)
     last = group_ref.shape[0] - 1
@@ -1267,16 +1277,17 @@ def _grouped_matmul_resident_kernel(group_ref, lhs_ref, rhs_hbm, o_ref,
         def _ahead():
             fetch(group_ref[nxt], 1 - slot).start()
 
-    o_ref[...] = jnp.dot(lhs_ref[...], w_ref[slot_ref[0]],
-                         preferred_element_type=jnp.float32
-                         ).astype(o_ref.dtype)
+    o_ref[...] = _tile_dot(lhs_ref[...], w_ref[slot_ref[0]],
+                           transposed).astype(o_ref.dtype)
 
 
-def _dense_grouped_matmul(lhs, rhs, tile_group, tm, out_dtype):
+def _dense_grouped_matmul(lhs, rhs, tile_group, tm, out_dtype,
+                          transposed=False):
     """Reference-math route: every tile against its own group's matrix."""
     M, K = lhs.shape
-    w = rhs[tile_group]                                     # [tiles, K, N]
-    out = jnp.einsum("tmk,tkn->tmn", lhs.reshape(M // tm, tm, K), w,
+    w = rhs[tile_group]                     # [tiles, K, N] (or [.., N, K])
+    out = jnp.einsum("tmk,tnk->tmn" if transposed else "tmk,tkn->tmn",
+                     lhs.reshape(M // tm, tm, K), w,
                      preferred_element_type=jnp.float32)
     return out.reshape(M, -1).astype(out_dtype)
 
@@ -1292,6 +1303,11 @@ def _resident_vmem_bytes(tm: int, K: int, N: int, itemsize: int) -> int:
     return 2 * K * N * itemsize + 2 * tm * K * itemsize + 3 * tm * N * 4
 
 
+def _multiples_of_128(n: int):
+    """The multiples of 128 that divide ``n``, largest first."""
+    return [t for t in range(n - n % 128, 0, -128) if n % t == 0]
+
+
 def grouped_matmul_blocks(tm: int, K: int, N: int,
                           itemsize: int = 2) -> Tuple[int, int, bool]:
     """(tk, tn, resident): the weight block a program holds and the plan
@@ -1301,25 +1317,41 @@ def grouped_matmul_blocks(tm: int, K: int, N: int,
     tiles) and the matrix fits VMEM twice, so a group's matrix is fetched
     once a visit and the fetch of the next group's hides behind all of
     this group's tiles. Otherwise K-SPLIT: whole rows of the matrix where
-    they fit (one contiguous fetch), about 2 MiB of bf16 a block, streamed
-    once a TILE — a decode step's short tiles (one a group: nothing to
-    reuse) and matrices too large to hold twice (``K`` 7168)."""
+    they fit (one contiguous fetch), streamed once a TILE — a decode
+    step's short tiles (one a group: nothing to reuse) and matrices too
+    large to hold twice (``K`` 7168). ``tn`` is ``N`` up to 2048, else its
+    largest divisor that is a multiple of 128 and at most 3584; ``tk`` the
+    largest such divisor of ``K`` that keeps the block at 2 MiB of bf16 or
+    under (at most 512 rows: as deep as the accumulator's tile is worth).
+    A ``K`` with no such divisor (1856 = 29 x 64) is taken whole, and
+    ``tn`` then narrows until the block is 4 MiB or under — never to
+    strips of a lane tile's width, whose rows would be 256-byte
+    fetches."""
     if tm >= 128 and _resident_vmem_bytes(tm, K, N, itemsize) \
             <= _RESIDENT_VMEM:
         return K, N, True
-    tn = N if N <= 2048 else next(t for t in (3584, 2048, 1024, 512, 256,
-                                              128, N) if N % t == 0)
-    tk = next((t for t in (512, 256, 128) if K % t == 0
-               and t * tn <= (1 << 20)), K)
+    wide = [t for t in _multiples_of_128(N) if t <= 3584]
+    tn = N if N <= 2048 or not wide else wide[0]
+    tk = next((t for t in _multiples_of_128(K)
+               if t <= 512 and t * tn <= (1 << 20)), K)
+    if tk == K and K * tn > (2 << 20):
+        tn = next((t for t in wide if K * t <= (2 << 20)), tn)
     return tk, tn, False
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
                    n_tiles: jax.Array, *, tm: int,
-                   out_dtype=jnp.float32, route: Optional[str] = None,
+                   out_dtype=jnp.float32, transposed: bool = False,
+                   route: Optional[str] = None,
                    interpret: Optional[bool] = None) -> jax.Array:
     """lhs [M, K] (M a multiple of ``tm``) x rhs [G, K, N] -> [M, N]: tile
-    t (rows t*tm ..) is multiplied by ``rhs[tile_group[t]]``. Only tiles
+    t (rows t*tm ..) is multiplied by ``rhs[tile_group[t]]``.
+    ``transposed``: rhs is [G, N, K], every group's matrix held with K
+    minor (a matrix whose N is no multiple of the lane width — 1856 — is
+    held so: the device stores [K, N] of such an N with K minor anyway,
+    and hands a kernel that wants it otherwise a COPY of all of it every
+    program); the same products, the contraction over both last
+    dimensions. Only tiles
     t < n_tiles[0] are computed; the rows of later tiles are UNDEFINED on
     the kernel route (the caller masks them), so a group without rows costs
     nothing. The tiles of one group must be ADJACENT in ``tile_group``
@@ -1340,33 +1372,35 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, tile_group: jax.Array,
     obs.count("kernels.routes_total", kernel="expert_grouped_matmul",
               route=route)
     if route == "dense":
-        return _dense_grouped_matmul(lhs, rhs, tile_group, tm, out_dtype)
+        return _dense_grouped_matmul(lhs, rhs, tile_group, tm, out_dtype,
+                                     transposed)
     if route != "kernel":
         raise ValueError(f"unknown grouped_matmul route {route!r}")
     return _grouped_matmul_call(tile_group.astype(jnp.int32), lhs, rhs,
                                 n_tiles, tm, jnp.dtype(out_dtype),
-                                _interpret(interpret))
+                                _interpret(interpret), transposed)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
 def _grouped_matmul_call(tile_group, lhs, rhs, n_tiles, tm, out_dtype,
-                         interpret):
+                         interpret, transposed=False):
     """The kernel route of :func:`grouped_matmul`. Jitted: a program calls
     it with the same shapes a layer (12 layers x gate / up / down), and a
     jitted wrapper is traced and lowered once a distinct call, not once a
     call site."""
     from jax.experimental.pallas import tpu as pltpu
-    (M, K), N = lhs.shape, rhs.shape[2]
+    (M, K), N = lhs.shape, rhs.shape[1 if transposed else 2]
     itemsize = jnp.dtype(rhs.dtype).itemsize
     tk, tn, resident = grouped_matmul_blocks(tm, K, N, itemsize)
     if resident:
-        kernel = _grouped_matmul_resident_kernel
+        kernel = functools.partial(_grouped_matmul_resident_kernel,
+                                   transposed=transposed)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(n_tiles[0],),
             in_specs=[pl.BlockSpec((tm, K), lambda t, g: (t, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((tm, N), lambda t, g: (t, 0)),
-            scratch_shapes=[pltpu.VMEM((2, K, N), rhs.dtype),
+            scratch_shapes=[pltpu.VMEM((2,) + rhs.shape[1:], rhs.dtype),
                             pltpu.SemaphoreType.DMA((2,)),
                             pltpu.SMEM((1,), jnp.int32)])
         params = pltpu.CompilerParams(
@@ -1374,12 +1408,15 @@ def _grouped_matmul_call(tile_group, lhs, rhs, n_tiles, tm, out_dtype,
             vmem_limit_bytes=_resident_vmem_bytes(tm, K, N, itemsize)
             + (8 << 20))
     else:
-        kernel = functools.partial(_grouped_matmul_kernel, n_k=K // tk)
+        kernel = functools.partial(_grouped_matmul_kernel, n_k=K // tk,
+                                   transposed=transposed)
+        w_spec = pl.BlockSpec((1, tn, tk), lambda n, t, k, g: (g[t], n, k)) \
+            if transposed else \
+            pl.BlockSpec((1, tk, tn), lambda n, t, k, g: (g[t], k, n))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(N // tn, n_tiles[0], K // tk),
             in_specs=[pl.BlockSpec((tm, tk), lambda n, t, k, g: (t, k)),
-                      pl.BlockSpec((1, tk, tn),
-                                   lambda n, t, k, g: (g[t], k, n))],
+                      w_spec],
             out_specs=pl.BlockSpec((tm, tn), lambda n, t, k, g: (t, n)),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)])
         params = pltpu.CompilerParams(
@@ -1940,6 +1977,312 @@ def gru_sequence_fused(xw: jax.Array, lengths: jax.Array, u: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# Mamba-2 state-space kernels: the recurrence of a head h of group g,
+#
+#     S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t (x) B_t^g      S in R^{P x N}
+#     y_t = S_t C_t^g                                 (+ D_h x_t outside)
+#
+# as a single step against per-slot state (ssm_state_update: a decode step)
+# and as the chunked (SSD) form over a whole prompt (ssd_chunk_scan: an
+# admission). Both keep a row's state PACKED (ssm_pack): two heads side by
+# side on the lanes, [H // 2, N, 2 P] — at P = 64 a [N, 128] tile a pair, all
+# 128 lanes in use, x and y as natural rows [1, 2 P], B and C as columns
+# [N, 1], so the step is multiply-adds with sublane and lane broadcasts and
+# one sublane reduction, no relayout of the state. The two heads of a pair
+# share a group (heads a group is even), so B and C are the pair's.
+# ---------------------------------------------------------------------------
+
+def ssm_pack(state: jax.Array) -> jax.Array:
+    """[..., H, P, N] (a head's state as the equations write it) ->
+    [..., H // 2, N, 2 P], the layout both state-space kernels keep."""
+    *lead, H, P, N = state.shape
+    s = jnp.moveaxis(state.reshape(*lead, H // 2, 2, P, N), -1, -3)
+    return s.reshape(*lead, H // 2, N, 2 * P)
+
+
+def ssm_unpack(packed: jax.Array) -> jax.Array:
+    """:func:`ssm_pack`'s inverse: [..., H // 2, N, 2 P] -> [..., H, P, N]."""
+    *lead, Hp, N, PP = packed.shape
+    s = jnp.moveaxis(packed.reshape(*lead, Hp, N, 2, PP // 2), -3, -1)
+    return s.reshape(*lead, 2 * Hp, PP // 2, N)
+
+
+def _ssm_route(kernel: str, route: Optional[str], heads: int, groups: int):
+    if route is None:
+        route = "kernel" if _on_tpu() else "dense"
+    if route not in ("kernel", "dense"):
+        raise ValueError(f"unknown {kernel} route {route!r}")
+    if heads % groups or (heads // groups) % 2:
+        raise ValueError(f"{kernel}: {heads} heads over {groups} groups "
+                         "are not whole pairs of heads a group")
+    from .. import obs
+    obs.count("kernels.routes_total", kernel=kernel, route=route)
+    return route
+
+
+def _ssm_update_kernel(order_ref, n_ref, s_ref, da_ref, dtx_ref, b_ref,
+                       c_ref, y_ref, s_out, *, pairs_per_group: int):
+    """Program i: the slot ``order[i]``, all of it. Blocks: s [1, H/2, N,
+    2P] f32 (read once, written once, in place); da, dtx, y [1, H/2, 2P]
+    f32 (``exp(dt A)``, ``dt x`` and the result, a row a pair); b, c [1, N,
+    G] f32 (a column a group)."""
+    i = pl.program_id(0)
+    n_pairs = s_ref.shape[1]
+
+    @pl.when(i < n_ref[0])
+    def _live():
+        for j in range(n_pairs):
+            g = j // pairs_per_group
+            s = s_ref[0, j].astype(jnp.float32) * da_ref[0, j:j + 1, :] \
+                + b_ref[0, :, g:g + 1] * dtx_ref[0, j:j + 1, :]
+            s_out[0, j] = s.astype(s_out.dtype)
+            y_ref[0, j:j + 1, :] = jnp.sum(s * c_ref[0, :, g:g + 1], axis=0,
+                                           keepdims=True)
+
+    @pl.when(i >= n_ref[0])
+    def _idle():                 # no live slot at all: the one walked stays
+        s_out[...] = s_ref[...]
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+def _dense_ssm_state_update(state, x, dt, a, bm, cm, live):
+    nat = ssm_unpack(state).astype(jnp.float32)             # [S, H, P, N]
+    rep = x.shape[1] // bm.shape[1]
+    new = jnp.exp(dt * a)[..., None, None] * nat \
+        + (dt[..., None] * x)[..., None] \
+        * jnp.repeat(bm, rep, axis=1)[:, :, None, :]
+    y = jnp.sum(new * jnp.repeat(cm, rep, axis=1)[:, :, None, :], axis=-1)
+    keep = live[:, None, None]
+    return (jnp.where(keep, y, 0.0),
+            ssm_pack(jnp.where(keep[..., None], new, nat)).astype(
+                state.dtype))
+
+
+def ssm_state_update(state: jax.Array, x: jax.Array, dt: jax.Array,
+                     a: jax.Array, bm: jax.Array, cm: jax.Array,
+                     live: Optional[jax.Array] = None, *,
+                     route: Optional[str] = None,
+                     interpret: Optional[bool] = None):
+    """One position of the recurrence for every LIVE slot, in place.
+
+    state [S, H // 2, N, 2 P] (:func:`ssm_pack`; float32, or a narrower
+    dtype the arithmetic is rounded to on the way out); x [S, H, P] f32; dt
+    [S, H] f32 (after its softplus); a [H] f32 (``-exp(A_log)``); bm, cm
+    [S, G, N] f32; live [S] bool (None: all). Returns (y [S, H, P] f32 =
+    ``S_new C`` — zero for a slot that is not live — and the new state, a
+    slot that is not live keeping what it had).
+
+    The kernel route (custom call ``ssm_state_update``) walks the live
+    slots alone — a scalar-prefetched list of them is the grid — reads a
+    slot's state once and writes it once over itself
+    (``input_output_aliases``: inside a program whose state buffer is
+    donated or carried, no second copy of it exists); the dense route is
+    the same arithmetic over the unpacked state."""
+    S, H, P = x.shape
+    G, N = bm.shape[1:]
+    route = _ssm_route("ssm_state_update", route, H, G)
+    live = jnp.ones((S,), bool) if live is None else live.astype(bool)
+    f32 = jnp.float32
+    x, dt, a = x.astype(f32), dt.astype(f32), a.astype(f32)
+    bm, cm = bm.astype(f32), cm.astype(f32)
+    if route == "dense":
+        return _dense_ssm_state_update(state, x, dt, a, bm, cm, live)
+    from jax.experimental.pallas import tpu as pltpu
+    da = jnp.repeat(jnp.exp(dt * a), P, axis=1).reshape(S, H // 2, 2 * P)
+    dtx = (dt[..., None] * x).reshape(S, H // 2, 2 * P)
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    n_live = jnp.sum(live, dtype=jnp.int32).reshape(1)
+    row = pl.BlockSpec((1, H // 2, 2 * P),
+                       lambda i, order, n: (order[i], 0, 0))
+    col = pl.BlockSpec((1, N, G), lambda i, order, n: (order[i], 0, 0))
+    st = pl.BlockSpec((1, H // 2, N, 2 * P),
+                      lambda i, order, n: (order[i], 0, 0, 0))
+    y, new = pl.pallas_call(
+        functools.partial(_ssm_update_kernel,
+                          pairs_per_group=H // G // 2),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(jnp.maximum(n_live[0], 1),),
+            in_specs=[st, row, row, col, col], out_specs=[row, st]),
+        out_shape=[jax.ShapeDtypeStruct((S, H // 2, 2 * P), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={2: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 * (H // 2) * N * 2 * P + (8 << 20)),
+        interpret=_interpret(interpret), name="ssm_state_update",
+    )(order, n_live, state, da, dtx, jnp.swapaxes(bm, 1, 2),
+      jnp.swapaxes(cm, 1, 2))
+    y = jnp.where(live[:, None, None], y.reshape(S, H, P), 0.0)
+    return y, new
+
+
+def _ssd_chunk_kernel(len_ref, x_ref, b_ref, c_ref, acol_ref, arow_ref,
+                      y_ref, s_ref, *, chunk: int, half: int):
+    """Program (r, g, c): chunk c of row r for the heads of group g. Blocks:
+    x [1, L, hpg P] (already ``dt x``, the operands' dtype) and y (f32) a
+    pair of heads every 2P lanes; b, c [1, L, N]; acol [1, 1, L, hpg] and
+    arow [1, 1, hpg, L] f32: the inclusive running sum of ``dt A`` inside
+    the chunk, a head a column / a row; s [1, hpg / 2, N, 2P] f32 — the
+    group's packed state, resident across the row's chunks (its block
+    does not move with c) and written back after the last."""
+    r, c = pl.program_id(0), pl.program_id(2)
+    L, lanes = chunk, 2 * half
+    n_pairs = s_ref.shape[1]
+
+    @pl.when(c == 0)
+    def _start():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    @pl.when(c * L >= len_ref[r])
+    def _past():                    # nothing of the row in this chunk
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(c * L < len_ref[r])
+    def _chunk():
+        bm, cm = b_ref[0], c_ref[0]
+        cd = bm.dtype
+        cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        below = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1) \
+            <= jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
+        first = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1) < half
+
+        def by_head(lo, hi):        # a value a head -> the pair's lanes
+            return jnp.where(first, lo, hi)
+        for j in range(n_pairs):
+            xp = x_ref[0, :, j * lanes:(j + 1) * lanes]         # [L, 2P]
+            col = [acol_ref[0, 0, :, h:h + 1] for h in (2 * j, 2 * j + 1)]
+            row = [arow_ref[0, 0, h:h + 1, :] for h in (2 * j, 2 * j + 1)]
+            y = jnp.zeros((L, lanes), jnp.float32)
+            for k in (0, 1):        # inside the chunk, a head at a time
+                m = cb * jnp.exp(jnp.where(below, col[k] - row[k], _NEG))
+                mine = first if k == 0 else jnp.logical_not(first)
+                y = y + jnp.dot(m.astype(cd),
+                                jnp.where(mine, xp, jnp.zeros_like(xp)),
+                                preferred_element_type=jnp.float32)
+            s = s_ref[0, j]                                     # [N, 2P]
+            y = y + by_head(jnp.exp(col[0]), jnp.exp(col[1])) * jnp.dot(
+                cm, s.astype(cd), preferred_element_type=jnp.float32)
+            end = [a[L - 1:L, :] for a in col]                  # [1, 1]
+            left = by_head(jnp.exp(end[0] - col[0]),
+                           jnp.exp(end[1] - col[1]))            # [L, 2P]
+            s_ref[0, j] = by_head(jnp.exp(end[0]), jnp.exp(end[1])) * s \
+                + jax.lax.dot_general(
+                    bm, (xp.astype(jnp.float32) * left).astype(cd),
+                    (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            y_ref[0, :, j * lanes:(j + 1) * lanes] = y
+
+
+def _dense_ssd_chunk_scan(xdt, a, bm, cm, chunk):
+    """The same chunked algebra in jax.numpy, heads unpacked. xdt [R, T, H,
+    P] and bm, cm [R, T, G, N] in the operands' dtype; a [R, T, H] f32."""
+    R, T, H, P = xdt.shape
+    G, N = bm.shape[2:]
+    nc, rep, cd = T // chunk, H // G, xdt.dtype
+    x = xdt.reshape(R, nc, chunk, H, P)
+    bh = jnp.repeat(bm.reshape(R, nc, chunk, G, N), rep, axis=3)
+    ch = jnp.repeat(cm.reshape(R, nc, chunk, G, N), rep, axis=3)
+    cum = jnp.cumsum(a.reshape(R, nc, chunk, H), axis=2)
+    ein = functools.partial(jnp.einsum,
+                            preferred_element_type=jnp.float32)
+    cb = ein("rclhn,rcshn->rchls", ch, bh)
+    seg = jnp.moveaxis(cum, 2, 3)[..., :, None] \
+        - jnp.moveaxis(cum, 2, 3)[..., None, :]                # [R,nc,H,L,L]
+    below = jnp.tril(jnp.ones((chunk, chunk), bool))
+    m = cb * jnp.exp(jnp.where(below, seg, _NEG))
+    y = ein("rchls,rcshp->rclhp", m.astype(cd), x)
+    left = jnp.exp(cum[:, :, -1:, :] - cum)                    # [R,nc,L,H]
+    add = ein("rclhp,rclhn->rchpn",
+              (x.astype(jnp.float32) * left[..., None]).astype(cd), bh)
+
+    def carry(s, inp):
+        add_c, end_c = inp
+        return jnp.exp(end_c)[..., None, None] * s + add_c, s
+    final, before = jax.lax.scan(
+        carry, jnp.zeros((R, H, P, N), jnp.float32),
+        (jnp.moveaxis(add, 1, 0), jnp.moveaxis(cum[:, :, -1, :], 1, 0)))
+    before = jnp.moveaxis(before, 0, 1)                        # [R,nc,H,P,N]
+    y = y + jnp.exp(cum)[..., None] * ein("rclhn,rchpn->rclhp", ch,
+                                          before.astype(cd))
+    return y.reshape(R, T, H, P), ssm_pack(final)
+
+
+def ssd_chunk_scan(x: jax.Array, dt: jax.Array, a: jax.Array,
+                   bm: jax.Array, cm: jax.Array,
+                   lengths: Optional[jax.Array] = None, *, chunk: int = 128,
+                   dtype=None, route: Optional[str] = None,
+                   interpret: Optional[bool] = None):
+    """The recurrence over whole rows from a zero state, in the chunked
+    (state-space-duality) form: inside a chunk of ``chunk`` positions
+    ``Y = ((C B^T) * L) (dt X)`` with ``L[t, s] = exp(sum_{s < r <= t} dt_r
+    A)`` for ``s <= t`` — matrix products — and across chunks the state
+    is carried: ``y_t += exp(cum_t) C_t S_before``, ``S = exp(cum_end)
+    S_before + sum_s exp(cum_end - cum_s) dt_s x_s (x) B_s``.
+
+    x [R, T, H, P] f32; dt [R, T, H] f32 (after its softplus); a [H] f32;
+    bm, cm [R, T, G, N]; lengths [R]: each row's OWN length (None: T).
+    ``dt`` is taken as zero past a row's length — decay 1, no input — so
+    the state returned is the state AT that length, whatever the padding
+    holds. ``dtype``: the dtype the four products' operands are rounded to
+    (accumulation, the decays and the carried state are float32). Returns
+    (y [R, T, H, P] f32 = ``S_t C_t``, the final state packed [R, H // 2,
+    N, 2 P] f32 (:func:`ssm_pack`)).
+
+    The kernel route (custom call ``ssd_chunk_scan``): one program a (row,
+    group, chunk), the chunks of a row in order with the group's state in
+    VMEM between them; a chunk wholly past its row's length does
+    nothing."""
+    R, T, H, P = x.shape
+    G, N = bm.shape[2:]
+    route = _ssm_route("ssd_chunk_scan", route, H, G)
+    cd = jnp.dtype(x.dtype if dtype is None else dtype)
+    lengths = jnp.full((R,), T, jnp.int32) if lengths is None \
+        else jnp.asarray(lengths, jnp.int32)
+    pad = -T % chunk
+    if pad:
+        x, dt, bm, cm = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),)
+                                 * (v.ndim - 2)) for v in (x, dt, bm, cm))
+    Tp = T + pad
+    dt = jnp.where(jnp.arange(Tp)[None, :, None] < lengths[:, None, None],
+                   dt.astype(jnp.float32), 0.0)
+    da = dt * a.astype(jnp.float32)
+    xdt = (x.astype(jnp.float32) * dt[..., None]).astype(cd)
+    bm, cm = bm.astype(cd), cm.astype(cd)
+    if route == "dense":
+        y, final = _dense_ssd_chunk_scan(xdt, da, bm, cm, chunk)
+        return y[:, :T], final
+    from jax.experimental.pallas import tpu as pltpu
+    hpg = H // G
+    cum = jnp.cumsum(da.reshape(R, Tp // chunk, chunk, G, hpg), axis=2)
+    acol = jnp.moveaxis(cum, 3, 1).reshape(R, G, Tp, hpg)
+    arow = jnp.swapaxes(acol, 2, 3)
+    wide = pl.BlockSpec((1, chunk, hpg * P), lambda r, g, c, n: (r, c, g))
+    bc = pl.BlockSpec((1, chunk, N), lambda r, g, c, n: (r, c, g))
+    y, final = pl.pallas_call(
+        functools.partial(_ssd_chunk_kernel, chunk=chunk, half=P),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(R, G, Tp // chunk),
+            in_specs=[wide, bc, bc,
+                      pl.BlockSpec((1, 1, chunk, hpg),
+                                   lambda r, g, c, n: (r, g, c, 0)),
+                      pl.BlockSpec((1, 1, hpg, chunk),
+                                   lambda r, g, c, n: (r, g, 0, c))],
+            out_specs=[wide,
+                       pl.BlockSpec((1, hpg // 2, N, 2 * P),
+                                    lambda r, g, c, n: (r, g, 0, 0))]),
+        out_shape=[jax.ShapeDtypeStruct((R, Tp, H * P), jnp.float32),
+                   jax.ShapeDtypeStruct((R, H // 2, N, 2 * P),
+                                        jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(interpret), name="ssd_chunk_scan",
+    )(lengths, xdt.reshape(R, Tp, H * P), bm.reshape(R, Tp, G * N),
+      cm.reshape(R, Tp, G * N), acol, arow)
+    return y.reshape(R, Tp, H, P)[:, :T], final
+
+
+# ---------------------------------------------------------------------------
 # Roofline cost models — Pallas custom calls report ZERO FLOPs/bytes to XLA's
 # cost analysis, so each kernel registers the analytic HBM bytes of one
 # dispatch with the obs cost ledger (obs/roofline.py register_kernel_cost).
@@ -2015,6 +2358,27 @@ def _lstm_sequence_fused_bytes(*, batch, seq_len, hidden, itemsize=4,
                               + hidden * hidden * gates)            # U
 
 
+def _ssm_state_update_bytes(*, updates, heads, head_dim, state, groups,
+                            state_itemsize=4):
+    """HBM bytes of ``updates`` single-position state updates (one a live
+    slot a Mamba layer a step — the count a program returns beside its
+    tokens): the packed state read once and written once, and the step's
+    own ``x, B, C, dt, y`` in float32."""
+    return float(updates) * (
+        2.0 * heads * head_dim * state * state_itemsize
+        + (2 * heads * head_dim + 2 * groups * state + heads) * 4.0)
+
+
+def _ssd_chunk_scan_bytes(*, tokens, heads, head_dim, state, groups,
+                          itemsize=2):
+    """HBM bytes of the chunked scan over ``tokens`` (position, Mamba
+    layer) pairs, padding included (the kernel streams it): ``x, B, C`` in
+    the operands' dtype, ``dt`` and ``y`` in float32, once each."""
+    return float(tokens) * (
+        (heads * head_dim + 2 * groups * state) * itemsize
+        + (heads + heads * head_dim) * 4.0)
+
+
 def _register_cost_models():
     from ..obs import roofline
     roofline.register_kernel_cost("decode_attention",
@@ -2025,6 +2389,9 @@ def _register_cost_models():
                                   _paged_prefill_attention_bytes)
     roofline.register_kernel_cost("paged_latent_attention",
                                   _paged_latent_attention_bytes)
+    roofline.register_kernel_cost("ssm_state_update",
+                                  _ssm_state_update_bytes)
+    roofline.register_kernel_cost("ssd_chunk_scan", _ssd_chunk_scan_bytes)
     roofline.register_kernel_cost("lstm_sequence_fused",
                                   _lstm_sequence_fused_bytes)
     roofline.register_kernel_cost(

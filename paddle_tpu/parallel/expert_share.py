@@ -127,17 +127,33 @@ class ExpertShare(nn.Module):
     """The routed experts this chip holds + the shared expert.
 
     ``experts_held``: the global ids of the experts whose weights live here
-    (``w_gate``/``w_up`` [held, d, f], ``w_down`` [held, f, d], in that
-    order). ``shared=False`` leaves the shared expert out (a share that is
-    summed with another's)."""
+    (``w_gate``/``w_up`` [held, d, f] — [held, f, d] where
+    ``up_transposed`` — and ``w_down`` [held, f, d], in that order).
+    ``shared=False`` leaves the shared expert out (a share that is summed
+    with another's).
+
+    An expert's FORM is stated, not assumed: ``gated=True`` is SwiGLU,
+    ``down(silu(gate(y)) * up(y))`` — three matrices, three grouped
+    products (DeepSeek-V3, LFM2); ``gated=False`` is ``down(relu(up(y))^2)``
+    — ``w_up`` and ``w_down`` only, two grouped products (Nemotron-H's
+    ``relu2``). The shared expert has the same form at ``shared_width``
+    (``n_shared * d_expert`` unless stated: Nemotron-H's is 3712 beside
+    experts of 1856)."""
 
     def __init__(self, d_model: int, d_expert: int, *, n_experts: int,
                  experts_held: Sequence[int], top_k: int, n_group: int,
                  topk_group: int, routed_scale: float,
                  norm_eps: float = 1e-20, n_shared: int = 1,
-                 shared: bool = True, dtype=jnp.float32,
+                 shared: bool = True, gated: bool = True,
+                 shared_width: Optional[int] = None,
+                 up_transposed: bool = False, dtype=jnp.float32,
                  init_std: float = 0.02):
         super().__init__()
+        self.gated = gated
+        # gate / up held [held, f, d] — as published, [out, in] — for an
+        # expert width that is no multiple of the lane width (1856), so
+        # that the multiple (d) is minor (pk.grouped_matmul says why)
+        self.up_transposed = up_transposed
         held = [int(e) for e in experts_held]
         if not held or len(set(held)) != len(held) or \
                 min(held) < 0 or max(held) >= n_experts:
@@ -154,14 +170,17 @@ class ExpertShare(nn.Module):
         init = normal(0.0, init_std)
         self.param("w_router", (d_model, n_experts), init, dtype=dtype)
         self.param("e_bias", (n_experts,), zeros, dtype=jnp.float32)
-        self.param("w_gate", (len(held), d_model, d_expert), init,
-                   dtype=dtype)
-        self.param("w_up", (len(held), d_model, d_expert), init, dtype=dtype)
+        up = (len(held), d_expert, d_model) if self.up_transposed \
+            else (len(held), d_model, d_expert)
+        if gated:
+            self.param("w_gate", up, init, dtype=dtype)
+        self.param("w_up", up, init, dtype=dtype)
         self.param("w_down", (len(held), d_expert, d_model), init,
                    dtype=dtype)
-        if shared and n_shared:
-            self.shared = nn.SwiGLU(d_model, n_shared * d_expert,
-                                    w_init=init, dtype=dtype)
+        width = shared_width or n_shared * d_expert
+        if shared and width:
+            self.shared = (nn.SwiGLU if gated else nn.ReluSquaredMLP)(
+                d_model, width, w_init=init, dtype=dtype)
         else:
             self.shared = None
 
@@ -174,8 +193,9 @@ class ExpertShare(nn.Module):
         return route(logits, params["e_bias"], **self.route_kw)
 
     def _held_part(self, params, y, tok, local, w, tm, route_):
-        """Σ over the pairs (tok, local expert, weight) of w * SwiGLU_e(y):
-        one pass of the three grouped products over a tile layout."""
+        """Σ over the pairs (tok, local expert, weight) of w * expert_e(y):
+        one pass of the grouped products (three of a gated expert, two of
+        a ``relu2`` one) over a tile layout."""
         T, d = y.shape
         A = tok.shape[0]
         n_held = len(self.held)
@@ -184,11 +204,17 @@ class ExpertShare(nn.Module):
         srcc = jnp.minimum(src, A - 1)
         rows = y[tok[srcc]]                                    # [M, d]
         kw = dict(tm=tm, route=route_)
-        g = pk.grouped_matmul(rows, params["w_gate"], tile_group, n_tiles,
-                              **kw)
-        u = pk.grouped_matmul(rows, params["w_up"], tile_group, n_tiles,
-                              **kw)
-        a = (jax.nn.silu(g) * u).astype(y.dtype)
+        up = dict(kw, transposed=self.up_transposed)
+        if self.gated:
+            g = pk.grouped_matmul(rows, params["w_gate"], tile_group,
+                                  n_tiles, **up)
+            u = pk.grouped_matmul(rows, params["w_up"], tile_group, n_tiles,
+                                  **up)
+            a = (jax.nn.silu(g) * u).astype(y.dtype)
+        else:
+            u = pk.grouped_matmul(rows, params["w_up"], tile_group, n_tiles,
+                                  **up)
+            a = jnp.square(jax.nn.relu(u)).astype(y.dtype)
         out = pk.grouped_matmul(a, params["w_down"], tile_group, n_tiles,
                                 **kw)
         # rows past the tiles in use are undefined on the kernel route
@@ -203,7 +229,7 @@ class ExpertShare(nn.Module):
         ``live`` [T] bool leaves dead rows (drained slots, prompt padding)
         out of the experts' work and of the counts."""
         T, d = y.shape
-        dt = params["w_gate"].dtype
+        dt = params["w_down"].dtype
         experts, weights = self.routing(params, y)
         k = experts.shape[1]
         local = jnp.asarray(self._local)[experts]              # [T, k]

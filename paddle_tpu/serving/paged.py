@@ -112,6 +112,22 @@ class PagePool:
     programs it ran before there were any. Slot rows are not shared by
     prefix (a model with them has no ``prefill_paged``).
 
+    A model whose slot rows are LARGE says ``slot_rows_in_place``
+    (NemotronHLM: a recurrence's carry, 49 MB a slot, 1.57 GB at 32
+    slots) and the pool never holds a second copy of them: the admit
+    program hands ``prefill`` the donated arrays themselves
+    (``slot_state=``) and takes them back written at the admitted slots'
+    indices — no full-width ``[slots, ...]`` array of fresh rows, no
+    ``where`` over all of it; the segment program carries them through
+    the scan, where the model updates them in place, and puts the dead
+    slots' rows back to their fill by a scatter at those slots alone.
+    Every other model keeps the programs it had — why there are two
+    paths: the ``where`` blend is what ``lfm2-serve-rag`` is measured on
+    (82 KB of tails a slot, where a second copy costs nothing), and its
+    programs change only with a parent / change pair of that cell on the
+    chip; Lfm2MoeLM's tails would run on the scatter path too, and the
+    blend can go once such a pair shows nothing lost (PERF.md section 7).
+
     The geometry defaults (``page_block`` 64, ``cache_bucket`` 256,
     ``prompt_buckets`` 32..512) are the values the GPT-2 and GigaChat serve
     cells of the chip benchmark run and warm up; ``lfm2-serve-rag`` passes
@@ -169,6 +185,8 @@ class PagePool:
         # ... and its per-SLOT rows (SlotRow; the class docstring says
         # what becomes of them): [slots, *shape], none for most models
         self._slot_rows = [r for r in stated if isinstance(r, SlotRow)]
+        self._in_place = bool(self._slot_rows) and getattr(
+            model, "slot_rows_in_place", False)
         self.slot_state = {
             r.name: jnp.full((slots,) + tuple(r.shape), r.fill, r.dtype)
             for r in self._slot_rows}
@@ -495,28 +513,33 @@ class PagePool:
             # a compile inside a serving window names its shape bucket
             obs.instant("serving.program_build", kind="admit", tpad=tpad)
             model, kv_dtype, bs = self.model, self.kv_dtype, self.bs
-            tpp = nbp * bs
+            tpp, in_place = nbp * bs, self._in_place
 
             def admit(params, state, prompts, lens, pages):
                 # pad_to=tpp: the transient cell holds prompt-bucket rows,
                 # not a max_len-padded (pinned-pool-sized) cache — the
                 # admission HBM spike stays proportional to the prompts
                 pools, slot_state = state
-                cell, last = model.prefill(params, prompts, lens,
-                                           kv_dtype=kv_dtype,
-                                           pad_to=tpp)
+                cell, last = model.prefill(
+                    params, prompts, lens, kv_dtype=kv_dtype, pad_to=tpp,
+                    **(dict(slot_state=slot_state) if in_place else {}))
                 first = jnp.argmax(last, axis=-1).astype(prompts.dtype)
                 out = {}
                 for nm, pool in pools.items():
                     rows = cell[nm][:, :tpp].reshape(
                         (prompts.shape[0], nbp, bs) + cell[nm].shape[2:])
                     out[nm] = pool.at[pages].set(rows.astype(pool.dtype))
-                # per-slot rows: only the slots this admission fills
-                took = lens > 0
-                slot_out = {
-                    nm: jnp.where(took.reshape((-1,) + (1,) * (v.ndim - 1)),
-                                  cell[nm].astype(v.dtype), v)
-                    for nm, v in slot_state.items()}
+                # per-slot rows: only the slots this admission fills —
+                # in place, the model wrote them at those indices itself
+                if in_place:
+                    slot_out = {nm: cell[nm] for nm in slot_state}
+                else:
+                    took = lens > 0
+                    slot_out = {
+                        nm: jnp.where(
+                            took.reshape((-1,) + (1,) * (v.ndim - 1)),
+                            cell[nm].astype(v.dtype), v)
+                        for nm, v in slot_state.items()}
                 return (out, slot_out), first, cell.get("stats", {})
             # cost-instrumented (PR 9 ledger): under an obs session the
             # dispatch feeds fluid.device_flops_total and admit() reads
@@ -564,6 +587,7 @@ class PagePool:
             obs.instant("serving.program_build", kind="segment", nb=nb)
             model, segment = self.model, self.segment
             fills = {r.name: r.fill for r in self._slot_rows}
+            in_place = self._in_place
 
             def seg(params, state, tables, pos, cur, live):
                 pools, slot_state = state
@@ -582,11 +606,23 @@ class PagePool:
                 # a slot that is not live keeps no per-slot state: a freed
                 # slot's rows are back at their fill after the next segment
                 # (no program of its own for that; none rolls idle either)
-                state_out = ({k: cell[k] for k in pools},
-                             {k: jnp.where(
-                                 live.reshape((-1,) + (1,) * (v.ndim - 1)),
-                                 cell[k], jnp.asarray(fills[k], v.dtype))
-                              for k, v in slot_state.items()})
+                if in_place:
+                    # ... by a scatter at the dead slots alone (a live
+                    # slot's index is out of range, and dropped): no pass
+                    # over the live slots' rows, no second array
+                    dead = jnp.where(live, live.shape[0],
+                                     jnp.arange(live.shape[0]))
+                    slot_out = {
+                        k: cell[k].at[dead].set(
+                            jnp.asarray(fills[k], v.dtype), mode="drop")
+                        for k, v in slot_state.items()}
+                else:
+                    slot_out = {
+                        k: jnp.where(
+                            live.reshape((-1,) + (1,) * (v.ndim - 1)),
+                            cell[k], jnp.asarray(fills[k], v.dtype))
+                        for k, v in slot_state.items()}
+                state_out = ({k: cell[k] for k in pools}, slot_out)
                 return (state_out, cur, jnp.moveaxis(toks, 0, 1),
                         cell.get("stats", {}))
             fn = obs.roofline.instrument(

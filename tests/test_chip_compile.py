@@ -272,6 +272,108 @@ def test_grouped_matmul_blocks_by_shape(tm, K, N, blocks):
     assert pk.grouped_matmul_blocks(tm, K, N, 2) == blocks
 
 
+# -- NemotronHLM's kernels at the nemotron3-ep8-serve-chatburst cell's shapes
+
+def test_ssm_state_update_compiles_in_place(S):
+    """The decode step's state update at the cell's real shapes: 32 slots x
+    64 heads of 64 x 128 in float32, packed [32, 128, 128] a slot (2 MB in
+    and 2 MB out a grid step, its own VMEM limit), the live slots a
+    scalar-prefetched grid. The state is donated and the kernel aliases
+    it: the compiled program holds NO second copy of the 64 MB (alias
+    bytes = the state's; temporaries a rounding error beside it)."""
+    H, P, G, N, B = 64, 64, 8, 128, 32
+    compiled = jax.jit(
+        lambda s, x, dt, a, b, c, live: pk.ssm_state_update(
+            s, x, dt, a, b, c, live, route="kernel", interpret=False),
+        donate_argnums=(0,)).lower(
+        S((B, H // 2, N, 2 * P)), S((B, H, P)), S((B, H)), S((H,)),
+        S((B, G, N)), S((B, G, N)), S((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    assert "ssm_state_update" in call.split(" = ")[0]
+    mem = compiled.memory_analysis()
+    state = B * H * P * N * 4
+    assert mem.alias_size_in_bytes == state
+    assert mem.temp_size_in_bytes < state // 16
+
+
+@pytest.mark.parametrize("rows, T", [(8, 256), (1, 2048)])
+def test_ssd_chunk_scan_compiles(S, rows, T):
+    """The admission's chunked scan at the cell's real shapes: a prefill
+    chunk of 8 rows x 256 or 1 x 2,048 positions, 64 heads of 64 over 8
+    groups of state 128, chunks of 128, bf16 operands: matrix products
+    with a transposed left side, column and row forms of the running
+    decay, the group's packed state resident across a row's chunks."""
+    H, P, G, N = 64, 64, 8, 128
+    text = _compile(lambda x, dt, a, b, c, n: pk.ssd_chunk_scan(
+        x, dt, a, b, c, n, dtype=jnp.bfloat16, route="kernel",
+        interpret=False),
+        S((rows, T, H, P)), S((rows, T, H)), S((H,)), S((rows, T, G, N)),
+        S((rows, T, G, N)), S((rows,), jnp.int32))
+    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    assert "ssd_chunk_scan" in call.split(" = ")[0]
+    assert f"f32[{rows},32,128,128]" in call       # the packed final state
+
+
+@pytest.mark.parametrize("rows, tm, K, N, transposed", [
+    (448, 16, 2688, 1856, True), (448, 16, 1856, 2688, False),
+    (12288, 256, 2688, 1856, True), (12288, 256, 1856, 2688, False)],
+    ids=["decode-up", "decode-down", "chunk-up", "chunk-down"])
+def test_relu2_expert_grouped_matmul_compiles(S, rows, tm, K, N, transposed):
+    """The 16 held relu2 experts' two grouped products at the cell's real
+    shapes, bf16: ``up`` held [16, 1856, 2688] (as published, the multiple
+    of 128 minor) and contracted over both last dimensions, ``down``
+    [16, 1856, 2688]; a decode step's 192 pairs in tiles of 16 (K-split:
+    7 blocks of 384 x 1856; whole-K strips of 896), an admission chunk's
+    8,192 in tiles of 256 (resident). No operand is re-laid out: the
+    weights reach the kernel in the layout the device stores them in."""
+    bf = jnp.bfloat16
+    w = (16, N, K) if transposed else (16, K, N)
+    text = _compile(lambda lhs, rhs, group, n: pk.grouped_matmul(
+        lhs, rhs, group, n, tm=tm, transposed=transposed, route="kernel",
+        interpret=False),
+        S((rows, K), bf), S(w, bf), S((rows // tm,), jnp.int32),
+        S((1,), jnp.int32))
+    assert "expert_grouped_matmul" in text
+    copies = [ln for ln in text.splitlines() if " copy(" in ln
+              and f"bf16[{w[0]},{w[1]},{w[2]}]" in ln.split(" copy(")[0]]
+    assert not copies
+
+
+def test_group_16_head_128_paged_decode_attention_compiles(S):
+    """The cell's paged read: 32 slots x 32 query heads of 128 over bf16
+    pools of 2 KV heads (a group of 16), the whole table of 44 over a
+    1409-page pool, pools as they are, [pages, 64, 2, 128]."""
+    text = _compile(lambda q, k, v, t, pos: pk.paged_decode_attention(
+        q, k, v, t, pos, scale=128 ** -0.5, route="kernel",
+        interpret=False),
+        S((32, 32, 128), jnp.float32), S((1409, 64, 2, 128), jnp.bfloat16),
+        S((1409, 64, 2, 128), jnp.bfloat16), S((32, 44), jnp.int32),
+        S((32,), jnp.int32))
+    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    assert "paged_decode_attention" in call.split(" = ")[0]
+    assert "bf16[1409,64,2,128]" in call and "f32[32,16,2,128]" in call
+
+
+@pytest.mark.parametrize("rows, T", [(8, 256), (1, 2048)])
+def test_group_16_head_128_flash_forward_compiles(S, rows, T):
+    """The cell's prefill chunks: 32 query heads of 128 over 2 KV heads
+    through the forward kernel's index map, at the shortest and the
+    longest prompt bucket."""
+    bf = jnp.bfloat16
+    text = _compile(lambda q, k, v: pk.flash_attention(
+        q, k, v, causal=True, scale=128 ** -0.5, interpret=False),
+        S((rows, T, 32, 128), bf), S((rows, T, 2, 128), bf),
+        S((rows, T, 2, 128), bf))
+    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    assert "flash_attention_fwd" in call.split(" = ")[0]
+    assert f"bf16[{rows * 2},{T},128]" in call
+
+
 def test_flash_attention_compiles_at_latent_head_width(S):
     """Prefill of the latent-attention model expands k and v and runs the
     flash kernel at head width 192 (128 + 64 rotary; v is 192 as well): 1.5
