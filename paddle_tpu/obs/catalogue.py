@@ -179,6 +179,20 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                                                    "TRACE of a call, labels: "
                                                    "kernel, state",
                                         ("kernel", "state")),
+    "kernels.paged_decode_plan_total": ("counter", "kernel-route calls of "
+                                                   "paged_decode_attention "
+                                                   "by the body their "
+                                                   "programs run: "
+                                                   "plan=group_mxu (fewer KV "
+                                                   "than query heads: a page "
+                                                   "times all its query heads "
+                                                   "on the MXU) or head_vpu "
+                                                   "(a KV head a query head), "
+                                                   "group = query heads a KV "
+                                                   "head; counted once per "
+                                                   "TRACE of a call, labels: "
+                                                   "plan, group",
+                                        ("plan", "group")),
     "kernels.routes_total": ("counter", "auto-route decisions at the "
                                         "kernel entry points; counted when "
                                         "the routing Python runs — once "

@@ -745,6 +745,10 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # no free lhs dimension is refused by Mosaic: "failed to parse
 # TPU_DotDimensionNumbersAttr parameter 'lhs_non_contracting_dims'".)
 # tests/test_chip_compile.py compiles every variant for a described v5e.
+#
+# That holds for a group of ONE (a KV head a query head). Grouped-query pools
+# (fewer KV heads than query heads) take a body of their own, on the MXU:
+# _grouped_decode_attn_kernel, below.
 # ---------------------------------------------------------------------------
 
 #: rows of cache one decode-attention program holds in VMEM: a [256, 12, 64]
@@ -766,10 +770,10 @@ def _decode_chunk(L: int) -> int:
 
 
 def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
-                        work_list: bool, groups: int = 1):
-    """One program of the decode read, dense-row or paged, float or int8 —
-    one body so every variant shares the softmax. A program reads chunk
-    ``c`` (rows c*chunk..) of sample ``b``'s cache.
+                        work_list: bool):
+    """One program of the decode read at a KV head a query head, dense-row
+    or paged, float or int8 — one body so every variant shares the softmax.
+    A program reads chunk ``c`` (rows c*chunk..) of sample ``b``'s cache.
 
     Dense rows (grid (B, chunks)): scalar-prefetched pos [B]; b and c are
     the program ids. Paged (grid (n_work,), ``work_list``): scalar-
@@ -781,13 +785,7 @@ def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
     appended before the read). Blocks: q [1, H, D]; k/v [1, chunk, H, D]
     (+ ks/vs [1, chunk, H] f32 when int8); o [1, H, D] f32, written by
     sample b's last chunk. Scratch m/l [H, 1], acc [H, D] f32 carry the
-    running softmax across one sample's chunks, which run back to back.
-
-    ``groups`` > 1 (grouped-query attention): H above is the KV heads';
-    q and o are [1, groups, H, D] (member g of every KV head's group of
-    query heads) and the scratch carries a leading ``groups`` axis. The
-    chunk's K and V are loaded ONCE and folded into each member's softmax
-    in turn, so a KV head's bytes serve its whole group."""
+    running softmax across one sample's chunks, which run back to back."""
     if quantized:
         (pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
          m_ref, l_ref, acc_ref) = refs[-10:]
@@ -817,19 +815,103 @@ def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
             k = k * ks_ref[0][..., None]
             v = v * vs_ref[0][..., None]
         j = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
-        for g in range(groups):
-            # member g of a group's refs; the whole ref without groups
-            g = (g,) if groups > 1 else (...,)
-            q = q_ref[(0,) + g].astype(jnp.float32) * scale  # [H, D]
-            s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # [chunk, H, 1]
-            s = jnp.where(j <= pos, s, _NEG)
-            m_prev = m_ref[g]                               # [H, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-            p = jnp.exp(s - m_new[None])
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[g] = l_ref[g] * corr + jnp.sum(p, axis=0)
-            acc_ref[g] = acc_ref[g] * corr + jnp.sum(p * v, axis=0)
-            m_ref[g] = m_new
+        q = q_ref[0].astype(jnp.float32) * scale            # [H, D]
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True)    # [chunk, H, 1]
+        s = jnp.where(j <= pos, s, _NEG)
+        m_prev = m_ref[...]                                 # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=0)
+        acc_ref[...] = acc_ref[...] * corr + jnp.sum(p * v, axis=0)
+        m_ref[...] = m_new
+
+    @pl.when(last)
+    def _finish():
+        o_ref[0] = acc_ref[...] / l_ref[...]
+
+
+def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
+                                q_ref, *refs, scale: float, chunk: int,
+                                quantized: bool, groups: int):
+    """One program of the PAGED read over grouped-query heads: H query
+    heads over Hkv = H // ``groups`` KV heads, query head h reading KV head
+    ``h // groups``. A sibling of :func:`_decode_attn_kernel` with the
+    same programs round the body (the work list, one page a program, the
+    running softmax in scratch, the finish on a slot's last page) and both
+    products on the MXU, where that body would do a query head's arithmetic
+    at a time on the VPU for one KV head's bytes.
+
+    Blocks: q / o [1, H, D] in the caller's head order; k/v [1, chunk, Hkv,
+    D] (+ ks/vs [1, chunk, Hkv] f32 when int8); scratch m/l [H, 1], acc
+    [H, D]. The page collapses to a [chunk * Hkv, D] matrix, row r holding
+    position r // Hkv of KV head r % Hkv — the block's own bytes in the
+    block's own order — and ONE product of all H queries against it gives
+    scores [H, chunk * Hkv]: the columns of the other groups' KV heads are
+    masked like rows past ``pos``, so their weights are exactly 0 and one
+    more product against V's matrix accumulates every head's values. The
+    MXU does Hkv times the flops the groups need and has them idle.
+
+    Precision: bf16 pools go to the MXU as they are, and the f32 operands
+    beside them (q, the softmax weights) as the sum of two bf16 halves,
+    hi + lo, so a product keeps ~16 bits of them; f32 and int8 pools
+    (dequantized here) multiply in f32. Accumulation and softmax are f32."""
+    if quantized:
+        k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    i = pl.program_id(0)
+    b, c, last = slot_ref[i], ord_ref[i], last_ref[i] == 1
+    H, D = q_ref.shape[1:]
+    Hkv = H // groups
+    R = chunk * Hkv
+
+    @pl.when(c == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    pos = pos_ref[b]
+
+    @pl.when(c * chunk <= pos)           # a dead page adds exactly nothing
+    def _live():
+        k, v = k_ref[0], v_ref[0]                           # [chunk, Hkv, D]
+        if quantized:
+            k = k.astype(jnp.float32) * ks_ref[0][..., None]
+            v = v.astype(jnp.float32) * vs_ref[0][..., None]
+        elif k.dtype != jnp.bfloat16 or Hkv % 2:
+            # (bf16 rows are packed in pairs: an odd head count does not
+            # collapse, Mosaic's "unsupported shape cast", so it goes f32)
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        k, v = k.reshape(R, D), v.reshape(R, D)
+
+        def mxu(x, w, dims):
+            """x [H, .] (f32) against a page matrix, f32 accumulation; the
+            bf16 halves go as ONE [2 H, .] operand, so the matrix is pushed
+            to the MXU once."""
+            dot = functools.partial(jax.lax.dot_general, dimension_numbers=(
+                dims, ((), ())), preferred_element_type=jnp.float32)
+            if w.dtype != jnp.bfloat16:
+                return dot(x, w)
+            hi = x.astype(jnp.bfloat16)
+            lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+            y = dot(jnp.concatenate([hi, lo], axis=0), w)
+            return y[:H] + y[H:]
+
+        q = q_ref[0].astype(jnp.float32) * scale            # [H, D]
+        s = mxu(q, k, ((1,), (1,)))                         # [H, R]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // groups
+        s = jnp.where((c * chunk + col // Hkv <= pos) & (col % Hkv == head),
+                      s, _NEG)
+        m_prev = m_ref[...]                                 # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)          # row 0 of a live page is every
+        corr = jnp.exp(m_prev - m_new)  # head's: m_new is a real score
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + mxu(p, v, ((1,), (0,)))
+        m_ref[...] = m_new
 
     @pl.when(last)
     def _finish():
@@ -837,15 +919,16 @@ def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
 
 
 def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, qo_spec, kv_spec,
-                      sc_spec, *, grid, scale, chunk, interpret, name):
+                      sc_spec, *, grid, scale, chunk, interpret, name,
+                      groups=1):
     """The one pallas_call behind decode_attention and
     paged_decode_attention: ``prefetch`` scalars (pos last; five of them
-    = the paged work list), then q, then k/v — each followed by its scale
-    operand when the cache is int8. q [B, H, D], or [B, groups, H, D] with
-    H the KV heads' (grouped-query attention); the result has q's shape.
+    = the paged work list), then q [B, H, D], then k/v — each followed by
+    its scale operand when the cache is int8. ``groups`` > 1 (the paged
+    read alone): k/v hold H // groups heads and the grouped body runs.
     ``name`` is the caller's: what a device trace shows the kernel as."""
     from jax.experimental.pallas import tpu as pltpu
-    *lead, H, D = q.shape[1:]
+    H, D = q.shape[1:]
     if k_scale is not None:
         kv_args, kv_specs = ((k, k_scale, v, v_scale),
                              [kv_spec, sc_spec, kv_spec, sc_spec])
@@ -854,13 +937,17 @@ def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, qo_spec, kv_spec,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=grid,
         in_specs=[qo_spec] + kv_specs, out_specs=qo_spec,
-        scratch_shapes=[pltpu.VMEM((*lead, H, 1), jnp.float32),
-                        pltpu.VMEM((*lead, H, 1), jnp.float32),
-                        pltpu.VMEM((*lead, H, D), jnp.float32)])
-    kernel = functools.partial(_decode_attn_kernel, scale=scale, chunk=chunk,
-                               quantized=k_scale is not None,
-                               work_list=len(grid) == 1,
-                               groups=lead[0] if lead else 1)
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, D), jnp.float32)])
+    if groups > 1:
+        kernel = functools.partial(_grouped_decode_attn_kernel, scale=scale,
+                                   chunk=chunk, quantized=k_scale is not None,
+                                   groups=groups)
+    else:
+        kernel = functools.partial(_decode_attn_kernel, scale=scale,
+                                   chunk=chunk, quantized=k_scale is not None,
+                                   work_list=len(grid) == 1)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
@@ -1016,8 +1103,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     q: [B, H, D]; k_pool/v_pool: [P, bs, Hkv, D] page pools (bf16/f32, or
     int8 with k_scale/v_scale [P, bs, Hkv] f32 pools), ``Hkv`` = H or a
     divisor of it (grouped-query attention: query head h reads KV head
-    ``h // (H // Hkv)``, and the kernel streams a page ONCE for the whole
-    group); tables: [B, NB] int32
+    ``h // (H // Hkv)``; the kernel streams a page ONCE for all its groups
+    and multiplies it by every query head at once on the MXU,
+    :func:`_grouped_decode_attn_kernel`, where Hkv = H keeps the VPU body
+    of :func:`decode_attention`); tables: [B, NB] int32
     page indices covering positions 0..NB*bs-1 (entries past a request's
     live pages point at the reserved null page — rows there sit past
     ``pos`` and are masked exactly like dense padding); pos: [B] int32,
@@ -1029,7 +1118,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     reads (one program per live page, driven by the scalar-prefetched work
     list), the dense gather + reference math for short reads / off-TPU.
     Both routes share one masked-softmax formulation over the SAME
-    assembled row order, so route choice never changes greedy tokens."""
+    assembled row order, so route choice never changes greedy tokens.
+    ``kernels.paged_decode_plan_total{plan, group}`` says, once a trace of
+    a kernel-route call, which body its programs run."""
     B, NB = tables.shape
     P, bs, H, D = k_pool.shape
     Hq = q.shape[1]
@@ -1054,23 +1145,18 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         work = paged_work_list(tables, pos, bs)
     *work, n_work = work
     G = Hq // H
-    if G == 1:
-        qo_spec = pl.BlockSpec((1, H, D), lambda i, slot, *_: (slot[i], 0, 0))
-    else:
-        # member-major: [B, G, Hkv, D], so a member's heads are one tile
-        q = jnp.swapaxes(q.reshape(B, H, G, D), 1, 2)
-        qo_spec = pl.BlockSpec((1, G, H, D),
-                               lambda i, slot, *_: (slot[i], 0, 0, 0))
+    obs.count("kernels.paged_decode_plan_total",
+              plan="group_mxu" if G > 1 else "head_vpu", group=str(G))
+    qo_spec = pl.BlockSpec((1, Hq, D), lambda i, slot, *_: (slot[i], 0, 0))
     page_spec = pl.BlockSpec((1, bs, H, D),
                              lambda i, slot, page, *_: (page[i], 0, 0, 0))
     sc_spec = pl.BlockSpec((1, bs, H),
                            lambda i, slot, page, *_: (page[i], 0, 0))
-    o = _decode_attn_call(
+    return _decode_attn_call(
         (*work, pos.astype(jnp.int32)), q, k_pool, v_pool, k_scale, v_scale,
         qo_spec, page_spec, sc_spec, grid=(n_work[0],), scale=scale_v,
         chunk=bs, interpret=_interpret(interpret),
-        name="paged_decode_attention")
-    return o if G == 1 else jnp.swapaxes(o, 1, 2).reshape(B, Hq, D)
+        name="paged_decode_attention", groups=G)
 
 
 # ---------------------------------------------------------------------------

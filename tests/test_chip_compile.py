@@ -168,22 +168,42 @@ def test_paged_decode_attention_compiles(S, slots, heads, NB, pages, kv):
             q, pool, pool, tables, pos)
 
 
-def test_grouped_query_paged_decode_attention_compiles(S):
-    """The lfm2-serve-rag cell's read: 32 slots x 32 query heads over bf16
-    pools of 8 KV heads (an (8, 64) tile: half a bf16 sublane tile, which
-    no other pool showed the compiler), the whole table of 72 over a
-    2305-page pool. The kernel keeps its own name in the program — the
-    benchmark's reader (chipbench/metrics/gqa_decode_roofline.py) finds it
-    by that — and takes the pools as they are, [pages, 64, 8, 64]."""
+def _grouped_paged_read(S, pool, q, NB):
+    """One grouped-query paged read compiled for the described chip: the
+    kernel keeps its own name in the program (the benchmark's readers,
+    chipbench/metrics/gqa_decode_roofline.py and gqa_head_dim_decode_
+    roofline.py, find it by that), takes the pools as they are — no copy
+    of one in the program — and q in the caller's head order: the grouped
+    body (one MXU product of a page by all its query heads) transposes
+    nothing outside the kernel."""
     text = _compile(lambda q, k, v, t, pos: pk.paged_decode_attention(
         q, k, v, t, pos, route="kernel", interpret=False),
-        S((32, 32, D), jnp.float32), S((2305, 64, 8, D), jnp.bfloat16),
-        S((2305, 64, 8, D), jnp.bfloat16), S((32, 72), jnp.int32),
-        S((32,), jnp.int32))
+        S(q, jnp.float32), pool, pool, S((q[0], NB), jnp.int32),
+        S((q[0],), jnp.int32))
     call = next(ln for ln in text.splitlines() if " custom-call(" in ln
                 and "tpu_custom_call" in ln)
     assert "paged_decode_attention" in call.split(" = ")[0]
-    assert "bf16[2305,64,8,64]" in call and "f32[32,4,8,64]" in call
+    dims = ",".join(map(str, pool.shape))
+    assert f"bf16[{dims}]" in call
+    assert "f32[%d,%d,%d]" % q in call.split(" custom-call(")[0]
+    assert not [ln for ln in text.splitlines() if " copy(" in ln
+                and f"bf16[{dims}]" in ln.split(" copy(")[0]]
+
+
+def test_grouped_query_paged_decode_attention_compiles(S, one_chip):
+    """The lfm2-serve-rag cell's read: 32 slots x 32 query heads over bf16
+    pools of 8 KV heads (an (8, 64) tile: half a bf16 sublane tile, which
+    no other pool showed the compiler), the whole table of 72 over a
+    2305-page pool [pages, 64, 8, 64]. The pools are given in the row-
+    major order a program that carries them holds them in (left to itself
+    the compiler lays a lone ENTRY parameter of 64-wide rows out pages-
+    minor and copies it): against such a pool the kernel asks for no
+    copy."""
+    from jax.experimental.layout import Format, Layout
+    pool = jax.ShapeDtypeStruct(
+        (2305, 64, 8, D), jnp.bfloat16,
+        sharding=Format(Layout(major_to_minor=(0, 1, 2, 3)), one_chip))
+    _grouped_paged_read(S, pool, (32, 32, D), 72)
 
 
 @pytest.mark.parametrize("rows, T", [(4, 512), (1, 4096)])
@@ -345,17 +365,10 @@ def test_relu2_expert_grouped_matmul_compiles(S, rows, tm, K, N, transposed):
 def test_group_16_head_128_paged_decode_attention_compiles(S):
     """The cell's paged read: 32 slots x 32 query heads of 128 over bf16
     pools of 2 KV heads (a group of 16), the whole table of 44 over a
-    1409-page pool, pools as they are, [pages, 64, 2, 128]."""
-    text = _compile(lambda q, k, v, t, pos: pk.paged_decode_attention(
-        q, k, v, t, pos, scale=128 ** -0.5, route="kernel",
-        interpret=False),
-        S((32, 32, 128), jnp.float32), S((1409, 64, 2, 128), jnp.bfloat16),
-        S((1409, 64, 2, 128), jnp.bfloat16), S((32, 44), jnp.int32),
-        S((32,), jnp.int32))
-    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
-                and "tpu_custom_call" in ln)
-    assert "paged_decode_attention" in call.split(" = ")[0]
-    assert "bf16[1409,64,2,128]" in call and "f32[32,16,2,128]" in call
+    1409-page pool, pools as they are, [pages, 64, 2, 128] (two bf16 rows
+    a packed sublane: the page collapses to a [128, 128] matrix in place)."""
+    _grouped_paged_read(S, S((1409, 64, 2, 128), jnp.bfloat16),
+                        (32, 32, 128), 44)
 
 
 @pytest.mark.parametrize("rows, T", [(8, 256), (1, 2048)])

@@ -239,9 +239,79 @@ def test_grouped_paged_decode_kernel_equals_dense_route():
     rep = pk.paged_decode_attention(q, jnp.repeat(kp, 4, 2),
                                     jnp.repeat(vp, 4, 2), tables, pos,
                                     route="kernel", interpret=True)
-    np.testing.assert_array_equal(np.asarray(kern), np.asarray(rep))
+    # (the group's body multiplies on the MXU, a head of its own on the
+    # VPU: the same numbers in another order of summation)
+    np.testing.assert_allclose(np.asarray(kern), np.asarray(rep), atol=1e-6)
     with pytest.raises(ValueError, match="whole groups"):
         pk.paged_decode_attention(q[:, :7], kp, vp, tables, pos)
+
+
+@pytest.mark.parametrize("given_work", [False, True], ids=["own-list", "work"])
+@pytest.mark.parametrize("kv", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("Hq, Hkv, D", [(8, 2, 16), (32, 8, 64),
+                                        (32, 2, 128)])
+def test_grouped_paged_decode_body_equals_dense_route(Hq, Hkv, D, kv,
+                                                      given_work):
+    """The grouped body (one product of all query heads against the page's
+    [rows * Hkv, D] matrix, the other groups' columns masked) against the
+    dense route, at a toy group, rag's and chatburst's: ragged lengths, an
+    idle slot on the null page, a slot whose last live row ends a page and
+    one whose last row starts one; float pools (bf16 goes to the product as
+    it is, q and the weights as hi + lo halves) and int8 with scales; the
+    work list built here or handed in, as a decode step hands it."""
+    rs = np.random.RandomState(Hq + Hkv + D)
+    B, NB, bs = 4, 4, 8
+    P = 1 + B * NB
+    G = Hq // Hkv
+    kp = jnp.asarray(rs.randn(P, bs, Hkv, D), jnp.float32)
+    vp = jnp.asarray(rs.randn(P, bs, Hkv, D), jnp.float32)
+    tables = jnp.asarray(1 + np.arange(B * NB).reshape(B, NB), jnp.int32)
+    tables = tables.at[1].set(0)                  # idle: the null page
+    pos = jnp.asarray([13, 0, 15, 24], jnp.int32)
+    q = jnp.asarray(rs.randn(B, Hq, D), jnp.float32)
+    kw = {}
+    if kv == "int8":
+        kp, kw["k_scale"] = pk.quantize_kv(kp)
+        vp, kw["v_scale"] = pk.quantize_kv(vp)
+    elif kv == "bf16":
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    dense = pk.paged_decode_attention(q, kp, vp, tables, pos, route="dense",
+                                      **kw)
+    work = pk.paged_work_list(tables, pos, bs) if given_work else None
+    kern = pk.paged_decode_attention(q, kp, vp, tables, pos, route="kernel",
+                                     interpret=True, work=work, **kw)
+    assert kern.shape == (B, Hq, D) and kern.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(kern), np.asarray(dense),
+                               atol=2e-5, rtol=2e-5)
+    # a KV head serves ITS group: against every head's own copy of it,
+    # which runs the body of a group of one
+    rep = {n: jnp.repeat(a, G, 2) for n, a in kw.items()}
+    own = pk.paged_decode_attention(q, jnp.repeat(kp, G, 2),
+                                    jnp.repeat(vp, G, 2), tables, pos,
+                                    route="kernel", interpret=True, **rep)
+    np.testing.assert_allclose(np.asarray(kern), np.asarray(own),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("Hq, Hkv, plan", [(4, 4, "head_vpu"),
+                                           (8, 2, "group_mxu"),
+                                           (32, 2, "group_mxu")])
+def test_paged_decode_plan_follows_the_group(Hq, Hkv, plan):
+    """kernels.paged_decode_plan_total: counted once a TRACE of a kernel-
+    route call, the body its programs run and the group's size; the shapes
+    alone choose (a group of one keeps the VPU body), and the dense route
+    counts nothing."""
+    from paddle_tpu import obs
+    q, kp, vp, tables, pos = _paged_case(Hq, Hkv)
+    r = obs.MetricsRegistry()
+    with obs.ObsSession(registry=r).installed():
+        pk.paged_decode_attention(q, kp, vp, tables, pos, route="dense")
+        pk.paged_decode_attention(q, kp, vp, tables, pos, route="kernel",
+                                  interpret=True)
+    c = r.counter("kernels.paged_decode_plan_total")
+    other = {"head_vpu": "group_mxu", "group_mxu": "head_vpu"}[plan]
+    assert c.get(plan=plan, group=str(Hq // Hkv)) == 1
+    assert c.get(plan=other, group=str(Hq // Hkv)) == 0
 
 
 def test_paged_decode_at_equal_heads_is_the_program_it_was(monkeypatch):
@@ -266,7 +336,7 @@ def test_paged_decode_at_equal_heads_is_the_program_it_was(monkeypatch):
     q, kp, vp, tables, pos = _paged_case(8, 2)
     pk.paged_decode_attention(q, kp, vp, tables, pos, route="kernel",
                               interpret=True)
-    assert seen["q"] == (3, 4, 2, 16) and seen["qo"] == (1, 4, 2, 16)
+    assert seen["q"] == (3, 8, 16) and seen["qo"] == (1, 8, 16)
     assert seen["kv"] == (1, 8, 2, 16)
 
 
