@@ -1330,6 +1330,11 @@ def cmd_serve(args):
     registered cost model and the shape facts it takes (``kv_heads`` where
     a cache row holds fewer heads than the queries have) — and optionally
     ``paged_read_layers`` (layers of a step that make that read; default all);
+    ``prefill_positions(n_rows, width, n_live)`` — the positions ``prefill``
+    runs through the depth for ``[n_rows, width]`` prompts of which
+    ``n_live`` hold one, from the walk it runs (``models.transformer
+    .LiveRowPrefill`` for a model on ``prefill_live_rows``): the admission's
+    account on the ``serving.prefill`` span;
     and, only for the prefix cache, ``prefill_paged`` (a model without it
     needs ``--no_prefix_cache``; with it on, ``serve`` refuses at start-up;
     slot rows are not shared by prefix). Optional: ``slot_rows_in_place =

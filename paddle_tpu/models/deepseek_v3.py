@@ -43,8 +43,8 @@ from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
 from ..parallel.expert_share import (ExpertShare, ProgramStats,
                                      ffn_or_experts)
-from .transformer import (PREFILL_TOKENS, CacheRow, paged_greedy,
-                          prefill_live_rows)
+from .transformer import (PREFILL_TOKENS, CacheRow, LiveRowPrefill,
+                          paged_greedy, prefill_live_rows)
 
 
 def _dot(x, w):
@@ -151,7 +151,7 @@ class DeepseekV3Block(nn.Module):
         return ffn_or_experts(self, params, h, live)
 
 
-class DeepseekV3LM(ProgramStats, nn.Module):
+class DeepseekV3LM(ProgramStats, LiveRowPrefill, nn.Module):
     """``vocab`` rows of embedding and (untied) head, ``n_layers`` blocks of
     which the first ``n_dense`` carry the dense FFN and the rest the expert
     layer over ``experts_held`` of ``n_experts``."""
@@ -219,6 +219,9 @@ class DeepseekV3LM(ProgramStats, nn.Module):
                              "in the parameters' dtype; there is no "
                              "quantised latent cache")
 
+    def prefill_chunk_tokens(self, width: int) -> int:
+        return PREFILL_TOKENS
+
     #: the decode read's registered cost model (obs/roofline.kernel_cost)
     paged_read_kernel = "paged_latent_attention"
 
@@ -284,7 +287,7 @@ class DeepseekV3LM(ProgramStats, nn.Module):
             lambda ids, n: self._sequence(params, ids, n, True), prompt, pos,
             params["embed"]["w"].shape[1],
             [jnp.zeros((B, T0, self.row), dt) for _ in self.blocks],
-            self.program_stats_zero(), PREFILL_TOKENS)
+            self.program_stats_zero(), self.prefill_chunk_tokens(T0))
         cell = {"pos": pos, "stats": stats}
         for i, lat in enumerate(latents):
             cell[f"kv{i}"] = jnp.pad(lat, ((0, 0), (0, limit - T0), (0, 0)))

@@ -38,8 +38,8 @@ from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
 from ..parallel.expert_share import (ExpertShare, ProgramStats,
                                      ffn_or_experts)
-from .transformer import (PREFILL_TOKENS, CacheRow, SlotRow, paged_greedy,
-                          prefill_live_rows)
+from .transformer import (PREFILL_TOKENS, CacheRow, LiveRowPrefill, SlotRow,
+                          paged_greedy, prefill_live_rows)
 
 
 def _dot(x, w):
@@ -113,7 +113,7 @@ class Lfm2Block(nn.Module):
                                  w_init=normal(0.0, init_std), dtype=dtype)
 
 
-class Lfm2MoeLM(ProgramStats, nn.Module):
+class Lfm2MoeLM(ProgramStats, LiveRowPrefill, nn.Module):
     """``vocab`` rows of embedding (and tied head), one block per entry of
     ``layer_types``; the first ``n_dense`` carry the dense FFN, the rest
     the expert layer over ``experts_held`` of ``n_experts``."""
@@ -180,6 +180,9 @@ class Lfm2MoeLM(ProgramStats, nn.Module):
             raise ValueError(f"kv_dtype {kv_dtype!r}: pages and slot state "
                              "are kept in the parameters' dtype; there is "
                              "no quantised cache for this model")
+
+    def prefill_chunk_tokens(self, width: int) -> int:
+        return PREFILL_TOKENS
 
     #: the decode read's registered cost model (obs/roofline.kernel_cost)
     paged_read_kernel = "paged_decode_attention"
@@ -265,7 +268,8 @@ class Lfm2MoeLM(ProgramStats, nn.Module):
             for r in rows}
         last, state, stats = prefill_live_rows(
             lambda ids, n: self._sequence(params, ids, n), prompt, pos,
-            self.d_model, state0, self.program_stats_zero(), PREFILL_TOKENS)
+            self.d_model, state0, self.program_stats_zero(),
+            self.prefill_chunk_tokens(T0))
         cell = {"pos": pos, "stats": stats}
         for nm, buf in state.items():
             cell[nm] = buf if nm in per_slot else jnp.pad(

@@ -43,8 +43,8 @@ from .. import nn
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
 from ..parallel.expert_share import ExpertShare, ProgramStats
-from .transformer import (PREFILL_TOKENS, CacheRow, SlotRow, paged_greedy,
-                          prefill_live_rows)
+from .transformer import (PREFILL_TOKENS, CacheRow, LiveRowPrefill, SlotRow,
+                          paged_greedy, prefill_live_rows)
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
 
@@ -116,7 +116,7 @@ class NemotronHBlock(nn.Module):
             raise ValueError(f"unknown layer kind {kind!r}")
 
 
-class NemotronHLM(ProgramStats, nn.Module):
+class NemotronHLM(ProgramStats, LiveRowPrefill, nn.Module):
     """``vocab`` rows of embedding and of an untied head, one block per
     character of ``pattern`` (``M`` / ``E`` / ``*``); the expert layers
     over ``experts_held`` of ``n_experts``."""
@@ -204,6 +204,11 @@ class NemotronHLM(ProgramStats, nn.Module):
                              "are kept as the model states them; there is "
                              "no quantised cache for this model")
 
+    def prefill_chunk_tokens(self, width: int) -> int:
+        """``PREFILL_TOKENS`` of rows a chunk, a row of
+        ``SOLO_ROW_TOKENS`` or more alone in its chunk."""
+        return width if width >= SOLO_ROW_TOKENS else PREFILL_TOKENS
+
     #: the decode read's registered cost model (obs/roofline.kernel_cost)
     paged_read_kernel = "paged_decode_attention"
 
@@ -226,10 +231,10 @@ class NemotronHLM(ProgramStats, nn.Module):
         layers': ``ssm_updates`` — (live slot, Mamba layer) state updates
         of the decode steps; ``scan_real`` / ``scan_padded`` — (position,
         Mamba layer) pairs the chunked scan ran that lie inside / past
-        their row's own length; ``rows`` — the rows an admission ran."""
+        their row's own length."""
         zero = jnp.zeros((), jnp.int32)
         return dict(super().program_stats_zero(), ssm_updates=zero,
-                    scan_real=zero, scan_padded=zero, rows=zero)
+                    scan_real=zero, scan_padded=zero)
 
     def _add_stats(self, stats, counts, live, n_rows, **more):
         out = dict(stats, **super()._add_stats(stats, counts, live, n_rows))
@@ -260,9 +265,6 @@ class NemotronHLM(ProgramStats, nn.Module):
                 "ssd_chunk_scan", tokens=real + padded,
                 itemsize=jnp.dtype(self.dtype).itemsize, **shape) or 0.0,
                 kernel="ssd_chunk_scan")
-        if program == "admit":
-            attrs = dict(attrs, rows=int(stats["rows"]),
-                         prompt_tokens=real // len(self.mamba_layers))
         return attrs
 
     # -- whole sequences ---------------------------------------------------
@@ -299,9 +301,7 @@ class NemotronHLM(ProgramStats, nn.Module):
         n_m = len(self.mamba_layers)
         stats = self._add_stats(
             self.program_stats_zero(), counts, live, B * T,
-            scan_real=n_real * n_m, scan_padded=(B * T - n_real) * n_m,
-            rows=B if lengths is None
-            else jnp.sum(jnp.asarray(lengths) > 0, dtype=jnp.int32))
+            scan_real=n_real * n_m, scan_padded=(B * T - n_real) * n_m)
         return h, state, stats
 
     def logits(self, params, h):
@@ -348,8 +348,7 @@ class NemotronHLM(ProgramStats, nn.Module):
         last, state, stats = prefill_live_rows(
             lambda ids, n: self._sequence(params, ids, n), prompt, pos,
             self.d_model, state0, self.program_stats_zero(),
-            T0 if T0 >= SOLO_ROW_TOKENS else PREFILL_TOKENS,
-            in_place=per_slot)
+            self.prefill_chunk_tokens(T0), in_place=per_slot)
         cell = {"pos": pos, "stats": stats}
         for nm, buf in state.items():
             cell[nm] = buf if nm in per_slot else jnp.pad(

@@ -54,6 +54,19 @@ class SlotRow(NamedTuple):
 PREFILL_TOKENS = 2048
 
 
+def live_row_walk(n_rows, width, chunk_tokens, n_live):
+    """The shape of :func:`prefill_live_rows`' walk over ``[n_rows, width]``
+    prompts of which ``n_live`` hold one: (rows a chunk — the most that
+    divide ``n_rows`` within ``chunk_tokens`` of ``width``-wide rows, at
+    least one — and the chunks walked). Arithmetic alone, so the traced
+    walk calls it with a traced ``n_live`` and the host, which has the
+    lengths, with an int: the page pool's count of the positions an
+    admission ran (``prefill_positions``) is the walk's own."""
+    rows = next(r for r in range(max(1, min(n_rows, chunk_tokens // width)),
+                                 0, -1) if n_rows % r == 0)
+    return rows, (n_live + rows - 1) // rows
+
+
 def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
                       chunk_tokens, in_place=()):
     """The admission walk ``DeepseekV3LM`` and ``Lfm2MoeLM`` share. Rows
@@ -78,10 +91,9 @@ def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
     at the rows that hold a prompt and nowhere else — the rows of length
     0 that fill up the last chunk keep what they hold."""
     B, T0 = prompt.shape
-    R = next(r for r in range(max(1, min(B, chunk_tokens // T0)), 0, -1)
-             if B % r == 0)
+    R, n_chunks = live_row_walk(B, T0, chunk_tokens,
+                                jnp.sum(pos > 0, dtype=jnp.int32))
     order = jnp.argsort(pos == 0, stable=True).astype(jnp.int32)
-    n_chunks = (jnp.sum(pos > 0, dtype=jnp.int32) + R - 1) // R
 
     def chunk(carry):
         i, last, state, stats = carry
@@ -104,6 +116,21 @@ def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
         (jnp.int32(0), jnp.zeros((B, d_model), jnp.float32), state0,
          stats0))
     return last, state, stats
+
+
+class LiveRowPrefill:
+    """A model whose ``prefill`` walks :func:`prefill_live_rows`: it says
+    what a chunk may hold (``prefill_chunk_tokens(width)``, which its
+    ``prefill`` hands the walk), and the page pool's question — how many
+    positions did an admission run through the depth — is answered by the
+    walk's own arithmetic."""
+
+    def prefill_positions(self, n_rows: int, width: int, n_live: int) -> int:
+        """Positions ``prefill`` runs for ``[n_rows, width]`` prompts of
+        which ``n_live`` hold one: chunks walked x rows a chunk x width."""
+        rows, chunks = live_row_walk(
+            n_rows, width, self.prefill_chunk_tokens(width), n_live)
+        return chunks * rows * width
 
 
 def paged_greedy(model, params, prompt, steps: int, page_block: int):
@@ -455,6 +482,12 @@ class TransformerLM(nn.Module):
                 rows += [CacheRow(f"k{i}_scale", (H,), jnp.float32, 1.0),
                          CacheRow(f"v{i}_scale", (H,), jnp.float32, 1.0)]
         return rows
+
+    def prefill_positions(self, n_rows: int, width: int, n_live: int) -> int:
+        """Positions :meth:`prefill` (and :meth:`prefill_paged`) runs
+        through the depth for ``[n_rows, width]`` prompts: every row at
+        full width, however many (``n_live``) hold a prompt."""
+        return n_rows * width
 
     #: the decode read's registered cost model (obs/roofline.kernel_cost)
     paged_read_kernel = "paged_decode_attention"

@@ -500,8 +500,37 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                                           "(bounded)", ("tenant",)),
     "serving.tpot_seconds": ("histogram", "per-output-token time after "
                                           "the first (completion - first "
-                                          "token) / (n - 1), labels: "
+                                          "token) / (n - 1); the request "
+                                          "ledger's done record splits "
+                                          "that time: decode_s in its own "
+                                          "segments, stalled_s behind "
+                                          "admissions_waited admissions "
+                                          "of others, the rest the "
+                                          "scheduler's host time; labels: "
                                           "tenant (bounded)", ("tenant",)),
+    "serving.tpot_stalled_seconds": (
+        "histogram", "the part of serving.tpot_seconds a request spent "
+                     "behind OTHER requests' admissions: the "
+                     "serving.prefill spans that ran while it was live "
+                     "(its own is TTFT's) / (n - 1) — the done record's "
+                     "stalled_s; labels: tenant (bounded)", ("tenant",)),
+    # the iteration's account: work run against work delivered
+    "serving.admit_positions_total": (
+        "counter", "positions the admit programs ran through the depth, "
+                   "labels: state (prompt = tokens of the admitted prompts "
+                   "that had to run — a prefix hit's shared part is not; "
+                   "padding = the rest: a row's tail to its bucket, rows "
+                   "that hold no prompt, rows that fill up a chunk); the "
+                   "serving.prefill span carries the same as "
+                   "prompt_tokens / positions", ("state",)),
+    "serving.segment_slot_steps_total": (
+        "counter", "slot-steps the segment programs ran (slots x "
+                   "segment), labels: state (emitted = delivered a token "
+                   "to a request; overshoot = a live slot's step past its "
+                   "request's last token, or the re-emitted first one; "
+                   "idle = a slot with no request); the serving.emit span "
+                   "behind the segment carries the same as emitted / "
+                   "live_steps / slot_steps", ("state",)),
     # disaggregation: KV-page shipping (serving/ship.py wire contract)
     "serving.ship_pages_total": ("counter", "KV pages exported for "
                                             "shipping to a decode worker "
@@ -626,14 +655,19 @@ SPANS: Dict[str, str] = {
     "coord.dispatch": "server-side handling of one coord RPC (args: op; "
                       "remote = the client's rpc.call span)",
     "serving.prefill": "one admission batch: ragged prefill + page "
-                       "placement (args: batch; with expert layers also "
-                       "routed_here, experts_touched, row_tiles, load_max of "
-                       "the admit program; with state-space layers also "
-                       "rows and prompt_tokens: the rows the admit program "
-                       "ran and the positions inside their own length)",
+                       "placement (args: batch; rows, prompt_tokens, "
+                       "positions: the rows that held a prompt, the tokens "
+                       "they had to run — a prefix hit's shared part is "
+                       "not — and the positions the admit programs ran "
+                       "through the depth, summed over the miss and the "
+                       "prefix-hit program; with expert layers also "
+                       "routed_here, experts_touched, row_tiles, load_max "
+                       "of the admit program). Its length is added to "
+                       "stalled_s of every request already live",
     "serving.segment": "one batched decode segment across live slots "
                        "(args: live; with expert layers also routed_here, "
-                       "experts_touched, row_tiles, load_max)",
+                       "experts_touched, row_tiles, load_max). Its length "
+                       "is added to decode_s of every request in it",
     "serving.schedule": "a locked section of the scheduler: reaping "
                         "cancels/deadlines, or deficit scheduling + "
                         "plan_admission + evict_for (args: phase = "
@@ -653,7 +687,10 @@ SPANS: Dict[str, str] = {
                    "experts_touched, row_tiles, load_max); serving.prefill "
                    "and serving.segment carry the same four",
     "serving.emit": "the locked token hand-out after a prefill or a "
-                    "segment (args: after = prefill | segment)",
+                    "segment (args: after = prefill | segment; after a "
+                    "segment also slot_steps = slots x segment the program "
+                    "ran, live_steps = live x segment, emitted = tokens "
+                    "requests received)",
     "serving.ship": "client side of one KV shipment: every srv_ship chunk "
                     "RPC for one request (args: xid, bytes, key)",
     "srv_ship": "decode-side landing of one ship chunk (args: xid, seq; "

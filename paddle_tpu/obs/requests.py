@@ -310,6 +310,17 @@ def format_timeline(st: dict) -> str:
         for k in ("n", "why", "reason", "to", "tenant", "folds"):
             if k in e:
                 row += f" {k}={e[k]}"
+        if "decode_s" in e and "stalled_s" in e and ttft is not None:
+            # the engine's account of the decode life (first token ->
+            # done): its own segments, other requests' admissions, and
+            # what is left, the scheduler's host time
+            dec, stall = float(e["decode_s"]), float(e["stalled_s"])
+            host = max(0.0, rel - ttft - dec - stall)
+            row += (f" decode={dec * 1e3:.2f}ms"
+                    f"/{e.get('segments', 0)}seg"
+                    f" stalled={stall * 1e3:.2f}ms"
+                    f"/{e.get('admissions_waited', 0)}adm"
+                    f" host={host * 1e3:.2f}ms")
         if e.get("resumed"):
             row += " resumed"
         lines.append(row)

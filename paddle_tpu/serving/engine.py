@@ -59,7 +59,8 @@ class _Rec:
     __slots__ = ("rid", "prompt", "eos_id", "left", "deadline", "t_submit",
                  "t_first", "t_done", "tokens", "done", "reason", "slot",
                  "skip", "cancelled", "collected", "tenant", "slo",
-                 "prefix_len", "ship", "key", "blocked")
+                 "prefix_len", "ship", "key", "blocked", "decode_s",
+                 "stalled_s", "segments", "admissions_waited")
 
     def __init__(self, rid, prompt, left, eos_id, deadline, t_submit,
                  tenant="default", slo="interactive", prefix_len=None):
@@ -86,6 +87,15 @@ class _Rec:
         #: round has passed it over — what splits its queue wait into
         #: waiting for a segment boundary and waiting for capacity
         self.blocked: Optional[tuple] = None
+        #: the decode life's account (first token -> done): seconds inside
+        #: the ``segments`` decode segments this request held a slot in,
+        #: and seconds behind the ``admissions_waited`` admissions of OTHER
+        #: requests that ran while it was live (its own is TTFT's); what is
+        #: left of ``t_done - t_first`` is the scheduler's host time
+        self.decode_s = 0.0
+        self.stalled_s = 0.0
+        self.segments = 0
+        self.admissions_waited = 0
 
 
 def _blocked_extra(rec: _Rec, now: float) -> dict:
@@ -480,6 +490,7 @@ class ServingEngine:
             group, adopts, members, pending = [], [], [], 0
             now = self._clock()
             busy = set(self._live)
+            stalled = list(self._live.values())   # live before this round
             free_slots = [s for s in range(self.pool.n_slots)
                           if s not in busy]
             quantum = float(self.pool.segment)
@@ -552,6 +563,7 @@ class ServingEngine:
         if not group and not adopts:
             return 0
         adopted = {rec.rid for _, rec in adopts}
+        began = self._clock()
         with obs.span("serving.prefill",
                       batch=len(group) + len(adopts)) as span, \
                 maybe_bucket(self._gp, "device"):
@@ -566,6 +578,9 @@ class ServingEngine:
         now = self._clock()
         with obs.span("serving.emit", after="prefill"), \
                 maybe_bucket(self._gp, "host_sync"), self._lock:
+            for rec in stalled:
+                rec.stalled_s += now - began
+                rec.admissions_waited += 1
             for rec in members:
                 # a cancel landing during the prefill only sets the flag
                 # (this thread owns finalization); the next _reap honors it
@@ -600,16 +615,21 @@ class ServingEngine:
             live = sorted(self._live)
         if not live:
             return
+        began = self._clock()
         with obs.span("serving.segment", live=len(live)) as span, \
                 maybe_bucket(self._gp, "device"):
             block = self.pool.run_segment(live)  # device work, lock released
             span.note(**self.pool.last_stats)
-        with obs.span("serving.emit", after="segment"), \
+        seg_s = self._clock() - began
+        with obs.span("serving.emit", after="segment") as emit, \
                 maybe_bucket(self._gp, "host_sync"), self._lock:
+            emitted = 0
             for slot in live:
                 rec = self._live.get(slot)
                 if rec is None or rec.done:
                     continue
+                rec.decode_s += seg_s
+                rec.segments += 1
                 usable = block[slot, rec.skip:]
                 rec.skip = 0
                 take, done, reason = clip_emission(usable, rec.left,
@@ -620,8 +640,22 @@ class ServingEngine:
                     # consecutive segments fold into one ledger record
                     obs.req_phase(rec.key, "decode", n=len(take))
                 rec.left -= len(take)
+                emitted += len(take)
                 if done:
                     self._release_locked(rec, reason)
+            # the segment's work against what it delivered: the program
+            # ran every slot for every step; a live slot's steps past its
+            # request's last token (and the re-emitted first one) are
+            # overshoot, the other slots' idle
+            slot_steps = self.pool.n_slots * self.pool.segment
+            live_steps = len(live) * self.pool.segment
+            emit.note(slot_steps=slot_steps, live_steps=live_steps,
+                      emitted=emitted)
+            for state, n in (("emitted", emitted),
+                             ("overshoot", live_steps - emitted),
+                             ("idle", slot_steps - live_steps)):
+                obs.count("serving.segment_slot_steps_total", n,
+                          state=state)
             self._set_gauges_locked()
 
     # -- internals (call with _lock held) ----------------------------------
@@ -639,13 +673,21 @@ class ServingEngine:
         if rec.key is not None:
             obs.req_phase(rec.key,
                           "cancel" if reason == "cancelled" else "done",
-                          reason=reason, tokens=len(rec.tokens))
+                          reason=reason, tokens=len(rec.tokens),
+                          decode_s=round(rec.decode_s, 6),
+                          stalled_s=round(rec.stalled_s, 6),
+                          segments=rec.segments,
+                          admissions_waited=rec.admissions_waited)
         if rec.t_first is not None and len(rec.tokens) > 1:
             # time-per-output-token over the tokens AFTER the first (TTFT
-            # owns the first) — the SLO pair dashboards alert on
+            # owns the first) — the SLO pair dashboards alert on — and the
+            # part of it spent behind other requests' admissions
+            gaps = len(rec.tokens) - 1
             obs.observe("serving.tpot_seconds",
-                        (rec.t_done - rec.t_first)
-                        / (len(rec.tokens) - 1), tenant=rec.tenant)
+                        (rec.t_done - rec.t_first) / gaps,
+                        tenant=rec.tenant)
+            obs.observe("serving.tpot_stalled_seconds",
+                        rec.stalled_s / gaps, tenant=rec.tenant)
         self._done_order.append(rec.rid)
         # bound the finished-record memory of a long-lived daemon without
         # dropping results nobody has read: purge COLLECTED records first,

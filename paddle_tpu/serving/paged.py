@@ -639,8 +639,13 @@ class PagePool:
         full-prefill dispatch for misses and the CoW + suffix-prefill
         dispatch for hits, insert the new full prompt blocks (and the
         last partial page) into the index, and return {slot: first
-        generated token}."""
-        self.last_stats = {}
+        generated token}. ``last_stats`` then holds the admission's
+        account — ``rows`` that held a prompt, the ``prompt_tokens`` they
+        had to run (a prefix hit's shared part is not run), the
+        ``positions`` its programs ran through the depth — beside what
+        the model made of its program's stats."""
+        work = dict.fromkeys(("rows", "prompt_tokens", "positions"), 0)
+        self.last_stats = work
         if not group:
             return {}
         if self.index is not None:
@@ -653,9 +658,10 @@ class PagePool:
 
         first = np.zeros((self.n_slots,), np.int32)
         if miss:
-            self._dispatch_miss(miss, first)
+            self._dispatch_miss(miss, first, work)
         if hits:
-            self._dispatch_hits(hits, cow, first)
+            self._dispatch_hits(hits, cow, first, work)
+        self.last_stats = dict(self.last_stats, **work)
         if self.index is not None:
             with obs.span("serving.index"):
                 for slot, plan in group:
@@ -700,7 +706,23 @@ class PagePool:
             self.prefill_tokens_total += plan.plen - plan.offset
             (hits if plan.offset else miss).append((slot, plan))
 
-    def _dispatch_miss(self, miss, first) -> None:
+    def _account(self, work, rows: int, prompt_tokens: int,
+                 width: int) -> None:
+        """Add one admit program over ``[n_slots, width]`` to the
+        admission's account ``work``: the positions it ran are the
+        model's own walk (``prefill_positions``: every row at full width,
+        or the chunks that hold the ``rows`` live ones)."""
+        positions = int(self.model.prefill_positions(self.n_slots, width,
+                                                     rows))
+        for k, v in (("rows", rows), ("prompt_tokens", prompt_tokens),
+                     ("positions", positions)):
+            work[k] += v
+        obs.count("serving.admit_positions_total", prompt_tokens,
+                  state="prompt")
+        obs.count("serving.admit_positions_total",
+                  positions - prompt_tokens, state="padding")
+
+    def _dispatch_miss(self, miss, first, work) -> None:
         """The cold path: ONE full-pool-width jitted prefill-and-scatter,
         numerically identical to the pre-prefix-cache admission."""
         with obs.span("serving.stage", what="prompts"):
@@ -716,6 +738,7 @@ class PagePool:
                 lens[slot] = plan.plen
                 n = min(nbp, len(self.slot_pages[slot]))
                 pages[slot, :n] = self.slot_pages[slot][:n]
+            self._account(work, len(miss), int(lens.sum()), tpad)
             fn = self._admit_fn(tpad, nbp)
             args = (self.params, (self.pools, self.slot_state),
                     jnp.asarray(prompts), jnp.asarray(lens),
@@ -731,7 +754,7 @@ class PagePool:
         for slot, _ in miss:
             first[slot] = f[slot]
 
-    def _dispatch_hits(self, hits, cow, first) -> None:
+    def _dispatch_hits(self, hits, cow, first, work) -> None:
         """The warm path: CoW copies + suffix prefill from each slot's
         offset, reading the shared prefix pages through the block table."""
         with obs.span("serving.stage", what="suffixes"):
@@ -753,6 +776,7 @@ class PagePool:
                 lens[slot] = sfx.size
                 if slot in cow:
                     src[slot], dst[slot] = cow[slot]
+            self._account(work, len(hits), int(lens.sum()), tpad)
             fn = self._hit_fn(tpad, nbr)
             args = (self.params, self.pools, jnp.asarray(suffix),
                     jnp.asarray(offsets), jnp.asarray(lens),

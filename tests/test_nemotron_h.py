@@ -212,7 +212,13 @@ def test_a_wide_row_runs_alone_in_its_chunk_and_gives_the_same_cell(
     live = np.asarray(lens) > 0      # nobody reads an empty row's logits
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                **TOL)
-    assert int(solo["stats"]["rows"]) == int(filled["stats"]["rows"]) == 3
+    # both walks ran the three rows that hold a prompt: the same real
+    # positions a Mamba layer, two chunks of two rows against three of one
+    n_m = len(model.mamba_layers)
+    assert int(solo["stats"]["scan_real"]) \
+        == int(filled["stats"]["scan_real"]) == 32 * n_m
+    assert int(filled["stats"]["scan_padded"]) == (2 * 2 * 16 - 32) * n_m
+    assert int(solo["stats"]["scan_padded"]) == (3 * 1 * 16 - 32) * n_m
     for nm in ("ssm0", "conv0", "k3", "v3"):
         np.testing.assert_allclose(np.asarray(solo[nm], np.float32)[live],
                                    np.asarray(filled[nm], np.float32)[live],
