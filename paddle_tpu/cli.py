@@ -1333,8 +1333,11 @@ def cmd_serve(args):
     ``prefill_positions(n_rows, width, n_live)`` — the positions ``prefill``
     runs through the depth for ``[n_rows, width]`` prompts of which
     ``n_live`` hold one, from the walk it runs (``models.transformer
-    .LiveRowPrefill`` for a model on ``prefill_live_rows``): the admission's
-    account on the ``serving.prefill`` span;
+    .LiveRowPrefill`` for a model on ``prefill_live_rows``, as all four
+    served models are: the pool hands an admission its whole width, the
+    model walks the rows that hold a prompt): the admission's account on
+    the ``serving.prefill`` span (the prefix-hit program, ``prefill_paged``,
+    is counted at slots x width by the pool itself);
     and, only for the prefix cache, ``prefill_paged`` (a model without it
     needs ``--no_prefix_cache``; with it on, ``serve`` refuses at start-up;
     slot rows are not shared by prefix). Optional: ``slot_rows_in_place =

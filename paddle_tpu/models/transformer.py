@@ -52,6 +52,9 @@ class SlotRow(NamedTuple):
 
 #: tokens a chunked prefill runs through the depth at once (rows x width)
 PREFILL_TOKENS = 2048
+#: the same for ``TransformerLM``, whose blocks are dense: chosen on the chip
+#: at the GPT-2 serve cells' shapes (PERF.md section 6, PR 38)
+LM_PREFILL_TOKENS = 512
 
 
 def live_row_walk(n_rows, width, chunk_tokens, n_live):
@@ -223,7 +226,7 @@ class TransformerBlock(nn.Module):
         return (out, (k, v)) if return_kv else out
 
 
-class TransformerLM(nn.Module):
+class TransformerLM(LiveRowPrefill, nn.Module):
     """GPT-style LM: token + learned position embeddings, N pre-LN blocks,
     final LN, head tied to the token embedding (weight sharing)."""
 
@@ -234,6 +237,7 @@ class TransformerLM(nn.Module):
         super().__init__()
         d_ff = d_ff or 4 * d_model
         self.vocab, self.max_len, self.tie_head = vocab, max_len, tie_head
+        self.d_model = d_model
         # jax.checkpoint per block: activations rematerialize in the
         # backward instead of living across the whole depth — the
         # FLOPs-for-HBM trade long-context training needs
@@ -347,6 +351,16 @@ class TransformerLM(nn.Module):
         garbage is overwritten strictly before it becomes readable. This is
         the slot-refill path of continuous batching (serving/batcher.py).
 
+        The rows run through the blocks ``LM_PREFILL_TOKENS`` at a time,
+        those that HOLD a prompt first, and the walk stops after the last
+        chunk that has one (:func:`prefill_live_rows`; the page pool hands
+        an admission its whole width with length 0 in the slots it is not
+        filling): a row of length 0 keeps the cache's fill (zeros, scales
+        1.0) unless it fills up the last live chunk, and its logits mean
+        nothing. Without ``lengths`` every row is live and the walk visits
+        them all. Only each row's last position reaches ``ln_f`` and the
+        head — never ``[B, T0, vocab]``.
+
         ``kv_dtype="int8"`` stores the caches as symmetric int8 rows with
         per-(position, head) f32 scales (``k{i}_scale``/``v{i}_scale`` in
         the cell) — decode's HBM cache read halves; the prompt forward
@@ -363,44 +377,52 @@ class TransformerLM(nn.Module):
         if kv_dtype not in (None, "int8"):
             raise ValueError(f"unsupported kv_dtype {kv_dtype!r} "
                              "(None or 'int8')")
+        prompt = jnp.asarray(prompt)       # the walk indexes it by a tracer
         B, T0 = prompt.shape
         limit = self.max_len if pad_to is None else min(pad_to, self.max_len)
         if limit < T0:
             raise ValueError(f"prefill cache limit {limit} (pad_to/max_len) "
                              f"is narrower than the prompt ({T0})")
-        x = self.embed(params["embed"], prompt)
-        x = x + params["pos_embed"][:T0].astype(x.dtype)
-        if lengths is None:
-            cell = {"pos": jnp.full((B,), T0, jnp.int32)}
-        else:
-            cell = {"pos": jnp.asarray(lengths, jnp.int32)}
-        pad = limit - T0
-        pad4 = ((0, 0), (0, pad), (0, 0), (0, 0))
+        pos = (jnp.full((B,), T0, jnp.int32) if lengths is None
+               else jnp.asarray(lengths, jnp.int32))
+        rows = self.cache_rows(params, kv_dtype)
+        # rows that never run keep the fill: scales 1.0, so the dequant of
+        # their (masked) rows stays finite, like the padded tail's
+        state0 = {r.name: jnp.full((B, T0) + r.shape, r.fill, r.dtype)
+                  for r in rows}
+        last, state, _ = prefill_live_rows(
+            lambda ids, n: self._prompt_rows(params, ids, kv_dtype),
+            prompt, pos, self.d_model, state0, {},
+            self.prefill_chunk_tokens(T0))
+        cell = {"pos": pos}
+        for r in rows:
+            cell[r.name] = jnp.pad(
+                state[r.name],
+                ((0, 0), (0, limit - T0)) + ((0, 0),) * len(r.shape),
+                constant_values=r.fill)
+        x = self.ln_f(params["ln_f"],
+                      last.astype(self._compute_dtype(params)))
+        return cell, (x @ params["embed"]["w"].T.astype(x.dtype)
+                      if self.tie_head else self.head(params["head"], x))
+
+    def _prompt_rows(self, params, ids, kv_dtype):
+        """One chunk of :meth:`prefill`'s walk: ids [R, T0] through the
+        blocks -> (hidden states before ``ln_f`` [R, T0, d], the rows'
+        cache entries by :meth:`cache_rows`' names, no stats)."""
+        x = self.embed(params["embed"], ids)
+        x = x + params["pos_embed"][:ids.shape[1]].astype(x.dtype)
+        rows = {}
         for i in range(len(self.blocks)):
             blk = self.blocks[i]
             q, k, v = blk.heads(params[f"blocks_{i}"], x)
             o = blk.attend(q, k, v)
             x = blk.finish(params[f"blocks_{i}"], x, o)
             if kv_dtype == "int8":
-                k8, ks = pk.quantize_kv(k)
-                v8, vs = pk.quantize_kv(v)
-                cell[f"k{i}"] = jnp.pad(k8, pad4)
-                cell[f"v{i}"] = jnp.pad(v8, pad4)
-                # padded scales are 1.0 so dequant of (masked) garbage rows
-                # stays finite
-                cell[f"k{i}_scale"] = jnp.pad(
-                    ks, ((0, 0), (0, pad), (0, 0)), constant_values=1.0)
-                cell[f"v{i}_scale"] = jnp.pad(
-                    vs, ((0, 0), (0, pad), (0, 0)), constant_values=1.0)
+                rows[f"k{i}"], rows[f"k{i}_scale"] = pk.quantize_kv(k)
+                rows[f"v{i}"], rows[f"v{i}_scale"] = pk.quantize_kv(v)
             else:
-                cell[f"k{i}"] = jnp.pad(k, pad4)
-                cell[f"v{i}"] = jnp.pad(v, pad4)
-        x = self.ln_f(params["ln_f"], x)
-        logits = (x @ params["embed"]["w"].T.astype(x.dtype)
-                  if self.tie_head else self.head(params["head"], x))
-        if lengths is None:
-            return cell, logits[:, -1]
-        return cell, logits[jnp.arange(B), cell["pos"] - 1]
+                rows[f"k{i}"], rows[f"v{i}"] = k, v
+        return x, rows, {}
 
     def _append_rows(self, cell, new_cell, i, k, v, pos):
         """Write this step's k/v rows ([B, S, H, Dh]) at pos..pos+S-1 and
@@ -483,11 +505,13 @@ class TransformerLM(nn.Module):
                          CacheRow(f"v{i}_scale", (H,), jnp.float32, 1.0)]
         return rows
 
-    def prefill_positions(self, n_rows: int, width: int, n_live: int) -> int:
-        """Positions :meth:`prefill` (and :meth:`prefill_paged`) runs
-        through the depth for ``[n_rows, width]`` prompts: every row at
-        full width, however many (``n_live``) hold a prompt."""
-        return n_rows * width
+    def prefill_chunk_tokens(self, width: int) -> int:
+        """``LM_PREFILL_TOKENS`` of rows a chunk, and never one row alone:
+        a chunk of one row writes its cache rows by a dynamic-update-slice
+        that costs a pass over the WHOLE ``[B, width]`` buffer of every
+        layer on the chip (PERF.md section 6, PR 38: 139 ms against 102
+        for four 512-token rows of gpt2-large)."""
+        return max(LM_PREFILL_TOKENS, 2 * width)
 
     #: the decode read's registered cost model (obs/roofline.kernel_cost)
     paged_read_kernel = "paged_decode_attention"

@@ -211,9 +211,10 @@ class ContinuousBatcher:
         return fn
 
     def _prefill_fn(self, tpad: int):
-        """Always full-pool-width [slots, tpad]: admissions place each new
-        request at ITS slot row (dummies elsewhere), so the only compile
-        axis is the prompt pad bucket — never the group size."""
+        """Always the pool's whole width [slots, tpad]: admissions place
+        each new request at ITS slot row (length 0 elsewhere: rows the
+        model's ``prefill`` walks past), so the only compile axis is the
+        prompt pad bucket — never the group size."""
         fn = self._prefill_fns.get(tpad)
         if fn is None:
             model = self.model

@@ -706,14 +706,15 @@ class PagePool:
             self.prefill_tokens_total += plan.plen - plan.offset
             (hits if plan.offset else miss).append((slot, plan))
 
-    def _account(self, work, rows: int, prompt_tokens: int,
-                 width: int) -> None:
+    def _account(self, work, rows: int, prompt_tokens: int, width: int,
+                 positions: Optional[int] = None) -> None:
         """Add one admit program over ``[n_slots, width]`` to the
-        admission's account ``work``: the positions it ran are the
-        model's own walk (``prefill_positions``: every row at full width,
-        or the chunks that hold the ``rows`` live ones)."""
-        positions = int(self.model.prefill_positions(self.n_slots, width,
-                                                     rows))
+        admission's account ``work``. The positions it ran: the caller's,
+        or the walk of the model's ``prefill`` (``prefill_positions``: the
+        chunks that hold the ``rows`` live ones)."""
+        if positions is None:
+            positions = int(self.model.prefill_positions(self.n_slots,
+                                                         width, rows))
         for k, v in (("rows", rows), ("prompt_tokens", prompt_tokens),
                      ("positions", positions)):
             work[k] += v
@@ -723,8 +724,10 @@ class PagePool:
                   positions - prompt_tokens, state="padding")
 
     def _dispatch_miss(self, miss, first, work) -> None:
-        """The cold path: ONE full-pool-width jitted prefill-and-scatter,
-        numerically identical to the pre-prefix-cache admission."""
+        """The cold path: ONE jitted prefill-and-scatter over the pool's
+        whole width, length 0 in the slots it is not filling (the model's
+        ``prefill`` walks the rows that hold a prompt); numerically
+        identical to the pre-prefix-cache admission."""
         with obs.span("serving.stage", what="prompts"):
             tpad = bucket_length(max(p.plen for _, p in miss),
                                  self.prompt_buckets)
@@ -776,7 +779,9 @@ class PagePool:
                 lens[slot] = sfx.size
                 if slot in cow:
                     src[slot], dst[slot] = cow[slot]
-            self._account(work, len(hits), int(lens.sum()), tpad)
+            # prefill_paged runs every slot at the suffix bucket's width
+            self._account(work, len(hits), int(lens.sum()), tpad,
+                          positions=self.n_slots * tpad)
             fn = self._hit_fn(tpad, nbr)
             args = (self.params, self.pools, jnp.asarray(suffix),
                     jnp.asarray(offsets), jnp.asarray(lens),

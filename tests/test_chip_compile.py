@@ -458,3 +458,37 @@ def test_sharded_gpt2_small_step_lowers_on_four_chips(topo, monkeypatch):
     w = params["blocks_0"]["mlp_in"]["w"]
     assert len({s for s in w.sharding.devices_indices_map(w.shape).values()
                 }) == 4
+
+
+@pytest.mark.parametrize("bucket", [128, 256, 512])
+def test_gpt2_large_admit_program_compiles(one_chip, S, bucket, monkeypatch):
+    """The admit program both GPT-2 serve cells run (``PagePool._admit_fn``
+    over ``TransformerLM.prefill``'s walk of the live rows: gpt2-large, 16
+    slots, 105 pages of 64 rows, f32) compiled whole for the described
+    chip at the cells' three widest prompt buckets: it fits beside the
+    weights, the flash kernel is in it (from ``SHORT_SEQ_DENSE`` rows
+    on), and the pools are written where they lie (donated)."""
+    monkeypatch.setattr(pk, "_interpret", lambda interpret: False)
+    from paddle_tpu.models import TransformerLM
+    from paddle_tpu.serving.paged import PagePool
+
+    model = TransformerLM(50257, d_model=1280, n_heads=20, n_layers=36,
+                          max_len=1024)
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
+    # two pages held here; the program is lowered over the cells' 105
+    pool = PagePool(model, params, slots=16, pages=2, page_block=64)
+    pools = {nm: S((105,) + a.shape[1:], a.dtype)
+             for nm, a in pool.pools.items()}
+    nbp = bucket // 64
+    compiled = pool._admit_fn(bucket, nbp)._jitted.lower(
+        params, (pools, {}), S((16, bucket), jnp.int32),
+        S((16,), jnp.int32), S((16, nbp), jnp.int32)).compile()
+    assert (("tpu_custom_call" in compiled.as_text())
+            == (bucket >= pk.SHORT_SEQ_DENSE))
+    mem = compiled.memory_analysis()
+    held = sum(int(np.prod(a.shape)) * 4 for a in pools.values())
+    assert mem.alias_size_in_bytes >= held
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 14 * 2 ** 30)

@@ -13,7 +13,7 @@ import pytest
 
 from paddle_tpu import obs
 from paddle_tpu.models import DeepseekV3LM, TransformerLM
-from paddle_tpu.models import deepseek_v3, lfm2, nemotron_h
+from paddle_tpu.models import deepseek_v3, lfm2, nemotron_h, transformer
 from paddle_tpu.models.transformer import live_row_walk
 from paddle_tpu.obs.requests import RequestLedger, format_timeline, stitch
 from paddle_tpu.serving import ServingEngine
@@ -223,8 +223,10 @@ def test_positions_are_the_walk_the_program_runs(case, monkeypatch,
     """``positions`` on the admission equals rows x width x chunks as the
     PROGRAM walked them: for the models on ``prefill_live_rows`` the
     traced walk and the host's count are fed by one function
-    (``live_row_walk``); ``TransformerLM``'s two admit programs run every
-    row of the pool at the bucket's width."""
+    (``live_row_walk``) — ``TransformerLM``'s miss program among them;
+    its prefix-hit program (``prefill_paged``) runs every row of the pool
+    at the suffix bucket's width, and an admission with a hit and a miss
+    sums the two."""
     reg = obs.MetricsRegistry()
     kw = dict(slots=4, segment=4, page_block=8, cache_bucket=32,
               prompt_buckets=(16, 32))
@@ -258,19 +260,23 @@ def test_positions_are_the_walk_the_program_runs(case, monkeypatch,
     else:
         model, params = paged_model_and_params
         shapes = []
-        prefill, prefill_paged = model.prefill, model.prefill_paged
+        # chunks hold 32 tokens here too, and never one row alone: two
+        # rows of the 16 bucket, two of the 32 bucket
+        monkeypatch.setattr(transformer, "LM_PREFILL_TOKENS", 32)
 
-        def seen(params, prompt, *a, **k):
-            shapes.append(("miss", prompt.shape))
-            return prefill(params, prompt, *a, **k)
+        # a model of its own: the programs are traced here, through the spies
+        model = TransformerLM(VOCAB, d_model=32, n_heads=4, n_layers=2,
+                              max_len=128)
+        chunk, prefill_paged = model._prompt_rows, model.prefill_paged
+
+        def seen(params, ids, *a, **k):
+            shapes.append(("miss", ids.shape))      # a CHUNK of the walk
+            return chunk(params, ids, *a, **k)
 
         def seen_paged(params, pools, tokens, *a, **k):
             shapes.append(("hit", tokens.shape))
             return prefill_paged(params, pools, tokens, *a, **k)
-        # a model of its own: the programs are traced here, through the spies
-        model = TransformerLM(VOCAB, d_model=32, n_heads=4, n_layers=2,
-                              max_len=128)
-        monkeypatch.setattr(model, "prefill", seen)
+        monkeypatch.setattr(model, "_prompt_rows", seen)
         monkeypatch.setattr(model, "prefill_paged", seen_paged)
         hit = case.endswith("prefix-hit")
         pool = PagePool(model, params, prefix_cache=hit, **kw)
@@ -287,21 +293,24 @@ def test_positions_are_the_walk_the_program_runs(case, monkeypatch,
             pool.admit([(1, pool.plan_admission(again, 4)),
                         (2, pool.plan_admission(wide, 4))])
             second = dict(pool.last_stats)
-        assert first == {"rows": 1, "prompt_tokens": 12, "positions": 4 * 16}
-        assert shapes[0] == ("miss", (4, 16))
+        # one live row of the 16 bucket: one chunk of two rows
+        assert first == {"rows": 1, "prompt_tokens": 12, "positions": 2 * 16}
+        assert shapes[0] == ("miss", (2, 16))
         if hit:
+            # the hit program at slots x width, the miss's one chunk
             assert sorted(shapes[1:]) == [("hit", (4, 16)),
-                                          ("miss", (4, 32))]
+                                          ("miss", (2, 32))]
             assert second == {"rows": 2, "prompt_tokens": 3 + 20,
-                              "positions": 4 * 16 + 4 * 32}
+                              "positions": 4 * 16 + 2 * 32}
             want = {"prompt": 12 + 3 + 20}
-            want["padding"] = 64 + 64 + 128 - want["prompt"]
+            want["padding"] = 32 + 64 + 64 - want["prompt"]
         else:
-            assert shapes[1:] == [("miss", (4, 32))]
+            # two live rows of the 32 bucket: one chunk of two rows
+            assert shapes[1:] == [("miss", (2, 32))]
             assert second == {"rows": 2, "prompt_tokens": 11 + 20,
-                              "positions": 4 * 32}
+                              "positions": 2 * 32}
             want = {"prompt": 12 + 11 + 20}
-            want["padding"] = 64 + 128 - want["prompt"]
+            want["padding"] = 32 + 64 - want["prompt"]
     counted = {m["labels"]["state"]: m["value"] for m in reg.collect()
                if m["name"] == "serving.admit_positions_total"}
     assert counted == want

@@ -70,6 +70,37 @@ def test_paged_matches_solo_decode(model_and_params, page_block):
     assert 0 < b.pool.peak_pages_used <= b.pool.capacity_pages
 
 
+@pytest.mark.parametrize("fills", [1, 2, 4], ids=["one", "two", "all"])
+def test_admission_walk_matches_paged_greedy(fills, monkeypatch):
+    """ONE admission that fills 1, 2 and all of 4 slots, two rows a chunk
+    of the walk (``prefill_live_rows``: a chunk filled up by a dead row,
+    one whole chunk, two chunks): first tokens, pages and the segments
+    after them are the model's solo paged decode."""
+    from paddle_tpu.models import TransformerLM, transformer
+    from paddle_tpu.serving.paged import PagePool
+    monkeypatch.setattr(transformer, "LM_PREFILL_TOKENS", 32)
+    # a model of its own: the programs are traced here, at this chunk
+    model = TransformerLM(VOCAB, d_model=D, n_heads=H, n_layers=L,
+                          max_len=MAX_LEN)
+    params = model.init(jax.random.PRNGKey(0))
+    pool = PagePool(model, params, slots=4, segment=4, page_block=8,
+                    cache_bucket=32, prompt_buckets=(16, 32))
+    rs = np.random.RandomState(7)
+    prompts = [rs.randint(0, VOCAB, n).astype(np.int32)
+               for n in (5, 13, 16, 9)[:fills]]
+    slots = [2, 0, 3, 1][:fills]             # not the first rows of the pool
+    first = pool.admit([(s, pool.plan_admission(p, 12))
+                        for s, p in zip(slots, prompts)])
+    assert pool.last_stats["positions"] == -(-fills // 2) * 2 * 16
+    blocks = [pool.run_segment(slots) for _ in range(3)]
+    for s, prompt in zip(slots, prompts):
+        toks = np.concatenate([b[s] for b in blocks])
+        assert toks[0] == first[s]      # a segment re-emits the current one
+        solo = np.asarray(transformer.paged_greedy(
+            model, params, jnp.asarray(prompt)[None], 12, 8))[0]
+        np.testing.assert_array_equal(solo[prompt.size:], toks)
+
+
 @pytest.mark.parametrize("case", ["defaults", "refusals"])
 def test_pool_defaults_and_grid_refusals(model_and_params, case):
     """The pool's geometry comes from its signature: page_block 64,

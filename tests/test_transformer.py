@@ -272,10 +272,98 @@ def test_bucketed_cached_decode_matches_unbucketed():
         model.generate_cached(params, prompt, steps=30, bucket=8)
 
 
-def test_prefill_logits_match_forward():
+def _forward_kv(model, params, ids):
+    """The full forward: (logits [B, T, V], every layer's k, v)."""
+    x = model.embed(params["embed"], ids)
+    x = x + params["pos_embed"][:ids.shape[1]].astype(x.dtype)
+    kv = []
+    for i in range(len(model.blocks)):
+        x, (k, v) = model.blocks[i](params[f"blocks_{i}"], x, return_kv=True)
+        kv += [k, v]
+    x = model.ln_f(params["ln_f"], x)
+    return x @ params["embed"]["w"].T, kv
+
+
+#: lengths of a [6, 8] ragged batch, two rows a chunk (16 tokens): four
+#: live rows walk two whole chunks; three walk two, the second filled up
+#: by a dead row; the other dead rows never run
+RAGGED = {"chunks": [5, 0, 8, 3, 7, 0], "dead-fill": [5, 0, 8, 0, 3, 0]}
+
+
+@pytest.mark.parametrize("case", [
+    "whole", "none-is-full-lengths",
+    *(f"{kv}-{name}" for kv in ("f32", "int8") for name in RAGGED)])
+def test_prefill_logits_match_forward(case, monkeypatch):
+    """``prefill`` against the full forward: last logits, and on the rows
+    that hold a prompt the cache rows too, however the walk chunks them
+    (models/transformer.py prefill_live_rows); rows of length 0 among
+    them keep the cache's fill and are left out of the depth but for
+    those that fill up the last live chunk."""
+    from paddle_tpu.models import transformer
+    from paddle_tpu.ops import pallas_kernels as pk
     model, params = _model(max_len=32)
-    prompt = jax.random.randint(jax.random.PRNGKey(10), (2, 7), 0, V)
-    _, last = model.prefill(params, prompt)
-    full = model(params, prompt)
-    np.testing.assert_allclose(np.asarray(last), np.asarray(full[:, -1]),
-                               rtol=1e-5, atol=1e-5)
+    if case == "whole":
+        prompt = jax.random.randint(jax.random.PRNGKey(10), (2, 7), 0, V)
+        _, last = model.prefill(params, prompt)
+        full = model(params, prompt)
+        np.testing.assert_allclose(np.asarray(last), np.asarray(full[:, -1]),
+                                   rtol=1e-5, atol=1e-5)
+        return
+    monkeypatch.setattr(transformer, "LM_PREFILL_TOKENS", 16)
+    # that many tokens of rows a chunk, and never one row alone
+    assert [model.prefill_chunk_tokens(w) for w in (4, 8, 16)] == [16, 16, 32]
+    prompt = jax.random.randint(jax.random.PRNGKey(10), (6, 8), 0, V)
+    if case == "none-is-full-lengths":
+        # every row live: three chunks of two rows
+        assert transformer.live_row_walk(6, 8, 16, 6) == (2, 3)
+        cell, last = model.prefill(params, prompt)
+        ragged, last_r = model.prefill(params, prompt, jnp.full((6,), 8))
+        np.testing.assert_array_equal(last, last_r)
+        for nm in cell:
+            np.testing.assert_array_equal(cell[nm], ragged[nm])
+        np.testing.assert_allclose(last, model(params, prompt)[:, -1],
+                                   rtol=1e-5, atol=1e-5)
+        return
+    kv, name = case.split("-", 1)
+    lens = np.asarray(RAGGED[name], np.int32)
+    live = lens > 0
+    assert transformer.live_row_walk(6, 8, 16, int(live.sum())) == (2, 2)
+    assert model.prefill_positions(6, 8, int(live.sum())) == 2 * 2 * 8
+    cell, last = model.prefill(params, prompt, jnp.asarray(lens),
+                               kv_dtype=None if kv == "f32" else "int8",
+                               pad_to=16)
+    logits, rows = _forward_kv(model, params, prompt)
+    np.testing.assert_array_equal(cell["pos"], lens)
+    for b in np.flatnonzero(live):
+        np.testing.assert_allclose(last[b], logits[b, lens[b] - 1],
+                                   rtol=1e-5, atol=1e-5)
+    # dead rows the walk never ran hold nothing; those it ran (to fill up
+    # its last chunk) hold a pad token's keys
+    never = [b for b in np.flatnonzero(~live)
+             if not np.asarray(cell["k0"])[b].any()]
+    for i in range(L):
+        for nm, want in ((f"k{i}", rows[2 * i]), (f"v{i}", rows[2 * i + 1])):
+            got = np.asarray(cell[nm])
+            assert got.shape == (6, 16, H, D // H)
+            # the pad and the rows that never ran: the pool's fill
+            assert not got[:, 8:].any() and not got[never].any()
+            if kv == "int8":
+                q8, scale = pk.quantize_kv(want)
+                scales = np.asarray(cell[nm + "_scale"])
+                assert scales.shape == (6, 16, H)
+                assert (scales[:, 8:] == 1.0).all()
+                assert (scales[never] == 1.0).all()
+                for b in np.flatnonzero(live):
+                    n = lens[b]
+                    np.testing.assert_allclose(scales[b, :n], scale[b, :n],
+                                               rtol=1e-5)
+                    assert np.abs(got[b, :n].astype(np.int32)
+                                  - np.asarray(q8[b, :n], np.int32)).max() <= 1
+            else:
+                for b in np.flatnonzero(live):
+                    np.testing.assert_allclose(got[b, :lens[b]],
+                                               want[b, :lens[b]],
+                                               rtol=1e-5, atol=1e-5)
+    ran = int((~live).sum()) - len(never)
+    # the dead rows the walk ran are those that filled up its last chunk
+    assert ran == 2 * 2 - int(live.sum())
