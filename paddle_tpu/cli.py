@@ -1314,7 +1314,7 @@ def cmd_serve(args):
     test mode: the same flags + seed reproduce the exact weights).
 
     What a ``--config`` model must offer the page pool (``TransformerLM``,
-    ``DeepseekV3LM``, ``Lfm2MoeLM`` and ``NemotronHLM`` do; serving/paged.py
+    ``DeepseekV3LM``, ``Lfm2MoeLM``, ``NemotronHLM`` and ``AfmoeLM`` do; serving/paged.py
     names no cache array itself): ``max_len``; ``cache_rows(params, kv_dtype)`` — its state
     as it states it, raising ValueError for a ``kv_dtype`` it has no cache
     for: ``CacheRow`` (name, trailing shape, dtype, fill) for what lives in
@@ -1333,7 +1333,7 @@ def cmd_serve(args):
     ``prefill_positions(n_rows, width, n_live)`` — the positions ``prefill``
     runs through the depth for ``[n_rows, width]`` prompts of which
     ``n_live`` hold one, from the walk it runs (``models.transformer
-    .LiveRowPrefill`` for a model on ``prefill_live_rows``, as all four
+    .LiveRowPrefill`` for a model on ``prefill_live_rows``, as all five
     served models are: the pool hands an admission its whole width, the
     model walks the rows that hold a prompt): the admission's account on
     the ``serving.prefill`` span (the prefix-hit program, ``prefill_paged``,
@@ -1347,6 +1347,14 @@ def cmd_serve(args):
     WRITTEN at the rows that hold a prompt and untouched elsewhere, and
     the segment program resets dead slots' rows by a scatter at those
     slots alone, so no program holds a second copy of them. Optional:
+    ``CacheRow(..., window=W)`` — a row read only W positions back
+    (AfmoeLM's sliding layers): the pool keeps it in a ring a slot that
+    stops growing, ``prefill`` then also takes ``pools=`` and ``write=``
+    (the donated pools and the pool's scatter: every chunk's rows go
+    straight into the pages and no cell holds keys and values),
+    ``decode_step_paged`` also takes ``ring_tables=``, the model states
+    ``window_read_layers`` beside ``paged_read_layers``, and such a model
+    needs ``--no_prefix_cache`` (docs/design/serving.md). Optional:
     ``program_stats_zero()``
     / ``note_program_stats(stats, program)`` for counts a program returns
     beside its tokens (they land on the ``serving.prefill`` and

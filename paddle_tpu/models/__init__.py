@@ -1,6 +1,7 @@
 """Model zoo — mirrors the reference's demo/benchmark/book model families
 (SURVEY.md §2.4 v1_api_demo + benchmark/paddle + fluid/tests/book)."""
 
+from .afmoe import AfmoeLM
 from .deepseek_v3 import DeepseekV3LM
 from .embeddings import DeepFM, Recommender, Word2Vec
 from .generative import GAN, VAE
@@ -21,5 +22,5 @@ __all__ = [
            "AttentionSeq2Seq", "LinearCRFTagger", "BiLSTMCRFTagger",
            "Word2Vec", "Recommender", "DeepFM", "GAN", "VAE",
            "TransformerLM", "TransformerBlock", "DeepseekV3LM", "Lfm2MoeLM",
-           "NemotronHLM",
+           "NemotronHLM", "AfmoeLM",
            "TransformerSeq2Seq", "CrossAttentionBlock"]

@@ -43,18 +43,11 @@ from .. import nn
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
 from ..parallel.expert_share import ExpertShare, ProgramStats
-from .transformer import (PREFILL_TOKENS, CacheRow, LiveRowPrefill, SlotRow,
-                          paged_greedy, prefill_live_rows)
+from .transformer import (PREFILL_TOKENS, SOLO_ROW_TOKENS, CacheRow,
+                          LiveRowPrefill, SlotRow, paged_greedy,
+                          prefill_live_rows)
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
-
-#: prompt rows of at least this many tokens are admitted ONE a chunk; under
-#: it a chunk fills ``PREFILL_TOKENS`` with rows. A chunk of TWO rows of 1,024
-#: never returns on a v5e from 13 layers on (6 layers: it does), with the
-#: chunked scan or the flash kernel on their dense routes just the same;
-#: 8 x 256, 4 x 512, 1 x 1,024 and 1 x 2,048 return at all 52 layers. The
-#: cause is not found (PERF.md sections 6 and 7, PR 35).
-SOLO_ROW_TOKENS = 1024
 
 
 def _dot(x, w):

@@ -28,11 +28,19 @@ from ..ops import pallas_kernels as pk
 class CacheRow(NamedTuple):
     """One array of a model's per-layer cache as it states it to the page
     pool (serving/paged.py), which allocates ``[pages, page_block, *shape]``
-    of ``dtype`` filled with ``fill`` and never names an array itself."""
+    of ``dtype`` filled with ``fill`` and never names an array itself.
+
+    ``window``: the row's REACH — a layer that reads only the last
+    ``window`` positions states it, and the pool keeps such a row in a RING
+    of its slot's own (``[slots x ring + 1, page_block, *shape]``, position
+    p in ring entry ``(p // page_block) % ring``) that stops growing with
+    the context; ``None``: every position is read for as long as the
+    request lives, and the row's pages grow with it."""
     name: str
     shape: tuple
     dtype: object
     fill: float = 0.0
+    window: Optional[int] = None
 
 
 class SlotRow(NamedTuple):
@@ -52,6 +60,14 @@ class SlotRow(NamedTuple):
 
 #: tokens a chunked prefill runs through the depth at once (rows x width)
 PREFILL_TOKENS = 2048
+#: prompt rows of at least this many tokens are admitted ONE a chunk by the
+#: deep stacks (NemotronHLM, AfmoeLM); under it a chunk fills
+#: ``PREFILL_TOKENS`` with rows. A chunk of TWO rows of 1,024 never returns on
+#: a v5e from 13 of NemotronHLM's layers on (6 layers: it does), with the
+#: chunked scan or the flash kernel on their dense routes just the same; 8 x
+#: 256, 4 x 512, 1 x 1,024 and 1 x 2,048 return at all 52. The cause is not
+#: found (PERF.md sections 6 and 7, PR 35).
+SOLO_ROW_TOKENS = 1024
 #: the same for ``TransformerLM``, whose blocks are dense: chosen on the chip
 #: at the GPT-2 serve cells' shapes (PERF.md section 6, PR 38)
 LM_PREFILL_TOKENS = 512
@@ -71,7 +87,7 @@ def live_row_walk(n_rows, width, chunk_tokens, n_live):
 
 
 def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
-                      chunk_tokens, in_place=()):
+                      chunk_tokens, in_place=(), write=None):
     """The admission walk ``DeepseekV3LM`` and ``Lfm2MoeLM`` share. Rows
     are independent of one another, so the depth runs a few rows at a time
     (``chunk_tokens``, the caller's ``PREFILL_TOKENS``): what a chunk
@@ -92,7 +108,11 @@ def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
     whose buffers are NOT fresh zeros but somebody's live arrays (the
     pool's per-slot rows, ``NemotronHLM.prefill``): a chunk writes them
     at the rows that hold a prompt and nowhere else — the rows of length
-    0 that fill up the last chunk keep what they hold."""
+    0 that fill up the last chunk keep what they hold. ``write(state, idx,
+    n, new) -> state``: the caller's own way of putting a chunk's ``new``
+    (rows ``idx`` of lengths ``n``) into ``state`` — the page pool's
+    scatter into its pages, so that no ``[B, T0, ...]`` buffer of every
+    row's keys and values stands between a chunk and the pool."""
     B, T0 = prompt.shape
     R, n_chunks = live_row_walk(B, T0, chunk_tokens,
                                 jnp.sum(pos > 0, dtype=jnp.int32))
@@ -104,7 +124,9 @@ def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
         n = pos[idx]
         h, new, st = sequence(prompt[idx], n)
         last = last.at[idx].set(h[jnp.arange(R), n - 1])
-        if in_place:
+        if write is not None:
+            state = write(state, idx, n, new)
+        elif in_place:
             held = jnp.where(n > 0, idx, B)         # B: dropped
             state = {k: (buf.at[held].set(new[k], mode="drop")
                          if k in in_place else buf.at[idx].set(new[k]))

@@ -173,7 +173,11 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                                                    "of a call: state=visited "
                                                    "is what the kernel walks "
                                                    "(causal: the pairs at or "
-                                                   "below the diagonal), "
+                                                   "below the diagonal; "
+                                                   "kernel=flash_window_"
+                                                   "attention_fwd: the band "
+                                                   "a window leaves of "
+                                                   "them), "
                                                    "state=grid the whole "
                                                    "square; counted once per "
                                                    "TRACE of a call, labels: "
@@ -433,6 +437,19 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                  "e.g. Lfm2MoeLM's convolution tails, NemotronHLM's "
                  "recurrent carry: 1.57 GB at 32 slots), all slots; 0 for "
                  "a model that states none"),
+    "serving.ring_bytes_held": (
+        "gauge", "bytes of the pool's RINGS: the cache rows that state a "
+                 "reach (CacheRow(window=): AfmoeLM's sliding layers), "
+                 "slots x ring + 1 pages of every such row; not set for a "
+                 "model that states none"),
+    "serving.cache_rows_read_total": (
+        "counter", "cache rows a layer's decode read COVERED over a "
+                   "segment's live slots and steps, of a model with both "
+                   "kinds of layer: kind=window min(pos + 1, window) a "
+                   "step, kind=full pos + 1; one layer's (x the layers of "
+                   "a kind for a step's); the serving.segment span "
+                   "carries the same as window_rows / full_rows, labels: "
+                   "kind", ("kind",)),
     "serving.slot_state_writes_total": (
         "counter", "admitted slots whose per-slot rows an admit program "
                    "wrote (one per request admitted by prefill; 0 for a "

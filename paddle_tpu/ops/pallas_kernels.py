@@ -77,21 +77,26 @@ def _interpret(interpret: Optional[bool]) -> bool:
 # a fixed part, and 512 streamed rows keep the MXU's weights busy.
 
 
-def _mask_tile(s, q_pos, k_start, kv_len, causal: bool, whole: bool):
+def _mask_tile(s, q_pos, k_start, kv_len, causal: bool, whole: bool,
+               window: Optional[int] = None):
     """Scores of one tile with its masked pairs at _NEG: keys at / past
     kv_len (padded, over-length) and, causally, keys past the row.
     ``q_pos`` [block_q, 1]; the tile's keys start at ``k_start``. A causal
     tile wider than it is tall ends on the diagonal, so only the columns
     of its last q-block can hold a pair past it (and, S == T, a padded key
     a real row sees): unless ``whole`` (kv_lens given), only those are
-    touched."""
+    touched. ``window``: the tile meets the band's LEFT edge — keys at
+    ``q_pos - window`` and before are masked too, in any column."""
     block_q, width = s.shape
-    skip = (width - 1) // block_q * block_q if causal and not whole else 0
+    skip = (width - 1) // block_q * block_q \
+        if causal and not whole and window is None else 0
     k_pos = k_start + skip + jax.lax.broadcasted_iota(
         jnp.int32, (1, width - skip), 1)
     valid = k_pos < kv_len
     if causal:
         valid = valid & (q_pos >= k_pos)
+    if window is not None:
+        valid = valid & (k_pos > q_pos - window)
     if not skip:
         return jnp.where(valid, s, _NEG)
     return jnp.concatenate(
@@ -104,11 +109,13 @@ def _diag_per_block(block_q: int, block_k: int) -> int:
 
 
 def _visited_units(n_q: int, n_k: int, block_q: int, block_k: int,
-                   causal: bool, by_k: bool) -> Tuple[int, int]:
+                   causal: bool, by_k: bool,
+                   window: Optional[int] = None) -> Tuple[int, int]:
     """(visited, grid) area of one batch x head square in units of the
     smaller block side squared (rounded up), for the forward / dq walk or
     (``by_k``) the dk/dv walk — the arithmetic the kernels do on traced
-    indices."""
+    indices. ``window`` (the forward walk alone): the k-blocks wholly left
+    of the band are not visited either."""
     u = min(block_q, block_k)
     grid = n_q * block_q * n_k * block_k
     if not causal:
@@ -122,19 +129,33 @@ def _visited_units(n_q: int, n_k: int, block_q: int, block_k: int,
                        * block_q * block_k + block_q * sum(widths)
                        for ki in range(n_k))
         else:
-            area = sum(((qi * block_q) // block_k) * block_k * block_q
-                       + block_q * widths[qi % r] for qi in range(n_q))
+            left = [0 if window is None or n_k == 1 else
+                    max(qi * block_q - window + 1, 0) // block_k
+                    for qi in range(n_q)]
+            area = sum(((qi * block_q) // block_k - left[qi]) * block_k
+                       * block_q + block_q * widths[qi % r]
+                       for qi in range(n_q))
     return -(-area // (u * u)), -(-grid // (u * u))
 
 
 def _walk_k(tile, finish, carry, qi, block_q: int, block_k: int, n_k: int,
-            kv_len, causal: bool, has_lens: bool) -> None:
+            kv_len, causal: bool, has_lens: bool,
+            window: Optional[int] = None) -> None:
     """The forward / dq walk over one q-block's keys (above): fold
     ``tile(carry, start, width, masked)`` over the tiles it visits and hand
-    the result to ``finish`` (which writes the program's outputs)."""
-    def blocks(hi, masked, carry):
+    the result to ``finish`` (which writes the program's outputs).
+
+    ``window`` (causal, the forward alone): row p sees the keys ``p -
+    window < j <= p``, a BAND. The walk starts at the k-block that holds
+    the first key the q-block's first row sees, not at block 0; the blocks
+    the band's left edge crosses are masked on the left (``tile(...,
+    masked="left")``), those between them and the diagonal stay clear, and
+    the diagonal tile is masked on the left only where a window narrower
+    than block_q + block_k can reach into it (static)."""
+    def blocks(hi, masked, carry, lo=0):
         return jax.lax.fori_loop(
-            0, hi, lambda ki, c: tile(c, ki * block_k, block_k, masked), carry)
+            lo, hi, lambda ki, c: tile(c, ki * block_k, block_k, masked),
+            carry)
 
     # k-blocks wholly at / past kv_len are masked entirely: not visited
     by_len = (kv_len + block_k - 1) // block_k
@@ -147,22 +168,37 @@ def _walk_k(tile, finish, carry, qi, block_q: int, block_k: int, n_k: int,
     if n_k > 1:
         clear = (qi * block_q) // block_k
         start = clear * block_k
+        lo = 0
+        if window is not None:
+            # [lo, inside): the blocks the band's left edge crosses —
+            # block ki lies wholly inside the band from ki * block_k >=
+            # (qi + 1) * block_q - window on
+            lo = jnp.maximum(qi * block_q - window + 1, 0) // block_k
+            inside = jnp.minimum(jnp.maximum(
+                (qi + 1) * block_q - window + block_k - 1, 0) // block_k,
+                clear)
+            carry = blocks(jnp.minimum(inside, by_len) if has_lens
+                           else inside, "left", carry, lo)
+            lo = inside
         # a clear block holds no key past the diagonal; with kv_lens it may
         # hold one past the sample's length, so those calls mask throughout
         carry = blocks(jnp.minimum(clear, by_len) if has_lens else clear,
-                       has_lens, carry)
+                       has_lens, carry, lo)
+    diagonal = "left" if window is not None \
+        and window < block_q + block_k else True
     if r == 1:
-        finish(tile(carry, start, block_q, True))
+        finish(tile(carry, start, block_q, diagonal))
         return
     for c in range(r):
         @pl.when(jax.lax.rem(qi, r) == c)
         def _diagonal(width=min((c + 1) * block_q, block_k)):
-            finish(tile(carry, start, width, True))
+            finish(tile(carry, start, width, diagonal))
 
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
                    block_k: int, scale: float, causal: bool, seq_len: int,
-                   true_len: int, has_lens: bool):
+                   true_len: int, has_lens: bool,
+                   window: Optional[int] = None):
     """One (batch*head, q-block) program: stream KV tiles, online softmax.
 
     q_ref: [1, block_q, D]; k_ref/v_ref: [1, T, D]; o_ref: [1, block_q, D];
@@ -177,14 +213,15 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
     kv_len = jnp.minimum(len_ref[0, 0, 0], true_len)
 
-    def tile(carry, start, width: int, masked: bool):
+    def tile(carry, start, width: int, masked):
         acc, m, l = carry
         k = k_ref[0, pl.ds(start, width), :]
         v = v_ref[0, pl.ds(start, width), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if masked:
-            s = _mask_tile(s, q_pos, start, kv_len, causal, has_lens)
+            s = _mask_tile(s, q_pos, start, kv_len, causal, has_lens,
+                           window if masked == "left" else None)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -204,7 +241,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
              jnp.full((block_q, 1), _NEG, jnp.float32),
              jnp.zeros((block_q, 1), jnp.float32))
     _walk_k(tile, finish, carry, qi, block_q, block_k, seq_len // block_k,
-            kv_len, causal, has_lens)
+            kv_len, causal, has_lens, window)
 
 
 # ---------------------------------------------------------------------------
@@ -393,7 +430,8 @@ def _lens_to_bh(kv_lens, B, H, S):
 
 
 def _count_block_pairs(kernel: str, bh: int, n_q: int, n_k: int, blk_q: int,
-                       blk_k: int, causal: bool, by_k: bool = False) -> None:
+                       blk_k: int, causal: bool, by_k: bool = False,
+                       window: Optional[int] = None) -> None:
     """``kernels.flash_block_pairs_total``: the area of the square one
     traced call's kernel walks (state=visited) against the whole square
     (state=grid), over all batch x head squares, in block pairs of the
@@ -401,20 +439,25 @@ def _count_block_pairs(kernel: str, bh: int, n_q: int, n_k: int, blk_q: int,
     TRACE, as ``kernels.routes_total`` is. The causal structure alone: what
     ``kv_lens`` skips besides depends on data and is not counted."""
     from .. import obs
-    visited, grid = _visited_units(n_q, n_k, blk_q, blk_k, causal, by_k)
+    visited, grid = _visited_units(n_q, n_k, blk_q, blk_k, causal, by_k,
+                                   window)
     obs.count("kernels.flash_block_pairs_total", bh * visited,
               kernel=kernel, state="visited")
     obs.count("kernels.flash_block_pairs_total", bh * grid,
               kernel=kernel, state="grid")
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7),
+                   static_argnames=("window",))
 def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
-                 kv_lens=None):
+                 kv_lens=None, window=None):
     """Returns (o [B,T,H,D], lse [B,T,H] f32). k/v may be shorter or longer
     than q (S != T) for cross-attention-shaped blocks; ``causal`` assumes
     S == T. ``kv_lens`` [B] masks each sample's keys past its true length
-    (variable-length batches / cross-attention over padded sources)."""
+    (variable-length batches / cross-attention over padded sources).
+    ``window``: the banded walk (:func:`_walk_k`), under a custom-call name
+    of its own, ``flash_window_attention_fwd`` — a trace, and the counters,
+    tell a band from a causal square."""
     B, T, H, D = q.shape
     S = k.shape[1]
     # grouped-query heads: k/v [B, S, Hkv, D]; program bh = b * H + h reads
@@ -426,12 +469,14 @@ def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
     blk_q, blk_k, Tp, Sp = _blocks(T, S, block_q, block_k, causal)
     qb, kb, vb = _to_bh(q, Tp), _to_bh(k, Sp), _to_bh(v, Sp)
     lensb = _lens_to_bh(kv_lens, B, H, S)
+    name = "flash_attention_fwd" if window is None \
+        else "flash_window_attention_fwd"
     kernel = functools.partial(_fa_fwd_kernel, block_k=blk_k, scale=scale,
                                causal=causal, seq_len=Sp, true_len=S,
-                               has_lens=kv_lens is not None)
+                               has_lens=kv_lens is not None, window=window)
     n_q, n_k = Tp // blk_q, Sp // blk_k
-    _count_block_pairs("flash_attention_fwd", B * H, n_q, n_k, blk_q, blk_k,
-                       causal)
+    _count_block_pairs(name, B * H, n_q, n_k, blk_q, blk_k, causal,
+                       window=window)
     grid = (B * H, n_q)
     out, lse = pl.pallas_call(
         kernel,
@@ -451,7 +496,7 @@ def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((B * H, Tp, 1), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_attention_fwd",
+        name=name,
     )(qb, kb, vb, lensb)
     o = _from_bh(out, B, T, H, D)
     lse = jnp.moveaxis(lse[:, :T, 0].reshape(B, H, T), 1, 2)
@@ -579,9 +624,10 @@ def decode_route(L: int, route: Optional[str] = None) -> str:
     return "kernel" if _on_tpu() and L >= SHORT_SEQ_DENSE else "dense"
 
 
-def _dense_attention(q, k, v, causal, scale, kv_lens):
+def _dense_attention(q, k, v, causal, scale, kv_lens, window=None):
     """Masked dense attention for short sequences — same semantics as the
-    flash kernels (causal + per-sample kv_lens), ordinary autodiff."""
+    flash kernels (causal + per-sample kv_lens, the band of ``window``),
+    ordinary autodiff."""
     T, S = q.shape[1], k.shape[1]
     if k.shape[2] != q.shape[2]:        # grouped-query heads: h reads h // G
         k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
@@ -594,15 +640,39 @@ def _dense_attention(q, k, v, causal, scale, kv_lens):
         s = jnp.where(ok, s, _NEG)
     if causal:
         mask = jnp.tril(jnp.ones((T, S), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((T, S), bool), -window)
         s = jnp.where(mask[None, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhts,bshd->bthd", p,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash_window(q, k, v, kv_lens, static):
+    """The banded forward (``static``: window, scale, blocks, interpret;
+    blocks None: the dense route). A custom_vjp only so that a gradient
+    is refused in words, before any kernel is differentiated."""
+    window, scale, block_q, block_k, interpret = static
+    if block_q is None:
+        return _dense_attention(q, k, v, True, scale, kv_lens, window)
+    return _fa_fwd_call(q, k, v, True, scale, block_q, block_k, interpret,
+                        kv_lens=kv_lens, window=window)[0]
+
+
+def _flash_window_bwd(static, res, g):
+    raise NotImplementedError(
+        "flash_attention(window=...) is forward-only: the backward kernels "
+        "walk no band")
+
+
+_flash_window.defvjp(lambda *a: (_flash_window(*a), None), _flash_window_bwd)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, scale: Optional[float] = None,
                     kv_lens: Optional[jax.Array] = None,
+                    window: Optional[int] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
@@ -623,6 +693,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     all three kernels visit only what lies at or below the diagonal (the
     walk is described above ``_mask_tile``).
 
+    ``window`` (with ``causal``): sliding-window attention — row p sees the
+    keys ``p - window < j <= p``. The forward kernel walks the BAND: a
+    q-block starts at the k-block that holds its first row's first key and
+    masks the blocks the band's left edge crosses on the left
+    (:func:`_walk_k`); its custom call is named
+    ``flash_window_attention_fwd``. Forward only — the backward kernels
+    walk no band, and differentiating such a call raises.
+
     Short sequences (max(T, S) < SHORT_SEQ_DENSE, no explicit blocks given)
     auto-route to a masked dense einsum: below that point the kernels'
     per-program overhead exceeds their HBM saving (measured — the NMT
@@ -639,17 +717,28 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # incoming cotangent, so no gradient reaches any key of theirs.
         valid = (kv_lens > 0)
         kv_lens = jnp.maximum(kv_lens, 1)
-    if (block_q is None and block_k is None
-            and max(q.shape[1], k.shape[1]) < SHORT_SEQ_DENSE):
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window is a causal band "
+                         "(causal=True)")
+    if q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"flash_attention: {q.shape[2]} query heads are not "
+            f"whole groups over {k.shape[2]} KV heads")
+    dense = (block_q is None and block_k is None
+             and max(q.shape[1], k.shape[1]) < SHORT_SEQ_DENSE)
+    if window is not None:
+        from .. import obs
+        obs.count("kernels.routes_total", kernel="flash_window_attention_fwd",
+                  route="dense" if dense else "kernel")
+        blocks = (None, None) if dense else _default_blocks(block_q, block_k)
+        o = _flash_window(q, k, v, kv_lens, (window, scale_v) + blocks
+                          + (_interpret(interpret),))
+    elif dense:
         o = _dense_attention(q, k, v, causal, scale_v, kv_lens)
     else:
         block_q, block_k = _default_blocks(block_q, block_k)
         interpret = _interpret(interpret)
         if k.shape[2] != q.shape[2]:
-            if q.shape[2] % k.shape[2]:
-                raise ValueError(
-                    f"flash_attention: {q.shape[2]} query heads are not "
-                    f"whole groups over {k.shape[2]} KV heads")
             o, _ = _fa_fwd_call(q, k, v, causal, scale_v, block_q, block_k,
                                 interpret, kv_lens=kv_lens)
         else:
@@ -833,7 +922,8 @@ def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
 
 def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
                                 q_ref, *refs, scale: float, chunk: int,
-                                quantized: bool, groups: int):
+                                quantized: bool, groups: int,
+                                window: Optional[int] = None):
     """One program of the PAGED read over grouped-query heads: H query
     heads over Hkv = H // ``groups`` KV heads, query head h reading KV head
     ``h // groups``. A sibling of :func:`_decode_attn_kernel` with the
@@ -852,6 +942,13 @@ def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
     more product against V's matrix accumulates every head's values. The
     MXU does Hkv times the flops the groups need and has them idle.
 
+    ``window``: the read of a sliding-window layer through a RING
+    (:func:`paged_work_list` with the same window). A slot's programs then
+    start at the page that holds position ``pos - window + 1``, not at page
+    0: ``ord_ref`` holds each page's ABSOLUTE number, the first of a slot
+    (where the running softmax starts) is worked out from ``pos``, and rows
+    at ``pos - window`` and before are masked like rows past ``pos``.
+
     Precision: bf16 pools go to the MXU as they are, and the f32 operands
     beside them (q, the softmax weights) as the sum of two bf16 halves,
     hi + lo, so a product keeps ~16 bits of them; f32 and int8 pools
@@ -865,14 +962,15 @@ def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
     H, D = q_ref.shape[1:]
     Hkv = H // groups
     R = chunk * Hkv
+    pos = pos_ref[b]
+    first = 0 if window is None else \
+        jnp.maximum(pos - window + 1, 0) // chunk
 
-    @pl.when(c == 0)
+    @pl.when(c == first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[b]
 
     @pl.when(c * chunk <= pos)           # a dead page adds exactly nothing
     def _live():
@@ -903,12 +1001,14 @@ def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
         s = mxu(q, k, ((1,), (1,)))                         # [H, R]
         col = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
         head = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // groups
-        s = jnp.where((c * chunk + col // Hkv <= pos) & (col % Hkv == head),
-                      s, _NEG)
+        row = c * chunk + col // Hkv
+        seen = row <= pos if window is None else \
+            (row <= pos) & (row > pos - window)
+        s = jnp.where(seen & (col % Hkv == head), s, _NEG)
         m_prev = m_ref[...]                                 # [H, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)          # row 0 of a live page is every
-        corr = jnp.exp(m_prev - m_new)  # head's: m_new is a real score
+        p = jnp.exp(s - m_new)          # a live page holds a row every head
+        corr = jnp.exp(m_prev - m_new)  # sees: m_new is a real score
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + mxu(p, v, ((1,), (0,)))
         m_ref[...] = m_new
@@ -920,7 +1020,7 @@ def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
 
 def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, qo_spec, kv_spec,
                       sc_spec, *, grid, scale, chunk, interpret, name,
-                      groups=1):
+                      groups=1, window=None):
     """The one pallas_call behind decode_attention and
     paged_decode_attention: ``prefetch`` scalars (pos last; five of them
     = the paged work list), then q [B, H, D], then k/v — each followed by
@@ -943,7 +1043,7 @@ def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, qo_spec, kv_spec,
     if groups > 1:
         kernel = functools.partial(_grouped_decode_attn_kernel, scale=scale,
                                    chunk=chunk, quantized=k_scale is not None,
-                                   groups=groups)
+                                   groups=groups, window=window)
     else:
         kernel = functools.partial(_decode_attn_kernel, scale=scale,
                                    chunk=chunk, quantized=k_scale is not None,
@@ -970,12 +1070,16 @@ def quantize_kv(x: jax.Array):
     return q, scale
 
 
-def _dense_decode_attention(q, k, v, pos, scale, k_scale, v_scale):
+def _dense_decode_attention(q, k, v, pos, scale, k_scale, v_scale,
+                            window=None):
     """Reference-math route (short caches / off-TPU): same masked-softmax
     formulation as the kernel, ordinary XLA ops. Quantized caches
     dequantize up front — numerically the kernel's contract, but the f32
     cache materializes, so this route only makes sense where the cache is
-    small anyway."""
+    small anyway. ``window`` = (window, ring): the rows are a RING's,
+    gathered in the ring's order — entry e holds the newest page ``a <=
+    pos // bs`` with ``a % ring == e`` — and the rows of ``(pos - window,
+    pos]`` are live."""
     if k_scale is not None:
         k = k.astype(jnp.float32) * k_scale[..., None]
         v = v.astype(jnp.float32) * v_scale[..., None]
@@ -985,7 +1089,15 @@ def _dense_decode_attention(q, k, v, pos, scale, k_scale, v_scale):
         v = jnp.repeat(v, q.shape[1] // v.shape[2], axis=2)
     s = jnp.einsum("bhd,bjhd->bhj", q.astype(jnp.float32) * scale,
                    k.astype(jnp.float32))
-    valid = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, :]
+    if window is None:
+        valid = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, :]
+    else:
+        ring, bs = window[1], L // window[1]
+        entry, row = jnp.arange(L) // bs, jnp.arange(L) % bs
+        top = (pos // bs)[:, None]
+        j = (top - (top - entry[None, :]) % ring) * bs + row[None, :]
+        valid = ((j >= 0) & (j <= pos[:, None])
+                 & (j > pos[:, None] - window[0]))[:, None, :]
     s = jnp.where(valid, s, _NEG)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -1064,7 +1176,8 @@ def gather_pages(pool: jax.Array, tables: jax.Array) -> jax.Array:
     return g.reshape((B, NB * pool.shape[1]) + pool.shape[2:])
 
 
-def paged_work_list(tables: jax.Array, pos: jax.Array, page_block: int):
+def paged_work_list(tables: jax.Array, pos: jax.Array, page_block: int,
+                    window: Optional[int] = None):
     """The paged read's launch geometry: the live (slot, page) pairs of a
     block table, slot by slot with each slot's pages in order.
 
@@ -1075,18 +1188,43 @@ def paged_work_list(tables: jax.Array, pos: jax.Array, page_block: int):
     i < n_work reads pool page ``page[i]`` = ``tables[slot[i],
     ordinal[i]]``; ``last[i]`` is 1 on a slot's final page. Items past
     n_work are padding no program reads. Depends on ``tables`` and ``pos``
-    alone: one list serves every layer of a decode step."""
+    alone: one list serves every layer of a decode step that reads the
+    same table to the same reach.
+
+    ``window``: the list of the layers that see ``window`` positions back
+    and keep their rows in a RING — ``tables`` [B, ring] is then the ring's
+    table, position p living in entry ``(p // page_block) % ring``. Slot b
+    holds the pages of ``(pos[b] - window, pos[b]]`` alone (``ring`` must
+    hold them: at most ``ceil(window / page_block) + 1``), ``ordinal`` is a
+    page's ABSOLUTE number ``p // page_block`` (the kernel's mask needs the
+    positions) and ``page`` the ring entry it lives in. A decode step of a
+    model with both kinds of layer builds two lists."""
     B, NB = tables.shape
     tables, pos = tables.astype(jnp.int32), pos.astype(jnp.int32)
-    n_pages = jnp.clip(pos // page_block + 1, 1, NB)          # [B]
+    if window is None:
+        n_pages = jnp.clip(pos // page_block + 1, 1, NB)          # [B]
+        ends = jnp.cumsum(n_pages)
+        item = jnp.arange(B * NB, dtype=jnp.int32)
+        slot = jnp.minimum(
+            jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+            B - 1)
+        ordinal = jnp.minimum(item - (ends - n_pages)[slot], NB - 1)
+        last = (ordinal == n_pages[slot] - 1).astype(jnp.int32)
+        return slot, tables[slot, ordinal], ordinal, last, ends[-1:]
+    if NB * page_block < window + page_block:
+        raise ValueError(f"a ring of {NB} pages of {page_block} cannot hold "
+                         f"a window of {window} positions")
+    first = jnp.maximum(pos - window + 1, 0) // page_block        # [B]
+    n_pages = pos // page_block - first + 1
     ends = jnp.cumsum(n_pages)
     item = jnp.arange(B * NB, dtype=jnp.int32)
     slot = jnp.minimum(
         jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
         B - 1)
-    ordinal = jnp.minimum(item - (ends - n_pages)[slot], NB - 1)
-    last = (ordinal == n_pages[slot] - 1).astype(jnp.int32)
-    return slot, tables[slot, ordinal], ordinal, last, ends[-1:]
+    ordinal = jnp.minimum(first[slot] + item - (ends - n_pages)[slot],
+                          (pos // page_block)[slot])
+    last = (ordinal == (pos // page_block)[slot]).astype(jnp.int32)
+    return slot, tables[slot, ordinal % NB], ordinal, last, ends[-1:]
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
@@ -1094,7 +1232,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            pos: jax.Array, *, scale: Optional[float] = None,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
-                           work=None,
+                           work=None, window: Optional[int] = None,
                            route: Optional[str] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Single-token attention read through a block table — the paged twin
@@ -1120,7 +1258,16 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     Both routes share one masked-softmax formulation over the SAME
     assembled row order, so route choice never changes greedy tokens.
     ``kernels.paged_decode_plan_total{plan, group}`` says, once a trace of
-    a kernel-route call, which body its programs run."""
+    a kernel-route call, which body its programs run.
+
+    ``window``: the read of a sliding-window layer — rows ``pos - window <
+    j <= pos`` — through a RING: ``tables`` [B, ring] names the ring's
+    pages, position p in entry ``(p // bs) % ring``, and ``work`` is
+    ``paged_work_list(tables, pos, bs, window)``. The kernel is the
+    grouped body (one KV head a query head is not asked of it) and its
+    custom call is NAMED ``paged_window_attention``, as are its counters:
+    what reads the trace counts a full read's bytes by every live row and
+    this one's by the window's."""
     B, NB = tables.shape
     P, bs, H, D = k_pool.shape
     Hq = q.shape[1]
@@ -1130,21 +1277,27 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     L = NB * bs
     scale_v = scale if scale is not None else D ** -0.5
     route = decode_route(L, route)
+    name = "paged_decode_attention" if window is None \
+        else "paged_window_attention"
     from .. import obs
-    obs.count("kernels.routes_total", kernel="paged_decode_attention",
-              route=route)
+    obs.count("kernels.routes_total", kernel=name, route=route)
     if route == "dense":
         k = gather_pages(k_pool, tables)
         v = gather_pages(v_pool, tables)
         ks = None if k_scale is None else gather_pages(k_scale, tables)
         vs = None if v_scale is None else gather_pages(v_scale, tables)
-        return _dense_decode_attention(q, k, v, pos, scale_v, ks, vs)
+        return _dense_decode_attention(
+            q, k, v, pos, scale_v, ks, vs,
+            None if window is None else (window, NB))
     if route != "kernel":
         raise ValueError(f"unknown paged_decode_attention route {route!r}")
-    if work is None:
-        work = paged_work_list(tables, pos, bs)
-    *work, n_work = work
     G = Hq // H
+    if window is not None and G == 1:
+        raise ValueError("paged_decode_attention: the windowed read is the "
+                         "grouped body's (fewer KV heads than query heads)")
+    if work is None:
+        work = paged_work_list(tables, pos, bs, window)
+    *work, n_work = work
     obs.count("kernels.paged_decode_plan_total",
               plan="group_mxu" if G > 1 else "head_vpu", group=str(G))
     qo_spec = pl.BlockSpec((1, Hq, D), lambda i, slot, *_: (slot[i], 0, 0))
@@ -1155,8 +1308,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     return _decode_attn_call(
         (*work, pos.astype(jnp.int32)), q, k_pool, v_pool, k_scale, v_scale,
         qo_spec, page_spec, sc_spec, grid=(n_work[0],), scale=scale_v,
-        chunk=bs, interpret=_interpret(interpret),
-        name="paged_decode_attention", groups=G)
+        chunk=bs, interpret=_interpret(interpret), name=name, groups=G,
+        window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -2425,6 +2578,14 @@ def _paged_prefill_attention_bytes(*, batch, pages, page_block, n_heads,
                                          itemsize=itemsize, steps=1)
 
 
+def _flash_window_attention_bytes(*, positions, n_heads, kv_heads, d_head,
+                                  itemsize=2):
+    """HBM bytes of banded flash forwards over ``positions`` (position,
+    layer) pairs, padding included: q and o of the query heads, k and v of
+    the KV heads, once each (a group's programs share a K/V block)."""
+    return 2.0 * positions * (n_heads + kv_heads) * d_head * itemsize
+
+
 def _paged_latent_attention_bytes(*, pages, page_block, row, itemsize=2):
     """HBM bytes of paged latent reads: ``pages`` pages of ``page_block``
     rows of ``row`` values, each read ONCE (keys and values are the same
@@ -2471,6 +2632,10 @@ def _register_cost_models():
                                   _decode_attention_bytes)
     roofline.register_kernel_cost("paged_decode_attention",
                                   _paged_decode_attention_bytes)
+    roofline.register_kernel_cost("paged_window_attention",
+                                  _paged_decode_attention_bytes)
+    roofline.register_kernel_cost("flash_window_attention_fwd",
+                                  _flash_window_attention_bytes)
     roofline.register_kernel_cost("paged_prefill_attention",
                                   _paged_prefill_attention_bytes)
     roofline.register_kernel_cost("paged_latent_attention",

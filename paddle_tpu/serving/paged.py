@@ -40,12 +40,14 @@ Invariants the exactness contract rides on:
 
 from __future__ import annotations
 
+import functools
 import weakref
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .. import obs
 from ..core.lod import bucket_length
@@ -128,6 +130,25 @@ class PagePool:
     chip; Lfm2MoeLM's tails would run on the scatter path too, and the
     blend can go once such a pair shows nothing lost (PERF.md section 7).
 
+    A ``CacheRow`` that states a REACH (``window``: AfmoeLM's sliding
+    layers) is a second kind of cache in the same pool: a pool of its own,
+    ``[slots x ring + 1, page_block, *shape]``, under a table of its own,
+    ``ring_tables`` [slots, ring] with ``ring = ceil((window + segment) /
+    page_block) + 1`` — position p of slot b lives in page ``ring_tables[b,
+    (p // page_block) % ring]``, so the newest page overwrites the one that
+    slid out of every reach. A slot's ring is its own from admission to
+    ``free_slot``: nothing is allocated or freed as the context slides, and
+    ``required_pages`` / ``fits`` / ``_ensure`` / ``page_bytes`` /
+    ``live_tokens`` count the GROWING rows alone. Such a model's admission
+    writes its pages IN PLACE: ``prefill`` is handed the pools and the
+    pool's ``write`` (a chunk's rows into the growing rows' pages, its
+    last ``ring`` pages into the ring), so no ``[slots, prompt bucket]``
+    copy of every layer's keys and values stands beside the pools; its
+    decode step is handed ``ring_tables``. A ring's rows belong to a slot,
+    not to a prefix: no prefix index over such a model. They ship
+    (:meth:`export_slot`) as the context's last ``ring`` pages under the
+    rows' names. A model that states no reach runs the programs it ran.
+
     The geometry defaults (``page_block`` 64, ``cache_bucket`` 256,
     ``prompt_buckets`` 32..512) are the values the GPT-2 and GigaChat serve
     cells of the chip benchmark run and warm up; ``lfm2-serve-rag`` passes
@@ -180,8 +201,30 @@ class PagePool:
         # that statement; the pool names no array itself.
         stated = model.cache_rows(params, kv_dtype)
         rows = [r for r in stated if not isinstance(r, SlotRow)]
-        self.pools = {r.name: jnp.full((self.pages, self.bs) + tuple(r.shape),
-                                       r.fill, r.dtype) for r in rows}
+        # rows that state a reach live in a ring a slot (class docstring)
+        reach = {r.window for r in rows if r.window is not None}
+        if len(reach) > 1:
+            raise ValueError(f"cache rows state different windows "
+                             f"{sorted(reach)}: the pool holds one ring")
+        self.window = reach.pop() if reach else None
+        self.ring = 0 if self.window is None else \
+            -(-(self.window + segment) // page_block) + 1
+        if self.ring and prefix_cache:
+            raise ValueError("prefix_cache over a model with windowed cache "
+                             "rows: a ring's rows belong to a slot, not to "
+                             "a prefix (serve --no_prefix_cache)")
+        self._ring_names = {r.name for r in rows if r.window is not None}
+        self.ring_tables = 1 + np.arange(
+            slots * self.ring, dtype=np.int32).reshape(slots, self.ring)
+        # what a program of a model with rings takes beside the others'
+        # arguments (an argument, not a constant of the closure: pools of
+        # any slot count share a model's programs)
+        self._ring_args = (jnp.asarray(self.ring_tables),) if self.ring \
+            else ()
+        self.pools = {
+            r.name: jnp.full(
+                ((slots * self.ring + 1 if r.window else self.pages),
+                 self.bs) + tuple(r.shape), r.fill, r.dtype) for r in rows}
         # ... and its per-SLOT rows (SlotRow; the class docstring says
         # what becomes of them): [slots, *shape], none for most models
         self._slot_rows = [r for r in stated if isinstance(r, SlotRow)]
@@ -202,11 +245,17 @@ class PagePool:
         self._read_geom = model.paged_read_geometry(params, kv_dtype)
         self._read_layers = getattr(model, "paged_read_layers",
                                     len(model.blocks))
-        # one page of every stated array in HBM bytes — the prefix index's
-        # reuse-ledger credit unit
-        self.page_bytes = float(self.bs * sum(
-            int(np.prod(r.shape, dtype=np.int64)) * jnp.dtype(r.dtype).itemsize
-            for r in rows))
+        # one page of every GROWING array in HBM bytes — the prefix index's
+        # reuse-ledger credit unit — and of every ringed one
+        def page_bytes(ringed):
+            return float(self.bs * sum(
+                int(np.prod(r.shape, dtype=np.int64))
+                * jnp.dtype(r.dtype).itemsize
+                for r in rows if (r.window is not None) == ringed))
+        self.page_bytes = page_bytes(False)
+        if self.ring:
+            obs.gauge_set("serving.ring_bytes_held",
+                          (slots * self.ring + 1) * page_bytes(True))
         #: what the model made of the last program's stats (span attrs)
         self.last_stats: Dict[str, float] = {}
         self.index: Optional[PrefixIndex] = (
@@ -431,8 +480,10 @@ class PagePool:
         plen = int(self.pos[slot])
         npg = -(-plen // self.bs)
         pages = jnp.asarray(self.tables[slot, :npg])
-        arrays = {nm: np.asarray(arr[pages])
-                  for nm, arr in self.pools.items()}
+        ringed = jnp.asarray(self._ring_pages(slot, plen))
+        arrays = {nm: np.asarray(
+            arr[ringed if nm in self._ring_names else pages])
+            for nm, arr in self.pools.items()}
         # the slot's per-slot rows travel under their own names, [*shape]
         arrays.update({nm: np.asarray(arr[slot])
                        for nm, arr in self.slot_state.items()})
@@ -442,6 +493,16 @@ class PagePool:
         obs.count("serving.ship_pages_total", npg)
         obs.count("serving.ship_bytes_total", len(payload))
         return manifest, payload
+
+    def _ring_pages(self, slot: int, plen: int) -> np.ndarray:
+        """``slot``'s ring pages that hold rows of a context of ``plen``
+        positions, oldest first: its last ``ring`` pages (all of them
+        where there are fewer) — what ships of a windowed row."""
+        if not self.ring:
+            return np.zeros((0,), np.int32)
+        npg = -(-int(plen) // self.bs)
+        span = np.arange(max(npg - self.ring, 0), npg)
+        return self.ring_tables[slot, span % self.ring]
 
     def check_shipment(self, plen: int, arrays: Dict[str, np.ndarray]
                        ) -> None:
@@ -466,7 +527,8 @@ class PagePool:
                 want = tuple(ref.shape[1:])
             else:
                 ref = self.pools[nm]
-                want = (npg,) + tuple(ref.shape[1:])
+                want = (min(npg, self.ring) if nm in self._ring_names
+                        else npg,) + tuple(ref.shape[1:])
             if tuple(rows.shape) != want:
                 raise ValueError(
                     f"shipped {nm!r} shape {tuple(rows.shape)} != expected "
@@ -494,32 +556,84 @@ class PagePool:
         self.slot_partial[slot] = None
         self._ensure(slot, plen)
         pages = jnp.asarray(self.tables[slot, :npg])
+        ringed = jnp.asarray(self._ring_pages(slot, plen))
         for nm, rows in arrays.items():
             rows = jnp.asarray(np.ascontiguousarray(rows))
             if nm in self.slot_state:
                 self.slot_state[nm] = self.slot_state[nm].at[slot].set(rows)
             else:
-                self.pools[nm] = self.pools[nm].at[pages].set(rows)
+                at = ringed if nm in self._ring_names else pages
+                self.pools[nm] = self.pools[nm].at[at].set(rows)
         self.pos[slot] = plen
         self.cur[slot] = int(first)
         self.prompt_tokens_total += plen
         obs.count("serving.adopted_total")
 
     # -- jitted programs ---------------------------------------------------
+    def _page_write(self, nbp: int):
+        """The admission's scatter for a model with rings, which writes its
+        pages IN PLACE a chunk at a time (``prefill(pools=, write=)``):
+        ``write(ring_tables, pages, pools, idx, n, new) -> pools`` puts a
+        chunk's rows (slots ``idx``, lengths ``n``; ``new[nm]`` [R, T,
+        *shape], T at most ``nbp`` pages) into the pools — every page of a
+        growing row at ``pages[idx]``, the last ``ring`` pages of a row's
+        context into its ring. What holds no prompt lands on the null
+        page."""
+        bs, ring, ringed = self.bs, self.ring, self._ring_names
+        n_ring = min(nbp, ring)
+
+        def write(ring_tables, pages, pools, idx, n, new):
+            top = jnp.maximum(n - 1, 0) // bs
+            a = top[:, None] - (n_ring - 1) + jnp.arange(n_ring)
+            at_ring = jnp.where(
+                (a >= 0) & (n > 0)[:, None],
+                jnp.take_along_axis(ring_tables[idx], a % ring, axis=1), 0)
+            take = jnp.arange(idx.shape[0])[:, None], jnp.clip(a, 0, nbp - 1)
+            out = {}
+            for nm, pool in pools.items():
+                rows = new[nm]
+                rows = jnp.pad(
+                    rows, ((0, 0), (0, nbp * bs - rows.shape[1]))
+                    + ((0, 0),) * (rows.ndim - 2)).reshape(
+                    (rows.shape[0], nbp, bs) + rows.shape[2:])
+                pool = pool.at[at_ring].set(rows[take].astype(pool.dtype)) \
+                    if nm in ringed \
+                    else pool.at[pages[idx]].set(rows.astype(pool.dtype))
+                # the pool stays in the row-major order it arrives in and
+                # the decode kernels read: left to itself the TPU compiler
+                # carries it through the walk's loop pages-by-head and
+                # COPIES all of it in and out (4 GB at the trinity cell's
+                # sizes: PERF.md section 6, PR 39)
+                out[nm] = with_layout_constraint(pool, Layout(
+                    major_to_minor=tuple(range(pool.ndim))))
+            return out
+        return write
+
     def _admit_fn(self, tpad: int, nbp: int):
-        key = ("admit", self.kv_dtype, self.bs, tpad, nbp)
+        key = ("admit", self.kv_dtype, self.bs, tpad, nbp, self.ring)
         fn = self._fns.get(key)
         if fn is None:
             # a compile inside a serving window names its shape bucket
             obs.instant("serving.program_build", kind="admit", tpad=tpad)
             model, kv_dtype, bs = self.model, self.kv_dtype, self.bs
             tpp, in_place = nbp * bs, self._in_place
+            write = self._page_write(nbp)
 
-            def admit(params, state, prompts, lens, pages):
+            def admit(params, state, prompts, lens, pages, *ring_tables):
                 # pad_to=tpp: the transient cell holds prompt-bucket rows,
                 # not a max_len-padded (pinned-pool-sized) cache — the
                 # admission HBM spike stays proportional to the prompts
                 pools, slot_state = state
+                if ring_tables:
+                    # ... and a model with rings writes its pages in place,
+                    # a chunk at a time: no cell of keys and values at all
+                    cell, last = model.prefill(
+                        params, prompts, lens, kv_dtype=kv_dtype, pad_to=tpp,
+                        pools=pools,
+                        write=functools.partial(write, ring_tables[0], pages))
+                    first = jnp.argmax(last, axis=-1).astype(prompts.dtype)
+                    return (({nm: cell[nm] for nm in pools}, slot_state),
+                            first, cell.get("stats", {}))
                 cell, last = model.prefill(
                     params, prompts, lens, kv_dtype=kv_dtype, pad_to=tpp,
                     **(dict(slot_state=slot_state) if in_place else {}))
@@ -589,8 +703,10 @@ class PagePool:
             fills = {r.name: r.fill for r in self._slot_rows}
             in_place = self._in_place
 
-            def seg(params, state, tables, pos, cur, live):
+            def seg(params, state, tables, pos, cur, live, *ring_tables):
                 pools, slot_state = state
+                ring_kw = dict(ring_tables=ring_tables[0]) if ring_tables \
+                    else {}
                 cell = dict(pools, **slot_state, pos=pos)
                 if hasattr(model, "program_stats_zero"):
                     cell["stats"] = model.program_stats_zero()
@@ -598,7 +714,7 @@ class PagePool:
                 def body(carry, _):
                     cell, cur = carry
                     logits, cell = model.decode_step_paged(
-                        params, cell, cur, tables, live=live)
+                        params, cell, cur, tables, live=live, **ring_kw)
                     nxt = jnp.argmax(logits, axis=-1).astype(cur.dtype)
                     return (cell, nxt), cur
                 (cell, cur), toks = jax.lax.scan(body, (cell, cur), None,
@@ -745,7 +861,7 @@ class PagePool:
             fn = self._admit_fn(tpad, nbp)
             args = (self.params, (self.pools, self.slot_state),
                     jnp.asarray(prompts), jnp.asarray(lens),
-                    jnp.asarray(pages))
+                    jnp.asarray(pages)) + self._ring_args
         with obs.span("serving.dispatch", program="admit"):
             (self.pools, self.slot_state), f, stats = fn(*args)
             self._note_admit_cost(fn, args)
@@ -877,7 +993,8 @@ class PagePool:
             alive[idx] = True
             args = (self.params, (self.pools, self.slot_state),
                     jnp.asarray(self.tables[:, :nb]), jnp.asarray(pos),
-                    jnp.asarray(self.cur), jnp.asarray(alive))
+                    jnp.asarray(self.cur), jnp.asarray(alive)) \
+                + self._ring_args
         with obs.span("serving.dispatch", program="segment"):
             (self.pools, self.slot_state), cur, toks, stats = fn(*args)
             obs.count("decode.dispatches_total", route="serve_segment")
@@ -900,6 +1017,8 @@ class PagePool:
                 self._read_kernel, pages=walked, page_block=self.bs,
                 **self._read_geom) or 0.0
             obs.count("kernels.bytes_total", read, kernel=self._read_kernel)
+            reach = self._note_ring_reads(pos, steps, idx) if self.ring \
+                else {}
             self.segments_total += 1
             self.read_bytes_total += read
             self.occupancy_num += self.live_tokens(live)
@@ -908,7 +1027,30 @@ class PagePool:
         with obs.span("serving.fetch", program="segment"):
             self.cur = np.array(cur)  # writable copy: admit() merges into it
             self._note_stats(stats, "segment")
+            self.last_stats = dict(self.last_stats, **reach)
             return np.asarray(toks)                   # [slots, segment]
+
+    def _note_ring_reads(self, pos, steps, live) -> Dict[str, int]:
+        """A segment's reads of a model with rings, from the host's own
+        ``pos``: the ring pages the windowed read's programs walked (every
+        slot, as pk.paged_work_list(window=) lists them, x the layers that
+        read so) with their modeled bytes, and the rows a windowed / a full
+        layer's read COVERED over the live slots and the steps — what the
+        enclosing ``serving.segment`` span carries."""
+        at = pos[:, None].astype(np.int64) + steps[None, :]
+        walked = self.model.window_read_layers * int(
+            (at // self.bs - np.maximum(at - self.window + 1, 0) // self.bs
+             + 1).sum())
+        obs.count("kernels.bytes_total", obs.roofline.kernel_cost(
+            "paged_window_attention", pages=walked, page_block=self.bs,
+            **self._read_geom) or 0.0, kernel="paged_window_attention")
+        rows = {"window_rows": int(np.minimum(at[live] + 1,
+                                              self.window).sum()),
+                "full_rows": int((at[live] + 1).sum())}
+        for kind in ("window", "full"):
+            obs.count("serving.cache_rows_read_total", rows[f"{kind}_rows"],
+                      kind=kind)
+        return rows
 
     def _note_stats(self, stats, program: str) -> None:
         """What a program returned beside its tokens (a model with

@@ -387,6 +387,48 @@ def test_group_16_head_128_flash_forward_compiles(S, rows, T):
     assert f"bf16[{rows * 2},{T},128]" in call
 
 
+# -- AfmoeLM's two reads at the trinity-ep8-serve-mixedlen cell's shapes ----
+
+@pytest.mark.parametrize("window, NB, pages, name", [
+    (2048, 34, 12 * 34 + 1, "paged_window_attention"),
+    (None, 140, 1681, "paged_decode_attention")])
+def test_group_8_paged_reads_of_both_kinds_compile(S, window, NB, pages,
+                                                   name):
+    """12 slots x 32 query heads of 128 over bf16 pools of 4 KV heads (a
+    group of 8): a sliding layer's read through its ring of 34 pages, under
+    its own name (what chipbench/metrics/window_decode_roofline.py finds it
+    by), and a full layer's over the whole table of 140."""
+    text = _compile(lambda q, k, v, t, pos: pk.paged_decode_attention(
+        q, k, v, t, pos, window=window, route="kernel", interpret=False),
+        S((12, 32, 128)), S((pages, 64, 4, 128), jnp.bfloat16),
+        S((pages, 64, 4, 128), jnp.bfloat16), S((12, NB), jnp.int32),
+        S((12,), jnp.int32))
+    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    assert call.split(" = ")[0].strip().lstrip("ROOT %").startswith(name)
+
+
+@pytest.mark.parametrize("rows, T", [(4, 512), (1, 8192)])
+@pytest.mark.parametrize("window", [2048, None], ids=["band", "square"])
+def test_group_8_head_128_flash_forwards_compile(S, rows, T, window):
+    """The cell's prefill chunks at the shortest and the longest prompt
+    bucket (K and V of 8,192 rows of 128 resident a program): the banded
+    walk under ``flash_window_attention_fwd`` with the result's shape the
+    reader takes rows and length from, the causal one as it was."""
+    bf = jnp.bfloat16
+    text = _compile(lambda q, k, v: pk.flash_attention(
+        q, k, v, causal=True, window=window, interpret=False),
+        S((rows, T, 32, 128), bf), S((rows, T, 4, 128), bf),
+        S((rows, T, 4, 128), bf))
+    call = next(ln for ln in text.splitlines() if " custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    name = "flash_attention_fwd" if window is None \
+        else "flash_window_attention_fwd"
+    assert call.split(" = ")[0].strip().lstrip("ROOT %").startswith(name)
+    assert f"bf16[{rows * 32},{T},128]" in call
+    assert f"bf16[{rows * 4},{T},128]" in call
+
+
 def test_flash_attention_compiles_at_latent_head_width(S):
     """Prefill of the latent-attention model expands k and v and runs the
     flash kernel at head width 192 (128 + 64 rotary; v is 192 as well): 1.5
