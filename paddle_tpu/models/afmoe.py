@@ -371,11 +371,11 @@ class AfmoeLM(ProgramStats, LiveRowPrefill, nn.Module):
             x = blk.input_norm(p["input_norm"], h)
             q, k, v, gate = blk.attn.project(p["attn"], x, pos)
             rd = reads[blk.sliding]
-            kp = cell[f"k{i}"].at[rd["page"], row].set(k)
-            vp = cell[f"v{i}"].at[rd["page"], row].set(v)
+            kp, k_rows = pk.put_rows(cell[f"k{i}"], rd["page"], row, k)
+            vp, v_rows = pk.put_rows(cell[f"v{i}"], rd["page"], row, v)
             new_cell[f"k{i}"], new_cell[f"v{i}"] = kp, vp
             o = pk.paged_decode_attention(
-                q, kp, vp, rd["tables"], pos, scale=blk.attn.scale,
+                q, k_rows, v_rows, rd["tables"], pos, scale=blk.attn.scale,
                 work=rd["work"], window=rd["window"], route=attn_route)
             h = h + blk.post_attn_norm(
                 p["post_attn_norm"], blk.attn.output(p["attn"], o, gate))

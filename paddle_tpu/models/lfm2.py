@@ -307,12 +307,12 @@ class Lfm2MoeLM(ProgramStats, LiveRowPrefill, nn.Module):
                 h = h + y
             else:
                 q, k, v = blk.attn.project(p["attn"], x, pos)
-                kp = cell[f"k{i}"].at[page, row].set(k)
-                vp = cell[f"v{i}"].at[page, row].set(v)
+                kp, k_rows = pk.put_rows(cell[f"k{i}"], page, row, k)
+                vp, v_rows = pk.put_rows(cell[f"v{i}"], page, row, v)
                 new_cell[f"k{i}"], new_cell[f"v{i}"] = kp, vp
                 o = pk.paged_decode_attention(
-                    q, kp, vp, tables, pos, scale=blk.attn.scale, work=work,
-                    route=attn_route)
+                    q, k_rows, v_rows, tables, pos, scale=blk.attn.scale,
+                    work=work, route=attn_route)
                 h = h + _dot(o.reshape(B, -1), p["attn"]["w_o"])
             h, c = ffn_or_experts(blk, p, h, live)
             if c is not None:

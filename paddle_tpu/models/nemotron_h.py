@@ -380,12 +380,12 @@ class NemotronHLM(ProgramStats, LiveRowPrefill, nn.Module):
                 h = h + y
             elif blk.kind == "attention":
                 q, k, v = blk.attn.project(p["attn"], x)
-                kp = cell[f"k{i}"].at[page, row].set(k)
-                vp = cell[f"v{i}"].at[page, row].set(v)
+                kp, k_rows = pk.put_rows(cell[f"k{i}"], page, row, k)
+                vp, v_rows = pk.put_rows(cell[f"v{i}"], page, row, v)
                 new_cell[f"k{i}"], new_cell[f"v{i}"] = kp, vp
                 o = pk.paged_decode_attention(
-                    q, kp, vp, tables, pos, scale=blk.attn.scale, work=work,
-                    route=attn_route)
+                    q, k_rows, v_rows, tables, pos, scale=blk.attn.scale,
+                    work=work, route=attn_route)
                 h = h + _dot(o.reshape(B, -1), p["attn"]["w_o"])
             else:
                 out, c = blk.moe(p["moe"], x, live)

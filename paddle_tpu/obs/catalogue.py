@@ -450,6 +450,24 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                    "a kind for a step's); the serving.segment span "
                    "carries the same as window_rows / full_rows, labels: "
                    "kind", ("kind",)),
+    "serving.pool_bytes_held": (
+        "gauge", "bytes of the pool's page arrays, all of them: "
+                 "state=logical as stated (shape x itemsize), "
+                 "state=device as the pool holds them on the device "
+                 "(GPT-2-large's f32[105, 64, 20, 64] is held [105, 64, "
+                 "24, 128], the (8, 128) tile a row-major (20, 64) row "
+                 "pads to: 2.48 -> 5.95 GB over 72 arrays), labels: state",
+                 ("state",)),
+    "serving.admit_pages_written_total": (
+        "counter", "pool pages an admit program wrote: ceil(length / "
+                   "page_block) a row that held an admitted prompt, each "
+                   "a page-sized write where the page lies (the rest of "
+                   "the bucket is not written)"),
+    "serving.admit_pages_bucket_total": (
+        "counter", "pages of the same programs' buckets (slots x pages a "
+                   "prompt bucket an admission): written / bucket is the "
+                   "share a scatter over the whole bucket would have "
+                   "sent somewhere other than the null page"),
     "serving.slot_state_writes_total": (
         "counter", "admitted slots whose per-slot rows an admit program "
                    "wrote (one per request admitted by prefill; 0 for a "

@@ -1176,6 +1176,40 @@ def gather_pages(pool: jax.Array, tables: jax.Array) -> jax.Array:
     return g.reshape((B, NB * pool.shape[1]) + pool.shape[2:])
 
 
+def pool_rows(pool: jax.Array, shape) -> jax.Array:
+    """A page pool as its rows are STATED, [P, bs, *shape]. A pool may be
+    HELD wider: the serving pool pads a row narrower than the chip's (8,
+    128) tile up to it, so that the array lies row-major on the chip the
+    way these kernels take it (serving/paged.py ``_held_shape``), and the
+    stated rows are the leading corner of the held ones — the same bytes
+    at the same offsets, a bitcast in the compiled program. A pool held as
+    stated comes back as it is."""
+    if pool.shape[2:] == tuple(shape):
+        return pool
+    return pool[(slice(None), slice(None))
+                + tuple(slice(0, n) for n in shape)]
+
+
+def put_rows(pool: jax.Array, page: jax.Array, row: jax.Array,
+             new: jax.Array):
+    """Write cache rows ``new`` [*lead, *shape] at ``(page, row)`` (each
+    [*lead]) of a pool held [P, bs, *shape] or wider (:func:`pool_rows`):
+    -> (the pool, its rows as stated — what the paged read takes). Rows
+    for a wider pool are padded to its width first and written WHOLE (the
+    padding takes zeros, which nothing reads): a scatter of whole rows is
+    one instruction on the chip, a scatter into the rows' leading corner
+    the compiler expands into a loop over ``lead`` — 16 small writes an
+    array a decode step, which doubled the GPT-2 cells' step (PERF.md
+    section 6, PR 40)."""
+    shape = new.shape[page.ndim:]
+    held = pool.shape[2:]
+    if held != shape:
+        new = jnp.pad(new, ((0, 0),) * page.ndim
+                      + tuple((0, h - n) for h, n in zip(held, shape)))
+    pool = pool.at[page, row].set(new)
+    return pool, pool_rows(pool, shape)
+
+
 def paged_work_list(tables: jax.Array, pos: jax.Array, page_block: int,
                     window: Optional[int] = None):
     """The paged read's launch geometry: the live (slot, page) pairs of a

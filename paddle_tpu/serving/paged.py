@@ -41,17 +41,18 @@ Invariants the exactness contract rides on:
 from __future__ import annotations
 
 import functools
+import warnings
 import weakref
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.layout import Layout, with_layout_constraint
 
 from .. import obs
 from ..core.lod import bucket_length
 from ..models.transformer import SlotRow
+from ..ops import pallas_kernels as pk
 from . import ship
 from .batcher import Request, clip_emission, validate_request
 from .prefix import Match, PrefixIndex
@@ -70,6 +71,98 @@ def _shared_fn_cache(model) -> dict:
     if d is None:
         d = _SHARED_FNS[model] = {}
     return d
+
+
+def _held_shape(a) -> tuple:
+    """The shape a pool array is HELD in on its device, read off the array
+    as the runtime lays it out at its STATED shape ``a.shape`` = [pages,
+    page_block, *row]. Where that is row-major — pages outermost, a head's
+    row minor: the order the paged read kernels take a pool in — the pool
+    holds the stated shape. Where it is not (64-wide rows lie pages-MINOR
+    on a TPU: ``f32[105, 64, 20, 64]`` comes ``{0,3,2,1}``), every program
+    that carries such a pool re-lays all of it out and back; the pool then
+    holds the row's last two dims padded up to the layout's tile, ``(24,
+    128)`` for that array, which the runtime does lay out row-major (a
+    rule read off this runtime, not a law of it: the pool looks at the
+    padded array's own layout too and keeps the stated one where the
+    padding bought nothing — ``PagePool``'s ``fresh``). The stated rows are the leading corner of the held ones, the same bytes
+    the kernel's row-major copy of the stated shape had (``pk.pool_rows``
+    is a bitcast in the compiled program), and the padding is bytes the
+    chip's tiles held anyway. (A stated ``Format`` on the arguments would
+    say the same without a second shape, and does not survive the
+    persistent compile cache: an executable read back from it expects the
+    default layout again — PERF.md section 6, PR 40.)"""
+    layout = a.format.layout
+    if a.ndim < 4 or _row_major(a):
+        return tuple(a.shape)
+    tile = layout.tiling[0]
+    if len(tile) != 2:
+        raise ValueError(f"a pool array {a.shape} lies under a first tile "
+                         f"{tile} of {len(tile)} dims; the held shape pads "
+                         "a row's last two")
+    return tuple(a.shape[:-2]) + tuple(
+        -(-n // t) * t for n, t in zip(a.shape[-2:], tile))
+
+
+def _row_major(a) -> bool:
+    return tuple(a.format.layout.major_to_minor) == tuple(range(a.ndim))
+
+
+def _write_pages(pools, cells, src, dst, n):
+    """Write pages into the pools WHERE THE PAGES LIE: for i < ``n``, pool
+    page ``dst[i]`` of every array takes the ``page_block`` positions from
+    ``src[i, 1] * page_block`` of row ``src[i, 0]`` of ``cells[nm]`` [rows,
+    T, *shape] (``src`` [N, 2], ``dst`` [N]). One ``dynamic_update_slice``
+    a page at a dynamic leading index, which the compiler performs in
+    place on a row-major pool; a scatter over the pages re-lays the WHOLE
+    pool out for itself and back, whatever order it is handed (PERF.md
+    section 6, PR 40). The page is cut out of a view of the cell with the
+    positions minor and turned round AFTER the cut: the compiler may hold
+    a cell in any order (GPT-2's lies positions-minor) and then changes
+    the order of the written pages alone; cut straight out of ``[rows, T,
+    ...]`` it re-lays every array of the cell out first, whole. A pool
+    held wider than the cell's rows (:func:`_held_shape`) takes the page
+    in its leading corner. What is past ``n`` is not written at all."""
+    def body(i, pools):
+        out = {}
+        for nm, pool in pools.items():
+            bs, rest = pool.shape[1], cells[nm].shape[2:]
+            view = jnp.moveaxis(cells[nm], 1, -1)        # [rows, *shape, T]
+            page = jax.lax.dynamic_slice(
+                view, (src[i, 0],) + (0,) * len(rest) + (src[i, 1] * bs,),
+                (1,) + rest + (bs,))
+            out[nm] = jax.lax.dynamic_update_slice(
+                pool, jnp.moveaxis(page, -1, 1).astype(pool.dtype),
+                (dst[i],) + (0,) * (pool.ndim - 1))
+        return out
+    return jax.lax.fori_loop(0, n, body, pools)
+
+
+def _listed(ok, src_page, dst):
+    """``(src, dst, n)`` of :func:`_write_pages` from [R, C] grids — pair
+    (r, c) takes page ``src_page[r, c]`` of row r to pool page ``dst[r,
+    c]`` — with the ``n`` pairs that are ``ok`` first."""
+    order = jnp.argsort(~ok.reshape(-1), stable=True)
+    return (jnp.stack([order // ok.shape[1], src_page.reshape(-1)[order]],
+                      axis=1), dst.reshape(-1)[order], ok.sum())
+
+
+def _copy_pages(pools, src, dst, n):
+    """Copy-on-write where the pages lie: for i < ``n``, pool page
+    ``dst[i]`` of every array becomes page ``src[i]`` (``src``, ``dst``
+    [N]), a page at a time like :func:`_write_pages` and for its reason.
+    A ``dst`` page is freshly owned and a ``src`` page a stored one, so no
+    copy reads what another wrote. What is past ``n`` is not copied."""
+    def body(i, pools):
+        out = {}
+        for nm, pool in pools.items():
+            rest = (0,) * (pool.ndim - 1)
+            page = jax.lax.dynamic_slice(pool, (src[i],) + rest,
+                                         (1,) + pool.shape[1:])
+            out[nm] = jax.lax.dynamic_update_slice(pool, page,
+                                                   (dst[i],) + rest)
+        return out
+    return jax.lax.fori_loop(0, n, body, pools)
 
 
 class _AdmitPlan:
@@ -221,10 +314,36 @@ class PagePool:
         # any slot count share a model's programs)
         self._ring_args = (jnp.asarray(self.ring_tables),) if self.ring \
             else ()
-        self.pools = {
-            r.name: jnp.full(
-                ((slots * self.ring + 1 if r.window else self.pages),
-                 self.bs) + tuple(r.shape), r.fill, r.dtype) for r in rows}
+        #: the rows as the model STATES them; the arrays may be held
+        #: wider (:func:`_held_shape`)
+        self._row_shapes = {r.name: tuple(r.shape) for r in rows}
+
+        def fresh(r):
+            a = jnp.full(((slots * self.ring + 1 if r.window else self.pages),
+                          self.bs) + tuple(r.shape), r.fill, r.dtype)
+            held = _held_shape(a)
+            if held == a.shape:
+                return a
+            wide = jnp.full(held, r.fill, r.dtype)
+            if _row_major(wide):
+                return wide
+            # the padding bought nothing: the stated array, as ever
+            warnings.warn(
+                f"pool arrays {a.shape} lie "
+                f"{a.format.layout.major_to_minor} on this device and "
+                f"{wide.format.layout.major_to_minor} padded to {held}: "
+                "held as stated, every program re-lays them out")
+            return a
+        self.pools = {r.name: fresh(r) for r in rows}
+        # the arrays' bytes as stated and as they lie on the device (a
+        # (20, 64) row is held (24, 128) there: PERF.md section 7)
+        for state, n in (
+                ("logical", sum(
+                    int(np.prod(a.shape[:2] + self._row_shapes[nm]))
+                    * a.dtype.itemsize for nm, a in self.pools.items())),
+                ("device", sum(a.on_device_size_in_bytes()
+                               for a in self.pools.values()))):
+            obs.gauge_set("serving.pool_bytes_held", n, state=state)
         # ... and its per-SLOT rows (SlotRow; the class docstring says
         # what becomes of them): [slots, *shape], none for most models
         self._slot_rows = [r for r in stated if isinstance(r, SlotRow)]
@@ -481,9 +600,10 @@ class PagePool:
         npg = -(-plen // self.bs)
         pages = jnp.asarray(self.tables[slot, :npg])
         ringed = jnp.asarray(self._ring_pages(slot, plen))
-        arrays = {nm: np.asarray(
-            arr[ringed if nm in self._ring_names else pages])
-            for nm, arr in self.pools.items()}
+        # ... as the model states them, whatever the pool holds them in
+        arrays = {nm: np.asarray(pk.pool_rows(
+            arr[ringed if nm in self._ring_names else pages],
+            self._row_shapes[nm])) for nm, arr in self.pools.items()}
         # the slot's per-slot rows travel under their own names, [*shape]
         arrays.update({nm: np.asarray(arr[slot])
                        for nm, arr in self.slot_state.items()})
@@ -528,7 +648,7 @@ class PagePool:
             else:
                 ref = self.pools[nm]
                 want = (min(npg, self.ring) if nm in self._ring_names
-                        else npg,) + tuple(ref.shape[1:])
+                        else npg, self.bs) + self._row_shapes[nm]
             if tuple(rows.shape) != want:
                 raise ValueError(
                     f"shipped {nm!r} shape {tuple(rows.shape)} != expected "
@@ -563,49 +683,63 @@ class PagePool:
                 self.slot_state[nm] = self.slot_state[nm].at[slot].set(rows)
             else:
                 at = ringed if nm in self._ring_names else pages
-                self.pools[nm] = self.pools[nm].at[at].set(rows)
+                self.pools[nm] = self._put_fn()(self.pools[nm], rows, at)
         self.pos[slot] = plen
         self.cur[slot] = int(first)
         self.prompt_tokens_total += plen
         obs.count("serving.adopted_total")
 
     # -- jitted programs ---------------------------------------------------
+    def _put_fn(self):
+        """:meth:`adopt_slot`'s write of shipped pages ``rows`` [n,
+        page_block, *shape] at pool pages ``at`` [n]: a program like the
+        others — the pool donated, the pages written where they lie — and
+        not an eager scatter, which re-lays the whole array out for itself
+        and back."""
+        fn = self._fns.get("put")
+        if fn is None:
+            def put(pool, rows, at):
+                n = at.shape[0]
+                src = jnp.stack([jnp.zeros((n,), jnp.int32),
+                                 jnp.arange(n, dtype=jnp.int32)], axis=1)
+                cell = rows.reshape((1, n * rows.shape[1]) + rows.shape[2:])
+                return _write_pages({"p": pool}, {"p": cell}, src, at, n)["p"]
+            fn = self._fns["put"] = jax.jit(put, donate_argnums=(0,))
+        return fn
+
     def _page_write(self, nbp: int):
-        """The admission's scatter for a model with rings, which writes its
+        """The admission's write for a model with rings, which writes its
         pages IN PLACE a chunk at a time (``prefill(pools=, write=)``):
         ``write(ring_tables, pages, pools, idx, n, new) -> pools`` puts a
         chunk's rows (slots ``idx``, lengths ``n``; ``new[nm]`` [R, T,
         *shape], T at most ``nbp`` pages) into the pools — every page of a
-        growing row at ``pages[idx]``, the last ``ring`` pages of a row's
-        context into its ring. What holds no prompt lands on the null
-        page."""
+        growing row that holds a prompt at ``pages[idx]``, the last
+        ``ring`` pages of a row's context into its ring — a page at a time
+        where it lies (:func:`_write_pages`: the pools stay in the order
+        they arrive in and the decode kernels read, through the walk's
+        loop too). What holds no prompt is not written."""
         bs, ring, ringed = self.bs, self.ring, self._ring_names
         n_ring = min(nbp, ring)
 
         def write(ring_tables, pages, pools, idx, n, new):
+            R = idx.shape[0]
+            j = jnp.broadcast_to(jnp.arange(nbp)[None, :], (R, nbp))
+            grown = _listed(j * bs < n[:, None], j, pages[idx])
             top = jnp.maximum(n - 1, 0) // bs
             a = top[:, None] - (n_ring - 1) + jnp.arange(n_ring)
-            at_ring = jnp.where(
-                (a >= 0) & (n > 0)[:, None],
-                jnp.take_along_axis(ring_tables[idx], a % ring, axis=1), 0)
-            take = jnp.arange(idx.shape[0])[:, None], jnp.clip(a, 0, nbp - 1)
+            rung = _listed(
+                (a >= 0) & (n > 0)[:, None], jnp.clip(a, 0, nbp - 1),
+                jnp.take_along_axis(ring_tables[idx], a % ring, axis=1))
+            cells = {nm: jnp.pad(
+                rows, ((0, 0), (0, nbp * bs - rows.shape[1]))
+                + ((0, 0),) * (rows.ndim - 2)) for nm, rows in new.items()}
+            # an array at a time: one loop over all the arrays of a kind
+            # keeps every one's chunk alive beside it (+0.5 GB of
+            # temporaries at the 8,192-token bucket of the trinity cell)
             out = {}
-            for nm, pool in pools.items():
-                rows = new[nm]
-                rows = jnp.pad(
-                    rows, ((0, 0), (0, nbp * bs - rows.shape[1]))
-                    + ((0, 0),) * (rows.ndim - 2)).reshape(
-                    (rows.shape[0], nbp, bs) + rows.shape[2:])
-                pool = pool.at[at_ring].set(rows[take].astype(pool.dtype)) \
-                    if nm in ringed \
-                    else pool.at[pages[idx]].set(rows.astype(pool.dtype))
-                # the pool stays in the row-major order it arrives in and
-                # the decode kernels read: left to itself the TPU compiler
-                # carries it through the walk's loop pages-by-head and
-                # COPIES all of it in and out (4 GB at the trinity cell's
-                # sizes: PERF.md section 6, PR 39)
-                out[nm] = with_layout_constraint(pool, Layout(
-                    major_to_minor=tuple(range(pool.ndim))))
+            for nm in pools:
+                out.update(_write_pages({nm: pools[nm]}, {nm: cells[nm]},
+                                        *(rung if nm in ringed else grown)))
             return out
         return write
 
@@ -638,11 +772,13 @@ class PagePool:
                     params, prompts, lens, kv_dtype=kv_dtype, pad_to=tpp,
                     **(dict(slot_state=slot_state) if in_place else {}))
                 first = jnp.argmax(last, axis=-1).astype(prompts.dtype)
-                out = {}
-                for nm, pool in pools.items():
-                    rows = cell[nm][:, :tpp].reshape(
-                        (prompts.shape[0], nbp, bs) + cell[nm].shape[2:])
-                    out[nm] = pool.at[pages].set(rows.astype(pool.dtype))
+                # the pages that hold an admitted prompt: page j of a row
+                # with j * bs < its length (a row not admitted has length
+                # 0, so nothing is sent to the null page)
+                j = jnp.broadcast_to(jnp.arange(nbp), pages.shape)
+                out = _write_pages(
+                    pools, {nm: cell[nm][:, :tpp] for nm in pools},
+                    *_listed(j * bs < lens[:, None], j, pages))
                 # per-slot rows: only the slots this admission fills —
                 # in place, the model wrote them at those indices itself
                 if in_place:
@@ -678,12 +814,10 @@ class PagePool:
             model = self.model
 
             def admit_sfx(params, pools, suffix, offsets, lens, tables,
-                          copy_src, copy_dst):
+                          copy_src, copy_dst, n_copies):
                 # CoW first: dst pages are freshly-owned copies of the
-                # stored partial pages (no-copy slots pass (0, 0) — the
-                # null page absorbs the self-copy like any drained write)
-                out = {nm: v.at[copy_dst].set(v[copy_src])
-                       for nm, v in pools.items()}
+                # stored partial pages (the first n_copies pairs)
+                out = _copy_pages(pools, copy_src, copy_dst, n_copies)
                 out, last = model.prefill_paged(params, out, suffix,
                                                 offsets, lens, tables)
                 first = jnp.argmax(last, axis=-1).astype(suffix.dtype)
@@ -741,8 +875,13 @@ class PagePool:
                 state_out = ({k: cell[k] for k in pools}, slot_out)
                 return (state_out, cur, jnp.moveaxis(toks, 0, 1),
                         cell.get("stats", {}))
+            # what the model asks of the TPU's compiler for its decode
+            # steps, if anything (another backend knows no such option)
+            options = getattr(model, "decode_compiler_options", None) \
+                if pk._on_tpu() else None
             fn = obs.roofline.instrument(
-                jax.jit(seg, donate_argnums=(1,)), "serving.segment")
+                jax.jit(seg, donate_argnums=(1,), compiler_options=options),
+                "serving.segment")
             self._fns[key] = fn
         return fn
 
@@ -864,6 +1003,9 @@ class PagePool:
                     jnp.asarray(pages)) + self._ring_args
         with obs.span("serving.dispatch", program="admit"):
             (self.pools, self.slot_state), f, stats = fn(*args)
+            obs.count("serving.admit_pages_written_total",
+                      int((-(-lens // self.bs)).sum()))
+            obs.count("serving.admit_pages_bucket_total", self.n_slots * nbp)
             self._note_admit_cost(fn, args)
             if self.slot_state:
                 obs.count("serving.slot_state_writes_total", len(miss))
@@ -893,8 +1035,11 @@ class PagePool:
                 suffix[slot, :sfx.size] = sfx
                 offsets[slot] = plan.offset
                 lens[slot] = sfx.size
-                if slot in cow:
-                    src[slot], dst[slot] = cow[slot]
+            # the copy-on-write pairs, listed first (the program copies
+            # that many and no null page onto itself)
+            pairs = [cow[slot] for slot, _ in hits if slot in cow]
+            for i, pair in enumerate(pairs):
+                src[i], dst[i] = pair
             # prefill_paged runs every slot at the suffix bucket's width
             self._account(work, len(hits), int(lens.sum()), tpad,
                           positions=self.n_slots * tpad)
@@ -902,7 +1047,7 @@ class PagePool:
             args = (self.params, self.pools, jnp.asarray(suffix),
                     jnp.asarray(offsets), jnp.asarray(lens),
                     jnp.asarray(self.tables[:, :nbr]), jnp.asarray(src),
-                    jnp.asarray(dst))
+                    jnp.asarray(dst), jnp.int32(len(pairs)))
         with obs.span("serving.dispatch", program="admit_prefix"):
             self.pools, f = fn(*args)
             self._note_admit_cost(fn, args)

@@ -19,6 +19,7 @@ written for a described device cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -502,6 +503,208 @@ def test_sharded_gpt2_small_step_lowers_on_four_chips(topo, monkeypatch):
                 }) == 4
 
 
+# -- pools held where they lie: no program copies one in or out (PR 40) -----
+
+def _default_format(S, shape, dtype):
+    """The format the described chip's runtime gives an array of this shape
+    when nothing is said: what a program's argument of it arrives in."""
+    return jax.jit(lambda p: p + 1).lower(S(shape, dtype)).compile() \
+        .input_formats[0][0]
+
+
+def _default_layout(S, shape, dtype):
+    return _default_format(S, shape, dtype).layout
+
+
+def _held(S, shape, dtype):
+    """``shape`` as the pool would hold it on the described chip: its own
+    rule (``paged._held_shape``) over the chip's default layout."""
+    import types
+    from paddle_tpu.serving import paged
+    held = paged._held_shape(types.SimpleNamespace(
+        format=_default_format(S, shape, dtype), shape=tuple(shape),
+        ndim=len(shape)))
+    # ... which the same runtime lays out row-major
+    assert tuple(_default_layout(S, held, dtype).major_to_minor) \
+        == tuple(range(len(held)))
+    return held
+
+
+#: a ``copy`` (or the start of an asynchronous one, whose result is a tuple
+#: that begins with the copy's) in optimized HLO text, group 1 its result
+#: ``dtype[dims]``; and HLO's names for jnp's dtypes
+_HLO_COPY = re.compile(r"= \(?(\w+\[[\d,]*\])[^\n=]*? copy(?:-start)?\(")
+_HLO_DTYPE = {"float32": "f32", "bfloat16": "bf16", "float16": "f16",
+              "int8": "s8", "int32": "s32"}
+
+
+def _pool_copies(text, pools, rows=()):
+    """``copy`` instructions of an optimized program that copy a whole
+    pool array, as held or as its rows are stated: what re-lays a pool
+    out."""
+    def hlo_type(a, shape):
+        name = jnp.dtype(a.dtype).name
+        return f"{_HLO_DTYPE.get(name, name)}[{','.join(map(str, shape))}]"
+    shapes = {hlo_type(a, shape) for a in pools.values()
+              for shape in [a.shape] + [a.shape[:2] + tuple(r) for r in rows]}
+    return [m.group(0) for m in _HLO_COPY.finditer(text)
+            if m.group(1) in shapes]
+
+
+def test_pool_copies_rule_finds_whole_pool_copies_alone():
+    """The rule the cases below hold the compiled programs to, on a text
+    with two copies of a pool array (one asynchronous), a copy of
+    something smaller, a ``copy(`` inside metadata and a consumer."""
+    text = ("  %copy.1 = f32[17,8,4,8]{0,3,2,1:T(8,128)} "
+            "copy(%param.3), metadata={}\n"
+            "  %copy-start.2 = (f32[17,8,4,8]{3,2,1,0:T(8,128)}, "
+            "f32[17,8,4,8]{0,3,2,1:T(8,128)S(1)}, u32[]{:S(2)}) "
+            "copy-start(%x)\n"
+            "  %copy-done.2 = f32[17,8,4,8]{3,2,1,0:T(8,128)} "
+            "copy-done(%copy-start.2)\n"
+            "  %copy.9 = f32[2,8,4,8]{3,2,1,0} copy(%y), "
+            "metadata={op_name=\"f32[17,8,4,8] copy(\"}\n"
+            "  %f = f32[17,8,4,8]{3,2,1,0} fusion(%copy.1)\n")
+    pools = {"k": jax.ShapeDtypeStruct((17, 8, 4, 8), jnp.float32)}
+    assert len(_pool_copies(text, pools)) == 2
+    assert len(_pool_copies(text, pools, [(4, 4)])) == 2
+    assert not _pool_copies(text, {"k": jax.ShapeDtypeStruct(
+        (17, 8, 4, 8), jnp.bfloat16)})
+
+
+def _gpt2_large_pool(S):
+    """gpt2-large's pool as both GPT-2 serve cells hold it — 16 slots, 105
+    pages of 64 rows, f32, 72 arrays stated ``[105, 64, 20, 64]`` —
+    described on the chip the way the pool holds it there: the runtime
+    lays the stated shape out pages-MINOR (``{0,3,2,1}``), so the rows are
+    held ``(24, 128)`` wide (two pages are held here)."""
+    from paddle_tpu.models import TransformerLM
+    from paddle_tpu.serving.paged import PagePool
+    model = TransformerLM(50257, d_model=1280, n_heads=20, n_layers=36,
+                          max_len=1024)
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
+    pool = PagePool(model, params, slots=16, pages=2, page_block=64)
+    stated = (105, 64, 20, 64)
+    assert tuple(_default_layout(S, stated, jnp.float32).major_to_minor) \
+        == (1, 2, 3, 0)
+    held = _held(S, stated, jnp.float32)
+    assert held == (105, 64, 24, 128)
+    pool.pools = {nm: S(held, a.dtype) for nm, a in pool.pools.items()}
+    return pool, params
+
+
+#: 72 arrays held f32[105, 64, 24, 128]: 82.6 MB each against 34.4 MB of
+#: stated rows — the bytes a row-major f32[105, 64, 20, 64] pads to there
+GPT2L_POOL_DEVICE_BYTES = 72 * 105 * 64 * 24 * 128 * 4
+
+
+@pytest.mark.parametrize("nb", [4, 16], ids=["cache-256", "cache-1024"])
+def test_gpt2_large_segment_program_keeps_the_pools_in_place(one_chip, S, nb,
+                                                             monkeypatch):
+    """The segment program both GPT-2 serve cells run (``PagePool._seg_fn``
+    : 32 decode steps over 16 slots, all 36 layers, the paged read on the
+    kernel route) compiled whole for the described chip under the
+    narrowest and the widest table: the pools go in and come out as the
+    pool holds them, in the runtime's own order — NO copy of a pool array
+    in the optimized program (the parent held 144: every pool re-laid out
+    from the runtime's pages-minor default for the kernel, and back),
+    every pool aliased at its size on the device, the kernel reading the
+    stated rows out of the held ones by a bitcast — and it fits beside
+    the weights."""
+    monkeypatch.setattr(pk, "_interpret", lambda interpret: False)
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    pool, params = _gpt2_large_pool(S)
+    compiled = pool._seg_fn(nb)._jitted.lower(
+        params, (pool.pools, {}), S((16, nb), jnp.int32), S((16,), jnp.int32),
+        S((16,), jnp.int32), S((16,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if " custom-call(" in ln
+             and "tpu_custom_call" in ln]
+    # the read keeps its name and its pool-shaped operands: what
+    # chipbench/metrics/paged_decode_roofline.py finds it by
+    assert calls and all("paged_decode_attention" in ln.split(" = ")[0]
+                         and "f32[105,64,20,64]" in ln for ln in calls)
+    assert not _pool_copies(text, pool.pools, [(20, 64)])
+    # a step's rows go in by ONE scatter an array, whole rows of the held
+    # width; a scatter into the rows' leading corner was expanded into a
+    # loop over the slots (73 loops, and twice the step on the chip)
+    assert text.count(" while(") == 1
+    assert len(re.findall(r"= f32\[105,64,24,128\]\S* scatter\(", text)) == 72
+    # the model's word to the compiler reached the program (``TransformerLM.
+    # decode_compiler_options``): a step's weights come into fast memory
+    # WHOLE, not in quarters — no ``slice-start`` in the loop's body and
+    # 820 asynchronous starts and waits where the compiler's own choice
+    # was 1,364 of a step's 2,085 operations, each an event the profiler
+    # must write (PERF.md section 6, PR 40)
+    start = text.index(
+        "\n%" + re.search(r"body=%?([\w.\-]+)", text).group(1) + " (")
+    body = text[start:text.index("\n}", start)]
+    waits = len(re.findall(r" (?:slice|copy)-(?:start|done)\(", body))
+    assert " slice-start(" not in body and waits <= 900, waits
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == GPT2L_POOL_DEVICE_BYTES
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 14 * 2 ** 30)
+    for fmt in jax.tree_util.tree_leaves(
+            (compiled.input_formats[0][1][0], compiled.output_formats[0][0])):
+        assert fmt.layout.major_to_minor == (0, 1, 2, 3)
+
+
+def test_page_writes_keep_rag_shaped_pools_in_place(S):
+    """The admission's page write and the pool's held shape at the
+    lfm2-serve-rag cell's — 6 arrays stated ``bf16[2305, 64, 8, 64]``,
+    which the runtime too lays out pages-minor, so held ``(8, 128)`` wide;
+    32 slots, a 512-token bucket: pages written one
+    ``dynamic_update_slice`` each into the leading corner of pools that
+    are donated and never copied, and a reader's view of the stated rows
+    (``pk.pool_rows``) is no copy either."""
+    from paddle_tpu.serving import paged
+    bf = jnp.bfloat16
+    stated = (2305, 64, 8, D)
+    assert tuple(_default_layout(S, stated, bf).major_to_minor) \
+        != (0, 1, 2, 3)
+    held = _held(S, stated, bf)
+    assert held == (2305, 64, 8, 128)
+    pools = {f"{kv}{i}": S(held, bf) for kv in "kv" for i in (1, 5, 9)}
+    cells = {nm: S((32, 512, 8, D), bf) for nm in pools}
+
+    def write(pools, cells, src, dst, n, tables):
+        pools = paged._write_pages(pools, cells, src, dst, n)
+        return pools, pk.gather_pages(
+            pk.pool_rows(pools["k1"], (8, D)), tables)
+    compiled = jax.jit(write, donate_argnums=(0,)).lower(
+        pools, cells, S((256, 2), jnp.int32), S((256,), jnp.int32),
+        S((), jnp.int32), S((32, 8), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "dynamic-update-slice" in text
+    assert not _pool_copies(text, pools, [(8, D)])
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 6 * 2305 * 64 * 8 * 128 * 2
+    for fmt in compiled.output_formats[0].values():
+        assert fmt.layout.major_to_minor == (0, 1, 2, 3)
+
+
+def test_gpt2_large_prefix_hit_program_keeps_the_pools_in_place(S):
+    """The prefix-hit program of the GPT-2 serve cells (``PagePool._hit_fn``
+    : copy-on-write of the matched partial pages, then
+    ``TransformerLM.prefill_paged`` over the suffixes) at the widest
+    bucket: the copy is a page at a time where the pages lie, so no pool
+    array is copied here either (a scatter of whole pages re-laid all 72
+    out and back, as the admission's did), every pool aliased."""
+    pool, params = _gpt2_large_pool(S)
+    compiled = pool._hit_fn(512, 8)._jitted.lower(
+        params, pool.pools, S((16, 512), jnp.int32), S((16,), jnp.int32),
+        S((16,), jnp.int32), S((16, 8), jnp.int32), S((16,), jnp.int32),
+        S((16,), jnp.int32), S((), jnp.int32)).compile()
+    assert not _pool_copies(compiled.as_text(), pool.pools, [(20, 64)])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == GPT2L_POOL_DEVICE_BYTES
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 14 * 2 ** 30)
+
+
 @pytest.mark.parametrize("bucket", [128, 256, 512])
 def test_gpt2_large_admit_program_compiles(one_chip, S, bucket, monkeypatch):
     """The admit program both GPT-2 serve cells run (``PagePool._admit_fn``
@@ -509,28 +712,22 @@ def test_gpt2_large_admit_program_compiles(one_chip, S, bucket, monkeypatch):
     slots, 105 pages of 64 rows, f32) compiled whole for the described
     chip at the cells' three widest prompt buckets: it fits beside the
     weights, the flash kernel is in it (from ``SHORT_SEQ_DENSE`` rows
-    on), and the pools are written where they lie (donated)."""
+    on), and the pools are written where they lie: donated, aliased at
+    their size on the device, NO copy of a pool array in the program (the
+    parent's scatter re-laid all 72 out and back) and none of a whole
+    array of the cell either (the pages are cut out of it first)."""
     monkeypatch.setattr(pk, "_interpret", lambda interpret: False)
-    from paddle_tpu.models import TransformerLM
-    from paddle_tpu.serving.paged import PagePool
-
-    model = TransformerLM(50257, d_model=1280, n_heads=20, n_layers=36,
-                          max_len=1024)
-    params = jax.tree_util.tree_map(
-        lambda a: S(a.shape, a.dtype),
-        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
-    # two pages held here; the program is lowered over the cells' 105
-    pool = PagePool(model, params, slots=16, pages=2, page_block=64)
-    pools = {nm: S((105,) + a.shape[1:], a.dtype)
-             for nm, a in pool.pools.items()}
+    pool, params = _gpt2_large_pool(S)
     nbp = bucket // 64
     compiled = pool._admit_fn(bucket, nbp)._jitted.lower(
-        params, (pools, {}), S((16, bucket), jnp.int32),
+        params, (pool.pools, {}), S((16, bucket), jnp.int32),
         S((16,), jnp.int32), S((16, nbp), jnp.int32)).compile()
-    assert (("tpu_custom_call" in compiled.as_text())
-            == (bucket >= pk.SHORT_SEQ_DENSE))
+    text = compiled.as_text()
+    assert (("tpu_custom_call" in text) == (bucket >= pk.SHORT_SEQ_DENSE))
+    assert not _pool_copies(text, pool.pools, [(20, 64)])
+    assert not [ln for ln in text.splitlines() if " copy(" in ln
+                and f"f32[16,{bucket},20,64]" in ln.split(" copy(")[0]]
     mem = compiled.memory_analysis()
-    held = sum(int(np.prod(a.shape)) * 4 for a in pools.values())
-    assert mem.alias_size_in_bytes >= held
+    assert mem.alias_size_in_bytes == GPT2L_POOL_DEVICE_BYTES
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 14 * 2 ** 30)

@@ -222,6 +222,22 @@ def test_program_build_instant_names_the_shape_bucket():
     assert not [e for e in events if e["name"] == "serving.program_build"]
 
 
+@pytest.mark.parametrize("state", ["logical", "device"])
+def test_pool_bytes_held_gauge_and_admit_page_counters(serve_run, state):
+    """The pool says what its page arrays hold as stated and as they lie
+    on the device (equal on the CPU, where nothing pads), and an
+    admission counts the pages it wrote beside its bucket's."""
+    got = {(smp["name"], smp["labels"].get("state")): smp.get("value")
+           for smp in serve_run[2]}
+    # 2 slots x 16 pages + null, pages of 8 rows x 4 heads x 8, k and v
+    # of 2 layers, f32
+    assert got[("serving.pool_bytes_held", state)] == 4 * 33 * 8 * 4 * 8 * 4
+    # prompts of 9 and 17 tokens: 2 + 3 pages written of the admissions'
+    # buckets
+    assert got[("serving.admit_pages_written_total", None)] == 5
+    assert got[("serving.admit_pages_bucket_total", None)] >= 2 * 3
+
+
 # -- why a request waited -------------------------------------------------------------
 
 def _blocked_run(model, params, *, slots, pages, sizes):

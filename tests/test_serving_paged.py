@@ -101,6 +101,274 @@ def test_admission_walk_matches_paged_greedy(fills, monkeypatch):
         np.testing.assert_array_equal(solo[prompt.size:], toks)
 
 
+# -- pools held where they lie, and the admission's page writes (PR 40) ------
+
+def _scatter_pages(pools, cells, src, dst, n):
+    """What ``_write_pages`` replaced — ONE scatter over the whole bucket,
+    every (row, page) pair of it, what holds no prompt sent wherever its
+    table entry points (the null page)."""
+    out = {}
+    for nm, pool in pools.items():
+        bs = pool.shape[1]
+        rows = cells[nm].reshape((cells[nm].shape[0], -1, bs)
+                                 + cells[nm].shape[2:])
+        corner = tuple(slice(0, k) for k in rows.shape[2:])
+        out[nm] = pool.at[(dst,) + corner].set(
+            rows[src[:, 0], src[:, 1]].astype(pool.dtype))
+    return out
+
+
+def _pages_minor_runtime(a):
+    """``_held_shape`` of an array the runtime lays out pages-minor under
+    an (8, 128) tile, as a TPU does a row narrower than its tile
+    (``f32[105, 64, 20, 64]``): the CPU lays everything out row-major, so
+    the answer is forced here."""
+    if a.ndim < 4:
+        return tuple(a.shape)
+    return tuple(a.shape[:-2]) + tuple(
+        -(-k // t) * t for k, t in zip(a.shape[-2:], (8, 128)))
+
+
+@pytest.fixture(params=["stated", "padded"])
+def held(request, monkeypatch):
+    """Pools held at the shape the model states (what the CPU's runtime
+    asks for) and held padded to the tile (what a TPU's does)."""
+    from paddle_tpu.serving import paged
+    if request.param == "padded":
+        monkeypatch.setattr(paged, "_held_shape", _pages_minor_runtime)
+    return request.param
+
+
+def test_pool_keeps_the_stated_arrays_where_padding_buys_nothing(
+        model_and_params, monkeypatch):
+    """The held shape is a rule read off one runtime: where the padded
+    array does not come out row-major either, the pool holds the arrays
+    as the model states them (the parent's programs, nothing padded) and
+    says so once."""
+    from paddle_tpu.serving import paged
+    model, params = model_and_params
+    monkeypatch.setattr(paged, "_held_shape", _pages_minor_runtime)
+    monkeypatch.setattr(paged, "_row_major", lambda a: False)
+    with pytest.warns(UserWarning, match="held as stated"):
+        pool = paged.PagePool(model, params, slots=2, segment=4, page_block=8,
+                              cache_bucket=32)
+    for nm, a in pool.pools.items():
+        assert a.shape == (pool.pages, pool.bs) + pool._row_shapes[nm], nm
+
+
+def test_held_shape_refuses_a_tile_it_cannot_pad_to():
+    """``_held_shape`` pads a row's last two dims to a 2-D first tile and
+    names any other tiling instead of guessing."""
+    import types
+    from paddle_tpu.serving import paged
+    lay = types.SimpleNamespace(major_to_minor=(1, 2, 3, 0),
+                                tiling=((1024,),))
+    a = types.SimpleNamespace(shape=(9, 8, 4, 8), ndim=4,
+                              format=types.SimpleNamespace(layout=lay))
+    with pytest.raises(ValueError, match="first tile"):
+        paged._held_shape(a)
+    lay.tiling = ((8, 128), (2, 1))
+    assert paged._held_shape(a) == (9, 8, 8, 128)
+
+
+def _marked_pool(model, params, kv_dtype, **kw):
+    """A pool whose every page holds values no admission writes, so that a
+    write where none belongs shows."""
+    from paddle_tpu.serving.paged import PagePool
+    pool = PagePool(model, params, slots=4, segment=4, page_block=8,
+                    cache_bucket=32, prompt_buckets=(16, 32),
+                    kv_dtype=kv_dtype, **kw)
+    rs = np.random.RandomState(5)
+    pool.pools = {nm: jnp.asarray(rs.randint(-100, 100, a.shape), a.dtype)
+                  for nm, a in pool.pools.items()}
+    return pool
+
+
+def _held_where_it_lay(pool, held):
+    """Every array is still the shape the pool built it in (the stated
+    rows ``[4, 8]``, or those padded to ``[8, 128]``), row-major."""
+    for nm, a in pool.pools.items():
+        want = pool._row_shapes[nm]
+        if held == "padded" and len(want) == 2:
+            want = (8, 128)
+        assert a.shape == (pool.pages, pool.bs) + want, nm
+        assert a.format.layout.major_to_minor == tuple(range(a.ndim)), nm
+
+
+def _stated(pool):
+    """The pool's arrays as numpy, their rows as the model states them."""
+    return {nm: np.asarray(pk.pool_rows(a, pool._row_shapes[nm]))
+            for nm, a in pool.pools.items()}
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["f32", "int8"])
+@pytest.mark.parametrize("lens", [(5,), (16, 9), (13, 1, 8, 24)],
+                         ids=["one", "two", "all"])
+def test_admission_writes_only_the_pages_it_admitted(model_and_params,
+                                                     monkeypatch, kv_dtype,
+                                                     lens, held):
+    """After an admission every page that holds no admitted prompt — the
+    null page, the free pages, the pages of slots admitted EARLIER — is
+    byte for byte what it was (the padding of a page held wider than its
+    rows too), and the pages it did write hold what the scatter over the
+    bucket put there."""
+    from paddle_tpu.serving import paged
+    model, params = model_and_params
+    rs = np.random.RandomState(9)
+    pool = _marked_pool(model, params, kv_dtype)
+    early = pool.admit([(1, pool.plan_admission(
+        rs.randint(0, VOCAB, 11).astype(np.int32), 6))])
+    assert list(early) == [1]
+    before = {nm: np.asarray(a) for nm, a in pool.pools.items()}
+    slots = [3, 0, 2, 1][:len(lens)]
+    if len(lens) == 4:              # all four: the early one goes first
+        pool.free_slot(1)
+    prompts = [rs.randint(0, VOCAB, n).astype(np.int32) for n in lens]
+    pool.admit([(s, pool.plan_admission(p, 6))
+                for s, p in zip(slots, prompts)])
+    wrote = {int(pg) for s, p in zip(slots, prompts)
+             for pg in pool.tables[s, :-(-p.size // 8)]}
+    assert 0 not in wrote and len(wrote) == sum(-(-n // 8) for n in lens)
+    kept = np.asarray([pg for pg in range(pool.pages) if pg not in wrote])
+    after = {nm: np.asarray(a) for nm, a in pool.pools.items()}
+    for nm in before:
+        np.testing.assert_array_equal(after[nm][kept], before[nm][kept],
+                                      err_msg=nm)
+    # ... and the written pages: what the scatter's programs put there
+    monkeypatch.setattr(paged, "_write_pages", _scatter_pages)
+    monkeypatch.setattr(paged, "_shared_fn_cache", lambda model: {})
+    ref = _marked_pool(model, params, kv_dtype)
+    rs = np.random.RandomState(9)
+    ref.admit([(1, ref.plan_admission(
+        rs.randint(0, VOCAB, 11).astype(np.int32), 6))])
+    if len(lens) == 4:
+        ref.free_slot(1)
+    ref.admit([(s, ref.plan_admission(p, 6))
+               for s, p in zip(slots, prompts)])
+    np.testing.assert_array_equal(ref.tables, pool.tables)
+    for nm, a in ref.pools.items():
+        np.testing.assert_array_equal(after[nm][1:], np.asarray(a)[1:],
+                                      err_msg=nm)
+    _held_where_it_lay(pool, held)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["f32", "int8"])
+@pytest.mark.parametrize("page_block", [8, 16, 32])
+def test_page_writes_serve_the_scatter_paths_tokens(model_and_params,
+                                                    monkeypatch, kv_dtype,
+                                                    page_block, held):
+    """The parity cases' traffic through the page-at-a-time write into
+    pools held as stated or padded, and through the scatter it replaced
+    into pools as stated (programs of its own, traced under the patch):
+    the same tokens, request for request."""
+    from paddle_tpu.serving import paged
+    model, params = model_and_params
+    rs = np.random.RandomState(3)
+    reqs = [Request(rid, rs.randint(0, VOCAB, int(rs.randint(3, 40))),
+                    int(rs.randint(1, 37))) for rid in range(9)]
+
+    def serve():
+        b = PagedBatcher(model, params, slots=4, segment=8,
+                         page_block=page_block, cache_bucket=32,
+                         kv_dtype=kv_dtype)
+        return b, b.serve(reqs)
+    b, got = serve()
+    assert all(a.shape[2:] == ((8, 128) if held == "padded" and a.ndim == 4
+                               else b.pool._row_shapes[nm])
+               for nm, a in b.pool.pools.items())
+    monkeypatch.undo()
+    monkeypatch.setattr(paged, "_write_pages", _scatter_pages)
+    monkeypatch.setattr(paged, "_shared_fn_cache", lambda model: {})
+    _, want = serve()
+    assert sorted(got) == sorted(want) == [r.rid for r in reqs]
+    for r in reqs:
+        np.testing.assert_array_equal(got[r.rid], want[r.rid])
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["f32", "int8"])
+@pytest.mark.parametrize("ends", ["same", "padded-to-stated",
+                                  "stated-to-padded"])
+def test_shipped_slot_lands_where_the_pages_lie(model_and_params,
+                                                monkeypatch, kv_dtype, ends):
+    """A slot ships as the rows the model states, whatever either pool
+    holds them in: ``export_slot`` cuts them out of a padded pool,
+    ``adopt_slot`` writes them — through a program that writes pages
+    where they lie, not an eager scatter over the pool — into the
+    adopted slot's pages and no other, and the segments after it are the
+    shipping pool's own."""
+    from paddle_tpu.serving import paged, ship
+    model, params = model_and_params
+    rs = np.random.RandomState(21)
+    prompt = rs.randint(0, VOCAB, 19).astype(np.int32)
+    if ends == "padded-to-stated":
+        monkeypatch.setattr(paged, "_held_shape", _pages_minor_runtime)
+    src = _marked_pool(model, params, kv_dtype)
+    first = src.admit([(2, src.plan_admission(prompt, 8))])[2]
+    manifest, payload = src.export_slot(2, first)
+    arrays = ship.unpack(manifest, payload)
+    assert all(arrays[nm].shape == (3, 8) + src._row_shapes[nm]
+               for nm in src.pools)
+    monkeypatch.undo()
+    if ends == "stated-to-padded":
+        monkeypatch.setattr(paged, "_held_shape", _pages_minor_runtime)
+    dst = _marked_pool(model, params, kv_dtype)
+    before = {nm: np.asarray(a) for nm, a in dst.pools.items()}
+    dst.adopt_slot(1, manifest["plen"], manifest["first"], arrays,
+                   dst.required_pages(prompt.size, 8))
+    _held_where_it_lay(dst, "padded" if ends == "stated-to-padded"
+                       else "stated")
+    wrote = [int(pg) for pg in dst.tables[1, :3]]
+    kept = np.asarray([pg for pg in range(dst.pages) if pg not in wrote])
+    for nm, a in dst.pools.items():
+        np.testing.assert_array_equal(np.asarray(a)[kept], before[nm][kept])
+    for nm, a in _stated(dst).items():
+        np.testing.assert_array_equal(a[wrote], arrays[nm])
+    np.testing.assert_array_equal(dst.run_segment([1])[1],
+                                  src.run_segment([2])[2])
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["f32", "int8"])
+def test_prefix_hit_copies_and_writes_only_its_own_pages(model_and_params,
+                                                         kv_dtype, held):
+    """The prefix-hit program carries the pools where they lie too: its
+    copy-on-write is a page copied where it lies (``_copy_pages``), not a
+    scatter over the pool. After a hit that diverges mid-block the pages
+    the hit slot does not OWN — the matched full blocks, the stored
+    partial page it copied from, every free page — are byte for byte what
+    they were (the null page takes the padded rows ``prefill_paged``
+    drains there), and its copy starts with the stored rows."""
+    model, params = model_and_params
+    rs = np.random.RandomState(5)
+    pool = _marked_pool(model, params, kv_dtype, prefix_cache=True)
+    shared = rs.randint(0, VOCAB, 21).astype(np.int32)   # 2 blocks + 5
+    pool.admit([(0, pool.plan_admission(shared, 6))])
+    before = {nm: np.asarray(a) for nm, a in pool.pools.items()}
+    # 19 shared tokens: two full-block hits, 3 rows into the stored tail
+    prompt = np.concatenate([shared[:19],
+                             rs.randint(0, VOCAB, 6).astype(np.int32)])
+    plan = pool.plan_admission(prompt, 6)
+    src = plan.match.partial.page
+    pool.admit([(1, plan)])
+    assert pool.cow_copies_total == 1
+    owned = [int(pg) for pg in pool.tables[1, 2:4]]      # positions 16..24
+    assert src not in owned and 0 not in owned
+    kept = np.asarray([pg for pg in range(1, pool.pages)
+                       if pg not in owned])
+    for nm, a in pool.pools.items():
+        a = np.asarray(a)
+        np.testing.assert_array_equal(a[kept], before[nm][kept], err_msg=nm)
+        np.testing.assert_array_equal(a[owned[0], :3], before[nm][src, :3],
+                                      err_msg=nm)
+    _held_where_it_lay(pool, held)
+    # ... and the tokens behind it are the solo decode's
+    solo = np.asarray(model.generate_cached(
+        params, jnp.asarray(prompt)[None], steps=6))[0, prompt.size:] \
+        if kv_dtype is None else None
+    toks = pool.run_segment([1])[1]
+    if solo is not None:
+        np.testing.assert_array_equal(toks[:4], solo[1:5])
+
+
 @pytest.mark.parametrize("case", ["defaults", "refusals"])
 def test_pool_defaults_and_grid_refusals(model_and_params, case):
     """The pool's geometry comes from its signature: page_block 64,

@@ -4,8 +4,9 @@ window, ``pk.paged_work_list``, ``pk.paged_decode_attention`` and
 parent's ``kernels.flash_block_pairs_total`` / plan counters;
 ``pk.grouped_matmul_blocks`` returns the parent's tuple at every (tm, K, N)
 the three expert cells call it with; and a page pool over a model that
-states no window builds the parent's admit and segment programs (the jaxpr's
-text). Beside them the new reach itself: the windowed read and the banded
+states no window builds the parent's segment program (the jaxpr's text; the
+admit program's fingerprint is PR 40's, whose write is a page at a time,
+printed the same way on that PR's tree). Beside them the new reach itself: the windowed read and the banded
 flash against their dense routes over ragged positions and lengths.
 
 ``GOLDEN`` was printed by this file run as a script on the parent commit
@@ -182,10 +183,10 @@ GOLDEN = {'blocks/128/1792/2048': [1792, 2048, True],
  'paged_read/dense/2': ['5667f40498527815', 1.0, 0.0],
  'paged_read/kernel/1': ['864d414c69b2d71c', 1.0, 1.0],
  'paged_read/kernel/2': ['9fd1e8c231df8bf6', 1.0, 1.0],
- 'programs/deepseek_v3': ['96468c8a21ae7bb5', '95fde98ee9fe12d7'],
- 'programs/gpt2': ['11e93e83654ba642', 'cbbda5484971172b'],
- 'programs/lfm2': ['2edbba88f6764ee2', 'b870028f323613a3'],
- 'programs/nemotron_h': ['f62c437394a93d92', '43b26abc6e4a81f6'],
+ 'programs/deepseek_v3': ['4310c24713a0c211', '95fde98ee9fe12d7'],
+ 'programs/gpt2': ['b345b8ed8fb7b5d0', 'cbbda5484971172b'],
+ 'programs/lfm2': ['31820185837e5f50', 'b870028f323613a3'],
+ 'programs/nemotron_h': ['d1066b4e2546ae0e', '43b26abc6e4a81f6'],
  'work_list': '2792a21f73e1639a'}
 
 
@@ -217,8 +218,11 @@ def test_grouped_matmul_blocks_are_the_parents(tm, K, N):
 @pytest.mark.parametrize("name", ["gpt2", "deepseek_v3", "lfm2",
                                   "nemotron_h"])
 def test_pool_programs_of_a_model_without_a_window_are_the_parents(name):
-    """No ring, no ring table among the arguments, no in-place page write:
-    the admit and the segment program are the parent's, text for text."""
+    """No ring, no ring table among the arguments: the SEGMENT program's
+    text is the one PR 39 pinned (PR 40's ``pk.put_rows`` over a pool held
+    as stated, as every pool on the CPU is, is the same scatter); the admit
+    program's is PR 40's, whose write is a page at a time where PR 39's
+    was one scatter."""
     assert list(fp_programs(name)) == GOLDEN[f"programs/{name}"]
 
 
