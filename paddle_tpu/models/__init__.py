@@ -6,6 +6,7 @@ from .deepseek_v3 import DeepseekV3LM
 from .embeddings import DeepFM, Recommender, Word2Vec
 from .generative import GAN, VAE
 from .lfm2 import Lfm2MoeLM
+from .keye_vl2 import KeyeSparseLM
 from .image import (AlexNet, GoogleNet, LeNet, ResNet, SmallNet,
                     VGG, resnet50)
 from .mlp import MnistMLP
@@ -22,5 +23,5 @@ __all__ = [
            "AttentionSeq2Seq", "LinearCRFTagger", "BiLSTMCRFTagger",
            "Word2Vec", "Recommender", "DeepFM", "GAN", "VAE",
            "TransformerLM", "TransformerBlock", "DeepseekV3LM", "Lfm2MoeLM",
-           "NemotronHLM", "AfmoeLM",
+           "NemotronHLM", "AfmoeLM", "KeyeSparseLM",
            "TransformerSeq2Seq", "CrossAttentionBlock"]

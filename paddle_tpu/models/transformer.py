@@ -35,12 +35,20 @@ class CacheRow(NamedTuple):
     of its slot's own (``[slots x ring + 1, page_block, *shape]``, position
     p in ring entry ``(p // page_block) % ring``) that stops growing with
     the context; ``None``: every position is read for as long as the
-    request lives, and the row's pages grow with it."""
+    request lives, and the row's pages grow with it.
+
+    ``held``: the shape the pool HOLDS the row in where the model's kernels
+    need one wider than the stated (KeyeSparseLM's 64-wide indexer key,
+    held at the chip's 128 lanes so that a page of it can be fetched by a
+    DMA): the stated row is the leading corner of the held one, what lies
+    past it keeps its fill, and everything that leaves the pool (a
+    shipment, ``pk.pool_rows``) is the stated row."""
     name: str
     shape: tuple
     dtype: object
     fill: float = 0.0
     window: Optional[int] = None
+    held: Optional[tuple] = None
 
 
 class SlotRow(NamedTuple):
@@ -99,8 +107,10 @@ def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
     admission costs what was admitted (but for the rows that fill up the
     last live chunk).
 
-    ``sequence(ids [R, T0], lengths [R]) -> (h [R, T0, d] f32, state,
-    stats)``; ``state0``: a pytree of ``[B, ...]`` buffers the chunks'
+    ``sequence(ids [R, T0], lengths [R]) -> (h [R, T0, d] f32 — or [R, d],
+    each row's hidden state at its last position already, from a sequence
+    that walks a row in blocks and keeps no more —, state, stats)``;
+    ``state0``: a pytree of ``[B, ...]`` buffers the chunks'
     ``state`` (same tree, ``[R, ...]``) is written into; ``stats0``: the
     tree the chunks' ``stats`` are summed into. Returns (each row's hidden
     state at its last position [B, d], state, stats); rows of length 0
@@ -123,7 +133,8 @@ def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
         idx = jax.lax.dynamic_slice(order, (i * R,), (R,))
         n = pos[idx]
         h, new, st = sequence(prompt[idx], n)
-        last = last.at[idx].set(h[jnp.arange(R), n - 1])
+        last = last.at[idx].set(h if h.ndim == 2
+                                else h[jnp.arange(R), n - 1])
         if write is not None:
             state = write(state, idx, n, new)
         elif in_place:

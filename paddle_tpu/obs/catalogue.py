@@ -507,6 +507,22 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                    "tiles a visit, all but the first of which find the "
                    "expert's matrix already fetched, labels: program",
         ("program",)),
+    "sparse.keys_scored_total": (
+        "counter", "keys a model that SELECTS what it reads "
+                   "(KeyeSparseLM's indexer) scored: program=segment live "
+                   "slot x step x layer x context, in the steps that read "
+                   "through the selection; program=admit the (query, key) "
+                   "pairs of the admitted rows' causal triangles x layers; "
+                   "the serving.segment span carries the same as "
+                   "keys_scored, serving.prefill as pairs_causal, labels: "
+                   "program", ("program",)),
+    "sparse.keys_selected_total": (
+        "counter", "keys the selection kept and the selected reads took, "
+                   "as pk.select_topk counted them on the device (at most "
+                   "index_topk a query; every key while a context is "
+                   "within it); the spans carry keys_selected (with the "
+                   "dense steps' rows, dense_rows) / pairs_selected, "
+                   "labels: program", ("program",)),
     "serving.prefix_hits_total": ("counter", "admissions that matched the "
                                              "prefix radix index and "
                                              "prefilled only their "

@@ -920,6 +920,22 @@ def _decode_attn_kernel(*refs, scale: float, chunk: int, quantized: bool,
         o_ref[0] = acc_ref[...] / l_ref[...]
 
 
+def _mxu_hi_lo(x, w, dims):
+    """x [H, .] (f32) against a matrix of cache rows, f32 accumulation: a
+    bf16 matrix goes to the MXU as it is and x as the sum of two bf16
+    halves, hi + lo, as ONE [2 H, .] operand, so the matrix is pushed to
+    the MXU once; any other matrix multiplies in its own dtype."""
+    dot = functools.partial(jax.lax.dot_general, dimension_numbers=(
+        dims, ((), ())), preferred_element_type=jnp.float32)
+    if w.dtype != jnp.bfloat16:
+        return dot(x, w)
+    H = x.shape[0]
+    hi = x.astype(jnp.bfloat16)
+    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    y = dot(jnp.concatenate([hi, lo], axis=0), w)
+    return y[:H] + y[H:]
+
+
 def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
                                 q_ref, *refs, scale: float, chunk: int,
                                 quantized: bool, groups: int,
@@ -984,19 +1000,7 @@ def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
             k, v = k.astype(jnp.float32), v.astype(jnp.float32)
         k, v = k.reshape(R, D), v.reshape(R, D)
 
-        def mxu(x, w, dims):
-            """x [H, .] (f32) against a page matrix, f32 accumulation; the
-            bf16 halves go as ONE [2 H, .] operand, so the matrix is pushed
-            to the MXU once."""
-            dot = functools.partial(jax.lax.dot_general, dimension_numbers=(
-                dims, ((), ())), preferred_element_type=jnp.float32)
-            if w.dtype != jnp.bfloat16:
-                return dot(x, w)
-            hi = x.astype(jnp.bfloat16)
-            lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-            y = dot(jnp.concatenate([hi, lo], axis=0), w)
-            return y[:H] + y[H:]
-
+        mxu = _mxu_hi_lo
         q = q_ref[0].astype(jnp.float32) * scale            # [H, D]
         s = mxu(q, k, ((1,), (1,)))                         # [H, R]
         col = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
@@ -2556,6 +2560,602 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, a: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# Attention that SELECTS what it reads (DeepSeek Sparse Attention's lightning
+# indexer; models/keye_vl2.py). A layer keeps a third row a token beside its
+# key and value: the INDEXER'S key, ``Di`` wide, one head. A query scores
+# every cached key with it, ``I[t, s] = sum_j w[t, j] * relu(qI[t, j] .
+# kI[s])`` over ``Hi`` small heads, the ``topk`` best are kept, and the
+# softmax runs over those alone. Five pieces, each with a dense route of the
+# same arithmetic for hosts that are not the TPU:
+#
+#   index_scores            an admission's scores, a block of queries against
+#                           the row's keys so far            [Q, L] f32
+#   index_scores_paged      a decode step's scores, a slot's pages of ``ik``
+#                           streamed a group of pages at a time   [B, L] f32
+#   select_topk             EXACT selection, no sort: the k-th largest score
+#                           of a row found by bisection over the scores' bit
+#                           patterns (32 counting passes over a row held in
+#                           VMEM), ties to the lower index by a second
+#                           bisection over the columns; gives the ADDITIVE
+#                           mask (0 selected, _NEG not) and the count
+#   selected_flash_attention  the admission's attention under that mask
+#   sparse_decode_attention   a decode step's read of the selected ROWS: each
+#                           row of k and of v fetched by its own DMA from the
+#                           page it lies in, so the bytes the read streams
+#                           follow the rows selected and not the context
+# ---------------------------------------------------------------------------
+
+_INT_MIN = -2 ** 31
+
+
+def _dense_index_scores(qi, w, ki):
+    s = jnp.einsum("hqd,ld->hql", qi, ki,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(jnp.moveaxis(w, 1, 0)[:, :, None] * jax.nn.relu(s),
+                   axis=0)
+
+
+def _index_scores_kernel(info_ref, q_ref, w_ref, k_ref, o_ref, *,
+                         block_q: int, block_k: int):
+    """Program (qi, ki): queries ``qi * block_q ..`` (the first at absolute
+    position ``info[0]``) against keys ``ki * block_k ..``; a tile wholly
+    past the last query's position is not computed (nor fetched, nor
+    written: the index maps stop at the last tile that is)."""
+    qi, ki = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(ki * block_k <= info_ref[0] + (qi + 1) * block_q - 1)
+    def _live():
+        k = k_ref[...]                                      # [block_k, Di]
+        acc = jnp.zeros((block_q, block_k), jnp.float32)
+        for j in range(q_ref.shape[0]):
+            s = jax.lax.dot_general(q_ref[j], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            acc = acc + w_ref[:, j:j + 1] * jnp.maximum(s, 0.0)
+        o_ref[...] = acc
+
+
+def index_scores(qi: jax.Array, w: jax.Array, ki: jax.Array, q0, *,
+                 block_q: int = 256, block_k: int = 512,
+                 route: Optional[str] = None,
+                 interpret: Optional[bool] = None) -> jax.Array:
+    """The indexer's scores of a block of queries: qi [Hi, Q, Di] (a head's
+    queries together), w [Q, Hi] f32, ki [L, Di] (the row's indexer keys,
+    the block's own among them), ``q0`` the absolute position of the first
+    query -> I [Q, L] f32, ``I[t, s] = sum_j w[t, j] relu(qi[j, t] .
+    ki[s])``. Entries with ``s`` past the block's last position are
+    UNDEFINED on the kernel route (:func:`select_topk` reads none of them).
+    Operands in their own dtype, float32 accumulation."""
+    Hi, Q, Di = qi.shape
+    L = ki.shape[0]
+    if route is None:
+        route = "kernel" if _on_tpu() else "dense"
+    from .. import obs
+    obs.count("kernels.routes_total", kernel="index_scores", route=route)
+    if route == "dense":
+        return _dense_index_scores(qi, w, ki)
+    from jax.experimental.pallas import tpu as pltpu
+    bq, bk = min(block_q, Q), min(block_k, L)
+    if Q % bq or L % bk:
+        raise ValueError(f"index_scores: {Q} queries x {L} keys are not "
+                         f"whole tiles of {bq} x {bk}")
+
+    def upto(qi_, ki_, info):
+        return jnp.minimum(ki_, (info[0] + (qi_ + 1) * bq - 1) // bk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(Q // bq, L // bk),
+        in_specs=[pl.BlockSpec((Hi, bq, Di), lambda a, b, i: (0, a, 0)),
+                  pl.BlockSpec((bq, Hi), lambda a, b, i: (a, 0)),
+                  pl.BlockSpec((bk, Di), lambda a, b, i: (upto(a, b, i), 0))],
+        out_specs=pl.BlockSpec((bq, bk),
+                               lambda a, b, i: (a, upto(a, b, i))))
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, block_q=bq, block_k=bk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Q, L), jnp.float32),
+        interpret=_interpret(interpret), name="index_scores",
+    )(jnp.asarray(q0, jnp.int32).reshape(1), qi, w.astype(jnp.float32), ki)
+
+
+#: pages of indexer keys one step of the decode scoring fetches together
+INDEX_PAGE_GROUP = 8
+
+
+def _index_scores_paged_kernel(tables_ref, pos_ref, q_ref, w_ref, ik_hbm,
+                               o_ref, buf, sem, *, bs: int, group: int):
+    """Program b: slot b's scores. The slot's pages of ``ik_hbm`` [P, bs,
+    Di] arrive ``group`` at a time (one DMA a page, the next group's in
+    flight while this one's product runs); a group's scores are one
+    product ``[Hi, Di] x [group * bs, Di]^T``. o [1, NG, group * bs]."""
+    from jax.experimental.pallas import tpu as pltpu
+    b = pl.program_id(0)
+    pos = pos_ref[b]
+    gw = group * bs
+    n_groups = (pos // bs) // group + 1
+
+    def copies(g, slot):
+        return [pltpu.make_async_copy(
+            ik_hbm.at[tables_ref[b, g * group + i]],
+            buf.at[slot, pl.ds(i * bs, bs)], sem.at[slot])
+            for i in range(group)]
+
+    o_ref[...] = jnp.full_like(o_ref, _NEG)
+    for c in copies(0, 0):
+        c.start()
+
+    def step(g, carry):
+        slot = jax.lax.rem(g, 2)
+
+        @pl.when(g + 1 < n_groups)
+        def _ahead():
+            for c in copies(g + 1, 1 - slot):
+                c.start()
+        for c in copies(g, slot):
+            c.wait()
+        s = jax.lax.dot_general(q_ref[0], buf[slot][:, :q_ref.shape[2]],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        sc = jnp.sum(w_ref[0] * jnp.maximum(s, 0.0), axis=0, keepdims=True)
+        col = g * gw + jax.lax.broadcasted_iota(jnp.int32, (1, gw), 1)
+        o_ref[0, pl.ds(g, 1), :] = jnp.where(col <= pos, sc, _NEG)
+        return carry
+    jax.lax.fori_loop(0, n_groups, step, 0)
+
+
+def index_scores_paged(qi: jax.Array, w: jax.Array, ik_pool: jax.Array,
+                       tables: jax.Array, pos: jax.Array, *,
+                       route: Optional[str] = None,
+                       interpret: Optional[bool] = None) -> jax.Array:
+    """A decode step's indexer scores through a block table: qi [B, Hi, Di],
+    w [B, Hi] f32, ik_pool [P, bs, W] with the keys in a row's first ``Di``
+    entries, tables [B, NB], pos [B] (the step's own key already written
+    at ``pos``) -> I [B, NB * bs] f32, ``_NEG`` past ``pos``. The read
+    streams the pages a slot has started and no others. ``W``: the chip
+    holds a row narrower than its 128 lanes in 128 lanes anyway, and a
+    page of such rows cannot be cut out of its tiles by a DMA (Mosaic:
+    "slice shape must be aligned to tiling"), so the serving pool holds
+    the indexer's row 128 wide (``CacheRow.held``), zeros past ``Di``; a
+    pool as narrow as its keys is widened here, by a copy (the solo
+    decode's, models/transformer.py ``paged_greedy``)."""
+    B, NB = tables.shape
+    P, bs, W = ik_pool.shape
+    Di = qi.shape[-1]
+    L = NB * bs
+    if route is None:
+        route = "kernel" if _on_tpu() else "dense"
+    from .. import obs
+    obs.count("kernels.routes_total", kernel="index_scores_paged",
+              route=route)
+    pos = pos.astype(jnp.int32)
+    if route == "dense":
+        k = gather_pages(ik_pool[:, :, :Di], tables)        # [B, L, Di]
+        s = jnp.einsum("bhd,bld->bhl", qi.astype(k.dtype), k,
+                       preferred_element_type=jnp.float32)
+        sc = jnp.sum(w.astype(jnp.float32)[:, :, None] * jax.nn.relu(s),
+                     axis=1)
+        return jnp.where(jnp.arange(L)[None, :] <= pos[:, None], sc, _NEG)
+    from jax.experimental.pallas import tpu as pltpu
+    if W % 128:
+        W = -(-W // 128) * 128
+        ik_pool = jnp.pad(ik_pool, ((0, 0), (0, 0), (0, W - ik_pool.shape[2])))
+    group = INDEX_PAGE_GROUP
+    ng = -(-NB // group)
+    tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, ng * group - NB)))
+    Hi = qi.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B,),
+        in_specs=[pl.BlockSpec((1, Hi, Di), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec((1, Hi, 1), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, ng, group * bs), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, group * bs, W), ik_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
+    out = pl.pallas_call(
+        functools.partial(_index_scores_paged_kernel, bs=bs, group=group),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, ng, group * bs), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(interpret), name="index_scores_paged",
+    )(tables, pos, qi.astype(ik_pool.dtype),
+      w.astype(jnp.float32)[:, :, None], ik_pool)
+    return out.reshape(B, ng * group * bs)[:, :L]
+
+
+def _dense_select_topk(scores, extent, k):
+    R, L = scores.shape
+    valid = jnp.arange(L)[None, :] < extent[:, None]
+    vals, idx = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf),
+                              min(k, L))
+    hit = jnp.zeros((R, L), bool).at[
+        jnp.arange(R)[:, None], idx].set(vals > -jnp.inf)
+    hit = hit & valid
+    return (jnp.where(hit, 0.0, _NEG).astype(jnp.float32),
+            jnp.sum(hit, axis=1, dtype=jnp.int32))
+
+
+def _select_kernel(top_ref, s_ref, ext_ref, bias_ref, cnt_ref, key_ref, *,
+                   k: int, chunk: int):
+    """Program i: ``rows`` rows of scores [rows, L] held whole in VMEM.
+    A score's bit pattern, sign-folded, orders as the score does; the k-th
+    largest key of a row is built a bit at a time, each bit one pass that
+    counts the keys at or above a candidate. Only the chunks under the
+    tile's largest extent (``top_ref[i]``) are read."""
+    i = pl.program_id(0)
+    rows, L = s_ref.shape
+    n_chunks = (top_ref[i] + chunk - 1) // chunk
+    ext = ext_ref[...]                                      # [rows, 1]
+
+    def cols(c):
+        return c * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+
+    def at(c):
+        return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+
+    def keys(c, carry):
+        bits = jax.lax.bitcast_convert_type(s_ref[:, at(c)], jnp.int32)
+        key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+        key_ref[:, at(c)] = jnp.where(cols(c) < ext, key, _INT_MIN)
+        return carry
+    jax.lax.fori_loop(0, n_chunks, keys, 0)
+
+    def count(hit):
+        """[rows, 1]: how many of a row's keys ``hit(key, cols)`` holds."""
+        acc = jax.lax.fori_loop(
+            0, n_chunks,
+            lambda c, a: a + hit(key_ref[:, at(c)], cols(c)).astype(
+                jnp.int32), jnp.zeros((rows, chunk), jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    zero = jnp.zeros((rows, 1), jnp.int32)
+    thr = jnp.where(count(lambda key, _: key >= 0) >= k, zero,
+                    zero + _INT_MIN)
+
+    def value_bit(j, thr):
+        cand = thr | jnp.left_shift(jnp.int32(1), 30 - j)
+        return jnp.where(count(lambda key, _: key >= cand) >= k, cand, thr)
+    thr = jax.lax.fori_loop(0, 31, value_bit, thr)
+    # the ties at the threshold go to the lowest columns: ``need`` of them.
+    # Where no row of the tile has more keys at its threshold than it
+    # needs (nearly always: float32 sums seldom tie), every tie is taken
+    # and the second bisection is not run.
+    need = k - count(lambda key, _: key > thr)
+    n_bits = max(1, (L - 1).bit_length())
+    at_thr = count(lambda key, col: (key == thr) & (col < ext))
+
+    def column_bit(j, cut):
+        cand = cut | jnp.left_shift(jnp.int32(1), n_bits - 1 - j)
+        below = count(lambda key, col: (key == thr) & (col < cand))
+        return jnp.where(below < need, cand, cut)
+    cut = jax.lax.cond(
+        jnp.max(at_thr - need) > 0,
+        lambda: jax.lax.fori_loop(0, n_bits, column_bit, zero),
+        lambda: zero + (L - 1))
+
+    def write(c, acc):
+        key, col = key_ref[:, at(c)], cols(c)
+        hit = (col < ext) & ((key > thr) | ((key == thr) & (col <= cut)))
+        bias_ref[:, at(c)] = jnp.where(hit, 0.0, _NEG)
+        return acc + hit.astype(jnp.int32)
+    acc = jax.lax.fori_loop(0, n_chunks, write,
+                            jnp.zeros((rows, chunk), jnp.int32))
+    cnt_ref[...] = jnp.sum(acc, axis=1, keepdims=True)
+
+    def blank(c, carry):
+        bias_ref[:, at(c)] = jnp.full((rows, chunk), _NEG, jnp.float32)
+        return carry
+    jax.lax.fori_loop(n_chunks, L // chunk, blank, 0)
+
+
+def select_topk(scores: jax.Array, extent: jax.Array, k: int, *,
+                rows: int = 8, chunk: Optional[int] = None,
+                route: Optional[str] = None,
+                interpret: Optional[bool] = None):
+    """EXACT top-``k`` of every row: scores [R, L] f32, extent [R] int32
+    (row r's keys are columns ``0 .. extent[r] - 1``; what lies past them
+    is never read) -> (bias [R, L] f32: 0 at the ``min(k, extent)``
+    largest scores of the row, ties to the lower column — jax.lax.top_k's
+    order — and ``_NEG`` everywhere else; count [R] int32 of the zeros).
+    No sort: see :func:`_select_kernel`."""
+    R, L = scores.shape
+    if route is None:
+        route = "kernel" if _on_tpu() else "dense"
+    from .. import obs
+    obs.count("kernels.routes_total", kernel="select_topk", route=route)
+    extent = jnp.clip(extent.astype(jnp.int32), 0, L)
+    if route == "dense":
+        return _dense_select_topk(scores, extent, k)
+    from jax.experimental.pallas import tpu as pltpu
+    # the widest chunk a pass walks a row in: fewer, longer loop bodies
+    chunk = chunk or next((c for c in (2048, 1024, 512, 256, 128)
+                           if L % c == 0), L)
+    rows, chunk = min(rows, R), min(chunk, L)
+    if R % rows or L % chunk:
+        raise ValueError(f"select_topk: [{R}, {L}] scores are not whole "
+                         f"tiles of {rows} rows and chunks of {chunk}")
+    top = jnp.max(extent.reshape(R // rows, rows), axis=1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(R // rows,),
+        in_specs=[pl.BlockSpec((rows, L), lambda i, t: (i, 0)),
+                  pl.BlockSpec((rows, 1), lambda i, t: (i, 0))],
+        out_specs=[pl.BlockSpec((rows, L), lambda i, t: (i, 0)),
+                   pl.BlockSpec((rows, 1), lambda i, t: (i, 0))],
+        scratch_shapes=[pltpu.VMEM((rows, L), jnp.int32)])
+    bias, cnt = pl.pallas_call(
+        functools.partial(_select_kernel, k=k, chunk=chunk),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((R, L), jnp.float32),
+                   jax.ShapeDtypeStruct((R, 1), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=6 * rows * L * 4 + (8 << 20)),
+        interpret=_interpret(interpret), name="select_topk",
+    )(top, scores.astype(jnp.float32), extent[:, None])
+    return bias, cnt[:, 0]
+
+
+def selected_rows(bias: jax.Array, k: int, page_block: int):
+    """The rows a mask selects, as a list: bias [B, L] (0 selected, as
+    :func:`select_topk` gives it, at most ``k`` a row) -> (position [B, k]
+    int32 ascending, n [B] int32 selected; entries past ``n`` are 0).
+    Neither a sort nor a scatter: a page's count places a list entry in
+    its page, and the page's running count places it in the page."""
+    B, L = bias.shape
+    bs = page_block
+    hit = (bias == 0.0).reshape(B, L // bs, bs)
+    inside = jnp.cumsum(hit.astype(jnp.int32), axis=2)      # [B, NP, bs]
+    upto = jnp.cumsum(inside[:, :, -1], axis=1)             # [B, NP]
+    j = jnp.arange(k, dtype=jnp.int32)
+    page = jnp.sum(upto[:, None, :] <= j[None, :, None], axis=2,
+                   dtype=jnp.int32)                         # [B, k]
+    page = jnp.minimum(page, L // bs - 1)
+    own = page[:, :, None] == jnp.arange(L // bs)[None, None, :]
+    before = jnp.sum(jnp.where(own, (upto - inside[:, :, -1])[:, None, :],
+                               0), axis=2)                  # [B, k]
+    # the page's running counts, fetched by a one-hot product (values to
+    # ``page_block``: exact in bfloat16 up to 256)
+    running = jnp.einsum("bkp,bps->bks", own.astype(jnp.bfloat16),
+                         inside.astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32)
+    row = jnp.sum(running <= (j[None, :] - before)[:, :, None].astype(
+        jnp.float32), axis=2, dtype=jnp.int32)
+    n = upto[:, -1]
+    ok = j[None, :] < n[:, None]
+    return jnp.where(ok, page * bs + jnp.minimum(row, bs - 1), 0), n
+
+
+#: selected rows one step of the sparse read gathers together
+SPARSE_ROWS = 256
+
+
+def _sparse_decode_kernel(loc_ref, n_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
+                          vbuf, sem, *, scale: float, rows: int, bs: int,
+                          groups: int):
+    """Program b: slot b's read of its ``n_ref[b]`` selected rows,
+    ``rows`` at a time: row j of the list lies at page ``loc // bs``, row
+    ``loc % bs`` of the pools [P, bs, Hkv, D] and is fetched by a DMA of
+    its own (k and v: two), the next ``rows`` in flight while these are
+    multiplied. The arithmetic is :func:`_grouped_decode_attn_kernel`'s: a
+    chunk collapses to ``[rows * Hkv, D]``, one product of all H queries
+    against it, the other groups' columns masked."""
+    from jax.experimental.pallas import tpu as pltpu
+    b = pl.program_id(0)
+    n = n_ref[b]
+    H, D = q_ref.shape[1:]
+    Hkv = H // groups
+    R = rows * Hkv
+    n_chunks = (n + rows - 1) // rows
+
+    def fetch(c, slot, wait: bool):
+        def one(i, carry):
+            loc = loc_ref[b, c * rows + i]
+            pg, r = loc // bs, jax.lax.rem(loc, bs)
+            for s, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(hbm.at[pg, r], buf.at[slot, i],
+                                           sem.at[s, slot])
+                cp.wait() if wait else cp.start()
+            return carry
+        jax.lax.fori_loop(0, rows, one, 0)
+
+    fetch(0, 0, False)
+    q = q_ref[0].astype(jnp.float32) * scale                # [H, D]
+
+    mxu = _mxu_hi_lo
+
+    def step(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _ahead():
+            fetch(c + 1, 1 - slot, False)
+        fetch(c, slot, True)
+        k, v = kbuf[slot], vbuf[slot]                       # [rows, Hkv, D]
+        if k.dtype != jnp.bfloat16 or Hkv % 2:
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        k, v = k.reshape(R, D), v.reshape(R, D)
+        s = mxu(q, k, ((1,), (1,)))                         # [H, R]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // groups
+        seen = (c * rows + col // Hkv < n) & (col % Hkv == head)
+        s = jnp.where(seen, s, _NEG)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        return (m_new, l_prev * corr + jnp.sum(p, axis=1, keepdims=True),
+                acc * corr + mxu(p, v, ((1,), (0,))))
+    _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, step,
+        (jnp.full((H, 1), _NEG, jnp.float32), jnp.zeros((H, 1), jnp.float32),
+         jnp.zeros((H, D), jnp.float32)))
+    o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
+
+
+def sparse_decode_attention(q: jax.Array, k_pool: jax.Array,
+                            v_pool: jax.Array, tables: jax.Array,
+                            where: jax.Array, n: jax.Array, *,
+                            scale: Optional[float] = None,
+                            route: Optional[str] = None,
+                            interpret: Optional[bool] = None) -> jax.Array:
+    """Single-token attention over SELECTED rows of a paged cache: q [B, H,
+    D]; k_pool / v_pool [P, bs, Hkv, D]; tables [B, NB]; where [B, K] int32
+    the selected positions of each slot (:func:`selected_rows`), the first
+    ``n`` [B] of them live -> o [B, H, D] f32, the softmax over those rows
+    alone. What the read streams is ``n`` rows of k and of v a slot,
+    whatever the context's length."""
+    B, K = where.shape
+    P, bs, Hkv, D = k_pool.shape
+    H = q.shape[1]
+    scale_v = scale if scale is not None else D ** -0.5
+    if route is None:
+        route = "kernel" if _on_tpu() else "dense"
+    from .. import obs
+    obs.count("kernels.routes_total", kernel="sparse_decode_attention",
+              route=route)
+    n = n.astype(jnp.int32)
+    # a position's place in the pool: its page's number x bs + its row
+    loc = jnp.take_along_axis(tables.astype(jnp.int32), where // bs,
+                              axis=1) * bs + where % bs
+    if route == "dense":
+        k = k_pool.reshape((P * bs, Hkv, D))[loc]           # [B, K, Hkv, D]
+        v = v_pool.reshape((P * bs, Hkv, D))[loc]
+        k = jnp.repeat(k, H // Hkv, axis=2).astype(jnp.float32)
+        v = jnp.repeat(v, H // Hkv, axis=2).astype(jnp.float32)
+        s = jnp.einsum("bhd,bjhd->bhj", q.astype(jnp.float32) * scale_v, k)
+        s = jnp.where((jnp.arange(K)[None, :] < n[:, None])[:, None, :], s,
+                      _NEG)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        return jnp.einsum("bhj,bjhd->bhd",
+                          p / jnp.sum(p, axis=-1, keepdims=True), v)
+    from jax.experimental.pallas import tpu as pltpu
+    rows = min(SPARSE_ROWS, K)
+    if K % rows:
+        raise ValueError(f"sparse_decode_attention: a list of {K} rows is "
+                         f"not whole chunks of {rows}")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, rows, Hkv, D), k_pool.dtype),
+                        pltpu.VMEM((2, rows, Hkv, D), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2))])
+    return pl.pallas_call(
+        functools.partial(_sparse_decode_kernel, scale=scale_v, rows=rows,
+                          bs=bs, groups=H // Hkv),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(interpret), name="sparse_decode_attention",
+    )(loc, n, q, k_pool, v_pool)
+
+
+def _selected_flash_kernel(info_ref, q_ref, k_ref, v_ref, b_ref, o_ref,
+                           m_ref, l_ref, acc_ref, *, scale: float,
+                           block_q: int, block_k: int):
+    """Program (g, qi, ki): KV head g's whole GROUP of query heads (their
+    ``block_q`` rows stacked: [G * block_q, D]) against key tile ki under
+    the additive mask tile [block_q, block_k], the same for every head.
+    Tiles past the q-block's last position are not visited."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    G, _, D = q_ref.shape[1:]
+    n_live = (info_ref[0] + (qi + 1) * block_q - 1) // block_k + 1
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(ki < n_live)
+    def _live():
+        q = q_ref[0].reshape(G * block_q, D)
+        s = jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = (s.reshape(G, block_q, block_k) + b_ref[...][None]).reshape(
+            G * block_q, block_k)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finish():
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).reshape(
+            G, block_q, D).astype(o_ref.dtype)
+
+
+def selected_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                             bias: jax.Array, q0, *,
+                             scale: Optional[float] = None,
+                             block_q: int = 128, block_k: int = 512,
+                             route: Optional[str] = None,
+                             interpret: Optional[bool] = None) -> jax.Array:
+    """An admission's attention under a per-query selection: q [Q, H, D] (a
+    block of queries, the first at absolute position ``q0``), k / v [L,
+    Hkv, D] (the row's keys, the block's own among them), bias [Q, L] f32
+    (:func:`select_topk`'s: 0 at the keys a query attends, ``_NEG``
+    elsewhere, the causal limit included) -> o [Q, H, D] in q's dtype.
+    Dense flash tiles under the mask; key tiles past the block's last
+    position are neither fetched nor multiplied."""
+    Q, H, D = q.shape
+    L, Hkv, _ = k.shape
+    G = H // Hkv
+    scale_v = scale if scale is not None else D ** -0.5
+    if route is None:
+        route = "kernel" if _on_tpu() else "dense"
+    from .. import obs
+    obs.count("kernels.routes_total", kernel="selected_flash_attention",
+              route=route)
+    if route == "dense":
+        s = jnp.einsum("qkgd,lkd->kgql",
+                       q.reshape(Q, Hkv, G, D).astype(jnp.float32),
+                       k.astype(jnp.float32)) * scale_v + bias[None, None]
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("kgql,lkd->qkgd", p, v.astype(jnp.float32))
+        return o.reshape(Q, H, D).astype(q.dtype)
+    from jax.experimental.pallas import tpu as pltpu
+    bq, bk = min(block_q, Q), min(block_k, L)
+    if Q % bq or L % bk:
+        raise ValueError(f"selected_flash_attention: {Q} queries x {L} keys "
+                         f"are not whole tiles of {bq} x {bk}")
+    qg = jnp.moveaxis(q, 1, 0).reshape(Hkv, G, Q, D)
+    kg, vg = jnp.moveaxis(k, 1, 0), jnp.moveaxis(v, 1, 0)   # [Hkv, L, D]
+
+    def upto(a, b, i):
+        return jnp.minimum(b, (i[0] + (a + 1) * bq - 1) // bk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(Hkv, Q // bq, L // bk),
+        in_specs=[
+            pl.BlockSpec((1, G, bq, D), lambda g, a, b, i: (g, 0, a, 0)),
+            pl.BlockSpec((1, bk, D), lambda g, a, b, i: (g, upto(a, b, i), 0)),
+            pl.BlockSpec((1, bk, D), lambda g, a, b, i: (g, upto(a, b, i), 0)),
+            pl.BlockSpec((bq, bk), lambda g, a, b, i: (a, upto(a, b, i)))],
+        out_specs=pl.BlockSpec((1, G, bq, D), lambda g, a, b, i: (g, 0, a, 0)),
+        scratch_shapes=[pltpu.VMEM((G * bq, 1), jnp.float32),
+                        pltpu.VMEM((G * bq, 1), jnp.float32),
+                        pltpu.VMEM((G * bq, D), jnp.float32)])
+    out = pl.pallas_call(
+        functools.partial(_selected_flash_kernel, scale=scale_v, block_q=bq,
+                          block_k=bk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Hkv, G, Q, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=_interpret(interpret), name="selected_flash_attention",
+    )(jnp.asarray(q0, jnp.int32).reshape(1), qg, kg, vg, bias)
+    return jnp.moveaxis(out.reshape(H, Q, D), 0, 1)
+
+
+# ---------------------------------------------------------------------------
 # Roofline cost models — Pallas custom calls report ZERO FLOPs/bytes to XLA's
 # cost analysis, so each kernel registers the analytic HBM bytes of one
 # dispatch with the obs cost ledger (obs/roofline.py register_kernel_cost).
@@ -2660,8 +3260,48 @@ def _ssd_chunk_scan_bytes(*, tokens, heads, head_dim, state, groups,
         + (heads + heads * head_dim) * 4.0)
 
 
+def _index_scores_paged_bytes(*, keys, index_dim, itemsize=2):
+    """HBM bytes of decode steps' indexer reads over ``keys`` cached keys
+    (live slot x step x layer x context, the count a program returns): a
+    key's ``index_dim`` values once each — the WORK's bytes; the pool
+    holds the row at the lane width and the kernel streams that."""
+    return float(keys) * index_dim * itemsize
+
+
+def _select_topk_bytes(*, keys):
+    """HBM bytes of selections over ``keys`` scores: each read once (f32)
+    and its mask entry written once (f32)."""
+    return float(keys) * 8.0
+
+
+def _sparse_decode_attention_bytes(*, rows, kv_heads, d_head, itemsize=2):
+    """HBM bytes of selected reads of ``rows`` cache rows (the count the
+    selection returns): a row of k and a row of v, each once."""
+    return 2.0 * rows * kv_heads * d_head * itemsize
+
+
+def _index_scores_bytes(*, pairs):
+    """HBM bytes of admissions' indexer scores over ``pairs`` causal
+    (query, key) pairs: a float32 score written for each."""
+    return float(pairs) * 4.0
+
+
+def _selected_flash_attention_bytes(*, pairs, kv_heads):
+    """HBM bytes of admissions' attention under the selection over
+    ``pairs`` causal (query, key) pairs: the float32 mask entry of each,
+    read once a KV head (a group's heads share the tile)."""
+    return float(pairs) * 4.0 * kv_heads
+
+
 def _register_cost_models():
     from ..obs import roofline
+    for name, fn in (
+            ("index_scores_paged", _index_scores_paged_bytes),
+            ("select_topk", _select_topk_bytes),
+            ("sparse_decode_attention", _sparse_decode_attention_bytes),
+            ("index_scores", _index_scores_bytes),
+            ("selected_flash_attention", _selected_flash_attention_bytes)):
+        roofline.register_kernel_cost(name, fn)
     roofline.register_kernel_cost("decode_attention",
                                   _decode_attention_bytes)
     roofline.register_kernel_cost("paged_decode_attention",

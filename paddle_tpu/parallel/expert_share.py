@@ -70,12 +70,21 @@ def row_tiles(counts, n_pairs: int):
 
 
 def route(scores_logits, bias, *, n_group: int, topk_group: int, top_k: int,
-          routed_scale: float, norm_eps: float = 1e-20):
-    """scores_logits [T, E] f32 (``y W_r``), bias [E] -> (experts [T, top_k]
-    int32, weights [T, top_k] f32)."""
+          routed_scale: float, norm_eps: float = 1e-20,
+          score: str = "sigmoid"):
+    """scores_logits [T, E] f32 (``y W_r``), bias [E] or None -> (experts
+    [T, top_k] int32, weights [T, top_k] f32). ``score``: the function the
+    model STATES — "sigmoid" of each logit (DeepSeek-V3 and its kin), or
+    "softmax" over ALL ``E`` logits (the width the router is published at,
+    before any share is applied)."""
     T, E = scores_logits.shape
-    s = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
-    pick = s + bias.astype(jnp.float32)
+    if score == "sigmoid":
+        s = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
+    elif score == "softmax":
+        s = jax.nn.softmax(scores_logits.astype(jnp.float32), axis=-1)
+    else:
+        raise ValueError(f"unknown router score function {score!r}")
+    pick = s if bias is None else s + bias.astype(jnp.float32)
     if n_group > 1:
         grouped = pick.reshape(T, n_group, E // n_group)
         group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
@@ -146,7 +155,8 @@ class ExpertShare(nn.Module):
                  norm_eps: float = 1e-20, n_shared: int = 1,
                  shared: bool = True, gated: bool = True,
                  shared_width: Optional[int] = None,
-                 up_transposed: bool = False, dtype=jnp.float32,
+                 up_transposed: bool = False, score: str = "sigmoid",
+                 bias: bool = True, dtype=jnp.float32,
                  init_std: float = 0.02):
         super().__init__()
         self.gated = gated
@@ -162,14 +172,15 @@ class ExpertShare(nn.Module):
         self.n_experts, self.held = n_experts, held
         self.route_kw = dict(n_group=n_group, topk_group=topk_group,
                              top_k=top_k, routed_scale=routed_scale,
-                             norm_eps=norm_eps)
+                             norm_eps=norm_eps, score=score)
         # global expert id -> local index; n_held where it is not here
         table = np.full((n_experts,), len(held), np.int32)
         table[held] = np.arange(len(held), dtype=np.int32)
         self._local = table
         init = normal(0.0, init_std)
         self.param("w_router", (d_model, n_experts), init, dtype=dtype)
-        self.param("e_bias", (n_experts,), zeros, dtype=jnp.float32)
+        if bias:
+            self.param("e_bias", (n_experts,), zeros, dtype=jnp.float32)
         up = (len(held), d_expert, d_model) if self.up_transposed \
             else (len(held), d_model, d_expert)
         if gated:
@@ -190,7 +201,7 @@ class ExpertShare(nn.Module):
         logits = jnp.dot(y.astype(jnp.float32),
                          params["w_router"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        return route(logits, params["e_bias"], **self.route_kw)
+        return route(logits, params.get("e_bias"), **self.route_kw)
 
     def _held_part(self, params, y, tok, local, w, tm, route_):
         """Σ over the pairs (tok, local expert, weight) of w * expert_e(y):

@@ -242,6 +242,17 @@ class PagePool:
     (:meth:`export_slot`) as the context's last ``ring`` pages under the
     rows' names. A model that states no reach runs the programs it ran.
 
+    A model that states no reach may still say ``admits_in_place``
+    (KeyeSparseLM: rows of up to 32,768 positions, three arrays a layer):
+    its admission is handed the pools and the pool's ``write`` the same
+    way, without a ring table, and says itself how many positions its walk
+    ran (``admitted_positions``: a row's own blocks, not its bucket). A
+    ``CacheRow`` may state the shape it is HELD in (``held``: that model's
+    64-wide indexer key at the chip's 128 lanes, so that a kernel can
+    fetch a page of it by DMA): the array is allocated at it, the stated
+    row is its leading corner (``pk.put_rows`` / ``pk.pool_rows``, as for
+    a row the pool pads itself), and a shipment carries the stated row.
+
     The geometry defaults (``page_block`` 64, ``cache_bucket`` 256,
     ``prompt_buckets`` 32..512) are the values the GPT-2 and GigaChat serve
     cells of the chip benchmark run and warm up; ``lfm2-serve-rag`` passes
@@ -319,8 +330,11 @@ class PagePool:
         self._row_shapes = {r.name: tuple(r.shape) for r in rows}
 
         def fresh(r):
+            # (a row that states a held shape is allocated at it: the
+            # stated row is its leading corner, like a padded one's)
             a = jnp.full(((slots * self.ring + 1 if r.window else self.pages),
-                          self.bs) + tuple(r.shape), r.fill, r.dtype)
+                          self.bs) + tuple(r.held or r.shape), r.fill,
+                         r.dtype)
             held = _held_shape(a)
             if held == a.shape:
                 return a
@@ -725,11 +739,13 @@ class PagePool:
             R = idx.shape[0]
             j = jnp.broadcast_to(jnp.arange(nbp)[None, :], (R, nbp))
             grown = _listed(j * bs < n[:, None], j, pages[idx])
-            top = jnp.maximum(n - 1, 0) // bs
-            a = top[:, None] - (n_ring - 1) + jnp.arange(n_ring)
-            rung = _listed(
-                (a >= 0) & (n > 0)[:, None], jnp.clip(a, 0, nbp - 1),
-                jnp.take_along_axis(ring_tables[idx], a % ring, axis=1))
+            rung = None     # no ring: a model that only ``admits_in_place``
+            if ring_tables is not None:
+                top = jnp.maximum(n - 1, 0) // bs
+                a = top[:, None] - (n_ring - 1) + jnp.arange(n_ring)
+                rung = _listed(
+                    (a >= 0) & (n > 0)[:, None], jnp.clip(a, 0, nbp - 1),
+                    jnp.take_along_axis(ring_tables[idx], a % ring, axis=1))
             cells = {nm: jnp.pad(
                 rows, ((0, 0), (0, nbp * bs - rows.shape[1]))
                 + ((0, 0),) * (rows.ndim - 2)) for nm, rows in new.items()}
@@ -752,19 +768,23 @@ class PagePool:
             model, kv_dtype, bs = self.model, self.kv_dtype, self.bs
             tpp, in_place = nbp * bs, self._in_place
             write = self._page_write(nbp)
+            pages_in_place = getattr(model, "admits_in_place", False)
 
             def admit(params, state, prompts, lens, pages, *ring_tables):
                 # pad_to=tpp: the transient cell holds prompt-bucket rows,
                 # not a max_len-padded (pinned-pool-sized) cache — the
                 # admission HBM spike stays proportional to the prompts
                 pools, slot_state = state
-                if ring_tables:
-                    # ... and a model with rings writes its pages in place,
-                    # a chunk at a time: no cell of keys and values at all
+                if ring_tables or pages_in_place:
+                    # ... and a model with rings (or one that says
+                    # ``admits_in_place``) writes its pages in place, a
+                    # chunk at a time: no cell of keys and values at all
                     cell, last = model.prefill(
                         params, prompts, lens, kv_dtype=kv_dtype, pad_to=tpp,
                         pools=pools,
-                        write=functools.partial(write, ring_tables[0], pages))
+                        write=functools.partial(
+                            write, ring_tables[0] if ring_tables else None,
+                            pages))
                     first = jnp.argmax(last, axis=-1).astype(prompts.dtype)
                     return (({nm: cell[nm] for nm in pools}, slot_state),
                             first, cell.get("stats", {}))
@@ -996,7 +1016,11 @@ class PagePool:
                 lens[slot] = plan.plen
                 n = min(nbp, len(self.slot_pages[slot]))
                 pages[slot, :n] = self.slot_pages[slot][:n]
-            self._account(work, len(miss), int(lens.sum()), tpad)
+            # (a model that walks a row's own blocks says how many
+            # positions that came to; the others' walk is the pool's)
+            ran = getattr(self.model, "admitted_positions", None)
+            self._account(work, len(miss), int(lens.sum()), tpad,
+                          positions=ran and ran(lens, tpad))
             fn = self._admit_fn(tpad, nbp)
             args = (self.params, (self.pools, self.slot_state),
                     jnp.asarray(prompts), jnp.asarray(lens),

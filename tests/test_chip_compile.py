@@ -430,6 +430,118 @@ def test_group_8_head_128_flash_forwards_compile(S, rows, T, window):
     assert f"bf16[{rows * 4},{T},128]" in call
 
 
+# -- KeyeSparseLM's selection at the keye-ep8-serve-longctx cell's shapes ----
+
+KEYE = dict(Q=2048, L=32768, B=8, NB=528, P=4225, K=2048)
+
+
+def _keye_cases():
+    bf, i32 = jnp.bfloat16, jnp.int32
+    Q, L, B, NB, P, K = (KEYE[k] for k in ("Q", "L", "B", "NB", "P", "K"))
+    kernel = dict(route="kernel", interpret=False)
+    return {
+        "index_scores": (
+            lambda q, w, k, q0: pk.index_scores(q, w, k, q0, **kernel),
+            [((16, Q, 64), bf), ((Q, 16),), ((L, 64), bf), ((), i32)]),
+        "index_scores_paged": (
+            lambda q, w, ik, t, p: pk.index_scores_paged(q, w, ik, t, p,
+                                                         **kernel),
+            [((B, 16, 64), bf), ((B, 16),), ((P, 64, 128), bf),
+             ((B, NB), i32), ((B,), i32)]),
+        "select_topk": (
+            lambda s, e: pk.select_topk(s, e, K, **kernel),
+            [((Q, L),), ((Q,), i32)]),
+        "select_topk-decode": (
+            lambda s, e: pk.select_topk(s, e, K, **kernel),
+            [((B, NB * 64),), ((B,), i32)]),
+        "sparse_decode_attention": (
+            lambda q, k, v, t, w, n: pk.sparse_decode_attention(
+                q, k, v, t, w, n, **kernel),
+            [((B, 32, 128),), ((P, 64, 4, 128), bf), ((P, 64, 4, 128), bf),
+             ((B, NB), i32), ((B, K), i32), ((B,), i32)]),
+        "selected_flash_attention": (
+            lambda q, k, v, b, q0: pk.selected_flash_attention(
+                q, k, v, b, q0, **kernel),
+            [((Q, 32, 128), bf), ((L, 4, 128), bf), ((L, 4, 128), bf),
+             ((Q, L),), ((), i32)]),
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "index_scores", "index_scores_paged", "select_topk",
+    "select_topk-decode", "sparse_decode_attention",
+    "selected_flash_attention"])
+def test_the_selections_kernels_compile_at_the_cells_shapes(S, name):
+    """A block of 2,048 queries against a 32,768-token row, and a decode
+    step of 8 slots over tables of 528 pages of 64: each of the five
+    kernels under its own name (what chipbench/metrics/
+    _keye_vl2_common.py finds it by). The indexer's pool is held 128 wide:
+    a page of 64-wide rows cannot be cut out of its tiles by a DMA."""
+    fn, avals = _keye_cases()[name]
+    text = _compile(fn, *(S(*a) for a in avals))
+    calls = [ln.split(" = ")[0].strip().lstrip("ROOT %")
+             for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert calls and all(c.startswith(name.split("-")[0]) for c in calls)
+
+
+def test_a_page_of_64_wide_rows_is_no_dma(S):
+    """Why ``CacheRow.held``: the scores' kernel over a pool as narrow as
+    the indexer's keys is refused by the chip's compiler, so the wrapper
+    widens such a pool (by a copy) and the serving pool holds it wide."""
+    fn, avals = _keye_cases()["index_scores_paged"]
+    narrow = [a if a[0] != (KEYE["P"], 64, 128) else ((KEYE["P"], 64, 64),
+                                                      jnp.bfloat16)
+              for a in avals]
+    text = _compile(fn, *(S(*a) for a in narrow))
+    assert "bf16[4225,64,128]" in text          # the widened copy
+
+
+@pytest.mark.parametrize("program", ["admit", "segment"])
+def test_keye_programs_keep_the_pools_in_place(one_chip, S, program,
+                                               monkeypatch):
+    """The cell's admit program at its one prompt bucket (32,768) and its
+    segment program, whole, at 2 of the 12 layers: no pool array is copied
+    (three kinds of row, the third held wider than stated), every pool is
+    aliased, and what a 32,768-token row expands is a BLOCK's: under 1 GiB
+    of temporaries (the same walk over 8,192-token rows a layer at a time
+    was 4.2 GB, PERF.md section 6, PR 39). All 12 layers, off this suite:
+    9.274 + 2.008 GiB (admit) and 9.273 + 0.029 GiB (segment)."""
+    import json
+    from chipbench import weights_keye_vl2
+    from paddle_tpu.serving.paged import PagePool
+    monkeypatch.setattr(pk, "_interpret", lambda interpret: False)
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "chipbench/configs/keye-vl2-30b-ep8-12l.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    model, shapes = weights_keye_vl2.model_and_shapes(cfg)
+    params = jax.tree_util.tree_map(lambda s: S(s.shape, s.dtype), shapes)
+    pool = PagePool.__new__(PagePool)
+    pool.model, pool.kv_dtype, pool.bs, pool.segment = model, None, 64, 32
+    pool.ring, pool._ring_names, pool._in_place = 0, set(), False
+    pool._slot_rows, pool._fns = [], {}
+    pools = {r.name: S((4225, 64) + tuple(r.held or r.shape), r.dtype)
+             for r in model.cache_rows({"embed": {"w": jnp.zeros(
+                 (1,), jnp.bfloat16)}})}
+    i32 = jnp.int32
+    if program == "admit":
+        compiled = pool._admit_fn(32768, 512)._jitted.lower(
+            params, (pools, {}), S((8, 32768), i32), S((8,), i32),
+            S((8, 512), i32)).compile()
+    else:
+        compiled = pool._seg_fn(528)._jitted.lower(
+            params, (pools, {}), S((8, 528), i32), S((8,), i32),
+            S((8,), i32), S((8,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert not _pool_copies(text, pools, [(4, 128), (64,)])
+    mem = compiled.memory_analysis()
+    held = sum(int(np.prod(a.shape)) * 2 for a in pools.values())
+    assert mem.alias_size_in_bytes == held
+    assert mem.temp_size_in_bytes < 2 ** 30
+
+
 def test_flash_attention_compiles_at_latent_head_width(S):
     """Prefill of the latent-attention model expands k and v and runs the
     flash kernel at head width 192 (128 + 64 rotary; v is 192 as well): 1.5
