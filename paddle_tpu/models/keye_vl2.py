@@ -198,8 +198,11 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         self.n_moe, self.n_held, self.top_k = n_layers, len(held), top_k
         self.embed = nn.Embedding(vocab, d_model, dtype=dtype,
                                   w_init=normal(0.0, init_std))
-        self.blocks = [KeyeBlock(
-            d_model, eps=eps, dtype=dtype, init_std=init_std,
+        # every layer is ONE constructor call repeated: ``_decode_layer``
+        # (below) computes any layer with ``blocks[0]``'s modules and that
+        # layer's parameters, which holds because no argument differs
+        layer = dict(
+            eps=eps, dtype=dtype, init_std=init_std,
             attn_kw=dict(n_heads=n_heads, kv_heads=kv_heads, d_head=d_head,
                          inv_freq=nn.yarn_inv_freq(d_head, rope_theta)),
             index_kw=dict(heads=index_heads, dim=index_dim,
@@ -208,10 +211,12 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
                         experts_held=held, top_k=top_k, n_group=1,
                         topk_group=1, routed_scale=1.0, norm_eps=0.0,
                         shared=False, score="softmax", bias=False))
-            for _ in range(n_layers)]
+        self.blocks = [KeyeBlock(d_model, **layer) for _ in range(n_layers)]
         self.norm_f = nn.RMSNorm(d_model, eps, dtype=dtype)
         self.head = nn.Embedding(vocab, d_model, dtype=dtype,
                                  w_init=normal(0.0, init_std))
+        self._decode_layer = jax.jit(self._decode_layer_impl,
+                                     static_argnames=("attn_route",))
 
     # -- what the page pool asks -------------------------------------------
     def cache_rows(self, params, kv_dtype: Optional[str] = None):
@@ -461,6 +466,48 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         return dict(state, pos=pos, stats=stats), self.logits(params, last)
 
     # -- one token against the paged cache ---------------------------------
+    def _decode_layer_impl(self, p, h, kc, vc, ic, pos, page, row, tables,
+                           work, past, live, alive, *, attn_route):
+        """One layer of :meth:`decode_step_paged`: the step's rows written
+        into the layer's three pools, the read, the experts -> (h, the
+        pools, the experts' counts, the keys the live slots' reads took).
+        A step calls this as ONE jitted function (``_decode_layer``) a
+        layer, so a program traces and lowers the layer — its ``cond``,
+        both reads, four kernels — once and not once a layer; XLA inlines
+        the calls and compiles what it compiled. ``blocks[0]``'s modules
+        stand for any layer's: ``__init__`` builds every block from the
+        same arguments, and ``p`` is the layer's own parameters. A model
+        whose layers differ would hand the layer's kind over as a static
+        argument. (Why this model alone: PERF.md section 6, PR 43.)"""
+        blk = self.blocks[0]
+        bs = kc.shape[1]
+        topk = min(self.index_topk, tables.shape[1] * bs)
+        x = blk.input_norm(p["input_norm"], h)
+        q, k, v = blk.attn.project(p["attn"], x, pos)
+        qi, ki, w = blk.idx.project(p["idx"], x, pos)
+        kp, k_rows = pk.put_rows(kc, page, row, k)
+        vp, v_rows = pk.put_rows(vc, page, row, v)
+        ip, _ = pk.put_rows(ic, page, row, ki)
+
+        def dense(_):
+            return pk.paged_decode_attention(
+                q, k_rows, v_rows, tables, pos, scale=blk.attn.scale,
+                work=work, route=attn_route), pos + 1
+
+        def sparse(_):
+            bias, cnt = pk.select_topk(
+                pk.index_scores_paged(qi, w, ip, tables, pos), pos + 1,
+                self.index_topk)
+            where, n = pk.selected_rows(bias, topk, bs)
+            return pk.sparse_decode_attention(
+                q, k_rows, v_rows, tables, where, n,
+                scale=blk.attn.scale), cnt
+        o, cnt = jax.lax.cond(past, sparse, dense, None)
+        h = h + blk.attn.output(p["attn"], o)
+        h, c = ffn_or_experts(blk, p, h, live)
+        return h, kp, vp, ip, c, jnp.sum(jnp.where(alive, cnt, 0),
+                                         dtype=jnp.int32)
+
     def decode_step_paged(self, params, cell, tokens, tables, *, live=None,
                           attn_route: Optional[str] = None):
         """TransformerLM.decode_step_paged's contract. Every layer writes
@@ -474,7 +521,6 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         pos = cell["pos"]
         bs = cell["k0"].shape[1]
         B = tokens.shape[0]
-        topk = min(self.index_topk, tables.shape[1] * bs)
         page = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
         row = pos % bs
         work = pk.paged_work_list(tables, pos, bs)
@@ -483,36 +529,15 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         h = self.embed(params["embed"], tokens).astype(jnp.float32)
         new_cell = {"pos": pos + 1}
         counts, selected = [], []
-        for i, blk in enumerate(self.blocks):
-            p = params[f"blocks_{i}"]
-            x = blk.input_norm(p["input_norm"], h)
-            q, k, v = blk.attn.project(p["attn"], x, pos)
-            qi, ki, w = blk.idx.project(p["idx"], x, pos)
-            kp, k_rows = pk.put_rows(cell[f"k{i}"], page, row, k)
-            vp, v_rows = pk.put_rows(cell[f"v{i}"], page, row, v)
-            ip, _ = pk.put_rows(cell[f"ik{i}"], page, row, ki)
+        for i in range(len(self.blocks)):
+            h, kp, vp, ip, c, cnt = self._decode_layer(
+                params[f"blocks_{i}"], h, cell[f"k{i}"], cell[f"v{i}"],
+                cell[f"ik{i}"], pos, page, row, tables, work, past, live,
+                alive, attn_route=attn_route)
             new_cell[f"k{i}"], new_cell[f"v{i}"], new_cell[f"ik{i}"] = \
                 kp, vp, ip
-
-            def dense(_):
-                return pk.paged_decode_attention(
-                    q, k_rows, v_rows, tables, pos, scale=blk.attn.scale,
-                    work=work, route=attn_route), pos + 1
-
-            def sparse(_):
-                bias, cnt = pk.select_topk(
-                    pk.index_scores_paged(qi, w, ip, tables, pos), pos + 1,
-                    self.index_topk)
-                where, n = pk.selected_rows(bias, topk, bs)
-                return pk.sparse_decode_attention(
-                    q, k_rows, v_rows, tables, where, n,
-                    scale=blk.attn.scale), cnt
-            o, cnt = jax.lax.cond(past, sparse, dense, None)
-            h = h + blk.attn.output(p["attn"], o)
-            h, c = ffn_or_experts(blk, p, h, live)
             counts.append(c)
-            selected.append(jnp.sum(jnp.where(alive, cnt, 0),
-                                    dtype=jnp.int32))
+            selected.append(cnt)
         if "stats" in cell:
             steps = jnp.sum(alive, dtype=jnp.int32)
             keys = jnp.sum(jnp.where(alive, pos + 1, 0), dtype=jnp.int32)

@@ -575,13 +575,22 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                    "serving.prefill span carries the same as "
                    "prompt_tokens / positions", ("state",)),
     "serving.segment_slot_steps_total": (
-        "counter", "slot-steps the segment programs ran (slots x "
-                   "segment), labels: state (emitted = delivered a token "
+        "counter", "slot-steps the segment programs ran (slots x the "
+                   "steps run), labels: state (emitted = delivered a token "
                    "to a request; overshoot = a live slot's step past its "
                    "request's last token, or the re-emitted first one; "
                    "idle = a slot with no request); the serving.emit span "
                    "behind the segment carries the same as emitted / "
                    "live_steps / slot_steps", ("state",)),
+    "serving.segment_steps_total": (
+        "counter", "decode steps of the segment dispatches, labels: state "
+                   "(run = steps the programs ran: the most a live request "
+                   "could still use, its budget and the first token its "
+                   "first segment re-emits, at most --segment; cut = "
+                   "--segment less that); cut / (run + cut) is the share "
+                   "of whole segments' steps the engine did not run; the "
+                   "serving.segment and serving.emit spans carry a "
+                   "dispatch's as steps", ("state",)),
     # disaggregation: KV-page shipping (serving/ship.py wire contract)
     "serving.ship_pages_total": ("counter", "KV pages exported for "
                                             "shipping to a decode worker "
@@ -715,8 +724,9 @@ SPANS: Dict[str, str] = {
                        "routed_here, experts_touched, row_tiles, load_max "
                        "of the admit program). Its length is added to "
                        "stalled_s of every request already live",
-    "serving.segment": "one batched decode segment across live slots "
-                       "(args: live; with expert layers also routed_here, "
+    "serving.segment": "one batched decode segment across live slots: "
+                       "at most --segment steps (args: live, steps = the "
+                       "steps it ran; with expert layers also routed_here, "
                        "experts_touched, row_tiles, load_max). Its length "
                        "is added to decode_s of every request in it",
     "serving.schedule": "a locked section of the scheduler: reaping "
@@ -739,9 +749,9 @@ SPANS: Dict[str, str] = {
                    "and serving.segment carry the same four",
     "serving.emit": "the locked token hand-out after a prefill or a "
                     "segment (args: after = prefill | segment; after a "
-                    "segment also slot_steps = slots x segment the program "
-                    "ran, live_steps = live x segment, emitted = tokens "
-                    "requests received)",
+                    "segment also steps = the steps the program ran, "
+                    "slot_steps = slots x steps, live_steps = live x "
+                    "steps, emitted = tokens requests received)",
     "serving.ship": "client side of one KV shipment: every srv_ship chunk "
                     "RPC for one request (args: xid, bytes, key)",
     "srv_ship": "decode-side landing of one ship chunk (args: xid, seq; "

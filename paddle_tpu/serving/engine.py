@@ -610,15 +610,24 @@ class ServingEngine:
 
     def decode_segment(self) -> None:
         """Phase 2: one batched decode dispatch over every live slot, then
-        collect tokens / finish requests / return pages."""
+        collect tokens / finish requests / return pages. The dispatch runs
+        the steps its live requests can still use — the longest remaining
+        budget, with the first token a request's first segment re-emits —
+        and at most ``segment``; an EOS cannot be foreseen and ends a
+        request inside a block as it always did."""
+        segment = self.pool.segment
         with self._lock:
             live = sorted(self._live)
+            owed = [rec.left + rec.skip for rec in self._live.values()]
         if not live:
             return
+        steps = min(segment, max(owed))
         began = self._clock()
-        with obs.span("serving.segment", live=len(live)) as span, \
+        with obs.span("serving.segment", live=len(live),
+                      steps=steps) as span, \
                 maybe_bucket(self._gp, "device"):
-            block = self.pool.run_segment(live)  # device work, lock released
+            # device work, lock released
+            block = self.pool.run_segment(live, steps)
             span.note(**self.pool.last_stats)
         seg_s = self._clock() - began
         with obs.span("serving.emit", after="segment") as emit, \
@@ -644,18 +653,20 @@ class ServingEngine:
                 if done:
                     self._release_locked(rec, reason)
             # the segment's work against what it delivered: the program
-            # ran every slot for every step; a live slot's steps past its
-            # request's last token (and the re-emitted first one) are
-            # overshoot, the other slots' idle
-            slot_steps = self.pool.n_slots * self.pool.segment
-            live_steps = len(live) * self.pool.segment
-            emit.note(slot_steps=slot_steps, live_steps=live_steps,
-                      emitted=emitted)
+            # ran every slot for every step it ran; a live slot's steps
+            # past its request's last token (and the re-emitted first one)
+            # are overshoot, the other slots' idle
+            slot_steps = self.pool.n_slots * steps
+            live_steps = len(live) * steps
+            emit.note(steps=steps, slot_steps=slot_steps,
+                      live_steps=live_steps, emitted=emitted)
             for state, n in (("emitted", emitted),
                              ("overshoot", live_steps - emitted),
                              ("idle", slot_steps - live_steps)):
                 obs.count("serving.segment_slot_steps_total", n,
                           state=state)
+            for state, n in (("run", steps), ("cut", segment - steps)):
+                obs.count("serving.segment_steps_total", n, state=state)
             self._set_gauges_locked()
 
     # -- internals (call with _lock held) ----------------------------------

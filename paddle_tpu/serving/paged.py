@@ -198,7 +198,7 @@ class PagePool:
     ``SlotRow`` (models/transformer.py: state of a fixed size whatever the
     context, e.g. Lfm2MoeLM's convolution tails) an array ``[slots,
     *shape]`` beside the pages (``slot_state``): written for the admitted
-    slots from ``prefill``'s cell, carried through the segment scan in the
+    slots from ``prefill``'s cell, carried through the segment loop in the
     cell — which keeps the LIVE slots' rows and puts every other slot's
     back to its fill, so a freed slot is clear after the next segment with
     no program of its own — shipped by :meth:`export_slot` /
@@ -214,7 +214,7 @@ class PagePool:
     (``slot_state=``) and takes them back written at the admitted slots'
     indices — no full-width ``[slots, ...]`` array of fresh rows, no
     ``where`` over all of it; the segment program carries them through
-    the scan, where the model updates them in place, and puts the dead
+    the loop, where the model updates them in place, and puts the dead
     slots' rows back to their fill by a scatter at those slots alone.
     Every other model keeps the programs it had — why there are two
     paths: the ``where`` blend is what ``lfm2-serve-rag`` is measured on
@@ -325,6 +325,10 @@ class PagePool:
         # any slot count share a model's programs)
         self._ring_args = (jnp.asarray(self.ring_tables),) if self.ring \
             else ()
+        # a segment's step count as the program takes it, [] int32 on the
+        # device, one a value: a dispatch puts nothing there for it
+        self._step_args = [jnp.asarray(n, jnp.int32)
+                           for n in range(segment + 1)]
         #: the rows as the model STATES them; the arrays may be held
         #: wider (:func:`_held_shape`)
         self._row_shapes = {r.name: tuple(r.shape) for r in rows}
@@ -857,7 +861,8 @@ class PagePool:
             fills = {r.name: r.fill for r in self._slot_rows}
             in_place = self._in_place
 
-            def seg(params, state, tables, pos, cur, live, *ring_tables):
+            def seg(params, state, tables, pos, cur, live, steps,
+                    *ring_tables):
                 pools, slot_state = state
                 ring_kw = dict(ring_tables=ring_tables[0]) if ring_tables \
                     else {}
@@ -865,14 +870,19 @@ class PagePool:
                 if hasattr(model, "program_stats_zero"):
                     cell["stats"] = model.program_stats_zero()
 
-                def body(carry, _):
-                    cell, cur = carry
+                # ``steps`` [] int32 is TRACED, 1..segment: the one program
+                # of this table width runs the steps the host asks for and
+                # writes step i's tokens into row i of a [segment, slots]
+                # block; the rows from ``steps`` on hold nothing
+                def body(i, carry):
+                    cell, cur, toks = carry
                     logits, cell = model.decode_step_paged(
                         params, cell, cur, tables, live=live, **ring_kw)
                     nxt = jnp.argmax(logits, axis=-1).astype(cur.dtype)
-                    return (cell, nxt), cur
-                (cell, cur), toks = jax.lax.scan(body, (cell, cur), None,
-                                                 length=segment)
+                    return cell, nxt, toks.at[i].set(cur)
+                cell, cur, toks = jax.lax.fori_loop(
+                    0, steps, body,
+                    (cell, cur, jnp.zeros((segment,) + cur.shape, cur.dtype)))
                 # a slot that is not live keeps no per-slot state: a freed
                 # slot's rows are back at their fill after the next segment
                 # (no program of its own for that; none rolls idle either)
@@ -1140,12 +1150,19 @@ class PagePool:
                 idx._credit(entry, idx.page_bytes * len(tail) / self.bs)
                 self.slot_partial[slot] = entry
 
-    def run_segment(self, live: Sequence[int]) -> np.ndarray:
-        """One decode segment across the whole pool; returns the emitted
-        token block [slots, segment] (drained slots' rows are garbage).
-        Grows live slots' tables first, so no mid-scan allocation exists.
-        A slot outside ``live`` decodes from pos 0 against its null table:
-        one work item of the paged read, page 0."""
+    def run_segment(self, live: Sequence[int],
+                    steps: Optional[int] = None) -> np.ndarray:
+        """One decode segment across the whole pool: ``steps`` decode steps
+        (``segment``, the most a dispatch runs, unless the caller knows
+        that no live slot can use as many); returns the emitted token block
+        [slots, steps] (drained slots' rows are garbage). The count is an
+        ARGUMENT of the table width's one program, not a program of its
+        own. Grows live slots' tables first, so no mid-loop allocation
+        exists. A slot outside ``live`` decodes from pos 0 against its null
+        table: one work item of the paged read, page 0."""
+        steps = self.segment if steps is None else int(steps)
+        if not 1 <= steps <= self.segment:
+            raise ValueError(f"steps {steps} outside 1..{self.segment}")
         with obs.span("serving.stage", what="tables"):
             for i in live:
                 self._ensure(i, int(self.pos[i]) + self.segment)
@@ -1162,8 +1179,8 @@ class PagePool:
             alive[idx] = True
             args = (self.params, (self.pools, self.slot_state),
                     jnp.asarray(self.tables[:, :nb]), jnp.asarray(pos),
-                    jnp.asarray(self.cur), jnp.asarray(alive)) \
-                + self._ring_args
+                    jnp.asarray(self.cur), jnp.asarray(alive),
+                    self._step_args[steps]) + self._ring_args
         with obs.span("serving.dispatch", program="segment"):
             (self.pools, self.slot_state), cur, toks, stats = fn(*args)
             obs.count("decode.dispatches_total", route="serve_segment")
@@ -1171,12 +1188,12 @@ class PagePool:
             # pos (pk.paged_work_list's rule, step by step) against the
             # whole table's — one per (layer, step, slot, page)
             calls = self._read_layers
-            steps = np.arange(self.segment, dtype=np.int32)
+            ran = np.arange(steps, dtype=np.int32)
             walked = calls * int(np.minimum(
-                (pos[:, None] + steps[None, :]) // self.bs + 1, nb).sum())
+                (pos[:, None] + ran[None, :]) // self.bs + 1, nb).sum())
             obs.count("serving.decode_pages_walked_total", walked)
             obs.count("serving.decode_pages_table_total",
-                      calls * self.segment * self.n_slots * nb)
+                      calls * steps * self.n_slots * nb)
             # modeled cache-read bytes through the ONE registered model of
             # the model's own decode read (ops/pallas_kernels
             # ._paged_decode_attention_bytes / _paged_latent_attention_bytes)
@@ -1186,18 +1203,18 @@ class PagePool:
                 self._read_kernel, pages=walked, page_block=self.bs,
                 **self._read_geom) or 0.0
             obs.count("kernels.bytes_total", read, kernel=self._read_kernel)
-            reach = self._note_ring_reads(pos, steps, idx) if self.ring \
+            reach = self._note_ring_reads(pos, ran, idx) if self.ring \
                 else {}
             self.segments_total += 1
             self.read_bytes_total += read
             self.occupancy_num += self.live_tokens(live)
             self.occupancy_den += max(self.pages_used, 1) * self.bs
-            self.pos[idx] += self.segment
+            self.pos[idx] += steps
         with obs.span("serving.fetch", program="segment"):
             self.cur = np.array(cur)  # writable copy: admit() merges into it
             self._note_stats(stats, "segment")
             self.last_stats = dict(self.last_stats, **reach)
-            return np.asarray(toks)                   # [slots, segment]
+            return np.asarray(toks)[:, :steps]        # [slots, steps]
 
     def _note_ring_reads(self, pos, steps, live) -> Dict[str, int]:
         """A segment's reads of a model with rings, from the host's own
@@ -1320,7 +1337,10 @@ class PagedBatcher:
         admit()
         while any(s is not None for s in slots):
             live = [i for i, s in enumerate(slots) if s is not None]
-            block = pool.run_segment(live)
+            # no more steps than the longest live budget (the block's first
+            # token is the prefill's, so budgets count from it)
+            block = pool.run_segment(
+                live, min(pool.segment, int(left[live].max())))
             for i in live:
                 r = slots[i]
                 take, done, _ = clip_emission(block[i], int(left[i]),

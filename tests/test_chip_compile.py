@@ -501,9 +501,10 @@ def test_a_page_of_64_wide_rows_is_no_dma(S):
 def test_keye_programs_keep_the_pools_in_place(one_chip, S, program,
                                                monkeypatch):
     """The cell's admit program at its one prompt bucket (32,768) and its
-    segment program, whole, at 2 of the 12 layers: no pool array is copied
-    (three kinds of row, the third held wider than stated), every pool is
-    aliased, and what a 32,768-token row expands is a BLOCK's: under 1 GiB
+    segment program (its step count a traced argument), whole, at 2 of the
+    12 layers: no pool array is copied (three kinds of row, the third held
+    wider than stated), every pool is aliased, the segment's steps are one
+    loop, and what a 32,768-token row expands is a BLOCK's: under 1 GiB
     of temporaries (the same walk over 8,192-token rows a layer at a time
     was 4.2 GB, PERF.md section 6, PR 39). All 12 layers, off this suite:
     9.274 + 2.008 GiB (admit) and 9.273 + 0.029 GiB (segment)."""
@@ -533,9 +534,14 @@ def test_keye_programs_keep_the_pools_in_place(one_chip, S, program,
     else:
         compiled = pool._seg_fn(528)._jitted.lower(
             params, (pools, {}), S((8, 528), i32), S((8,), i32),
-            S((8,), i32), S((8,), jnp.bool_)).compile()
+            S((8,), i32), S((8,), jnp.bool_), S((), i32)).compile()
     text = compiled.as_text()
     assert not _pool_copies(text, pools, [(4, 128), (64,)])
+    if program == "segment":
+        # the steps are ONE loop under the traced count (the reads below
+        # ``index_topk`` and the selected ones are a ``conditional`` a
+        # layer inside it, no loop of their own)
+        assert text.count(" while(") == 1
     mem = compiled.memory_analysis()
     held = sum(int(np.prod(a.shape)) * 2 for a in pools.values())
     assert mem.alias_size_in_bytes == held
@@ -716,8 +722,9 @@ GPT2L_POOL_DEVICE_BYTES = 72 * 105 * 64 * 24 * 128 * 4
 def test_gpt2_large_segment_program_keeps_the_pools_in_place(one_chip, S, nb,
                                                              monkeypatch):
     """The segment program both GPT-2 serve cells run (``PagePool._seg_fn``
-    : 32 decode steps over 16 slots, all 36 layers, the paged read on the
-    kernel route) compiled whole for the described chip under the
+    : as many decode steps as its traced argument says, 32 at most, over 16
+    slots, all 36 layers, the paged read on the kernel route) compiled
+    whole for the described chip under the
     narrowest and the widest table: the pools go in and come out as the
     pool holds them, in the runtime's own order — NO copy of a pool array
     in the optimized program (the parent held 144: every pool re-laid out
@@ -730,7 +737,7 @@ def test_gpt2_large_segment_program_keeps_the_pools_in_place(one_chip, S, nb,
     pool, params = _gpt2_large_pool(S)
     compiled = pool._seg_fn(nb)._jitted.lower(
         params, (pool.pools, {}), S((16, nb), jnp.int32), S((16,), jnp.int32),
-        S((16,), jnp.int32), S((16,), jnp.bool_)).compile()
+        S((16,), jnp.int32), S((16,), jnp.bool_), S((), jnp.int32)).compile()
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if " custom-call(" in ln
              and "tpu_custom_call" in ln]
@@ -741,7 +748,9 @@ def test_gpt2_large_segment_program_keeps_the_pools_in_place(one_chip, S, nb,
     assert not _pool_copies(text, pool.pools, [(20, 64)])
     # a step's rows go in by ONE scatter an array, whole rows of the held
     # width; a scatter into the rows' leading corner was expanded into a
-    # loop over the slots (73 loops, and twice the step on the chip)
+    # loop over the slots (73 loops, and twice the step on the chip);
+    # the one loop is the steps', its trip count the program's argument,
+    # and the block of tokens it fills a step is no loop either
     assert text.count(" while(") == 1
     assert len(re.findall(r"= f32\[105,64,24,128\]\S* scatter\(", text)) == 72
     # the model's word to the compiler reached the program (``TransformerLM.

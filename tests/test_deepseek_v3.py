@@ -533,11 +533,11 @@ def test_engine_counts_what_the_expert_layer_routed(tiny):
                 values[(row["name"], row["labels"]["program"])] = row["value"]
         spans = [e for e in session.tracer.snapshot()
                  if e.get("name") == "serving.segment"]
-        # 9 prompt tokens; then every step a segment computes for the one
-        # live slot (the first re-emits the prefill's token, the last
-        # overshoots), 2 expert layers, top 4
+        # 9 prompt tokens; then every step a segment RAN for the one live
+        # slot (the first re-emits the prefill's token; the last runs the
+        # one step the budget still owes), 2 expert layers, top 4
         assert values[("moe.assignments_total", "admit")] == 9 * 4 * 2
-        steps = 4 * len(spans)
+        steps = sum(e["args"]["steps"] for e in spans)
         assert steps >= 8
         assert values[("moe.assignments_total", "segment")] == steps * 4 * 2
         here = values[("moe.assignments_here_total", "segment")]

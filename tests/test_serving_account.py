@@ -36,9 +36,9 @@ def _scripted_engine(model, params, t, **kw):
         t[0] += 0.5
         return admit(group)
 
-    def slow_segment(live):
+    def slow_segment(live, steps=None):
         t[0] += 0.25
-        return run_segment(live)
+        return run_segment(live, steps)
     eng.pool.admit, eng.pool.run_segment = slow_admit, slow_segment
     return eng
 
@@ -103,8 +103,8 @@ def test_slot_steps_are_emitted_plus_overshoot_plus_idle(
     """Three requests on three of four slots: one ends by EOS in its second
     segment (the first token of a run without one that differs from those
     before it: the tenth), one mid-segment on its budget, one runs two
-    whole segments and a part. Every slot-step the programs ran is one of
-    the three states, on the spans and on the counter alike, and
+    whole segments and a CUT one, alone. Every slot-step the programs ran
+    is one of the three states, on the spans and on the counter alike, and
     ``emitted`` is the tokens requests received after their first."""
     model, params = paged_model_and_params
     rs = np.random.RandomState(5)
@@ -130,32 +130,37 @@ def test_slot_steps_are_emitted_plus_overshoot_plus_idle(
                  and e["args"]["after"] == "segment"]
     assert [(len(toks), why) for toks, _, why in got] == [
         (9, "eos"), (5, "length"), (20, "length")]
-    assert [a["slot_steps"] for a in emits] == [32, 32, 32]
-    assert [a["live_steps"] for a in emits] == [24, 16, 8]
+    # the last segment runs the 4 steps its one request still needs
+    assert [a["steps"] for a in emits] == [8, 8, 4]
+    assert [a["slot_steps"] for a in emits] == [32, 32, 16]
+    assert [a["live_steps"] for a in emits] == [24, 16, 4]
     # after the first tokens (the admission's): 7 + 4 + 7, then 1 + 8, then 4
     assert [a["emitted"] for a in emits] == [18, 9, 4]
     assert sum(a["emitted"] for a in emits) \
         == sum(len(toks) for toks, _, _ in got) - 3
     counted = {m["labels"]["state"]: m["value"] for m in reg.collect()
                if m["name"] == "serving.segment_slot_steps_total"}
-    assert counted == {"emitted": 31, "overshoot": 48 - 31, "idle": 96 - 48}
+    assert counted == {"emitted": 31, "overshoot": 44 - 31, "idle": 80 - 44}
     assert sum(counted.values()) == sum(a["slot_steps"] for a in emits)
+    steps = {m["labels"]["state"]: m["value"] for m in reg.collect()
+             if m["name"] == "serving.segment_steps_total"}
+    assert steps == {"run": 20, "cut": 4}
 
 
 # -- an admission's work --------------------------------------------------------
 
-def _deepseek():
+def _deepseek(max_len=64):
     return DeepseekV3LM(
         64, d_model=32, n_heads=4, n_layers=3, n_dense=1, dense_width=64,
         expert_width=16, n_experts=16, experts_held=[0, 1, 2, 3], top_k=4,
         n_group=4, topk_group=2, q_rank=24, kv_rank=16, d_nope=8, d_rope=8,
-        d_v=16, rope_theta=1e5, max_len=64, rope_scaling={
+        d_v=16, rope_theta=1e5, max_len=max_len, rope_scaling={
             "factor": 64, "beta_fast": 32, "beta_slow": 1, "mscale": 1,
             "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
             "rope_type": "yarn"})
 
 
-def _lfm2():
+def _lfm2(max_len=64):
     from chipbench import weights_lfm2
     return weights_lfm2.model_and_shapes({
         "vocab_size": 96, "hidden_size": 32, "intermediate_size": 48,
@@ -168,10 +173,10 @@ def _lfm2():
         "experts_held": list(range(8)), "num_experts_per_tok": 2,
         "norm_topk_prob": True, "routed_scaling_factor": 1,
         "conv_L_cache": 3, "norm_eps": 1e-5, "rope_theta": 1000000,
-        "n_positions": 64}, jnp.float32)[0]
+        "n_positions": max_len}, jnp.float32)[0]
 
 
-def _nemotron_h():
+def _nemotron_h(max_len=64):
     from chipbench import weights_nemotron_h
     return weights_nemotron_h.model_and_shapes({
         "vocab_size": 96, "hidden_size": 32,
@@ -184,7 +189,7 @@ def _nemotron_h():
         "experts_held": [0, 2, 3, 5, 7], "num_experts_per_tok": 2,
         "norm_topk_prob": True, "routed_scaling_factor": 2.5,
         "time_step_min": 0.001, "time_step_max": 0.1,
-        "time_step_floor": 1e-4, "n_positions": 64}, jnp.float32)[0]
+        "time_step_floor": 1e-4, "n_positions": max_len}, jnp.float32)[0]
 
 
 def _count_positions_walked(model, monkeypatch):

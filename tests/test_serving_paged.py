@@ -771,7 +771,7 @@ def test_engine_dispatch_failure_fails_loudly(model_and_params):
     eng = ServingEngine(model, params, slots=1, segment=8, page_block=8,
                         cache_bucket=32, queue_cap=4)
 
-    def boom(live):
+    def boom(live, steps=None):
         raise RuntimeError("synthetic device failure")
     eng.pool.run_segment = boom
     eng.start()

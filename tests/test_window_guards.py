@@ -6,7 +6,8 @@ parent's ``kernels.flash_block_pairs_total`` / plan counters;
 the three expert cells call it with; and a page pool over a model that
 states no window builds the parent's segment program (the jaxpr's text; the
 admit program's fingerprint is PR 40's, whose write is a page at a time,
-printed the same way on that PR's tree). Beside them the new reach itself: the windowed read and the banded
+printed the same way on that PR's tree; the segment program's is PR 43's,
+whose loop takes its step count as an argument). Beside them the new reach itself: the windowed read and the banded
 flash against their dense routes over ragged positions and lengths.
 
 ``GOLDEN`` was printed by this file run as a script on the parent commit
@@ -153,7 +154,7 @@ def fp_programs(name):
                               ).hexdigest()[:16]
     return (text(pool._admit_fn(16, 2), i32(4, 16), i32(4), i32(4, 2)),
             text(pool._seg_fn(8), i32(4, 8), i32(4), i32(4),
-                 jax.ShapeDtypeStruct((4,), bool)))
+                 jax.ShapeDtypeStruct((4,), bool), i32()))
 
 
 GOLDEN = {'blocks/128/1792/2048': [1792, 2048, True],
@@ -183,10 +184,10 @@ GOLDEN = {'blocks/128/1792/2048': [1792, 2048, True],
  'paged_read/dense/2': ['5667f40498527815', 1.0, 0.0],
  'paged_read/kernel/1': ['864d414c69b2d71c', 1.0, 1.0],
  'paged_read/kernel/2': ['9fd1e8c231df8bf6', 1.0, 1.0],
- 'programs/deepseek_v3': ['4310c24713a0c211', '95fde98ee9fe12d7'],
- 'programs/gpt2': ['b345b8ed8fb7b5d0', 'cbbda5484971172b'],
- 'programs/lfm2': ['31820185837e5f50', 'b870028f323613a3'],
- 'programs/nemotron_h': ['d1066b4e2546ae0e', '43b26abc6e4a81f6'],
+ 'programs/deepseek_v3': ['4310c24713a0c211', 'bbe72cd4baf9003d'],
+ 'programs/gpt2': ['b345b8ed8fb7b5d0', '03052607977a64ee'],
+ 'programs/lfm2': ['31820185837e5f50', '260e49e985d13d61'],
+ 'programs/nemotron_h': ['d1066b4e2546ae0e', '69023f72181ebc31'],
  'work_list': '2792a21f73e1639a'}
 
 
@@ -218,11 +219,12 @@ def test_grouped_matmul_blocks_are_the_parents(tm, K, N):
 @pytest.mark.parametrize("name", ["gpt2", "deepseek_v3", "lfm2",
                                   "nemotron_h"])
 def test_pool_programs_of_a_model_without_a_window_are_the_parents(name):
-    """No ring, no ring table among the arguments: the SEGMENT program's
-    text is the one PR 39 pinned (PR 40's ``pk.put_rows`` over a pool held
-    as stated, as every pool on the CPU is, is the same scatter); the admit
-    program's is PR 40's, whose write is a page at a time where PR 39's
-    was one scatter."""
+    """No ring, no ring table among the arguments. The admit program's
+    text is PR 40's (a page written at a time where PR 39's was one
+    scatter) and PR 43 left it so; the SEGMENT program's was PR 39's until
+    PR 43 gave it its step count as an argument (a ``fori_loop`` under a
+    traced bound where the scan ran 32 steps; printed again on that
+    tree)."""
     assert list(fp_programs(name)) == GOLDEN[f"programs/{name}"]
 
 
