@@ -24,10 +24,14 @@ A decode step, a layer: write k, v, kI at ``pos``; while every slot's
 context is within ``index_topk`` the read is pk.paged_decode_attention as
 it stands (the selection would keep every key). Past it: score the slot's
 ``pos + 1`` indexer keys (pk.index_scores_paged), select
-(pk.select_topk: exact, no sort), list the selected rows
-(pk.selected_rows) and read THOSE rows of k and v, each by a DMA of its
-own (pk.sparse_decode_attention) — what the value read streams follows the
-rows selected, not the context.
+(pk.select_topk: exact, no sort) and read the selected rows of k and v
+under that mask (pk.sparse_decode_attention): the read fetches a page's
+aligned RUN of rows (pk.sparse_run: the whole page, in the serving pool)
+with one descriptor where the run holds a selected row and skips it where
+it holds none, and the softmax is over the selected rows alone — what the
+value read streams follows the pages the selection touches, not the
+context. The program counts what it fetched (``read``:
+``program_stats_zero``).
 
 An admission runs ONE row at a time and, inside the row, a BLOCK of
 ``block_tokens`` positions at a time through the whole depth
@@ -270,14 +274,17 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         the rows one layer read through the dense kernel, in the steps
         whose contexts were all within ``index_topk``. ``sparse_steps`` /
         ``dense_steps``: the live slot-steps that read through the
-        selection / through the dense kernel. ``pairs_causal``: the
+        selection / through the dense kernel. ``read`` [2]: what the live
+        slots' selected reads FETCHED, all layers — rows of k (as many of
+        v), and descriptors (one a run of k, one of v:
+        pk.sparse_decode_attention's ``runs``). ``pairs_causal``: the
         (query, key) pairs of an admission's causal triangles, one layer's
         (float32: a share's denominator, not an account)."""
         zero = jnp.zeros((), jnp.int32)
         return dict(super().program_stats_zero(),
                     selected=jnp.zeros((len(self.blocks),), jnp.int32),
                     scored=zero, dense_rows=zero, sparse_steps=zero,
-                    dense_steps=zero,
+                    dense_steps=zero, read=jnp.zeros((2,), jnp.int32),
                     pairs_causal=jnp.zeros((), jnp.float32))
 
     def _add_stats(self, stats, counts, live, n_rows, **more):
@@ -298,10 +305,17 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         if program == "segment":
             scored = int(stats["scored"]) * layers
             dense = int(stats["dense_rows"]) * layers
+            rows_fetched, descriptors = (int(n) for n in stats["read"])
             attrs.update(keys_scored=scored, keys_selected=selected + dense,
                          dense_rows=dense,
                          sparse_steps=int(stats["sparse_steps"]),
-                         dense_steps=int(stats["dense_steps"]))
+                         dense_steps=int(stats["dense_steps"]),
+                         rows_fetched=rows_fetched,
+                         read_descriptors=descriptors)
+            obs.count("sparse.rows_fetched_total", rows_fetched,
+                      program=program)
+            obs.count("sparse.read_descriptors_total", descriptors,
+                      program=program)
             work = {"index_scores_paged": dict(
                         keys=scored, index_dim=self.index_dim,
                         itemsize=kv["itemsize"]),
@@ -470,7 +484,8 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
                            work, past, live, alive, *, attn_route):
         """One layer of :meth:`decode_step_paged`: the step's rows written
         into the layer's three pools, the read, the experts -> (h, the
-        pools, the experts' counts, the keys the live slots' reads took).
+        pools, the experts' counts, the keys the live slots' reads took,
+        the runs their selected reads fetched).
         A step calls this as ONE jitted function (``_decode_layer``) a
         layer, so a program traces and lowers the layer — its ``cond``,
         both reads, four kernels — once and not once a layer; XLA inlines
@@ -480,8 +495,6 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         whose layers differ would hand the layer's kind over as a static
         argument. (Why this model alone: PERF.md section 6, PR 43.)"""
         blk = self.blocks[0]
-        bs = kc.shape[1]
-        topk = min(self.index_topk, tables.shape[1] * bs)
         x = blk.input_norm(p["input_norm"], h)
         q, k, v = blk.attn.project(p["attn"], x, pos)
         qi, ki, w = blk.idx.project(p["idx"], x, pos)
@@ -492,21 +505,21 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         def dense(_):
             return pk.paged_decode_attention(
                 q, k_rows, v_rows, tables, pos, scale=blk.attn.scale,
-                work=work, route=attn_route), pos + 1
+                work=work, route=attn_route), pos + 1, jnp.zeros_like(pos)
 
         def sparse(_):
             bias, cnt = pk.select_topk(
                 pk.index_scores_paged(qi, w, ip, tables, pos), pos + 1,
                 self.index_topk)
-            where, n = pk.selected_rows(bias, topk, bs)
-            return pk.sparse_decode_attention(
-                q, k_rows, v_rows, tables, where, n,
-                scale=blk.attn.scale), cnt
-        o, cnt = jax.lax.cond(past, sparse, dense, None)
+            o, runs = pk.sparse_decode_attention(
+                q, k_rows, v_rows, tables, bias, pos, scale=blk.attn.scale)
+            return o, cnt, runs
+        o, cnt, runs = jax.lax.cond(past, sparse, dense, None)
         h = h + blk.attn.output(p["attn"], o)
         h, c = ffn_or_experts(blk, p, h, live)
-        return h, kp, vp, ip, c, jnp.sum(jnp.where(alive, cnt, 0),
-                                         dtype=jnp.int32)
+        return (h, kp, vp, ip, c) + tuple(
+            jnp.sum(jnp.where(alive, n, 0), dtype=jnp.int32)
+            for n in (cnt, runs))
 
     def decode_step_paged(self, params, cell, tokens, tables, *, live=None,
                           attn_route: Optional[str] = None):
@@ -528,9 +541,9 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         alive = jnp.ones((B,), bool) if live is None else live
         h = self.embed(params["embed"], tokens).astype(jnp.float32)
         new_cell = {"pos": pos + 1}
-        counts, selected = [], []
+        counts, selected, runs = [], [], jnp.int32(0)
         for i in range(len(self.blocks)):
-            h, kp, vp, ip, c, cnt = self._decode_layer(
+            h, kp, vp, ip, c, cnt, n = self._decode_layer(
                 params[f"blocks_{i}"], h, cell[f"k{i}"], cell[f"v{i}"],
                 cell[f"ik{i}"], pos, page, row, tables, work, past, live,
                 alive, attn_route=attn_route)
@@ -538,12 +551,14 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
                 kp, vp, ip
             counts.append(c)
             selected.append(cnt)
+            runs = runs + n
         if "stats" in cell:
             steps = jnp.sum(alive, dtype=jnp.int32)
             keys = jnp.sum(jnp.where(alive, pos + 1, 0), dtype=jnp.int32)
             new_cell["stats"] = self._add_stats(
                 cell["stats"], counts, live, B,
                 selected=jnp.where(past, jnp.stack(selected), 0),
+                read=runs * jnp.array([pk.sparse_run(bs), 2], jnp.int32),
                 scored=jnp.where(past, keys, 0),
                 dense_rows=jnp.where(past, 0, keys),
                 sparse_steps=jnp.where(past, steps, 0),

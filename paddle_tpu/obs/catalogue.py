@@ -523,6 +523,20 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                    "within it); the spans carry keys_selected (with the "
                    "dense steps' rows, dense_rows) / pairs_selected, "
                    "labels: program", ("program",)),
+    "sparse.rows_fetched_total": (
+        "counter", "rows of k (as many of v) the live slots' selected "
+                   "decode reads FETCHED, all layers: the read's unit is an "
+                   "aligned run of one page's rows (pk.sparse_run), fetched "
+                   "whole where it holds a selected row, so this over "
+                   "sparse.keys_selected_total is the rows moved a row "
+                   "used; the serving.segment span carries the same as "
+                   "rows_fetched, labels: program", ("program",)),
+    "sparse.read_descriptors_total": (
+        "counter", "DMA descriptors those reads issued: one a fetched run "
+                   "of k, one of v (2 a selected ROW before the read "
+                   "fetched runs); the serving.segment span carries the "
+                   "same as read_descriptors, labels: program",
+        ("program",)),
     "serving.prefix_hits_total": ("counter", "admissions that matched the "
                                              "prefix radix index and "
                                              "prefilled only their "

@@ -27,6 +27,7 @@ cells' own flash-attention calls.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -2579,10 +2580,12 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, a: jax.Array,
 #                           bisection over the columns; gives the ADDITIVE
 #                           mask (0 selected, _NEG not) and the count
 #   selected_flash_attention  the admission's attention under that mask
-#   sparse_decode_attention   a decode step's read of the selected ROWS: each
-#                           row of k and of v fetched by its own DMA from the
-#                           page it lies in, so the bytes the read streams
-#                           follow the rows selected and not the context
+#   sparse_decode_attention   a decode step's read of the selected rows under
+#                           that mask: a page's aligned RUN of rows fetched
+#                           by one descriptor where it holds a selected row,
+#                           skipped where it holds none, so what the read
+#                           streams follows the pages the selection touches
+#                           and not the context
 # ---------------------------------------------------------------------------
 
 _INT_MIN = -2 ** 31
@@ -2923,41 +2926,76 @@ def selected_rows(bias: jax.Array, k: int, page_block: int):
     return jnp.where(ok, page * bs + jnp.minimum(row, bs - 1), 0), n
 
 
-#: selected rows one step of the sparse read gathers together
-SPARSE_ROWS = 256
+#: positions of a slot's context one step of the selected read covers
+SPARSE_ROWS = 1024
+#: rows of ONE page a descriptor of the selected read fetches, at most
+SPARSE_RUN = 64
 
 
-def _sparse_decode_kernel(loc_ref, n_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
-                          vbuf, sem, *, scale: float, rows: int, bs: int,
-                          groups: int):
-    """Program b: slot b's read of its ``n_ref[b]`` selected rows,
-    ``rows`` at a time: row j of the list lies at page ``loc // bs``, row
-    ``loc % bs`` of the pools [P, bs, Hkv, D] and is fetched by a DMA of
-    its own (k and v: two), the next ``rows`` in flight while these are
-    multiplied. The arithmetic is :func:`_grouped_decode_attn_kernel`'s: a
-    chunk collapses to ``[rows * Hkv, D]``, one product of all H queries
-    against it, the other groups' columns masked."""
+def sparse_run(page_block: int) -> int:
+    """The unit the selected read fetches: an aligned run of this many
+    rows of one page — the longest that divides both ``SPARSE_RUN`` and the
+    page, so a run never leaves its page (a 64-row page is one run)."""
+    return math.gcd(SPARSE_RUN, page_block)
+
+
+def _sparse_decode_kernel(src_ref, pos_ref, q_ref, b_ref, k_hbm, v_hbm, o_ref,
+                          kbuf, vbuf, sem, *, scale: float, rows: int,
+                          run: int, bs: int, groups: int):
+    """Program b: slot b's read of its selected rows, a chunk of ``rows``
+    positions of the context at a time. ``src_ref[b, r]`` places run r of
+    the context — ``run`` consecutive rows of one page — in the pools [P,
+    bs * Hkv, D]: ``page * bs + first row`` where the run holds a selected
+    row, -1 where it holds none. A run that holds one is fetched WHOLE by
+    ONE descriptor (k and v: two) into its place in the chunk's ``[rows *
+    Hkv, D]`` buffer — the matrix the two products take, row ``p * Hkv + g``
+    position p of KV head g —, the next chunk's runs in flight while this
+    one's are multiplied; a run with -1 is not fetched, and a wait is made
+    for every run started and no other. The mask ``b_ref`` [1, L / 128,
+    128] (0 selected) says which of a chunk's rows count: a position's bit
+    is spread over its Hkv columns by a 0/1 product, and every other
+    column — a fetched run's unselected rows, whatever an unfetched run
+    left in the buffer, the other groups' KV heads — is masked as padding
+    is (``_NEG`` before the max, weight 0 after), so the softmax is over
+    the selected rows alone. The value buffer is zeroed once a call: a
+    weight of 0 meets what the pool holds or zeros, never what the chip
+    left in VMEM. The arithmetic is :func:`_grouped_decode_attn_kernel`'s:
+    one product of all H queries against the chunk."""
     from jax.experimental.pallas import tpu as pltpu
     b = pl.program_id(0)
-    n = n_ref[b]
     H, D = q_ref.shape[1:]
     Hkv = H // groups
-    R = rows * Hkv
-    n_chunks = (n + rows - 1) // rows
+    R, rr, per, tiles = rows * Hkv, run * Hkv, rows // run, rows // 128
+    n_chunks = pos_ref[b] // rows + 1
+
+    @pl.when(b == 0)
+    def _once():
+        vbuf[...] = jnp.zeros_like(vbuf)
 
     def fetch(c, slot, wait: bool):
         def one(i, carry):
-            loc = loc_ref[b, c * rows + i]
-            pg, r = loc // bs, jax.lax.rem(loc, bs)
-            for s, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
-                cp = pltpu.make_async_copy(hbm.at[pg, r], buf.at[slot, i],
-                                           sem.at[s, slot])
-                cp.wait() if wait else cp.start()
+            src = src_ref[b, c * per + i]
+
+            @pl.when(src >= 0)
+            def _held():
+                at = pl.ds(pl.multiple_of(jax.lax.rem(src, bs) * Hkv, rr), rr)
+                to = pl.ds(pl.multiple_of(i * rr, rr), rr)
+                for s, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                    cp = pltpu.make_async_copy(hbm.at[src // bs, at],
+                                               buf.at[slot, to],
+                                               sem.at[s, slot])
+                    cp.wait() if wait else cp.start()
             return carry
-        jax.lax.fori_loop(0, rows, one, 0)
+        jax.lax.fori_loop(0, per, one, 0)
 
     fetch(0, 0, False)
     q = q_ref[0].astype(jnp.float32) * scale                # [H, D]
+    wide = 128 * Hkv
+    spread = (jax.lax.broadcasted_iota(jnp.int32, (128, wide), 1) // Hkv
+              == jax.lax.broadcasted_iota(jnp.int32, (128, wide), 0)
+              ).astype(jnp.float32)
+    own = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1) % Hkv \
+        == jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // groups
 
     mxu = _mxu_hi_lo
 
@@ -2969,20 +3007,19 @@ def _sparse_decode_kernel(loc_ref, n_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
         def _ahead():
             fetch(c + 1, 1 - slot, False)
         fetch(c, slot, True)
-        k, v = kbuf[slot], vbuf[slot]                       # [rows, Hkv, D]
-        if k.dtype != jnp.bfloat16 or Hkv % 2:
-            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
-        k, v = k.reshape(R, D), v.reshape(R, D)
-        s = mxu(q, k, ((1,), (1,)))                         # [H, R]
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
-        head = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // groups
-        seen = (c * rows + col // Hkv < n) & (col % Hkv == head)
-        s = jnp.where(seen, s, _NEG)
+        hit = (b_ref[0, pl.ds(pl.multiple_of(c * tiles, tiles), tiles), :]
+               == 0.0).astype(jnp.float32)                  # [tiles, 128]
+        cols = jax.lax.dot_general(hit, spread, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        seen = own & (jnp.concatenate(
+            [jnp.broadcast_to(cols[j:j + 1], (H, wide))
+             for j in range(tiles)], axis=1) > 0.5)         # [H, R]
+        s = jnp.where(seen, mxu(q, kbuf[slot], ((1,), (1,))), _NEG)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         return (m_new, l_prev * corr + jnp.sum(p, axis=1, keepdims=True),
-                acc * corr + mxu(p, v, ((1,), (0,))))
+                acc * corr + mxu(p, vbuf[slot], ((1,), (0,))))
     _, l, acc = jax.lax.fori_loop(
         0, n_chunks, step,
         (jnp.full((H, 1), _NEG, jnp.float32), jnp.zeros((H, 1), jnp.float32),
@@ -2992,64 +3029,78 @@ def _sparse_decode_kernel(loc_ref, n_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
 
 def sparse_decode_attention(q: jax.Array, k_pool: jax.Array,
                             v_pool: jax.Array, tables: jax.Array,
-                            where: jax.Array, n: jax.Array, *,
+                            bias: jax.Array, pos: jax.Array, *,
                             scale: Optional[float] = None,
                             route: Optional[str] = None,
-                            interpret: Optional[bool] = None) -> jax.Array:
+                            interpret: Optional[bool] = None):
     """Single-token attention over SELECTED rows of a paged cache: q [B, H,
-    D]; k_pool / v_pool [P, bs, Hkv, D]; tables [B, NB]; where [B, K] int32
-    the selected positions of each slot (:func:`selected_rows`), the first
-    ``n`` [B] of them live -> o [B, H, D] f32, the softmax over those rows
-    alone. What the read streams is ``n`` rows of k and of v a slot,
-    whatever the context's length."""
-    B, K = where.shape
+    D]; k_pool / v_pool [P, bs, Hkv, D]; tables [B, NB]; bias [B, NB * bs]
+    :func:`select_topk`'s additive mask (0 at a slot's selected positions,
+    all of them at or before ``pos`` [B]) -> (o [B, H, D] f32, the softmax
+    over those rows alone; runs [B] int32).
+
+    The unit the read fetches is a RUN: :func:`sparse_run` consecutive
+    rows of one page, one descriptor for k and one for v, where the run
+    holds a selected row; a run that holds none is not fetched. ``runs``
+    counts a slot's fetched runs (both routes: it is the mask's own
+    property), so the read moved ``runs * sparse_run`` rows with ``2 *
+    runs`` descriptors, whatever the context's length. A fetched run's
+    unselected rows take a weight of exactly 0 — as the dense paged read
+    gives a page's rows past ``pos`` — so what they hold must be finite,
+    as a pool's rows are."""
+    B, L = bias.shape
     P, bs, Hkv, D = k_pool.shape
     H = q.shape[1]
+    if tables.shape != (B, L // bs) or L % bs:
+        raise ValueError(f"sparse_decode_attention: a mask of {L} positions "
+                         f"against tables {tables.shape} of {bs}-row pages")
     scale_v = scale if scale is not None else D ** -0.5
     if route is None:
         route = "kernel" if _on_tpu() else "dense"
     from .. import obs
     obs.count("kernels.routes_total", kernel="sparse_decode_attention",
               route=route)
-    n = n.astype(jnp.int32)
-    # a position's place in the pool: its page's number x bs + its row
-    loc = jnp.take_along_axis(tables.astype(jnp.int32), where // bs,
-                              axis=1) * bs + where % bs
+    run = sparse_run(bs)
+    hit = bias == 0.0
+    held = jnp.any(hit.reshape(B, L // run, run), axis=2)   # [B, runs]
+    runs = jnp.sum(held, axis=1, dtype=jnp.int32)
     if route == "dense":
-        k = k_pool.reshape((P * bs, Hkv, D))[loc]           # [B, K, Hkv, D]
-        v = v_pool.reshape((P * bs, Hkv, D))[loc]
-        k = jnp.repeat(k, H // Hkv, axis=2).astype(jnp.float32)
-        v = jnp.repeat(v, H // Hkv, axis=2).astype(jnp.float32)
-        s = jnp.einsum("bhd,bjhd->bhj", q.astype(jnp.float32) * scale_v, k)
-        s = jnp.where((jnp.arange(K)[None, :] < n[:, None])[:, None, :], s,
-                      _NEG)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        return jnp.einsum("bhj,bjhd->bhd",
-                          p / jnp.sum(p, axis=-1, keepdims=True), v)
+        k = jnp.repeat(gather_pages(k_pool, tables), H // Hkv, axis=2)
+        v = jnp.repeat(gather_pages(v_pool, tables), H // Hkv, axis=2)
+        s = jnp.einsum("bhd,bjhd->bhj", q.astype(jnp.float32) * scale_v,
+                       k.astype(jnp.float32))
+        w = jax.nn.softmax(jnp.where(hit[:, None, :], s, _NEG), axis=-1)
+        return jnp.einsum("bhj,bjhd->bhd", w, v.astype(jnp.float32)), runs
     from jax.experimental.pallas import tpu as pltpu
-    rows = min(SPARSE_ROWS, K)
-    if K % rows:
-        raise ValueError(f"sparse_decode_attention: a list of {K} rows is "
-                         f"not whole chunks of {rows}")
+    # a run's place in the pool: its page's number x bs + its first row
+    first = (tables.astype(jnp.int32)[:, :, None] * bs
+             + jnp.arange(0, bs, run, dtype=jnp.int32)).reshape(B, L // run)
+    rows = min(SPARSE_ROWS, -(-L // 128) * 128)
+    more = -L % rows                    # whole chunks: no run, no hit
+    src = jnp.pad(jnp.where(held, first, -1), ((0, 0), (0, more // run)),
+                  constant_values=-1)
+    mask = jnp.pad(bias.astype(jnp.float32), ((0, 0), (0, more)),
+                   constant_values=_NEG).reshape(B, (L + more) // 128, 128)
+    buf = pltpu.VMEM((2, rows * Hkv, D), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(B,),
         in_specs=[pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec((1,) + mask.shape[1:], lambda b, *_: (b, 0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((2, rows, Hkv, D), k_pool.dtype),
-                        pltpu.VMEM((2, rows, Hkv, D), v_pool.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2))])
-    return pl.pallas_call(
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))])
+    o = pl.pallas_call(
         functools.partial(_sparse_decode_kernel, scale=scale_v, rows=rows,
-                          bs=bs, groups=H // Hkv),
+                          run=run, bs=bs, groups=H // Hkv),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(interpret), name="sparse_decode_attention",
-    )(loc, n, q, k_pool, v_pool)
+    )(src, pos.astype(jnp.int32), q, mask,
+      k_pool.reshape(P, bs * Hkv, D), v_pool.reshape(P, bs * Hkv, D))
+    return o, runs
 
 
 def _selected_flash_kernel(info_ref, q_ref, k_ref, v_ref, b_ref, o_ref,

@@ -455,10 +455,10 @@ def _keye_cases():
             lambda s, e: pk.select_topk(s, e, K, **kernel),
             [((B, NB * 64),), ((B,), i32)]),
         "sparse_decode_attention": (
-            lambda q, k, v, t, w, n: pk.sparse_decode_attention(
-                q, k, v, t, w, n, **kernel),
+            lambda q, k, v, t, b, p: pk.sparse_decode_attention(
+                q, k, v, t, b, p, **kernel)[0],
             [((B, 32, 128),), ((P, 64, 4, 128), bf), ((P, 64, 4, 128), bf),
-             ((B, NB), i32), ((B, K), i32), ((B,), i32)]),
+             ((B, NB), i32), ((B, NB * 64),), ((B,), i32)]),
         "selected_flash_attention": (
             lambda q, k, v, b, q0: pk.selected_flash_attention(
                 q, k, v, b, q0, **kernel),

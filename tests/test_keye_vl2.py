@@ -289,12 +289,13 @@ def test_the_kernels_agree_with_their_dense_routes():
         pk.index_scores_paged(qd, wd, pool, tables, pos, route="kernel",
                               interpret=True), a, atol=1e-5)
     bias, _ = pk.select_topk(a, pos + 1, 32, route="dense")
-    where, n = pk.selected_rows(bias, 32, bs)
     kp, vp, q = f(P, bs, Hkv, D), f(P, bs, Hkv, D), f(B, H, D)
-    got = pk.sparse_decode_attention(q, kp, vp, tables, where, n,
-                                     route="kernel", interpret=True)
-    np.testing.assert_allclose(got, pk.sparse_decode_attention(
-        q, kp, vp, tables, where, n, route="dense"), atol=1e-5)
+    got, runs = pk.sparse_decode_attention(q, kp, vp, tables, bias, pos,
+                                           route="kernel", interpret=True)
+    want, same = pk.sparse_decode_attention(q, kp, vp, tables, bias, pos,
+                                            route="dense")
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert (np.asarray(runs) == np.asarray(same)).all()
     # ... and the selected read IS the masked read of every row
     kk, vv = pk.gather_pages(kp, tables), pk.gather_pages(vp, tables)
     s = jnp.einsum("bhd,bjhd->bhj", q * D ** -0.5,
