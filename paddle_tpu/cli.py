@@ -1313,52 +1313,18 @@ def cmd_serve(args):
     ``--vocab/--d_model/...`` flags and ``--seed`` (the bring-up and e2e
     test mode: the same flags + seed reproduce the exact weights).
 
-    What a ``--config`` model must offer the page pool (``TransformerLM``,
-    ``DeepseekV3LM``, ``Lfm2MoeLM``, ``NemotronHLM`` and ``AfmoeLM`` do; serving/paged.py
-    names no cache array itself): ``max_len``; ``cache_rows(params, kv_dtype)`` — its state
-    as it states it, raising ValueError for a ``kv_dtype`` it has no cache
-    for: ``CacheRow`` (name, trailing shape, dtype, fill) for what lives in
-    PAGES, a row a token, and ``SlotRow`` (the same four) for what lives
-    PER SLOT at a fixed size whatever the context (Lfm2MoeLM's convolution
-    tails, NemotronHLM's recurrent carry); ``prefill(params, prompts, lengths, kv_dtype=, pad_to=)`` ->
-    (cell with one ``[B, pad_to, *shape]`` entry per cache row, one ``[B,
-    *shape]`` entry per slot row — each row's state at its own length — and
-    ``pos``, last logits); ``decode_step_paged(params, cell, tokens, tables,
-    live=)`` -> (logits, new cell) over the pools ``[pages, page_block,
-    *shape]`` and the slot rows ``[slots, *shape]``; ``paged_read_kernel``
-    and ``paged_read_geometry(params, kv_dtype)`` — the decode read's
-    registered cost model and the shape facts it takes (``kv_heads`` where
-    a cache row holds fewer heads than the queries have) — and optionally
-    ``paged_read_layers`` (layers of a step that make that read; default all);
-    ``prefill_positions(n_rows, width, n_live)`` — the positions ``prefill``
-    runs through the depth for ``[n_rows, width]`` prompts of which
-    ``n_live`` hold one, from the walk it runs (``models.transformer
-    .LiveRowPrefill`` for a model on ``prefill_live_rows``, as all five
-    served models are: the pool hands an admission its whole width, the
-    model walks the rows that hold a prompt): the admission's account on
-    the ``serving.prefill`` span (the prefix-hit program, ``prefill_paged``,
-    is counted at slots x width by the pool itself);
-    and, only for the prefix cache, ``prefill_paged`` (a model without it
-    needs ``--no_prefix_cache``; with it on, ``serve`` refuses at start-up;
-    slot rows are not shared by prefix). Optional: ``slot_rows_in_place =
-    True`` — for slot rows too large to hold twice (NemotronHLM's 1.57 GB
-    at 32 slots): ``prefill`` then also takes ``slot_state=`` (the pool's
-    own ``[slots, *shape]`` arrays, donated) and returns them in the cell
-    WRITTEN at the rows that hold a prompt and untouched elsewhere, and
-    the segment program resets dead slots' rows by a scatter at those
-    slots alone, so no program holds a second copy of them. Optional:
-    ``CacheRow(..., window=W)`` — a row read only W positions back
-    (AfmoeLM's sliding layers): the pool keeps it in a ring a slot that
-    stops growing, ``prefill`` then also takes ``pools=`` and ``write=``
-    (the donated pools and the pool's scatter: every chunk's rows go
-    straight into the pages and no cell holds keys and values),
-    ``decode_step_paged`` also takes ``ring_tables=``, the model states
-    ``window_read_layers`` beside ``paged_read_layers``, and such a model
-    needs ``--no_prefix_cache`` (docs/design/serving.md). Optional:
-    ``program_stats_zero()``
-    / ``note_program_stats(stats, program)`` for counts a program returns
-    beside its tokens (they land on the ``serving.prefill`` and
-    ``serving.segment`` spans). The dtype of weights and cache follows the
+    A ``--config`` model is a ``paddle_tpu.models.paged_lm.PagedLM`` (as
+    ``TransformerLM``, ``DeepseekV3LM``, ``Lfm2MoeLM``, ``NemotronHLM``,
+    ``AfmoeLM`` and ``KeyeSparseLM`` are): that class IS the contract the
+    page pool reads — ``max_len``, ``cache_rows`` (``CacheRow`` for what
+    lives in pages, ``SlotRow`` for what lives per slot), ONE ``prefill``
+    signature, ``decode_step_paged``, the decode read's cost model, what a
+    program returns beside its tokens — each with its default, and a new
+    model writes its blocks, ``cache_rows``, ``_sequence`` and
+    ``_decode_layer`` (docs/design/serving.md, "What a served model
+    states"). A model without ``prefill_paged`` needs ``--no_prefix_cache``
+    (with the prefix cache on, ``serve`` refuses at start-up), as does one
+    whose rows state a window. The dtype of weights and cache follows the
     arrays the script hands over: there is no dtype flag.
 
     ``--prompt_buckets 512,1024,2048,4096`` names the prompt lengths

@@ -6,7 +6,8 @@ layers, a routed-expert layer with a shared expert after them
 groups) — under SANDWICH norms: ``h += post_attn_norm(attn(input_norm(h)))``;
 ``h += post_mlp_norm(mlp(pre_mlp_norm(h)))``. With ``embed_scale`` the
 embedding's output is multiplied by it (muP: ``sqrt(d_model)``). A final
-RMSNorm, an untied head. The fifth model class behind ``serve --config``.
+RMSNorm, an untied head. The fifth model class behind ``serve --config``,
+a ``PagedLM`` (models/paged_lm.py).
 
 ``layer_types`` makes two kinds of attention layer, and they differ in two
 things. ``sliding_attention``: half-split RoPE on q and k, and the query at
@@ -44,13 +45,8 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
-from ..parallel.expert_share import ExpertShare, ProgramStats
-from .transformer import (PREFILL_TOKENS, SOLO_ROW_TOKENS, CacheRow,
-                          LiveRowPrefill, paged_greedy, prefill_live_rows)
-
-
-def _dot(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+from ..parallel.expert_share import ExpertShare
+from .paged_lm import CacheRow, PagedLM, _dot
 
 
 class GatedGroupedAttention(nn.Module):
@@ -139,7 +135,7 @@ class AfmoeBlock(nn.Module):
         return h + self.post_mlp_norm(params["post_mlp_norm"], out), counts
 
 
-class AfmoeLM(ProgramStats, LiveRowPrefill, nn.Module):
+class AfmoeLM(PagedLM):
     """``vocab`` rows of embedding and of an untied head, one block per
     entry of ``layer_types``; the first ``n_dense`` carry the dense FFN, the
     rest the expert layer over ``experts_held`` of ``n_experts``."""
@@ -178,7 +174,6 @@ class AfmoeLM(ProgramStats, LiveRowPrefill, nn.Module):
                           else dict(moe_kw=moe_kw)))
             for i, kind in enumerate(layer_types)]
         self.window_read_layers = sum(b.sliding for b in self.blocks)
-        self.paged_read_layers = len(self.blocks) - self.window_read_layers
         if not self.paged_read_layers:
             raise ValueError("the paged engine needs at least one "
                              "full_attention layer (its pages carry the "
@@ -197,30 +192,14 @@ class AfmoeLM(ProgramStats, LiveRowPrefill, nn.Module):
                          window=self.window if blk.sliding else None)
                 for i, blk in enumerate(self.blocks) for n in "kv"]
 
-    @staticmethod
-    def _no_kv_dtype(kv_dtype):
-        if kv_dtype is not None:
-            raise ValueError(f"kv_dtype {kv_dtype!r}: pages are kept in the "
-                             "parameters' dtype; there is no quantised "
-                             "cache for this model")
+    prefill_chunk_tokens = PagedLM.solo_row_chunk_tokens
 
-    def prefill_chunk_tokens(self, width: int) -> int:
-        """``PREFILL_TOKENS`` of rows a chunk, a row of
-        ``SOLO_ROW_TOKENS`` or more alone in its chunk."""
-        return width if width >= SOLO_ROW_TOKENS else PREFILL_TOKENS
-
-    #: the full layers' decode read's registered cost model
-    #: (obs/roofline.kernel_cost); the sliding layers' is
-    #: ``paged_window_attention``, over the same geometry
-    paged_read_kernel = "paged_decode_attention"
-
-    def paged_read_geometry(self, params, kv_dtype=None):
-        return {"n_heads": self.n_heads, "kv_heads": self.kv_heads,
-                "d_head": self.d_head, "kv_dtype": None,
-                "itemsize": jnp.dtype(self._compute_dtype(params)).itemsize}
-
-    def _compute_dtype(self, params):
-        return params["embed"]["w"].dtype
+    @property
+    def paged_read_layers(self):
+        """The full layers: their decode read's registered cost model is
+        ``paged_read_kernel``, the sliding layers' (``window_read_layers``)
+        ``paged_window_attention``, over the same geometry."""
+        return len(self.blocks) - self.window_read_layers
 
     # -- what a program returns beside its tokens ---------------------------
     def program_stats_zero(self):
@@ -228,12 +207,6 @@ class AfmoeLM(ProgramStats, LiveRowPrefill, nn.Module):
         the (position, sliding layer) pairs the banded flash kernel ran."""
         return dict(super().program_stats_zero(),
                     band_positions=jnp.zeros((), jnp.int32))
-
-    def _add_stats(self, stats, counts, live, n_rows, band_positions=0):
-        out = dict(stats, **super()._add_stats(stats, counts, live, n_rows))
-        out["band_positions"] = stats["band_positions"] + jnp.asarray(
-            band_positions, jnp.int32)
-        return out
 
     def note_program_stats(self, stats, program: str):
         from .. import obs
@@ -250,16 +223,14 @@ class AfmoeLM(ProgramStats, LiveRowPrefill, nn.Module):
 
     # -- whole sequences ---------------------------------------------------
     def _embed(self, params, ids):
-        h = self.embed(params["embed"], ids).astype(jnp.float32)
+        h = super()._embed(params, ids)
         return h * self.embed_scale if self.embed_scale != 1.0 else h
 
     def _sequence(self, params, ids, lengths):
         """ids [B, T] -> (h [B, T, d] f32, state: ``k{i}`` / ``v{i}`` [B,
         T, Hkv, D] of every layer, stats)."""
         B, T = ids.shape
-        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        live = None if lengths is None else \
-            positions < jnp.asarray(lengths, jnp.int32)[:, None]
+        positions, live = self._positions_live(ids, lengths)
         h = self._embed(params, ids)
         state, counts = {}, []
         for i, blk in enumerate(self.blocks):
@@ -280,116 +251,20 @@ class AfmoeLM(ProgramStats, LiveRowPrefill, nn.Module):
             band_positions=B * T * self.window_read_layers)
         return h, state, stats
 
-    def logits(self, params, h):
-        x = self.norm_f(params["norm_f"], h)
-        w = params["head"]["w"]                 # [vocab, d], as published
-        return jax.lax.dot_general(x.astype(w.dtype), w,
-                                   (((x.ndim - 1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-
-    def __call__(self, params, ids, **kw):
-        """ids [B, T] -> logits [B, T, V] f32."""
-        h, _, _ = self._sequence(params, ids, None)
-        return self.logits(params, h)
-
-    def prefill(self, params, prompt, lengths=None, *,
-                kv_dtype: Optional[str] = None,
-                pad_to: Optional[int] = None, pools=None, write=None):
-        """TransformerLM.prefill's contract: (cell, last logits [B, V]);
-        the cell holds ``pos``, ``stats`` and ``k{i}`` / ``v{i}`` of every
-        layer. Only the rows that HOLD a prompt run
-        (``prefill_live_rows``: ``PREFILL_TOKENS`` at a time, a row of
-        ``SOLO_ROW_TOKENS`` or more alone, live rows first), and only each
-        row's last position reaches the head.
-
-        ``pools`` + ``write`` (the page pool's admission): ``k{i}`` /
-        ``v{i}`` are the pool's own arrays, and every chunk's rows go into
-        them through ``write(pools, idx, n, rows)`` — the pool's scatter,
-        every row of a full layer into its pages and the last rows of a
-        sliding layer into its ring; they come back WRITTEN, the same
-        buffers when the caller donated them. Without them the rows come
-        back whole, ``[B, pad_to, Hkv, D]``, sliding layers' too (the solo
-        decode reads them through one table)."""
-        self._no_kv_dtype(kv_dtype)
-        B, T0 = prompt.shape
-        limit = self.max_len if pad_to is None else min(pad_to, self.max_len)
-        if limit < T0:
-            raise ValueError(f"prefill cache limit {limit} (pad_to/max_len) "
-                             f"is narrower than the prompt ({T0})")
-        pos = (jnp.full((B,), T0, jnp.int32) if lengths is None
-               else jnp.asarray(lengths, jnp.int32))
-        state0 = pools if pools is not None else {
-            r.name: jnp.zeros((B, T0) + r.shape, r.dtype)
-            for r in self.cache_rows(params)}
-        last, state, stats = prefill_live_rows(
-            lambda ids, n: self._sequence(params, ids, n), prompt, pos,
-            self.d_model, state0, self.program_stats_zero(),
-            self.prefill_chunk_tokens(T0), write=write)
-        if pools is None:
-            state = {nm: jnp.pad(buf, ((0, 0), (0, limit - T0), (0, 0),
-                                       (0, 0))) for nm, buf in state.items()}
-        return dict(state, pos=pos, stats=stats), self.logits(params, last)
-
     # -- one token against the paged cache ---------------------------------
-    def decode_step_paged(self, params, cell, tokens, tables, *, live=None,
-                          attn_route: Optional[str] = None,
-                          ring_tables=None):
-        """TransformerLM.decode_step_paged's contract. Every layer writes
-        the step's k, v (after the norms, and RoPE in a sliding layer) into
-        its pool ``k{i}`` / ``v{i}`` [P, bs, Hkv, D] and reads through
-        pk.paged_decode_attention, a KV head serving its group of query
-        heads. A full layer writes at ``tables[b, pos // bs]`` and reads
-        every row to ``pos``; a sliding layer writes at ``ring_tables[b,
-        (pos // bs) % ring]`` and reads the window's rows through the ring
-        (``ring_tables`` None: through ``tables`` itself, a ring that
-        never wraps — the solo decode). Two work lists a step, one a kind.
-        ``live`` [B] marks the slots whose tokens count (and whose experts
-        run); ``cell["stats"]``, when present, accumulates
-        :meth:`program_stats_zero`'s tree."""
-        pos = cell["pos"]
-        bs = cell["k0"].shape[1]
-        if ring_tables is None:
-            ring_tables = tables
-
-        def read_of(table, window):
-            """One kind of layer's write page and read geometry."""
-            entry = pos // bs if window is None \
-                else (pos // bs) % table.shape[1]
-            return dict(
-                tables=table, window=window,
-                work=pk.paged_work_list(table, pos, bs, window),
-                page=jnp.take_along_axis(table, entry[:, None], axis=1)[:, 0])
-        reads = {False: read_of(tables, None),
-                 True: read_of(ring_tables, self.window)}
-        row = pos % bs
-        B = tokens.shape[0]
-        h = self._embed(params, tokens)
-        new_cell = {"pos": pos + 1}
-        counts = []
-        for i, blk in enumerate(self.blocks):
-            p = params[f"blocks_{i}"]
-            x = blk.input_norm(p["input_norm"], h)
-            q, k, v, gate = blk.attn.project(p["attn"], x, pos)
-            rd = reads[blk.sliding]
-            kp, k_rows = pk.put_rows(cell[f"k{i}"], rd["page"], row, k)
-            vp, v_rows = pk.put_rows(cell[f"v{i}"], rd["page"], row, v)
-            new_cell[f"k{i}"], new_cell[f"v{i}"] = kp, vp
-            o = pk.paged_decode_attention(
-                q, k_rows, v_rows, rd["tables"], pos, scale=blk.attn.scale,
-                work=rd["work"], window=rd["window"], route=attn_route)
-            h = h + blk.post_attn_norm(
-                p["post_attn_norm"], blk.attn.output(p["attn"], o, gate))
-            h, c = blk.mlp(p, h, live)
-            if c is not None:
-                counts.append(c)
-        if "stats" in cell:
-            new_cell["stats"] = self._add_stats(cell["stats"], counts, live,
-                                                B)
-        return self.logits(params, h), new_cell
-
-    def generate_cached(self, params, prompt, steps: int, *,
-                        page_block: int = 64):
-        """Greedy continuation through prefill + the paged decode step
-        (one private table a sample): prompt [B, T0] -> [B, T0 + steps].
-        The solo decode a served stream is compared with."""
-        return paged_greedy(self, params, prompt, steps, page_block)
+    def _decode_layer(self, i, blk, p, h, cell, step):
+        """The step's k, v (after the norms, and RoPE in a sliding layer)
+        written into the layer's pools ``k{i}`` / ``v{i}`` [P, bs, Hkv, D]
+        and read back through the read of its kind: a full layer's at
+        ``tables`` and every row to ``pos``, a sliding layer's at the ring
+        and the window's rows."""
+        rd = step.ringed if blk.sliding else step.full
+        x = blk.input_norm(p["input_norm"], h)
+        q, k, v, gate = blk.attn.project(p["attn"], x, rd.pos)
+        o, kp, vp = rd.write_and_attend(
+            q, k, v, cell[f"k{i}"], cell[f"v{i}"], scale=blk.attn.scale,
+            route=step.attn_route)
+        h = h + blk.post_attn_norm(
+            p["post_attn_norm"], blk.attn.output(p["attn"], o, gate))
+        h, c = blk.mlp(p, h, step.live)
+        return h, {f"k{i}": kp, f"v{i}": vp}, c

@@ -1,10 +1,9 @@
 """DeepSeek-V3's block as a served language model: multi-head LATENT
 attention with a YaRN rotary slice, RMSNorm, a SwiGLU dense FFN in the
 leading layers and a routed-expert layer (parallel/expert_share.py) after
-them, an untied head. The second model class behind ``serve --config``:
-it offers the paged pool the same entry points as ``TransformerLM``
-(``max_len``, ``cache_rows``, ``prefill``, ``decode_step_paged``) and the
-pool runs it with the code it runs GPT-2 with.
+them, an untied head. The second model class behind ``serve --config``: a
+``PagedLM`` (models/paged_lm.py), which the pool runs with the code it runs
+GPT-2 with.
 
 What the cache holds is the point of latent attention: ONE row a token a
 layer — the normed latent ``c_kv`` (``kv_rank`` wide) followed by the
@@ -35,20 +34,13 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 
 from .. import nn
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
-from ..parallel.expert_share import (ExpertShare, ProgramStats,
-                                     ffn_or_experts)
-from .transformer import (PREFILL_TOKENS, CacheRow, LiveRowPrefill,
-                          paged_greedy, prefill_live_rows)
-
-
-def _dot(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+from ..parallel.expert_share import ExpertShare, ffn_or_experts
+from .paged_lm import CacheRow, PagedLM, _dot
 
 
 class LatentAttention(nn.Module):
@@ -151,7 +143,7 @@ class DeepseekV3Block(nn.Module):
         return ffn_or_experts(self, params, h, live)
 
 
-class DeepseekV3LM(ProgramStats, LiveRowPrefill, nn.Module):
+class DeepseekV3LM(PagedLM):
     """``vocab`` rows of embedding and (untied) head, ``n_layers`` blocks of
     which the first ``n_dense`` carry the dense FFN and the rest the expert
     layer over ``experts_held`` of ``n_experts``."""
@@ -212,16 +204,6 @@ class DeepseekV3LM(ProgramStats, LiveRowPrefill, nn.Module):
         return [CacheRow(f"kv{i}", (self.row,), dt)
                 for i in range(len(self.blocks))]
 
-    @staticmethod
-    def _no_kv_dtype(kv_dtype):
-        if kv_dtype is not None:
-            raise ValueError(f"kv_dtype {kv_dtype!r}: latent rows are kept "
-                             "in the parameters' dtype; there is no "
-                             "quantised latent cache")
-
-    def prefill_chunk_tokens(self, width: int) -> int:
-        return PREFILL_TOKENS
-
     #: the decode read's registered cost model (obs/roofline.kernel_cost)
     paged_read_kernel = "paged_latent_attention"
 
@@ -229,17 +211,14 @@ class DeepseekV3LM(ProgramStats, LiveRowPrefill, nn.Module):
         return {"row": self.row,
                 "itemsize": jnp.dtype(self._compute_dtype(params)).itemsize}
 
-    def _compute_dtype(self, params):
-        return params["embed"]["w"].dtype
-
-    # -- whole sequences ---------------------------------------------------
-    def _sequence(self, params, ids, lengths, keep_latents):
+    # -- whole sequences (the expanded path) -------------------------------
+    def _sequence(self, params, ids, lengths):
+        """ids [B, T] -> (h [B, T, d] f32, ``kv{i}`` [B, T, row] of every
+        layer, stats)."""
         B, T = ids.shape
-        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        live = None if lengths is None else \
-            positions < jnp.asarray(lengths, jnp.int32)[:, None]
-        h = self.embed(params["embed"], ids).astype(jnp.float32)
-        latents, counts = [], []
+        positions, live = self._positions_live(ids, lengths)
+        h = self._embed(params, ids)
+        latents, counts = {}, []
         for i, blk in enumerate(self.blocks):
             p = params[f"blocks_{i}"]
             x = blk.attn_norm(p["attn_norm"], h)
@@ -249,91 +228,23 @@ class DeepseekV3LM(ProgramStats, LiveRowPrefill, nn.Module):
             h, c = blk.feed_forward(p, h, live)
             if c is not None:
                 counts.append(c)
-            if keep_latents:
-                latents.append(lat)
+            latents[f"kv{i}"] = lat
         stats = self._add_stats(self.program_stats_zero(), counts, live,
                                 B * T)
         return h, latents, stats
 
-    def logits(self, params, h):
-        return _dot(self.norm_f(params["norm_f"], h), params["head"])
-
-    def __call__(self, params, ids, **kw):
-        """ids [B, T] -> logits [B, T, V] f32 (the expanded path)."""
-        h, _, _ = self._sequence(params, ids, None, False)
-        return self.logits(params, h)
-
-    def prefill(self, params, prompt, lengths=None, *,
-                kv_dtype: Optional[str] = None,
-                pad_to: Optional[int] = None):
-        """TransformerLM.prefill's contract: (cell, last logits [B, V]);
-        the cell holds ``pos``, one ``kv{i}`` [B, pad_to, row] a layer and
-        ``stats`` (:meth:`program_stats_zero`'s tree over the live prompt
-        tokens). Rows of length 0 (slots an admission is not filling) are
-        not computed, but for those that fill up the last live chunk:
-        their cache rows and logits come back zero or garbage, and the
-        pool reads neither."""
-        self._no_kv_dtype(kv_dtype)
-        B, T0 = prompt.shape
-        limit = self.max_len if pad_to is None else min(pad_to, self.max_len)
-        if limit < T0:
-            raise ValueError(f"prefill cache limit {limit} (pad_to/max_len) "
-                             f"is narrower than the prompt ({T0})")
-        pos = (jnp.full((B,), T0, jnp.int32) if lengths is None
-               else jnp.asarray(lengths, jnp.int32))
-        # a few rows at a time, and only the rows that HOLD a prompt
-        dt = self._compute_dtype(params)
-        last, latents, stats = prefill_live_rows(
-            lambda ids, n: self._sequence(params, ids, n, True), prompt, pos,
-            params["embed"]["w"].shape[1],
-            [jnp.zeros((B, T0, self.row), dt) for _ in self.blocks],
-            self.program_stats_zero(), self.prefill_chunk_tokens(T0))
-        cell = {"pos": pos, "stats": stats}
-        for i, lat in enumerate(latents):
-            cell[f"kv{i}"] = jnp.pad(lat, ((0, 0), (0, limit - T0), (0, 0)))
-        return cell, self.logits(params, last)
-
-    # -- one token against the paged cache ---------------------------------
-    def decode_step_paged(self, params, cell, tokens, tables, *,
-                          live=None, attn_route: Optional[str] = None):
-        """TransformerLM.decode_step_paged's contract over latent pools
-        ``kv{i}`` [P, bs, row]: the step's row is written at page
-        ``tables[b, pos // bs]``, row ``pos % bs``, then the absorbed read
-        walks the live pages. ``live`` [B] bool marks the slots whose
-        tokens count (and whose experts run); ``cell["stats"]``, when
-        present, accumulates :meth:`program_stats_zero`'s tree."""
-        pos = cell["pos"]
-        bs = cell["kv0"].shape[1]
-        work = pk.paged_work_list(tables, pos, bs)
-        page = jnp.take_along_axis(tables, (pos // bs)[:, None],
-                                   axis=1)[:, 0]
-        row = pos % bs
-        h = self.embed(params["embed"], tokens).astype(jnp.float32)
-        new_cell = {"pos": pos + 1}
-        counts = []
-        for i, blk in enumerate(self.blocks):
-            p = params[f"blocks_{i}"]
-            x = blk.attn_norm(p["attn_norm"], h)
-            q_nope, q_rope, lat = blk.attn.project(p["attn"], x, pos)
-            pool = cell[f"kv{i}"].at[page, row].set(lat)
-            new_cell[f"kv{i}"] = pool
-            o_lat = pk.paged_latent_attention(
-                blk.attn.absorb(p["attn"], q_nope, q_rope), pool, tables,
-                pos, d_value=blk.attn.kv_rank, scale=blk.attn.scale,
-                work=work, route=attn_route)
-            h = h + _dot(blk.attn.unabsorb(p["attn"], o_lat),
-                         p["attn"]["w_o"])
-            h, c = blk.feed_forward(p, h, live)
-            if c is not None:
-                counts.append(c)
-        if "stats" in cell:
-            new_cell["stats"] = self._add_stats(cell["stats"], counts, live,
-                                                tokens.shape[0])
-        return self.logits(params, h), new_cell
-
-    def generate_cached(self, params, prompt, steps: int, *,
-                        page_block: int = 64):
-        """Greedy continuation through prefill + the paged decode step
-        (one private table a sample): prompt [B, T0] -> [B, T0 + steps].
-        The solo decode a served stream is compared with."""
-        return paged_greedy(self, params, prompt, steps, page_block)
+    # -- one token against the paged cache (the absorbed path) -------------
+    def _decode_layer(self, i, blk, p, h, cell, step):
+        """The step's latent row written into ``kv{i}`` [P, bs, row], then
+        the absorbed read over the live pages."""
+        rd = step.full
+        x = blk.attn_norm(p["attn_norm"], h)
+        q_nope, q_rope, lat = blk.attn.project(p["attn"], x, rd.pos)
+        pool = cell[f"kv{i}"].at[rd.page, rd.row].set(lat)
+        o_lat = pk.paged_latent_attention(
+            blk.attn.absorb(p["attn"], q_nope, q_rope), pool, rd.tables,
+            rd.pos, d_value=blk.attn.kv_rank, scale=blk.attn.scale,
+            work=rd.work, route=step.attn_route)
+        h = h + _dot(blk.attn.unabsorb(p["attn"], o_lat), p["attn"]["w_o"])
+        h, c = blk.feed_forward(p, h, step.live)
+        return h, {f"kv{i}": pool}, c

@@ -1,7 +1,8 @@
 """Keye-VL-2.0's language model as a served stack: a pre-norm block of
 grouped-query attention that SELECTS what it reads, and a routed-expert
 layer with a softmax router and no shared expert (parallel/expert_share.py,
-``score="softmax"``). The sixth model class behind ``serve --config``.
+``score="softmax"``). The sixth model class behind ``serve --config``, a
+``PagedLM`` (models/paged_lm.py).
 
 The selection is DeepSeek Sparse Attention's lightning indexer. Beside q, k
 and v a layer projects, from the same normed input, ``index_heads`` small
@@ -65,17 +66,11 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
-from ..parallel.expert_share import (ExpertShare, ProgramStats,
-                                     ffn_or_experts)
-from .transformer import CacheRow, LiveRowPrefill, paged_greedy, \
-    prefill_live_rows
+from ..parallel.expert_share import ExpertShare, ffn_or_experts
+from .paged_lm import CacheRow, PagedLM, _dot
 
 #: the lane width the indexer's row is held at (module docstring)
 LANES = 128
-
-
-def _dot(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
 
 
 class GroupedAttention(nn.Module):
@@ -169,17 +164,16 @@ class KeyeBlock(nn.Module):
                                **moe_kw)
 
 
-class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
+class KeyeSparseLM(PagedLM):
     """``vocab`` rows of embedding and of an untied head, ``n_layers``
     blocks, each an expert layer over ``experts_held`` of ``n_experts``."""
 
-    #: the page pool hands ``prefill`` its pools and its ``write``: no
-    #: [slots, prompt bucket] copy of the rows stands beside the pools
+    #: rows of up to 32,768 positions, three arrays a layer: no [slots,
+    #: prompt bucket] copy of them beside the pools
     admits_in_place = True
-    #: the decode read's registered cost model while a context is within
-    #: ``index_topk``; past it the model's own counters speak
+    #: ``paged_read_kernel`` is the read's cost model while a context is
+    #: within ``index_topk``; past it the model's own counters speak
     #: (``note_program_stats``), so the pool counts no page walked
-    paged_read_kernel = "paged_decode_attention"
     paged_read_layers = 0
 
     def __init__(self, vocab: int, *, d_model: int, n_heads: int,
@@ -219,8 +213,8 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         self.norm_f = nn.RMSNorm(d_model, eps, dtype=dtype)
         self.head = nn.Embedding(vocab, d_model, dtype=dtype,
                                  w_init=normal(0.0, init_std))
-        self._decode_layer = jax.jit(self._decode_layer_impl,
-                                     static_argnames=("attn_route",))
+        self._layer = jax.jit(self._decode_layer_impl,
+                              static_argnames=("attn_route",))
 
     # -- what the page pool asks -------------------------------------------
     def cache_rows(self, params, kv_dtype: Optional[str] = None):
@@ -238,13 +232,6 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
                 held=(-(-self.index_dim // LANES) * LANES,)))
         return rows
 
-    @staticmethod
-    def _no_kv_dtype(kv_dtype):
-        if kv_dtype is not None:
-            raise ValueError(f"kv_dtype {kv_dtype!r}: pages are kept in the "
-                             "parameters' dtype; there is no quantised "
-                             "cache for this model")
-
     def prefill_chunk_tokens(self, width: int) -> int:
         """ONE row a chunk, whatever its width: the row walks its own
         blocks (``_sequence``)."""
@@ -255,14 +242,6 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
         depth: each row's own blocks, not its bucket."""
         q = min(self.block_tokens, width)
         return int(sum(-(-int(n) // q) * q for n in lengths if n > 0))
-
-    def paged_read_geometry(self, params, kv_dtype=None):
-        return {"n_heads": self.n_heads, "kv_heads": self.kv_heads,
-                "d_head": self.d_head, "kv_dtype": None,
-                "itemsize": jnp.dtype(self._compute_dtype(params)).itemsize}
-
-    def _compute_dtype(self, params):
-        return params["embed"]["w"].dtype
 
     # -- what a program returns beside its tokens ---------------------------
     def program_stats_zero(self):
@@ -286,12 +265,6 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
                     scored=zero, dense_rows=zero, sparse_steps=zero,
                     dense_steps=zero, read=jnp.zeros((2,), jnp.int32),
                     pairs_causal=jnp.zeros((), jnp.float32))
-
-    def _add_stats(self, stats, counts, live, n_rows, **more):
-        out = dict(stats, **super()._add_stats(stats, counts, live, n_rows))
-        for k, v in more.items():
-            out[k] = stats[k] + jnp.asarray(v, stats[k].dtype)
-        return out
 
     def note_program_stats(self, stats, program: str):
         from .. import obs
@@ -419,13 +392,6 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
                 kb[None], vb[None], ib[None]
         return last[None] if whole else last, state, stats
 
-    def logits(self, params, h):
-        x = self.norm_f(params["norm_f"], h)
-        w = params["head"]["w"]                 # [vocab, d], as published
-        return jax.lax.dot_general(x.astype(w.dtype), w,
-                                   (((x.ndim - 1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-
     def _blocks_of(self, width: int):
         """(block, padded width) of a row ``width`` wide."""
         q = min(self.block_tokens, -(-width // 8) * 8)
@@ -439,73 +405,33 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
             self.logits(params, self._sequence(params, row[None], None)[0]
                         [0, :T]) for row in ids])
 
-    def prefill(self, params, prompt, lengths=None, *,
-                kv_dtype: Optional[str] = None,
-                pad_to: Optional[int] = None, pools=None, write=None):
-        """TransformerLM.prefill's contract: (cell, last logits [B, V]);
-        the cell holds ``pos``, ``stats`` and ``k{i}`` / ``v{i}`` /
-        ``ik{i}`` of every layer. The rows that HOLD a prompt run one
-        after another (``prefill_live_rows``), each a block at a time
-        (``_sequence``), and only each row's last position reaches the
-        head.
-
-        ``pools`` + ``write`` (the page pool's admission): the rows are
-        the pool's own arrays, and every row's keys go into them through
-        ``write(pools, idx, n, rows)``; they come back WRITTEN, the same
-        buffers when the caller donated them. Without them the rows come
-        back whole, ``[B, pad_to, ...]``."""
-        self._no_kv_dtype(kv_dtype)
-        B, T0 = prompt.shape
-        limit = self.max_len if pad_to is None else min(pad_to, self.max_len)
-        if limit < T0:
-            raise ValueError(f"prefill cache limit {limit} (pad_to/max_len) "
-                             f"is narrower than the prompt ({T0})")
-        pos = (jnp.full((B,), T0, jnp.int32) if lengths is None
-               else jnp.asarray(lengths, jnp.int32))
-        Tp = self._blocks_of(T0)[1]
-        if pools is not None and Tp != T0:
-            raise ValueError(f"a prompt bucket of {T0} is not whole blocks "
-                             f"of {self._blocks_of(T0)[0]} positions")
-        state0 = pools if pools is not None else {
-            r.name: jnp.zeros((B, Tp) + r.shape, r.dtype)
-            for r in self.cache_rows(params)}
-        last, state, stats = prefill_live_rows(
-            lambda ids, n: self._sequence(params, ids, n),
-            jnp.pad(prompt, ((0, 0), (0, Tp - T0))), pos, self.d_model,
-            state0, self.program_stats_zero(), Tp, write=write)
-        if pools is None:
-            state = {nm: jnp.pad(buf[:, :min(Tp, limit)], (
-                (0, 0), (0, max(limit - Tp, 0))) + ((0, 0),) * (buf.ndim - 2))
-                for nm, buf in state.items()}
-        return dict(state, pos=pos, stats=stats), self.logits(params, last)
-
     # -- one token against the paged cache ---------------------------------
-    def _decode_layer_impl(self, p, h, kc, vc, ic, pos, page, row, tables,
-                           work, past, live, alive, *, attn_route):
-        """One layer of :meth:`decode_step_paged`: the step's rows written
-        into the layer's three pools, the read, the experts -> (h, the
-        pools, the experts' counts, the keys the live slots' reads took,
-        the runs their selected reads fetched).
-        A step calls this as ONE jitted function (``_decode_layer``) a
-        layer, so a program traces and lowers the layer — its ``cond``,
-        both reads, four kernels — once and not once a layer; XLA inlines
-        the calls and compiles what it compiled. ``blocks[0]``'s modules
-        stand for any layer's: ``__init__`` builds every block from the
-        same arguments, and ``p`` is the layer's own parameters. A model
-        whose layers differ would hand the layer's kind over as a static
+    def _decode_layer_impl(self, p, h, kc, vc, ic, rd, past, live, alive, *,
+                           attn_route):
+        """One layer of a decode step: the step's rows written into the
+        layer's three pools, the read, the experts -> (h, the pools, the
+        experts' counts, the keys the live slots' reads took, the runs
+        their selected reads fetched).
+        A step calls this as ONE jitted function (``_layer``) a layer, so a
+        program traces and lowers the layer — its ``cond``, both reads,
+        four kernels — once and not once a layer; XLA inlines the calls
+        and compiles what it compiled. ``blocks[0]``'s modules stand for
+        any layer's: ``__init__`` builds every block from the same
+        arguments, and ``p`` is the layer's own parameters. A model whose
+        layers differ would hand the layer's kind over as a static
         argument. (Why this model alone: PERF.md section 6, PR 43.)"""
         blk = self.blocks[0]
+        pos, tables = rd.pos, rd.tables
         x = blk.input_norm(p["input_norm"], h)
         q, k, v = blk.attn.project(p["attn"], x, pos)
         qi, ki, w = blk.idx.project(p["idx"], x, pos)
-        kp, k_rows = pk.put_rows(kc, page, row, k)
-        vp, v_rows = pk.put_rows(vc, page, row, v)
-        ip, _ = pk.put_rows(ic, page, row, ki)
+        kp, k_rows = rd.put(kc, k)
+        vp, v_rows = rd.put(vc, v)
+        ip, _ = rd.put(ic, ki)
 
         def dense(_):
-            return pk.paged_decode_attention(
-                q, k_rows, v_rows, tables, pos, scale=blk.attn.scale,
-                work=work, route=attn_route), pos + 1, jnp.zeros_like(pos)
+            return rd.attend(q, k_rows, v_rows, scale=blk.attn.scale,
+                             route=attn_route), pos + 1, jnp.zeros_like(pos)
 
         def sparse(_):
             bias, cnt = pk.select_topk(
@@ -521,53 +447,34 @@ class KeyeSparseLM(ProgramStats, LiveRowPrefill, nn.Module):
             jnp.sum(jnp.where(alive, n, 0), dtype=jnp.int32)
             for n in (cnt, runs))
 
-    def decode_step_paged(self, params, cell, tokens, tables, *, live=None,
-                          attn_route: Optional[str] = None):
-        """TransformerLM.decode_step_paged's contract. Every layer writes
-        the step's k, v and kI at ``tables[b, pos // bs]`` and reads as the
-        module docstring says: the dense paged read while EVERY slot's
-        context is within ``index_topk`` (one ``cond`` a step, on the
-        longest), else scores -> selection -> the selected rows. ``live``
-        [B] marks the slots whose tokens count (and whose experts run);
-        ``cell["stats"]``, when present, accumulates
-        :meth:`program_stats_zero`'s tree."""
-        pos = cell["pos"]
-        bs = cell["k0"].shape[1]
-        B = tokens.shape[0]
-        page = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
-        row = pos % bs
-        work = pk.paged_work_list(tables, pos, bs)
-        past = jnp.max(pos) >= self.index_topk
-        alive = jnp.ones((B,), bool) if live is None else live
-        h = self.embed(params["embed"], tokens).astype(jnp.float32)
-        new_cell = {"pos": pos + 1}
-        counts, selected, runs = [], [], jnp.int32(0)
-        for i in range(len(self.blocks)):
-            h, kp, vp, ip, c, cnt, n = self._decode_layer(
-                params[f"blocks_{i}"], h, cell[f"k{i}"], cell[f"v{i}"],
-                cell[f"ik{i}"], pos, page, row, tables, work, past, live,
-                alive, attn_route=attn_route)
-            new_cell[f"k{i}"], new_cell[f"v{i}"], new_cell[f"ik{i}"] = \
-                kp, vp, ip
-            counts.append(c)
-            selected.append(cnt)
-            runs = runs + n
-        if "stats" in cell:
-            steps = jnp.sum(alive, dtype=jnp.int32)
-            keys = jnp.sum(jnp.where(alive, pos + 1, 0), dtype=jnp.int32)
-            new_cell["stats"] = self._add_stats(
-                cell["stats"], counts, live, B,
-                selected=jnp.where(past, jnp.stack(selected), 0),
-                read=runs * jnp.array([pk.sparse_run(bs), 2], jnp.int32),
-                scored=jnp.where(past, keys, 0),
-                dense_rows=jnp.where(past, 0, keys),
-                sparse_steps=jnp.where(past, steps, 0),
-                dense_steps=jnp.where(past, 0, steps))
-        return self.logits(params, h), new_cell
+    def _step_extra(self, pos, live):
+        """(whether the longest context is past ``index_topk``: one
+        ``cond`` a step, so every layer reads the same way; the slots that
+        count)."""
+        return (jnp.max(pos) >= self.index_topk,
+                jnp.ones(pos.shape, bool) if live is None else live)
 
-    def generate_cached(self, params, prompt, steps: int, *,
-                        page_block: int = 64):
-        """Greedy continuation through prefill + the paged decode step
-        (one private table a sample): prompt [B, T0] -> [B, T0 + steps].
-        The solo decode a served stream is compared with."""
-        return paged_greedy(self, params, prompt, steps, page_block)
+    def _decode_layer(self, i, blk, p, h, cell, step):
+        """Every layer writes the step's k, v and kI at ``tables[b, pos //
+        bs]`` and reads as the module docstring says: the dense paged read
+        while EVERY slot's context is within ``index_topk``, else scores ->
+        selection -> the selected rows."""
+        past, alive = step.extra
+        h, kp, vp, ip, c, cnt, runs = self._layer(
+            p, h, cell[f"k{i}"], cell[f"v{i}"], cell[f"ik{i}"], step.full,
+            past, step.live, alive, attn_route=step.attn_route)
+        return h, {f"k{i}": kp, f"v{i}": vp, f"ik{i}": ip}, c, (cnt, runs)
+
+    def _step_stats(self, step, notes):
+        past, alive = step.extra
+        pos, bs = step.full.pos, step.page_block
+        selected, runs = zip(*notes)
+        steps = jnp.sum(alive, dtype=jnp.int32)
+        keys = jnp.sum(jnp.where(alive, pos + 1, 0), dtype=jnp.int32)
+        return dict(
+            selected=jnp.where(past, jnp.stack(selected), 0),
+            read=sum(runs) * jnp.array([pk.sparse_run(bs), 2], jnp.int32),
+            scored=jnp.where(past, keys, 0),
+            dense_rows=jnp.where(past, 0, keys),
+            sparse_steps=jnp.where(past, steps, 0),
+            dense_steps=jnp.where(past, 0, steps))

@@ -5,8 +5,7 @@ followed by a SwiGLU FFN — dense in the leading layers, a routed-expert
 layer (parallel/expert_share.py: sigmoid scores, top-k of score + bias over
 all experts, no groups, no shared expert) after them; RMSNorm throughout, a
 final norm, the head tied to the embedding. The third model class behind
-``serve --config``: it offers the paged pool the entry points
-``TransformerLM`` and ``DeepseekV3LM`` offer.
+``serve --config``, a ``PagedLM`` (models/paged_lm.py).
 
 Two kinds of state live side by side. An attention layer keeps keys and
 values in PAGES, ``kv_heads`` heads a row (fewer than the query heads: a
@@ -30,20 +29,13 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 
 from .. import nn
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
-from ..parallel.expert_share import (ExpertShare, ProgramStats,
-                                     ffn_or_experts)
-from .transformer import (PREFILL_TOKENS, CacheRow, LiveRowPrefill, SlotRow,
-                          paged_greedy, prefill_live_rows)
-
-
-def _dot(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+from ..parallel.expert_share import ExpertShare, ffn_or_experts
+from .paged_lm import CacheRow, PagedLM, SlotRow, _dot
 
 
 class GroupedQueryAttention(nn.Module):
@@ -113,7 +105,7 @@ class Lfm2Block(nn.Module):
                                  w_init=normal(0.0, init_std), dtype=dtype)
 
 
-class Lfm2MoeLM(ProgramStats, LiveRowPrefill, nn.Module):
+class Lfm2MoeLM(PagedLM):
     """``vocab`` rows of embedding (and tied head), one block per entry of
     ``layer_types``; the first ``n_dense`` carry the dense FFN, the rest
     the expert layer over ``experts_held`` of ``n_experts``."""
@@ -174,31 +166,10 @@ class Lfm2MoeLM(ProgramStats, LiveRowPrefill, nn.Module):
                                   dt) for n in "kv"]
         return rows
 
-    @staticmethod
-    def _no_kv_dtype(kv_dtype):
-        if kv_dtype is not None:
-            raise ValueError(f"kv_dtype {kv_dtype!r}: pages and slot state "
-                             "are kept in the parameters' dtype; there is "
-                             "no quantised cache for this model")
-
-    def prefill_chunk_tokens(self, width: int) -> int:
-        return PREFILL_TOKENS
-
-    #: the decode read's registered cost model (obs/roofline.kernel_cost)
-    paged_read_kernel = "paged_decode_attention"
-
     @property
     def paged_read_layers(self):
         """Layers of a decode step that read the pages."""
         return len(self.attn_layers)
-
-    def paged_read_geometry(self, params, kv_dtype=None):
-        return {"n_heads": self.n_heads, "kv_heads": self.kv_heads,
-                "d_head": self.d_head, "kv_dtype": None,
-                "itemsize": jnp.dtype(self._compute_dtype(params)).itemsize}
-
-    def _compute_dtype(self, params):
-        return params["embed"]["w"].dtype
 
     # -- whole sequences ---------------------------------------------------
     def _sequence(self, params, ids, lengths):
@@ -206,10 +177,8 @@ class Lfm2MoeLM(ProgramStats, LiveRowPrefill, nn.Module):
         T, Hkv, D] and ``conv{i}`` [B, taps - 1, d] at each row's length,
         stats)."""
         B, T = ids.shape
-        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        live = None if lengths is None else \
-            positions < jnp.asarray(lengths, jnp.int32)[:, None]
-        h = self.embed(params["embed"], ids).astype(jnp.float32)
+        positions, live = self._positions_live(ids, lengths)
+        h = self._embed(params, ids)
         state, counts = {}, []
         for i, blk in enumerate(self.blocks):
             p = params[f"blocks_{i}"]
@@ -230,101 +199,23 @@ class Lfm2MoeLM(ProgramStats, LiveRowPrefill, nn.Module):
                                 B * T)
         return h, state, stats
 
-    def logits(self, params, h):
-        x = self.norm_f(params["norm_f"], h)
-        w = params["embed"]["w"]                # the head is the embedding
-        return jax.lax.dot_general(x.astype(w.dtype), w,
-                                   (((x.ndim - 1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-
-    def __call__(self, params, ids, **kw):
-        """ids [B, T] -> logits [B, T, V] f32."""
-        h, _, _ = self._sequence(params, ids, None)
-        return self.logits(params, h)
-
-    def prefill(self, params, prompt, lengths=None, *,
-                kv_dtype: Optional[str] = None,
-                pad_to: Optional[int] = None):
-        """TransformerLM.prefill's contract: (cell, last logits [B, V]);
-        the cell holds ``pos``, ``k{i}`` / ``v{i}`` [B, pad_to, Hkv, D] for
-        the attention layers, ``conv{i}`` [B, taps - 1, d] (each row's tail
-        at its own length) for the convolution layers, and ``stats``. Only
-        the rows that HOLD a prompt run (``prefill_live_rows``:
-        ``PREFILL_TOKENS`` at a time, live rows first); the others' cell
-        entries come back zero and the pool reads none of them. Only each
-        row's last position reaches the head."""
-        self._no_kv_dtype(kv_dtype)
-        B, T0 = prompt.shape
-        limit = self.max_len if pad_to is None else min(pad_to, self.max_len)
-        if limit < T0:
-            raise ValueError(f"prefill cache limit {limit} (pad_to/max_len) "
-                             f"is narrower than the prompt ({T0})")
-        pos = (jnp.full((B,), T0, jnp.int32) if lengths is None
-               else jnp.asarray(lengths, jnp.int32))
-        rows = self.cache_rows(params)
-        per_slot = {r.name for r in rows if isinstance(r, SlotRow)}
-        state0 = {r.name: jnp.zeros(
-            (B,) + (() if r.name in per_slot else (T0,)) + r.shape, r.dtype)
-            for r in rows}
-        last, state, stats = prefill_live_rows(
-            lambda ids, n: self._sequence(params, ids, n), prompt, pos,
-            self.d_model, state0, self.program_stats_zero(),
-            self.prefill_chunk_tokens(T0))
-        cell = {"pos": pos, "stats": stats}
-        for nm, buf in state.items():
-            cell[nm] = buf if nm in per_slot else jnp.pad(
-                buf, ((0, 0), (0, limit - T0), (0, 0), (0, 0)))
-        return cell, self.logits(params, last)
-
     # -- one token against the paged cache ---------------------------------
-    def decode_step_paged(self, params, cell, tokens, tables, *,
-                          live=None, attn_route: Optional[str] = None):
-        """TransformerLM.decode_step_paged's contract. Attention layers
-        write the step's k, v (after the norms and RoPE) into their pools
-        ``k{i}`` / ``v{i}`` [P, bs, Hkv, D] at page ``tables[b, pos // bs]``
-        and read through pk.paged_decode_attention, a KV head serving its
-        group of query heads, on one work list for all of them; convolution
-        layers roll the slot's tail ``conv{i}`` [B, taps - 1, d]. ``live``
-        [B] marks the slots whose tokens count (and whose experts run);
-        ``cell["stats"]``, when present, accumulates
-        :meth:`program_stats_zero`'s tree."""
-        pos = cell["pos"]
-        bs = cell[f"k{self.attn_layers[0]}"].shape[1]
-        work = pk.paged_work_list(tables, pos, bs)
-        page = jnp.take_along_axis(tables, (pos // bs)[:, None],
-                                   axis=1)[:, 0]
-        row = pos % bs
-        B = tokens.shape[0]
-        h = self.embed(params["embed"], tokens).astype(jnp.float32)
-        new_cell = {"pos": pos + 1}
-        counts = []
-        for i, blk in enumerate(self.blocks):
-            p = params[f"blocks_{i}"]
-            x = blk.op_norm(p["op_norm"], h)
-            if blk.kind == "conv":
-                y, new_cell[f"conv{i}"] = blk.conv.step(
-                    p["conv"], x, cell[f"conv{i}"])
-                h = h + y
-            else:
-                q, k, v = blk.attn.project(p["attn"], x, pos)
-                kp, k_rows = pk.put_rows(cell[f"k{i}"], page, row, k)
-                vp, v_rows = pk.put_rows(cell[f"v{i}"], page, row, v)
-                new_cell[f"k{i}"], new_cell[f"v{i}"] = kp, vp
-                o = pk.paged_decode_attention(
-                    q, k_rows, v_rows, tables, pos, scale=blk.attn.scale,
-                    work=work, route=attn_route)
-                h = h + _dot(o.reshape(B, -1), p["attn"]["w_o"])
-            h, c = ffn_or_experts(blk, p, h, live)
-            if c is not None:
-                counts.append(c)
-        if "stats" in cell:
-            new_cell["stats"] = self._add_stats(cell["stats"], counts, live,
-                                                B)
-        return self.logits(params, h), new_cell
-
-    def generate_cached(self, params, prompt, steps: int, *,
-                        page_block: int = 64):
-        """Greedy continuation through prefill + the paged decode step
-        (one private table a sample): prompt [B, T0] -> [B, T0 + steps].
-        The solo decode a served stream is compared with."""
-        return paged_greedy(self, params, prompt, steps, page_block)
+    def _decode_layer(self, i, blk, p, h, cell, step):
+        """An attention layer writes the step's k, v (after the norms and
+        RoPE) into its pools ``k{i}`` / ``v{i}`` [P, bs, Hkv, D] and reads
+        them back, a KV head serving its group of query heads; a
+        convolution layer rolls the slot's tail ``conv{i}`` [B, taps - 1,
+        d]."""
+        x = blk.op_norm(p["op_norm"], h)
+        if blk.kind == "conv":
+            y, tail = blk.conv.step(p["conv"], x, cell[f"conv{i}"])
+            h, rows = h + y, {f"conv{i}": tail}
+        else:
+            q, k, v = blk.attn.project(p["attn"], x, step.full.pos)
+            o, kp, vp = step.full.write_and_attend(
+                q, k, v, cell[f"k{i}"], cell[f"v{i}"], scale=blk.attn.scale,
+                route=step.attn_route)
+            h = h + _dot(o.reshape(h.shape[0], -1), p["attn"]["w_o"])
+            rows = {f"k{i}": kp, f"v{i}": vp}
+        h, c = ffn_or_experts(blk, p, h, step.live)
+        return h, rows, c

@@ -6,8 +6,8 @@ with ``gated=False``: experts and the shared expert are ``down(relu(up(y))
 ^ 2)``, two matrices; DeepSeek-V3's sigmoid router without groups), ``*``
 grouped-query attention WITHOUT any positional term (position comes from
 the state-space layers) — then a final RMSNorm and an untied head. The
-fourth model class behind ``serve --config``: it offers the paged pool the
-entry points the other three offer.
+fourth model class behind ``serve --config``, a ``PagedLM``
+(models/paged_lm.py).
 
 Two kinds of state, the larger one not pages. An attention layer keeps
 keys and values in PAGES (``kv_heads`` heads a row; a KV head serves its
@@ -36,22 +36,15 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 
 from .. import nn
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
-from ..parallel.expert_share import ExpertShare, ProgramStats
-from .transformer import (PREFILL_TOKENS, SOLO_ROW_TOKENS, CacheRow,
-                          LiveRowPrefill, SlotRow, paged_greedy,
-                          prefill_live_rows)
+from ..parallel.expert_share import ExpertShare
+from .paged_lm import CacheRow, PagedLM, SlotRow, _dot
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
-
-
-def _dot(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
 
 
 class PlainGroupedAttention(nn.Module):
@@ -109,7 +102,7 @@ class NemotronHBlock(nn.Module):
             raise ValueError(f"unknown layer kind {kind!r}")
 
 
-class NemotronHLM(ProgramStats, LiveRowPrefill, nn.Module):
+class NemotronHLM(PagedLM):
     """``vocab`` rows of embedding and of an untied head, one block per
     character of ``pattern`` (``M`` / ``E`` / ``*``); the expert layers
     over ``experts_held`` of ``n_experts``."""
@@ -190,33 +183,12 @@ class NemotronHLM(ProgramStats, LiveRowPrefill, nn.Module):
                                   dt) for n in "kv"]
         return rows
 
-    @staticmethod
-    def _no_kv_dtype(kv_dtype):
-        if kv_dtype is not None:
-            raise ValueError(f"kv_dtype {kv_dtype!r}: pages and slot state "
-                             "are kept as the model states them; there is "
-                             "no quantised cache for this model")
-
-    def prefill_chunk_tokens(self, width: int) -> int:
-        """``PREFILL_TOKENS`` of rows a chunk, a row of
-        ``SOLO_ROW_TOKENS`` or more alone in its chunk."""
-        return width if width >= SOLO_ROW_TOKENS else PREFILL_TOKENS
-
-    #: the decode read's registered cost model (obs/roofline.kernel_cost)
-    paged_read_kernel = "paged_decode_attention"
+    prefill_chunk_tokens = PagedLM.solo_row_chunk_tokens
 
     @property
     def paged_read_layers(self):
         """Layers of a decode step that read the pages."""
         return len(self.attn_layers)
-
-    def paged_read_geometry(self, params, kv_dtype=None):
-        return {"n_heads": self.n_heads, "kv_heads": self.kv_heads,
-                "d_head": self.d_head, "kv_dtype": None,
-                "itemsize": jnp.dtype(self._compute_dtype(params)).itemsize}
-
-    def _compute_dtype(self, params):
-        return params["embed"]["w"].dtype
 
     # -- what a program returns beside its tokens ---------------------------
     def program_stats_zero(self):
@@ -228,12 +200,6 @@ class NemotronHLM(ProgramStats, LiveRowPrefill, nn.Module):
         zero = jnp.zeros((), jnp.int32)
         return dict(super().program_stats_zero(), ssm_updates=zero,
                     scan_real=zero, scan_padded=zero)
-
-    def _add_stats(self, stats, counts, live, n_rows, **more):
-        out = dict(stats, **super()._add_stats(stats, counts, live, n_rows))
-        for k, v in more.items():
-            out[k] = stats[k] + jnp.asarray(v, jnp.int32)
-        return out
 
     def note_program_stats(self, stats, program: str):
         from .. import obs
@@ -266,10 +232,8 @@ class NemotronHLM(ProgramStats, LiveRowPrefill, nn.Module):
         T, Hkv, D], ``ssm{i}`` and ``conv{i}`` at each row's length,
         stats)."""
         B, T = ids.shape
-        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        live = None if lengths is None else \
-            positions < jnp.asarray(lengths, jnp.int32)[:, None]
-        h = self.embed(params["embed"], ids).astype(jnp.float32)
+        _, live = self._positions_live(ids, lengths)
+        h = self._embed(params, ids)
         state, counts = {}, []
         for i, blk in enumerate(self.blocks):
             p = params[f"blocks_{i}"]
@@ -297,110 +261,30 @@ class NemotronHLM(ProgramStats, LiveRowPrefill, nn.Module):
             scan_real=n_real * n_m, scan_padded=(B * T - n_real) * n_m)
         return h, state, stats
 
-    def logits(self, params, h):
-        x = self.norm_f(params["norm_f"], h)
-        w = params["head"]["w"]                 # [vocab, d], as published
-        return jax.lax.dot_general(x.astype(w.dtype), w,
-                                   (((x.ndim - 1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-
-    def __call__(self, params, ids, **kw):
-        """ids [B, T] -> logits [B, T, V] f32."""
-        h, _, _ = self._sequence(params, ids, None)
-        return self.logits(params, h)
-
-    def prefill(self, params, prompt, lengths=None, *,
-                kv_dtype: Optional[str] = None,
-                pad_to: Optional[int] = None, slot_state=None):
-        """TransformerLM.prefill's contract: (cell, last logits [B, V]);
-        the cell holds ``pos``, ``k{i}`` / ``v{i}`` [B, pad_to, Hkv, D] for
-        the attention layers, ``ssm{i}`` / ``conv{i}`` [B, ...] for the
-        Mamba layers, and ``stats``. Only the rows that HOLD a prompt run
-        (``prefill_live_rows``: ``PREFILL_TOKENS`` at a time, live rows
-        first). ``slot_state``: the pool's own ``[slots, ...]`` arrays of
-        the per-slot rows — they come back in the cell WRITTEN at the
-        rows that hold a prompt and untouched elsewhere, the same buffers
-        when the caller donated them (``slot_rows_in_place``); without
-        it the rows come back in fresh zero arrays. Only each row's last
-        position reaches the head."""
-        self._no_kv_dtype(kv_dtype)
-        B, T0 = prompt.shape
-        limit = self.max_len if pad_to is None else min(pad_to, self.max_len)
-        if limit < T0:
-            raise ValueError(f"prefill cache limit {limit} (pad_to/max_len) "
-                             f"is narrower than the prompt ({T0})")
-        pos = (jnp.full((B,), T0, jnp.int32) if lengths is None
-               else jnp.asarray(lengths, jnp.int32))
-        rows = self.cache_rows(params)
-        per_slot = {r.name for r in rows if isinstance(r, SlotRow)}
-        state0 = {r.name: jnp.zeros((B, T0) + r.shape, r.dtype)
-                  for r in rows if r.name not in per_slot}
-        state0.update(slot_state if slot_state is not None else {
-            r.name: jnp.zeros((B,) + r.shape, r.dtype)
-            for r in rows if r.name in per_slot})
-        last, state, stats = prefill_live_rows(
-            lambda ids, n: self._sequence(params, ids, n), prompt, pos,
-            self.d_model, state0, self.program_stats_zero(),
-            self.prefill_chunk_tokens(T0), in_place=per_slot)
-        cell = {"pos": pos, "stats": stats}
-        for nm, buf in state.items():
-            cell[nm] = buf if nm in per_slot else jnp.pad(
-                buf, ((0, 0), (0, limit - T0), (0, 0), (0, 0)))
-        return cell, self.logits(params, last)
-
     # -- one token against the paged cache ---------------------------------
-    def decode_step_paged(self, params, cell, tokens, tables, *,
-                          live=None, attn_route: Optional[str] = None):
-        """TransformerLM.decode_step_paged's contract. Attention layers
-        write the step's k, v into their pools ``k{i}`` / ``v{i}`` [P, bs,
-        Hkv, D] at page ``tables[b, pos // bs]`` and read through
-        pk.paged_decode_attention on one work list for all of them; Mamba
-        layers roll the slot's ``conv{i}`` and update ``ssm{i}`` through
-        pk.ssm_state_update — in place, the live slots alone. ``live`` [B]
-        marks the slots whose tokens count (whose experts run, whose
-        state moves); ``cell["stats"]``, when present, accumulates
-        :meth:`program_stats_zero`'s tree."""
-        pos = cell["pos"]
-        bs = cell[f"k{self.attn_layers[0]}"].shape[1]
-        work = pk.paged_work_list(tables, pos, bs)
-        page = jnp.take_along_axis(tables, (pos // bs)[:, None],
-                                   axis=1)[:, 0]
-        row = pos % bs
-        B = tokens.shape[0]
-        h = self.embed(params["embed"], tokens).astype(jnp.float32)
-        new_cell = {"pos": pos + 1}
-        counts = []
-        for i, blk in enumerate(self.blocks):
-            p = params[f"blocks_{i}"]
-            x = blk.norm(p["norm"], h)
-            if blk.kind == "mamba":
-                y, new_cell[f"ssm{i}"], new_cell[f"conv{i}"] = \
-                    blk.mixer.step(p["mixer"], x, cell[f"ssm{i}"],
-                                   cell[f"conv{i}"], live)
-                h = h + y
-            elif blk.kind == "attention":
-                q, k, v = blk.attn.project(p["attn"], x)
-                kp, k_rows = pk.put_rows(cell[f"k{i}"], page, row, k)
-                vp, v_rows = pk.put_rows(cell[f"v{i}"], page, row, v)
-                new_cell[f"k{i}"], new_cell[f"v{i}"] = kp, vp
-                o = pk.paged_decode_attention(
-                    q, k_rows, v_rows, tables, pos, scale=blk.attn.scale,
-                    work=work, route=attn_route)
-                h = h + _dot(o.reshape(B, -1), p["attn"]["w_o"])
-            else:
-                out, c = blk.moe(p["moe"], x, live)
-                h = h + out
-                counts.append(c)
-        if "stats" in cell:
-            n_live = B if live is None else jnp.sum(live, dtype=jnp.int32)
-            new_cell["stats"] = self._add_stats(
-                cell["stats"], counts, live, B,
-                ssm_updates=n_live * len(self.mamba_layers))
-        return self.logits(params, h), new_cell
+    def _decode_layer(self, i, blk, p, h, cell, step):
+        """A Mamba layer rolls the slot's ``conv{i}`` and updates ``ssm{i}``
+        through pk.ssm_state_update — in place, the live slots alone; an
+        attention layer writes the step's k, v into its pools ``k{i}`` /
+        ``v{i}`` [P, bs, Hkv, D] and reads them back; an expert layer runs
+        the live slots' tokens."""
+        x = blk.norm(p["norm"], h)
+        if blk.kind == "mamba":
+            y, ssm, conv = blk.mixer.step(p["mixer"], x, cell[f"ssm{i}"],
+                                          cell[f"conv{i}"], step.live)
+            return h + y, {f"ssm{i}": ssm, f"conv{i}": conv}, None
+        if blk.kind == "attention":
+            q, k, v = blk.attn.project(p["attn"], x)
+            o, kp, vp = step.full.write_and_attend(
+                q, k, v, cell[f"k{i}"], cell[f"v{i}"], scale=blk.attn.scale,
+                route=step.attn_route)
+            return (h + _dot(o.reshape(h.shape[0], -1), p["attn"]["w_o"]),
+                    {f"k{i}": kp, f"v{i}": vp}, None)
+        out, c = blk.moe(p["moe"], x, step.live)
+        return h + out, {}, c
 
-    def generate_cached(self, params, prompt, steps: int, *,
-                        page_block: int = 64):
-        """Greedy continuation through prefill + the paged decode step
-        (one private table a sample): prompt [B, T0] -> [B, T0 + steps].
-        The solo decode a served stream is compared with."""
-        return paged_greedy(self, params, prompt, steps, page_block)
+    def _step_stats(self, step, notes):
+        B = step.full.pos.shape[0]
+        n_live = B if step.live is None \
+            else jnp.sum(step.live, dtype=jnp.int32)
+        return dict(ssm_updates=n_live * len(self.mamba_layers))

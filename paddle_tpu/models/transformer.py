@@ -14,7 +14,7 @@ whole-model bf16 compute with f32 master params handled by callers, and a
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,177 +23,13 @@ from .. import nn
 from .. import obs
 from ..nn.initializer import normal
 from ..ops import pallas_kernels as pk
+from .paged_lm import CacheRow, PagedLM, prefill_live_rows
 
 
-class CacheRow(NamedTuple):
-    """One array of a model's per-layer cache as it states it to the page
-    pool (serving/paged.py), which allocates ``[pages, page_block, *shape]``
-    of ``dtype`` filled with ``fill`` and never names an array itself.
-
-    ``window``: the row's REACH — a layer that reads only the last
-    ``window`` positions states it, and the pool keeps such a row in a RING
-    of its slot's own (``[slots x ring + 1, page_block, *shape]``, position
-    p in ring entry ``(p // page_block) % ring``) that stops growing with
-    the context; ``None``: every position is read for as long as the
-    request lives, and the row's pages grow with it.
-
-    ``held``: the shape the pool HOLDS the row in where the model's kernels
-    need one wider than the stated (KeyeSparseLM's 64-wide indexer key,
-    held at the chip's 128 lanes so that a page of it can be fetched by a
-    DMA): the stated row is the leading corner of the held one, what lies
-    past it keeps its fill, and everything that leaves the pool (a
-    shipment, ``pk.pool_rows``) is the stated row."""
-    name: str
-    shape: tuple
-    dtype: object
-    fill: float = 0.0
-    window: Optional[int] = None
-    held: Optional[tuple] = None
-
-
-class SlotRow(NamedTuple):
-    """Per-SLOT state a model states beside its pages (``cache_rows`` may
-    list both): state of a fixed size whatever the context — a short
-    convolution's tail, a recurrence's carry. The page pool allocates
-    ``[slots, *shape]`` of ``dtype``, writes an admitted slot's entry from
-    the ``[B, *shape]`` array of this name in ``prefill``'s cell, hands it
-    to ``decode_step_paged`` in the cell and keeps what comes back for the
-    live slots (any other slot's goes back to ``fill``: a freed slot is
-    clear after the next segment), and ships it with the slot's pages."""
-    name: str
-    shape: tuple
-    dtype: object
-    fill: float = 0.0
-
-
-#: tokens a chunked prefill runs through the depth at once (rows x width)
-PREFILL_TOKENS = 2048
-#: prompt rows of at least this many tokens are admitted ONE a chunk by the
-#: deep stacks (NemotronHLM, AfmoeLM); under it a chunk fills
-#: ``PREFILL_TOKENS`` with rows. A chunk of TWO rows of 1,024 never returns on
-#: a v5e from 13 of NemotronHLM's layers on (6 layers: it does), with the
-#: chunked scan or the flash kernel on their dense routes just the same; 8 x
-#: 256, 4 x 512, 1 x 1,024 and 1 x 2,048 return at all 52. The cause is not
-#: found (PERF.md sections 6 and 7, PR 35).
-SOLO_ROW_TOKENS = 1024
-#: the same for ``TransformerLM``, whose blocks are dense: chosen on the chip
-#: at the GPT-2 serve cells' shapes (PERF.md section 6, PR 38)
+#: tokens ``TransformerLM``'s chunked prefill runs through its (dense)
+#: blocks at once: chosen on the chip at the GPT-2 serve cells' shapes
+#: (PERF.md section 6, PR 38)
 LM_PREFILL_TOKENS = 512
-
-
-def live_row_walk(n_rows, width, chunk_tokens, n_live):
-    """The shape of :func:`prefill_live_rows`' walk over ``[n_rows, width]``
-    prompts of which ``n_live`` hold one: (rows a chunk — the most that
-    divide ``n_rows`` within ``chunk_tokens`` of ``width``-wide rows, at
-    least one — and the chunks walked). Arithmetic alone, so the traced
-    walk calls it with a traced ``n_live`` and the host, which has the
-    lengths, with an int: the page pool's count of the positions an
-    admission ran (``prefill_positions``) is the walk's own."""
-    rows = next(r for r in range(max(1, min(n_rows, chunk_tokens // width)),
-                                 0, -1) if n_rows % r == 0)
-    return rows, (n_live + rows - 1) // rows
-
-
-def prefill_live_rows(sequence, prompt, pos, d_model, state0, stats0,
-                      chunk_tokens, in_place=(), write=None):
-    """The admission walk ``DeepseekV3LM`` and ``Lfm2MoeLM`` share. Rows
-    are independent of one another, so the depth runs a few rows at a time
-    (``chunk_tokens``, the caller's ``PREFILL_TOKENS``): what a chunk
-    expands is bounded by that, not by slots x prompt bucket. And only rows
-    that HOLD a prompt run at
-    all: the page pool hands every admission the whole pool's width with
-    length 0 in the slots it is not filling, so the rows are taken live
-    ones first and the walk stops after the last chunk that has one — an
-    admission costs what was admitted (but for the rows that fill up the
-    last live chunk).
-
-    ``sequence(ids [R, T0], lengths [R]) -> (h [R, T0, d] f32 — or [R, d],
-    each row's hidden state at its last position already, from a sequence
-    that walks a row in blocks and keeps no more —, state, stats)``;
-    ``state0``: a pytree of ``[B, ...]`` buffers the chunks'
-    ``state`` (same tree, ``[R, ...]``) is written into; ``stats0``: the
-    tree the chunks' ``stats`` are summed into. Returns (each row's hidden
-    state at its last position [B, d], state, stats); rows of length 0
-    keep their zeros. ``in_place``: names of ``state0`` (a dict then)
-    whose buffers are NOT fresh zeros but somebody's live arrays (the
-    pool's per-slot rows, ``NemotronHLM.prefill``): a chunk writes them
-    at the rows that hold a prompt and nowhere else — the rows of length
-    0 that fill up the last chunk keep what they hold. ``write(state, idx,
-    n, new) -> state``: the caller's own way of putting a chunk's ``new``
-    (rows ``idx`` of lengths ``n``) into ``state`` — the page pool's
-    scatter into its pages, so that no ``[B, T0, ...]`` buffer of every
-    row's keys and values stands between a chunk and the pool."""
-    B, T0 = prompt.shape
-    R, n_chunks = live_row_walk(B, T0, chunk_tokens,
-                                jnp.sum(pos > 0, dtype=jnp.int32))
-    order = jnp.argsort(pos == 0, stable=True).astype(jnp.int32)
-
-    def chunk(carry):
-        i, last, state, stats = carry
-        idx = jax.lax.dynamic_slice(order, (i * R,), (R,))
-        n = pos[idx]
-        h, new, st = sequence(prompt[idx], n)
-        last = last.at[idx].set(h if h.ndim == 2
-                                else h[jnp.arange(R), n - 1])
-        if write is not None:
-            state = write(state, idx, n, new)
-        elif in_place:
-            held = jnp.where(n > 0, idx, B)         # B: dropped
-            state = {k: (buf.at[held].set(new[k], mode="drop")
-                         if k in in_place else buf.at[idx].set(new[k]))
-                     for k, buf in state.items()}
-        else:
-            state = jax.tree_util.tree_map(
-                lambda buf, x: buf.at[idx].set(x), state, new)
-        return (i + 1, last, state,
-                jax.tree_util.tree_map(jnp.add, stats, st))
-    _, last, state, stats = jax.lax.while_loop(
-        lambda c: c[0] < n_chunks, chunk,
-        (jnp.int32(0), jnp.zeros((B, d_model), jnp.float32), state0,
-         stats0))
-    return last, state, stats
-
-
-class LiveRowPrefill:
-    """A model whose ``prefill`` walks :func:`prefill_live_rows`: it says
-    what a chunk may hold (``prefill_chunk_tokens(width)``, which its
-    ``prefill`` hands the walk), and the page pool's question — how many
-    positions did an admission run through the depth — is answered by the
-    walk's own arithmetic."""
-
-    def prefill_positions(self, n_rows: int, width: int, n_live: int) -> int:
-        """Positions ``prefill`` runs for ``[n_rows, width]`` prompts of
-        which ``n_live`` hold one: chunks walked x rows a chunk x width."""
-        rows, chunks = live_row_walk(
-            n_rows, width, self.prefill_chunk_tokens(width), n_live)
-        return chunks * rows * width
-
-
-def paged_greedy(model, params, prompt, steps: int, page_block: int):
-    """Greedy continuation through ``model.prefill`` + its paged decode
-    step, one private block table a sample: prompt [B, T0] -> [B, T0 +
-    steps]. The solo decode a served stream is compared with, for any
-    model that states its rows (``cache_rows``: pages are cut from the
-    prefill's cell, slot rows carried as they come)."""
-    B = prompt.shape[0]
-    nb = model.max_len // page_block
-    cell, last = model.prefill(params, prompt)
-    tables = 1 + jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
-    state = {"pos": cell["pos"]}
-    for r in model.cache_rows(params):
-        if isinstance(r, SlotRow):
-            state[r.name] = cell[r.name]
-            continue
-        rows = cell[r.name].reshape((B * nb, page_block) + r.shape)
-        state[r.name] = jnp.concatenate(
-            [jnp.zeros((1, page_block) + r.shape, r.dtype), rows])
-    cur = jnp.argmax(last, axis=-1).astype(prompt.dtype)
-    out = [prompt, cur[:, None]]
-    for _ in range(steps - 1):
-        logits, state = model.decode_step_paged(params, state, cur, tables)
-        cur = jnp.argmax(logits, axis=-1).astype(prompt.dtype)
-        out.append(cur[:, None])
-    return jnp.concatenate(out, axis=1)
 
 
 class TransformerBlock(nn.Module):
@@ -259,9 +95,12 @@ class TransformerBlock(nn.Module):
         return (out, (k, v)) if return_kv else out
 
 
-class TransformerLM(LiveRowPrefill, nn.Module):
+class TransformerLM(PagedLM):
     """GPT-style LM: token + learned position embeddings, N pre-LN blocks,
-    final LN, head tied to the token embedding (weight sharing)."""
+    final LN, head tied to the token embedding (weight sharing). A
+    ``PagedLM`` for the contract the page pool reads; its ``prefill``,
+    ``decode_step_paged`` and ``prefill_paged`` are its own (quantised
+    rows, learned positions, the prefix-hit program)."""
 
     def __init__(self, vocab: int, d_model: int = 512, n_heads: int = 8,
                  n_layers: int = 6, d_ff: Optional[int] = None,
@@ -370,7 +209,8 @@ class TransformerLM(LiveRowPrefill, nn.Module):
     # -- incremental decoding (the serving path) ---------------------------
     def prefill(self, params, prompt, lengths=None, *,
                 kv_dtype: Optional[str] = None,
-                pad_to: Optional[int] = None):
+                pad_to: Optional[int] = None, pools=None, write=None,
+                slot_state=None):
         """Run the prompt once, materializing per-layer KV caches padded to
         max_len. Returns (cell, last_logits [B, V]); cell carries the caches
         and the per-sample write position.
@@ -406,10 +246,17 @@ class TransformerLM(LiveRowPrefill, nn.Module):
         first prompt-bucket rows into its page pool, and padding the
         transient cell to max_len would spike peak HBM to the pinned-pool
         size paging exists to avoid. Must be >= the prompt width; the
-        dense decode paths keep the max_len default."""
+        dense decode paths keep the max_len default.
+
+        ``pools`` / ``write`` / ``slot_state`` (``PagedLM.prefill``'s ways
+        to write in place) are not for this model: it states no slot rows
+        and admits through the cell."""
         if kv_dtype not in (None, "int8"):
             raise ValueError(f"unsupported kv_dtype {kv_dtype!r} "
                              "(None or 'int8')")
+        if any(a is not None for a in (pools, write, slot_state)):
+            raise ValueError("TransformerLM.prefill returns its rows in the "
+                             "cell: it takes no pools, write or slot_state")
         prompt = jnp.asarray(prompt)       # the walk indexes it by a tracer
         B, T0 = prompt.shape
         limit = self.max_len if pad_to is None else min(pad_to, self.max_len)
@@ -546,12 +393,9 @@ class TransformerLM(LiveRowPrefill, nn.Module):
         for four 512-token rows of gpt2-large)."""
         return max(LM_PREFILL_TOKENS, 2 * width)
 
-    #: the decode read's registered cost model (obs/roofline.kernel_cost)
-    paged_read_kernel = "paged_decode_attention"
-
     def paged_read_geometry(self, params, kv_dtype: Optional[str] = None):
-        """The shape facts that cost model takes beside (pages,
-        page_block)."""
+        """The shape facts the read's cost model takes beside (pages,
+        page_block): every head its own key, rows quantised or not."""
         return {"n_heads": self.blocks[0].n_heads,
                 "d_head": self.blocks[0].d_head, "kv_dtype": kv_dtype,
                 "itemsize": jnp.dtype(self._compute_dtype(params)).itemsize}
@@ -992,11 +836,6 @@ class TransformerLM(LiveRowPrefill, nn.Module):
                       kernel="decode_attention")
         obs.count("decode.tokens_total", B * steps, route="fused")
         return jnp.concatenate([prompt, jnp.stack(toks, axis=1)], axis=1)
-
-    def _compute_dtype(self, params):
-        """dtype of the attention k/v activations (follows the embedding
-        table, which the cache rows inherit)."""
-        return params["embed"]["w"].dtype
 
 
 def _sample_token(logits, rng, sample, top_k, temperature):

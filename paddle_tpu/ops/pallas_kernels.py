@@ -2718,7 +2718,7 @@ def index_scores_paged(qi: jax.Array, w: jax.Array, ik_pool: jax.Array,
     "slice shape must be aligned to tiling"), so the serving pool holds
     the indexer's row 128 wide (``CacheRow.held``), zeros past ``Di``; a
     pool as narrow as its keys is widened here, by a copy (the solo
-    decode's, models/transformer.py ``paged_greedy``)."""
+    decode's, models/paged_lm.py ``paged_greedy``)."""
     B, NB = tables.shape
     P, bs, W = ik_pool.shape
     Di = qi.shape[-1]
@@ -2894,36 +2894,6 @@ def select_topk(scores: jax.Array, extent: jax.Array, k: int, *,
         interpret=_interpret(interpret), name="select_topk",
     )(top, scores.astype(jnp.float32), extent[:, None])
     return bias, cnt[:, 0]
-
-
-def selected_rows(bias: jax.Array, k: int, page_block: int):
-    """The rows a mask selects, as a list: bias [B, L] (0 selected, as
-    :func:`select_topk` gives it, at most ``k`` a row) -> (position [B, k]
-    int32 ascending, n [B] int32 selected; entries past ``n`` are 0).
-    Neither a sort nor a scatter: a page's count places a list entry in
-    its page, and the page's running count places it in the page."""
-    B, L = bias.shape
-    bs = page_block
-    hit = (bias == 0.0).reshape(B, L // bs, bs)
-    inside = jnp.cumsum(hit.astype(jnp.int32), axis=2)      # [B, NP, bs]
-    upto = jnp.cumsum(inside[:, :, -1], axis=1)             # [B, NP]
-    j = jnp.arange(k, dtype=jnp.int32)
-    page = jnp.sum(upto[:, None, :] <= j[None, :, None], axis=2,
-                   dtype=jnp.int32)                         # [B, k]
-    page = jnp.minimum(page, L // bs - 1)
-    own = page[:, :, None] == jnp.arange(L // bs)[None, None, :]
-    before = jnp.sum(jnp.where(own, (upto - inside[:, :, -1])[:, None, :],
-                               0), axis=2)                  # [B, k]
-    # the page's running counts, fetched by a one-hot product (values to
-    # ``page_block``: exact in bfloat16 up to 256)
-    running = jnp.einsum("bkp,bps->bks", own.astype(jnp.bfloat16),
-                         inside.astype(jnp.bfloat16),
-                         preferred_element_type=jnp.float32)
-    row = jnp.sum(running <= (j[None, :] - before)[:, :, None].astype(
-        jnp.float32), axis=2, dtype=jnp.int32)
-    n = upto[:, -1]
-    ok = j[None, :] < n[:, None]
-    return jnp.where(ok, page * bs + jnp.minimum(row, bs - 1), 0), n
 
 
 #: positions of a slot's context one step of the selected read covers

@@ -296,32 +296,44 @@ def ffn_or_experts(blk, params, h, live):
 
 
 class ProgramStats:
-    """What a served model with expert layers returns beside a program's
-    tokens, and what the host makes of it. The model gives ``n_moe``
-    (expert layers), ``n_held`` (experts held here) and ``top_k``."""
+    """What a served model returns beside a program's tokens, and what the
+    host makes of it: the expert layers' account where the model has any
+    — it gives ``n_moe`` (expert layers), ``n_held`` (experts held here)
+    and ``top_k`` — and whatever a model counts beside that, by name."""
+
+    n_moe = n_held = top_k = 0
 
     def program_stats_zero(self):
         """Accumulators a program returns beside its tokens: the live
         (token, choice) pairs that landed on each held expert, per expert
         layer; the held experts touched and the row tiles their grouped
         products walked (:func:`row_tiles`), each summed over steps and
-        layers; the live tokens routed, summed over steps."""
+        layers; the live tokens routed, summed over steps. None of them
+        for a model without expert layers: no stats."""
+        if not self.n_moe:
+            return {}
         return {"routed": jnp.zeros((self.n_moe, self.n_held), jnp.int32),
                 "touched": jnp.zeros((), jnp.int32),
                 "row_tiles": jnp.zeros((), jnp.int32),
                 "tokens": jnp.zeros((), jnp.int32)}
 
-    def _add_stats(self, stats, counts, live, n_rows):
-        if not counts:
-            return stats
-        c = jnp.stack(counts)
-        n = n_rows if live is None else jnp.sum(live, dtype=jnp.int32)
-        return {"routed": stats["routed"] + c,
-                "touched": stats["touched"] + jnp.sum(c > 0,
-                                                      dtype=jnp.int32),
-                "row_tiles": stats["row_tiles"]
+    def _add_stats(self, stats, counts, live, n_rows, **more):
+        """``stats`` with the expert layers' ``counts`` of ``n_rows`` rows
+        (those ``live`` marks) added, and every ``more[name]`` to the
+        accumulator of its name."""
+        out = dict(stats)
+        if counts:
+            c = jnp.stack(counts)
+            n = n_rows if live is None else jnp.sum(live, dtype=jnp.int32)
+            out.update(
+                routed=stats["routed"] + c,
+                touched=stats["touched"] + jnp.sum(c > 0, dtype=jnp.int32),
+                row_tiles=stats["row_tiles"]
                 + row_tiles(c, n_rows * self.top_k),
-                "tokens": stats["tokens"] + n}
+                tokens=stats["tokens"] + n)
+        for k, v in more.items():
+            out[k] = stats[k] + jnp.asarray(v, stats[k].dtype)
+        return out
 
     def note_program_stats(self, stats, program: str):
         """Host side of :meth:`program_stats_zero`: count what a program

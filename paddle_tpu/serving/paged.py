@@ -51,7 +51,7 @@ import numpy as np
 
 from .. import obs
 from ..core.lod import bucket_length
-from ..models.transformer import SlotRow
+from ..models.paged_lm import SlotRow
 from ..ops import pallas_kernels as pk
 from . import ship
 from .batcher import Request, clip_emission, validate_request
@@ -193,9 +193,11 @@ class PagePool:
     program per (suffix-pad, read-pages) bucket pair, one segment program
     per cache-read bucket (in pages).
 
+    The model is a ``PagedLM`` (models/paged_lm.py): everything this class
+    reads off it is an attribute declared there, with its default.
     What the pool holds is what the MODEL states (``cache_rows``): a
     ``CacheRow`` becomes a page pool ``[pages, page_block, *shape]``, a
-    ``SlotRow`` (models/transformer.py: state of a fixed size whatever the
+    ``SlotRow`` (models/paged_lm.py: state of a fixed size whatever the
     context, e.g. Lfm2MoeLM's convolution tails) an array ``[slots,
     *shape]`` beside the pages (``slot_state``): written for the admitted
     slots from ``prefill``'s cell, carried through the segment loop in the
@@ -276,7 +278,7 @@ class PagePool:
         if cache_bucket % page_block:
             raise ValueError(f"cache_bucket {cache_bucket} must be a "
                              f"multiple of page_block {page_block}")
-        if prefix_cache and not hasattr(model, "prefill_paged"):
+        if prefix_cache and model.prefill_paged is None:
             raise ValueError(
                 f"prefix_cache needs the model's suffix admission "
                 f"(prefill_paged), which {type(model).__name__} does not "
@@ -365,8 +367,7 @@ class PagePool:
         # ... and its per-SLOT rows (SlotRow; the class docstring says
         # what becomes of them): [slots, *shape], none for most models
         self._slot_rows = [r for r in stated if isinstance(r, SlotRow)]
-        self._in_place = bool(self._slot_rows) and getattr(
-            model, "slot_rows_in_place", False)
+        self._in_place = bool(self._slot_rows) and model.slot_rows_in_place
         self.slot_state = {
             r.name: jnp.full((slots,) + tuple(r.shape), r.fill, r.dtype)
             for r in self._slot_rows}
@@ -377,11 +378,10 @@ class PagePool:
                       slots * self.slot_state_bytes)
         # the decode read's registered cost model, the shape facts it
         # takes beside (pages, page_block), and how many layers of a step
-        # make that read (every block unless the model says otherwise)
+        # make that read
         self._read_kernel = model.paged_read_kernel
         self._read_geom = model.paged_read_geometry(params, kv_dtype)
-        self._read_layers = getattr(model, "paged_read_layers",
-                                    len(model.blocks))
+        self._read_layers = model.paged_read_layers
         # one page of every GROWING array in HBM bytes — the prefix index's
         # reuse-ledger credit unit — and of every ringed one
         def page_bytes(ringed):
@@ -772,30 +772,29 @@ class PagePool:
             model, kv_dtype, bs = self.model, self.kv_dtype, self.bs
             tpp, in_place = nbp * bs, self._in_place
             write = self._page_write(nbp)
-            pages_in_place = getattr(model, "admits_in_place", False)
+            pages_in_place = bool(self.ring) or model.admits_in_place
 
             def admit(params, state, prompts, lens, pages, *ring_tables):
-                # pad_to=tpp: the transient cell holds prompt-bucket rows,
-                # not a max_len-padded (pinned-pool-sized) cache — the
-                # admission HBM spike stays proportional to the prompts
+                # the pool's three ways to take an admission's rows
+                # (``PagedLM.prefill``). pad_to=tpp: a transient cell holds
+                # prompt-bucket rows, not a max_len-padded
+                # (pinned-pool-sized) cache — the admission HBM spike stays
+                # proportional to the prompts ...
                 pools, slot_state = state
-                if ring_tables or pages_in_place:
-                    # ... and a model with rings (or one that says
-                    # ``admits_in_place``) writes its pages in place, a
-                    # chunk at a time: no cell of keys and values at all
-                    cell, last = model.prefill(
-                        params, prompts, lens, kv_dtype=kv_dtype, pad_to=tpp,
-                        pools=pools,
-                        write=functools.partial(
-                            write, ring_tables[0] if ring_tables else None,
-                            pages))
-                    first = jnp.argmax(last, axis=-1).astype(prompts.dtype)
-                    return (({nm: cell[nm] for nm in pools}, slot_state),
-                            first, cell.get("stats", {}))
                 cell, last = model.prefill(
                     params, prompts, lens, kv_dtype=kv_dtype, pad_to=tpp,
-                    **(dict(slot_state=slot_state) if in_place else {}))
+                    pools=pools if pages_in_place else None,
+                    write=functools.partial(
+                        write, ring_tables[0] if ring_tables else None,
+                        pages) if pages_in_place else None,
+                    slot_state=slot_state if in_place else None)
                 first = jnp.argmax(last, axis=-1).astype(prompts.dtype)
+                if pages_in_place:
+                    # ... and a model with rings (or one that says
+                    # ``admits_in_place``) wrote its pages in place, a
+                    # chunk at a time: no cell of keys and values at all
+                    return (({nm: cell[nm] for nm in pools}, slot_state),
+                            first, cell.get("stats", {}))
                 # the pages that hold an admitted prompt: page j of a row
                 # with j * bs < its length (a row not admitted has length
                 # 0, so nothing is sent to the null page)
@@ -867,8 +866,9 @@ class PagePool:
                 ring_kw = dict(ring_tables=ring_tables[0]) if ring_tables \
                     else {}
                 cell = dict(pools, **slot_state, pos=pos)
-                if hasattr(model, "program_stats_zero"):
-                    cell["stats"] = model.program_stats_zero()
+                stats = model.program_stats_zero()
+                if stats:               # (a model that counts nothing: {})
+                    cell["stats"] = stats
 
                 # ``steps`` [] int32 is TRACED, 1..segment: the one program
                 # of this table width runs the steps the host asks for and
@@ -907,8 +907,8 @@ class PagePool:
                         cell.get("stats", {}))
             # what the model asks of the TPU's compiler for its decode
             # steps, if anything (another backend knows no such option)
-            options = getattr(model, "decode_compiler_options", None) \
-                if pk._on_tpu() else None
+            options = model.decode_compiler_options if pk._on_tpu() \
+                else None
             fn = obs.roofline.instrument(
                 jax.jit(seg, donate_argnums=(1,), compiler_options=options),
                 "serving.segment")
@@ -991,15 +991,13 @@ class PagePool:
             self.prefill_tokens_total += plan.plen - plan.offset
             (hits if plan.offset else miss).append((slot, plan))
 
-    def _account(self, work, rows: int, prompt_tokens: int, width: int,
-                 positions: Optional[int] = None) -> None:
-        """Add one admit program over ``[n_slots, width]`` to the
-        admission's account ``work``. The positions it ran: the caller's,
-        or the walk of the model's ``prefill`` (``prefill_positions``: the
-        chunks that hold the ``rows`` live ones)."""
-        if positions is None:
-            positions = int(self.model.prefill_positions(self.n_slots,
-                                                         width, rows))
+    def _account(self, work, rows: int, prompt_tokens: int,
+                 positions: int) -> None:
+        """Add one admit program to the admission's account ``work``: the
+        ``rows`` that held a prompt, the ``prompt_tokens`` they had to run
+        and the ``positions`` the program ran through the depth (the
+        model's own count of its walk, ``admitted_positions``, or the
+        prefix-hit program's slots x width)."""
         for k, v in (("rows", rows), ("prompt_tokens", prompt_tokens),
                      ("positions", positions)):
             work[k] += v
@@ -1026,11 +1024,8 @@ class PagePool:
                 lens[slot] = plan.plen
                 n = min(nbp, len(self.slot_pages[slot]))
                 pages[slot, :n] = self.slot_pages[slot][:n]
-            # (a model that walks a row's own blocks says how many
-            # positions that came to; the others' walk is the pool's)
-            ran = getattr(self.model, "admitted_positions", None)
-            self._account(work, len(miss), int(lens.sum()), tpad,
-                          positions=ran and ran(lens, tpad))
+            self._account(work, len(miss), int(lens.sum()),
+                          self.model.admitted_positions(lens, tpad))
             fn = self._admit_fn(tpad, nbp)
             args = (self.params, (self.pools, self.slot_state),
                     jnp.asarray(prompts), jnp.asarray(lens),
@@ -1075,8 +1070,8 @@ class PagePool:
             for i, pair in enumerate(pairs):
                 src[i], dst[i] = pair
             # prefill_paged runs every slot at the suffix bucket's width
-            self._account(work, len(hits), int(lens.sum()), tpad,
-                          positions=self.n_slots * tpad)
+            self._account(work, len(hits), int(lens.sum()),
+                          self.n_slots * tpad)
             fn = self._hit_fn(tpad, nbr)
             args = (self.params, self.pools, jnp.asarray(suffix),
                     jnp.asarray(offsets), jnp.asarray(lens),

@@ -366,7 +366,7 @@ def test_the_pool_counts_growing_pages_alone_and_refuses_a_prefix_index(lm):
     with pytest.raises(ValueError, match="prefix_cache"):
         PagePool(model, params, **dict(POOL, prefix_cache=True))
     # one reach a pool: rows of two windows are refused
-    from paddle_tpu.models.transformer import CacheRow
+    from paddle_tpu.models.paged_lm import CacheRow
 
     class TwoReaches:
         max_len = 128

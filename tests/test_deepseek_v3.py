@@ -201,12 +201,12 @@ def test_prefill_runs_only_the_rows_that_hold_a_prompt(tiny, monkeypatch):
     """The pool hands an admission its whole width, length 0 in the slots
     it is not filling: those rows are skipped (zeros come back), the live
     ones read as if they were alone, whatever the chunking."""
-    from paddle_tpu.models import deepseek_v3
+    from paddle_tpu.models import paged_lm
     model, params = tiny
     ids = jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, VOCAB)
     lens = jnp.asarray([5, 0, 0, 16, 0, 9, 0, 0], jnp.int32)
     live = np.asarray(lens) > 0
-    monkeypatch.setattr(deepseek_v3, "PREFILL_TOKENS", 32)   # 2 rows a chunk
+    monkeypatch.setattr(paged_lm, "PREFILL_TOKENS", 32)   # 2 rows a chunk
     cell, last = model.prefill(params, ids, lens, pad_to=16)
     alone, last_alone = model.prefill(params, ids[live], lens[live],
                                       pad_to=16)
@@ -356,7 +356,7 @@ def test_a_program_returns_the_row_tiles_of_its_own_counts(program):
     if program == "admit":
         ids = jax.random.randint(jax.random.PRNGKey(5), (8, 64), 0, VOCAB)
         lengths = jnp.asarray([64, 64, 64, 64, 64, 64, 64, 3], jnp.int32)
-        _, _, stats = model._sequence(params, ids, lengths, False)
+        _, _, stats = model._sequence(params, ids, lengths)
         tm = 128
     else:
         B, bs = 4, 8
@@ -494,7 +494,7 @@ def test_pool_refuses_what_latent_rows_cannot_do_yet(tiny):
     model, params = tiny
     with pytest.raises(ValueError, match="prefill_paged"):
         PagePool(model, params, prefix_cache=True, **POOL)
-    with pytest.raises(ValueError, match="no quantised latent cache"):
+    with pytest.raises(ValueError, match="no quantised cache"):
         PagePool(model, params, kv_dtype="int8", **POOL)
 
 

@@ -25,7 +25,7 @@ import pytest
 
 from chipbench import weights_keye_vl2
 from chipbench.reference import keye_vl2 as ref
-from paddle_tpu.models.transformer import CacheRow
+from paddle_tpu.models.paged_lm import CacheRow
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.parallel import expert_share
 from paddle_tpu.parallel.expert_share import ExpertShare
@@ -242,19 +242,6 @@ def test_select_topk_is_jax_lax_top_k_ties_included(route):
         want[np.asarray(idx)] = True
         assert ((np.asarray(bias[r]) == 0.0) == want).all(), r
         assert int(cnt[r]) == min(k, n)
-
-
-def test_selected_rows_lists_the_mask_in_order():
-    rs = np.random.RandomState(1)
-    scores = jnp.asarray(rs.randn(8, 128), jnp.float32)
-    extent = jnp.asarray([1, 7, 16, 17, 60, 100, 128, 128], jnp.int32)
-    bias, cnt = pk.select_topk(scores, extent, 16, route="dense")
-    where, n = pk.selected_rows(bias, 16, 8)
-    assert (np.asarray(n) == np.asarray(cnt)).all()
-    for b in range(8):
-        want = np.nonzero(np.asarray(bias[b]) == 0.0)[0]
-        assert (np.asarray(where[b])[:len(want)] == want).all()
-        assert (np.asarray(where[b])[len(want):] == 0).all()
 
 
 def test_the_kernels_agree_with_their_dense_routes():

@@ -24,7 +24,7 @@ from chipbench import weights_lfm2
 from chipbench.reference import lfm2 as ref
 from paddle_tpu import nn
 from paddle_tpu.models import DeepseekV3LM, Lfm2MoeLM, TransformerLM
-from paddle_tpu.models.transformer import CacheRow, SlotRow
+from paddle_tpu.models.paged_lm import CacheRow, SlotRow
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.parallel import expert_share
 from paddle_tpu.serving.paged import PagePool

@@ -29,7 +29,7 @@ from chipbench import weights_nemotron_h
 from chipbench.reference import nemotron_h as ref
 from paddle_tpu import nn
 from paddle_tpu.models import Lfm2MoeLM, NemotronHLM
-from paddle_tpu.models.transformer import SlotRow
+from paddle_tpu.models.paged_lm import SlotRow
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.parallel import expert_share
 from paddle_tpu.serving.paged import PagePool
@@ -199,7 +199,7 @@ def test_a_wide_row_runs_alone_in_its_chunk_and_gives_the_same_cell(
     independent of one another, so the cell and the logits are what rows
     filling a chunk give — to the order of float32 sums inside a chunk's
     grouped products (TOL), and ``==`` where nothing is summed."""
-    from paddle_tpu.models import nemotron_h as mod
+    from paddle_tpu.models import paged_lm as mod
     model, params = lm
     assert mod.SOLO_ROW_TOKENS == 1024 == mod.PREFILL_TOKENS // 2
     ids = np.random.RandomState(21).randint(0, 96, (4, 16)).astype(np.int32)

@@ -13,8 +13,8 @@ import pytest
 
 from paddle_tpu import obs
 from paddle_tpu.models import DeepseekV3LM, TransformerLM
-from paddle_tpu.models import deepseek_v3, lfm2, nemotron_h, transformer
-from paddle_tpu.models.transformer import live_row_walk
+from paddle_tpu.models import paged_lm, transformer
+from paddle_tpu.models.paged_lm import live_row_walk
 from paddle_tpu.obs.requests import RequestLedger, format_timeline, stitch
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.paged import PagePool
@@ -213,12 +213,11 @@ def _count_positions_walked(model, monkeypatch):
     return walked
 
 
-#: model -> (its module, how to build it). Chunks hold 32 tokens here: two
-#: rows of the 16 bucket, one of the 32 bucket (for NemotronHLM also by
+#: model -> how to build it. Chunks hold 32 tokens here: two rows of the
+#: 16 bucket, one of the 32 bucket (for NemotronHLM also by
 #: ``SOLO_ROW_TOKENS``, which makes a 32-wide row walk alone)
-WALKERS = {"deepseek_v3": (deepseek_v3, _deepseek),
-           "lfm2": (lfm2, _lfm2),
-           "nemotron_h": (nemotron_h, _nemotron_h)}
+WALKERS = {"deepseek_v3": _deepseek, "lfm2": _lfm2,
+           "nemotron_h": _nemotron_h}
 
 
 @pytest.mark.parametrize("case", ["transformer", "transformer-prefix-hit",
@@ -237,11 +236,9 @@ def test_positions_are_the_walk_the_program_runs(case, monkeypatch,
               prompt_buckets=(16, 32))
     lens = (5, 13, 9)
     if case in WALKERS:
-        mod, build = WALKERS[case]
-        monkeypatch.setattr(mod, "PREFILL_TOKENS", 32)
-        if case == "nemotron_h":
-            monkeypatch.setattr(mod, "SOLO_ROW_TOKENS", 32)
-        model = build()
+        monkeypatch.setattr(paged_lm, "PREFILL_TOKENS", 32)
+        monkeypatch.setattr(paged_lm, "SOLO_ROW_TOKENS", 32)
+        model = WALKERS[case]()
         params = model.init(jax.random.PRNGKey(0))
         walked = _count_positions_walked(model, monkeypatch)
         pool = PagePool(model, params, **kw)

@@ -76,7 +76,7 @@ def test_admission_walk_matches_paged_greedy(fills, monkeypatch):
     of the walk (``prefill_live_rows``: a chunk filled up by a dead row,
     one whole chunk, two chunks): first tokens, pages and the segments
     after them are the model's solo paged decode."""
-    from paddle_tpu.models import TransformerLM, transformer
+    from paddle_tpu.models import TransformerLM, paged_lm, transformer
     from paddle_tpu.serving.paged import PagePool
     monkeypatch.setattr(transformer, "LM_PREFILL_TOKENS", 32)
     # a model of its own: the programs are traced here, at this chunk
@@ -96,7 +96,7 @@ def test_admission_walk_matches_paged_greedy(fills, monkeypatch):
     for s, prompt in zip(slots, prompts):
         toks = np.concatenate([b[s] for b in blocks])
         assert toks[0] == first[s]      # a segment re-emits the current one
-        solo = np.asarray(transformer.paged_greedy(
+        solo = np.asarray(paged_lm.paged_greedy(
             model, params, jnp.asarray(prompt)[None], 12, 8))[0]
         np.testing.assert_array_equal(solo[prompt.size:], toks)
 

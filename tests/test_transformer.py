@@ -296,10 +296,10 @@ RAGGED = {"chunks": [5, 0, 8, 3, 7, 0], "dead-fill": [5, 0, 8, 0, 3, 0]}
 def test_prefill_logits_match_forward(case, monkeypatch):
     """``prefill`` against the full forward: last logits, and on the rows
     that hold a prompt the cache rows too, however the walk chunks them
-    (models/transformer.py prefill_live_rows); rows of length 0 among
+    (models/paged_lm.py prefill_live_rows); rows of length 0 among
     them keep the cache's fill and are left out of the depth but for
     those that fill up the last live chunk."""
-    from paddle_tpu.models import transformer
+    from paddle_tpu.models import paged_lm, transformer
     from paddle_tpu.ops import pallas_kernels as pk
     model, params = _model(max_len=32)
     if case == "whole":
@@ -315,7 +315,7 @@ def test_prefill_logits_match_forward(case, monkeypatch):
     prompt = jax.random.randint(jax.random.PRNGKey(10), (6, 8), 0, V)
     if case == "none-is-full-lengths":
         # every row live: three chunks of two rows
-        assert transformer.live_row_walk(6, 8, 16, 6) == (2, 3)
+        assert paged_lm.live_row_walk(6, 8, 16, 6) == (2, 3)
         cell, last = model.prefill(params, prompt)
         ragged, last_r = model.prefill(params, prompt, jnp.full((6,), 8))
         np.testing.assert_array_equal(last, last_r)
@@ -327,7 +327,7 @@ def test_prefill_logits_match_forward(case, monkeypatch):
     kv, name = case.split("-", 1)
     lens = np.asarray(RAGGED[name], np.int32)
     live = lens > 0
-    assert transformer.live_row_walk(6, 8, 16, int(live.sum())) == (2, 2)
+    assert paged_lm.live_row_walk(6, 8, 16, int(live.sum())) == (2, 2)
     assert model.prefill_positions(6, 8, int(live.sum())) == 2 * 2 * 8
     cell, last = model.prefill(params, prompt, jnp.asarray(lens),
                                kv_dtype=None if kv == "f32" else "int8",
