@@ -1315,7 +1315,7 @@ def cmd_serve(args):
 
     A ``--config`` model is a ``paddle_tpu.models.paged_lm.PagedLM`` (as
     ``TransformerLM``, ``DeepseekV3LM``, ``Lfm2MoeLM``, ``NemotronHLM``,
-    ``AfmoeLM`` and ``KeyeSparseLM`` are): that class IS the contract the
+    ``AfmoeLM``, ``KeyeSparseLM`` and ``MimoV2LM`` are): that class IS the contract the
     page pool reads — ``max_len``, ``cache_rows`` (``CacheRow`` for what
     lives in pages, ``SlotRow`` for what lives per slot), ONE ``prefill``
     signature, ``decode_step_paged``, the decode read's cost model, what a

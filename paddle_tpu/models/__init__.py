@@ -7,6 +7,7 @@ from .embeddings import DeepFM, Recommender, Word2Vec
 from .generative import GAN, VAE
 from .lfm2 import Lfm2MoeLM
 from .keye_vl2 import KeyeSparseLM
+from .mimo_v2 import MimoV2LM
 from .image import (AlexNet, GoogleNet, LeNet, ResNet, SmallNet,
                     VGG, resnet50)
 from .mlp import MnistMLP
@@ -23,5 +24,5 @@ __all__ = [
            "AttentionSeq2Seq", "LinearCRFTagger", "BiLSTMCRFTagger",
            "Word2Vec", "Recommender", "DeepFM", "GAN", "VAE",
            "TransformerLM", "TransformerBlock", "DeepseekV3LM", "Lfm2MoeLM",
-           "NemotronHLM", "AfmoeLM", "KeyeSparseLM",
+           "NemotronHLM", "AfmoeLM", "KeyeSparseLM", "MimoV2LM",
            "TransformerSeq2Seq", "CrossAttentionBlock"]

@@ -202,23 +202,23 @@ class PagedRead(NamedTuple):
         its rows as stated: what a paged read takes)."""
         return pk.put_rows(pool, self.page, self.row, new)
 
-    def attend(self, q, k_rows, v_rows, *, scale, route=None):
+    def attend(self, q, k_rows, v_rows, *, scale, route=None, sink=None):
         """The grouped-query read: q [B, H, D] over the live pages'
         ``k_rows`` / ``v_rows`` of ``Hkv`` heads, a KV head serving its
         group of query heads, the window's rows alone where one is
-        stated."""
+        stated; ``sink`` [H]: the heads' sink logits."""
         return pk.paged_decode_attention(
             q, k_rows, v_rows, self.tables, self.pos, scale=scale,
-            work=self.work, window=self.window, route=route)
+            work=self.work, window=self.window, sink=sink, route=route)
 
     def write_and_attend(self, q, k, v, k_pool, v_pool, *, scale,
-                         route=None):
+                         route=None, sink=None):
         """A grouped-query layer's whole step against its two pools: the
-        step's k, v written, then the read -> (o [B, H, D], the pools)."""
+        step's k, v written, then the read -> (o [B, H, Dv], the pools)."""
         k_pool, k_rows = self.put(k_pool, k)
         v_pool, v_rows = self.put(v_pool, v)
-        return (self.attend(q, k_rows, v_rows, scale=scale, route=route),
-                k_pool, v_pool)
+        return (self.attend(q, k_rows, v_rows, scale=scale, route=route,
+                            sink=sink), k_pool, v_pool)
 
 
 class DecodeStep(NamedTuple):
@@ -254,6 +254,10 @@ class PagedLM(ProgramStats, nn.Module):
     #: the pool hands ``prefill`` its pools and its ``write``: no [slots,
     #: prompt bucket] copy of the rows stands beside the pools
     admits_in_place = False
+    #: of a row that states a window the pool is handed its last ``ring``
+    #: pages alone (``prefill(tail=)``), not the row: a model whose
+    #: ``_sequence`` walks a row in blocks and keeps no more of such a row
+    admits_window_tails = False
     #: the pool hands ``prefill`` its own slot-row arrays (``slot_state=``)
     #: and takes them back written at the filled slots' indices
     slot_rows_in_place = False
@@ -271,9 +275,13 @@ class PagedLM(ProgramStats, nn.Module):
         read over the growing pages."""
         return len(self.blocks)
 
-    def paged_read_geometry(self, params, kv_dtype: Optional[str] = None):
+    def paged_read_geometry(self, params, kv_dtype: Optional[str] = None,
+                            kind: str = "full"):
         """The shape facts the read's cost model takes beside (pages,
-        page_block): the grouped-query form."""
+        page_block), for the layers of ``kind`` — "full" (the growing
+        pages' read, ``paged_read_kernel``) or "window" (the rings'): the
+        grouped-query form, the same for both kinds unless the model's
+        kinds differ (MimoV2LM: KV heads a kind, k and v widths apart)."""
         return {"n_heads": self.n_heads, "kv_heads": self.kv_heads,
                 "d_head": self.d_head, "kv_dtype": None,
                 "itemsize": jnp.dtype(self._compute_dtype(params)).itemsize}
@@ -372,7 +380,7 @@ class PagedLM(ProgramStats, nn.Module):
 
     def prefill(self, params, prompt, lengths=None, *,
                 kv_dtype: Optional[str] = None, pad_to: Optional[int] = None,
-                pools=None, write=None, slot_state=None):
+                pools=None, write=None, slot_state=None, tail=None):
         """Run the prompts [B, T0] once -> (cell, last logits [B, V]). The
         cell holds ``pos`` (``lengths``, or T0 a row), ``stats``
         (:meth:`program_stats_zero`'s tree over the live prompt tokens) and
@@ -395,7 +403,9 @@ class PagedLM(ProgramStats, nn.Module):
         rows going into them through ``write(pools, idx, n, rows)`` and
         coming back written, no ``[B, T0]`` cell at all
         (``admits_in_place``, or rows that state a window). Donated
-        buffers come back the same buffers."""
+        buffers come back the same buffers. ``tail`` = (pages, page_block)
+        (``admits_window_tails``): ``_sequence`` hands ``write`` a windowed
+        row's last ``pages`` pages alone."""
         self._no_kv_dtype(kv_dtype)
         prompt = jnp.asarray(prompt)
         B, T0 = prompt.shape
@@ -417,8 +427,10 @@ class PagedLM(ProgramStats, nn.Module):
         state0.update(given)
         if Tp != T0:
             prompt = jnp.pad(prompt, ((0, 0), (0, Tp - T0)))
+        # (only a model that ``admits_window_tails`` takes the keyword)
+        kw = {} if tail is None else {"tail": tail}
         last, state, stats = prefill_live_rows(
-            lambda ids, n: self._sequence(params, ids, n), prompt, pos,
+            lambda ids, n: self._sequence(params, ids, n, **kw), prompt, pos,
             params["embed"]["w"].shape[1], state0, self.program_stats_zero(),
             self.prefill_chunk_tokens(Tp), in_place=tuple(slot_state or ()),
             write=write)

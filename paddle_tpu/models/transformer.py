@@ -210,7 +210,7 @@ class TransformerLM(PagedLM):
     def prefill(self, params, prompt, lengths=None, *,
                 kv_dtype: Optional[str] = None,
                 pad_to: Optional[int] = None, pools=None, write=None,
-                slot_state=None):
+                slot_state=None, tail=None):
         """Run the prompt once, materializing per-layer KV caches padded to
         max_len. Returns (cell, last_logits [B, V]); cell carries the caches
         and the per-sample write position.

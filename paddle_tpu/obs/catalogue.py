@@ -439,9 +439,19 @@ CATALOGUE: Dict[str, Tuple[str, ...]] = {
                  "a model that states none"),
     "serving.ring_bytes_held": (
         "gauge", "bytes of the pool's RINGS: the cache rows that state a "
-                 "reach (CacheRow(window=): AfmoeLM's sliding layers), "
-                 "slots x ring + 1 pages of every such row; not set for a "
-                 "model that states none"),
+                 "reach (CacheRow(window=): AfmoeLM's and MimoV2LM's "
+                 "sliding layers), slots x ring + 1 pages of every such "
+                 "row as STATED (k and v each its own width and heads); "
+                 "not set for a model that states none"),
+    "attention.sink_rows_total": (
+        "counter", "(live query, layer) pairs whose read was HANDED a "
+                   "sink operand (MimoV2LM's sliding layers), counted "
+                   "where the kernels are called: program=segment live "
+                   "slot x step x such layer, program=admit the admitted "
+                   "rows' real positions x such layers; the spans carry "
+                   "the same as sink_rows; parameters without a sink, or "
+                   "a call site that dropped it, read 0, labels: program",
+        ("program",)),
     "serving.cache_rows_read_total": (
         "counter", "cache rows a layer's decode read COVERED over a "
                    "segment's live slots and steps, of a model with both "

@@ -196,19 +196,24 @@ def _walk_k(tile, finish, carry, qi, block_q: int, block_k: int, n_k: int,
             finish(tile(carry, start, width, diagonal))
 
 
-def _fa_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
+def _fa_fwd_kernel(q_ref, k_ref, v_ref, len_ref, *rest,
                    block_k: int, scale: float, causal: bool, seq_len: int,
                    true_len: int, has_lens: bool,
                    window: Optional[int] = None):
     """One (batch*head, q-block) program: stream KV tiles, online softmax.
 
-    q_ref: [1, block_q, D]; k_ref/v_ref: [1, T, D]; o_ref: [1, block_q, D];
+    q_ref: [1, block_q, D]; k_ref: [1, T, D]; v_ref: [1, T, Dv] (Dv = D,
+    or narrower values); o_ref: [1, block_q, Dv];
     lse_ref: [1, block_q, 1] (f32 logsumexp residual for the backward pass;
     kept 3D with a trailing unit dim so the block obeys TPU tiling rules).
     len_ref: [1, 1, 1] int32 — THIS sample's true kv length (variable-length
     / LoD masking: keys at or past it never enter the softmax).
+    ``rest``: (o_ref, lse_ref), after a ``sink_ref`` [1, 1, 1] f32 where the
+    call has one — the head's sink LOGIT, which joins the finished softmax's
+    denominator (under a max that includes it) and no value.
     """
-    _, block_q, d = q_ref.shape
+    sink_ref, o_ref, lse_ref = rest if len(rest) == 3 else (None,) + rest
+    block_q, d = q_ref.shape[1], v_ref.shape[2]
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
@@ -234,6 +239,11 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
 
     def finish(carry):
         acc, m, l = carry
+        if sink_ref is not None:
+            b = sink_ref[0]                                 # [1, 1]
+            m_new = jnp.maximum(m, b)
+            corr = jnp.exp(m - m_new)
+            acc, l, m = acc * corr, l * corr + jnp.exp(b - m_new), m_new
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
         lse_ref[0] = m + jnp.log(l_safe)
@@ -451,16 +461,19 @@ def _count_block_pairs(kernel: str, bh: int, n_q: int, n_k: int, blk_q: int,
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7),
                    static_argnames=("window",))
 def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
-                 kv_lens=None, window=None):
-    """Returns (o [B,T,H,D], lse [B,T,H] f32). k/v may be shorter or longer
+                 kv_lens=None, window=None, sink=None):
+    """Returns (o [B,T,H,Dv], lse [B,T,H] f32). k/v may be shorter or longer
     than q (S != T) for cross-attention-shaped blocks; ``causal`` assumes
     S == T. ``kv_lens`` [B] masks each sample's keys past its true length
     (variable-length batches / cross-attention over padded sources).
     ``window``: the banded walk (:func:`_walk_k`), under a custom-call name
     of its own, ``flash_window_attention_fwd`` — a trace, and the counters,
-    tell a band from a causal square."""
+    tell a band from a causal square. v may be NARROWER than q and k
+    (``Dv`` wide: o is); ``sink`` [H] f32: a logit a head in the softmax's
+    denominator (lse includes it). A call with neither is the program it
+    was."""
     B, T, H, D = q.shape
-    S = k.shape[1]
+    S, Dv = k.shape[1], v.shape[3]
     # grouped-query heads: k/v [B, S, Hkv, D]; program bh = b * H + h reads
     # KV row b * Hkv + h // G = bh // G, so a group's G x n_q consecutive
     # programs name the same K/V block and it is fetched once for them
@@ -479,27 +492,30 @@ def _fa_fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
     _count_block_pairs(name, B * H, n_q, n_k, blk_q, blk_k, causal,
                        window=window)
     grid = (B * H, n_q)
+    row = pl.BlockSpec((1, 1, 1), lambda bh, qi: (bh, 0, 0))
+    sinks = () if sink is None else (jnp.tile(
+        sink.astype(jnp.float32), B)[:, None, None],)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, blk_q, D), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, Sp, D), kv_at),
-            pl.BlockSpec((1, Sp, D), kv_at),
-            pl.BlockSpec((1, 1, 1), lambda bh, qi: (bh, 0, 0)),
-        ],
+            pl.BlockSpec((1, Sp, Dv), kv_at),
+            row,
+        ] + [row] * len(sinks),
         out_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, blk_q, Dv), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, blk_q, 1), lambda bh, qi: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tp, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Tp, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tp, 1), jnp.float32),
         ],
         interpret=interpret,
         name=name,
-    )(qb, kb, vb, lensb)
-    o = _from_bh(out, B, T, H, D)
+    )(qb, kb, vb, lensb, *sinks)
+    o = _from_bh(out, B, T, H, Dv)
     lse = jnp.moveaxis(lse[:, :T, 0].reshape(B, H, T), 1, 2)
     return o, lse
 
@@ -625,10 +641,12 @@ def decode_route(L: int, route: Optional[str] = None) -> str:
     return "kernel" if _on_tpu() and L >= SHORT_SEQ_DENSE else "dense"
 
 
-def _dense_attention(q, k, v, causal, scale, kv_lens, window=None):
+def _dense_attention(q, k, v, causal, scale, kv_lens, window=None,
+                     sink=None, with_lse=False):
     """Masked dense attention for short sequences — same semantics as the
-    flash kernels (causal + per-sample kv_lens, the band of ``window``),
-    ordinary autodiff."""
+    flash kernels (causal + per-sample kv_lens, the band of ``window``,
+    values narrower than keys, a ``sink`` logit a head in the denominator),
+    ordinary autodiff. ``with_lse``: (o, lse [B, T, H] f32)."""
     T, S = q.shape[1], k.shape[1]
     if k.shape[2] != q.shape[2]:        # grouped-query heads: h reads h // G
         k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
@@ -644,9 +662,23 @@ def _dense_attention(q, k, v, causal, scale, kv_lens, window=None):
         if window is not None:
             mask = mask & ~jnp.tril(jnp.ones((T, S), bool), -window)
         s = jnp.where(mask[None, None], s, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhts,bshd->bthd", p,
-                      v.astype(jnp.float32)).astype(q.dtype)
+    if sink is None and not with_lse:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        m = jnp.max(s, axis=-1, keepdims=True)
+        if sink is not None:
+            b = sink.astype(jnp.float32)[None, :, None, None]
+            m = jnp.maximum(m, b)
+        e = jnp.exp(s - m)
+        l = jnp.sum(e, axis=-1, keepdims=True)
+        if sink is not None:
+            l = l + jnp.exp(b - m)
+        p = e / l
+    o = jnp.einsum("bhts,bshd->bthd", p,
+                   v.astype(jnp.float32)).astype(q.dtype)
+    if with_lse:
+        return o, jnp.moveaxis((m + jnp.log(l))[..., 0], 1, 2)
+    return o
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -674,6 +706,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, scale: Optional[float] = None,
                     kv_lens: Optional[jax.Array] = None,
                     window: Optional[int] = None,
+                    sink: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
@@ -702,6 +735,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``flash_window_attention_fwd``. Forward only — the backward kernels
     walk no band, and differentiating such a call raises.
 
+    v may be NARROWER than q and k (``v.shape[-1]`` < D: o is as wide as
+    v), and ``sink`` [H] gives every query head a learned logit that joins
+    its softmax's denominator and no value (``P_j = exp(s_j) / (sum exp(s)
+    + exp(sink))``). Either makes the call :func:`flash_attention_with_lse`'s
+    o, forward-only; a call with neither is the program it was.
+
     Short sequences (max(T, S) < SHORT_SEQ_DENSE, no explicit blocks given)
     auto-route to a masked dense einsum: below that point the kernels'
     per-program overhead exceeds their HBM saving (measured — the NMT
@@ -725,12 +764,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError(
             f"flash_attention: {q.shape[2]} query heads are not "
             f"whole groups over {k.shape[2]} KV heads")
-    dense = (block_q is None and block_k is None
-             and max(q.shape[1], k.shape[1]) < SHORT_SEQ_DENSE)
-    if window is not None:
-        from .. import obs
-        obs.count("kernels.routes_total", kernel="flash_window_attention_fwd",
-                  route="dense" if dense else "kernel")
+    dense = _short_dense(q, k, block_q, block_k)
+    if sink is not None or v.shape[-1] != D:
+        o, _ = flash_attention_with_lse(
+            q, k, v, causal=causal, scale=scale_v, kv_lens=kv_lens,
+            window=window, sink=sink, block_q=block_q, block_k=block_k,
+            interpret=interpret, short_dense=True)
+    elif window is not None:
+        _count_window_route(dense)
         blocks = (None, None) if dense else _default_blocks(block_q, block_k)
         o = _flash_window(q, k, v, kv_lens, (window, scale_v) + blocks
                           + (_interpret(interpret),))
@@ -750,25 +791,58 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return o
 
 
+def _short_dense(q, k, block_q, block_k) -> bool:
+    """flash_attention's rule for the dense route: no blocks asked for and
+    both sequences under SHORT_SEQ_DENSE."""
+    return (block_q is None and block_k is None
+            and max(q.shape[1], k.shape[1]) < SHORT_SEQ_DENSE)
+
+
+def _count_window_route(dense: bool):
+    from .. import obs
+    obs.count("kernels.routes_total", kernel="flash_window_attention_fwd",
+              route="dense" if dense else "kernel")
+
+
 def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              causal: bool = False,
                              scale: Optional[float] = None,
+                             kv_lens: Optional[jax.Array] = None,
+                             window: Optional[int] = None,
+                             sink: Optional[jax.Array] = None,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
-                             interpret: Optional[bool] = None):
+                             interpret: Optional[bool] = None,
+                             short_dense: bool = False):
     """Forward-only attention returning ``(o, lse)`` with lse: [B, T, H] f32.
 
-    Building block for ring attention: partial results over disjoint KV shards
-    merge exactly via logaddexp (parallel/ring_attention.py). Not
-    differentiable — ring attention installs its own VJP that reuses the
-    Pallas backward kernels per ring step.
+    Building block for what merges partial reads: results over disjoint sets
+    of keys merge exactly via logaddexp (parallel/ring_attention.py's KV
+    shards; models/mimo_v2.py's blocks of a row). Not differentiable — ring
+    attention installs its own VJP that reuses the Pallas backward kernels
+    per ring step.
+
+    ``kv_lens``, ``window`` (with ``causal``), values narrower than keys
+    and ``sink`` [H] as :func:`flash_attention` has them: o is as wide as
+    v, and the sink is inside lse. ``short_dense``: take flash_attention's
+    dense route for short sequences (the default keeps every call a kernel:
+    ring attention's backward recomputes from the kernel's lse).
     """
     D = q.shape[-1]
     scale_v = scale if scale is not None else D ** -0.5
+    if window is not None and not causal:
+        raise ValueError("flash_attention_with_lse: a window is a causal "
+                         "band (causal=True)")
+    dense = short_dense and _short_dense(q, k, block_q, block_k)
+    if window is not None:
+        _count_window_route(dense)
+    if dense:
+        return _dense_attention(q, k, v, causal, scale_v, kv_lens, window,
+                                sink, with_lse=True)
     block_q, block_k = _default_blocks(block_q, block_k)
-    interpret = _interpret(interpret)
     return _fa_fwd_call(q, k, v, causal, scale_v, block_q, block_k,
-                        interpret)
+                        _interpret(interpret), kv_lens=kv_lens,
+                        window=window, sink=sink)
 
 
 def flash_block_grads(q, k, v, o, lse, do, *, causal: bool = False,
@@ -940,7 +1014,8 @@ def _mxu_hi_lo(x, w, dims):
 def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
                                 q_ref, *refs, scale: float, chunk: int,
                                 quantized: bool, groups: int,
-                                window: Optional[int] = None):
+                                window: Optional[int] = None,
+                                has_sink: bool = False):
     """One program of the PAGED read over grouped-query heads: H query
     heads over Hkv = H // ``groups`` KV heads, query head h reading KV head
     ``h // groups``. A sibling of :func:`_decode_attn_kernel` with the
@@ -966,12 +1041,19 @@ def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
     (where the running softmax starts) is worked out from ``pos``, and rows
     at ``pos - window`` and before are masked like rows past ``pos``.
 
+    v may be narrower than k (``Dv``: o and acc are), and ``has_sink`` puts
+    a ``sink_ref`` [H, 1] f32 after v: a logit a head that joins the
+    finished softmax's denominator (under a max that includes it).
+
     Precision: bf16 pools go to the MXU as they are, and the f32 operands
     beside them (q, the softmax weights) as the sum of two bf16 halves,
     hi + lo, so a product keeps ~16 bits of them; f32 and int8 pools
     (dequantized here) multiply in f32. Accumulation and softmax are f32."""
+    sink_ref = None
     if quantized:
         k_ref, ks_ref, v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    elif has_sink:
+        k_ref, v_ref, sink_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
         k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     i = pl.program_id(0)
@@ -999,7 +1081,7 @@ def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
             # (bf16 rows are packed in pairs: an odd head count does not
             # collapse, Mosaic's "unsupported shape cast", so it goes f32)
             k, v = k.astype(jnp.float32), v.astype(jnp.float32)
-        k, v = k.reshape(R, D), v.reshape(R, D)
+        k, v = k.reshape(R, D), v.reshape(R, v.shape[-1])
 
         mxu = _mxu_hi_lo
         q = q_ref[0].astype(jnp.float32) * scale            # [H, D]
@@ -1020,42 +1102,60 @@ def _grouped_decode_attn_kernel(slot_ref, _, ord_ref, last_ref, pos_ref,
 
     @pl.when(last)
     def _finish():
-        o_ref[0] = acc_ref[...] / l_ref[...]
+        if sink_ref is None:
+            o_ref[0] = acc_ref[...] / l_ref[...]
+        else:
+            m, b = m_ref[...], sink_ref[...]
+            m_new = jnp.maximum(m, b)
+            corr = jnp.exp(m - m_new)
+            o_ref[0] = acc_ref[...] * corr / (l_ref[...] * corr
+                                              + jnp.exp(b - m_new))
 
 
 def _decode_attn_call(prefetch, q, k, v, k_scale, v_scale, qo_spec, kv_spec,
                       sc_spec, *, grid, scale, chunk, interpret, name,
-                      groups=1, window=None):
+                      groups=1, window=None, v_spec=None, sink=None):
     """The one pallas_call behind decode_attention and
     paged_decode_attention: ``prefetch`` scalars (pos last; five of them
     = the paged work list), then q [B, H, D], then k/v — each followed by
     its scale operand when the cache is int8. ``groups`` > 1 (the paged
     read alone): k/v hold H // groups heads and the grouped body runs.
-    ``name`` is the caller's: what a device trace shows the kernel as."""
+    ``name`` is the caller's: what a device trace shows the kernel as.
+    ``v_spec`` (the grouped body alone): v's own block where its rows are
+    narrower than k's — o is then as wide as v; ``sink`` [H] f32: the
+    heads' sink logits, an operand after v."""
     from jax.experimental.pallas import tpu as pltpu
     H, D = q.shape[1:]
+    o_spec = qo_spec
     if k_scale is not None:
         kv_args, kv_specs = ((k, k_scale, v, v_scale),
                              [kv_spec, sc_spec, kv_spec, sc_spec])
     else:
-        kv_args, kv_specs = (k, v), [kv_spec, kv_spec]
+        kv_args, kv_specs = (k, v), [kv_spec, v_spec or kv_spec]
+    if v_spec is not None:
+        D = v.shape[-1]
+        o_spec = pl.BlockSpec((1, H, D), qo_spec.index_map)
+    if sink is not None:
+        kv_args += (sink.astype(jnp.float32)[:, None],)
+        kv_specs.append(pl.BlockSpec((H, 1), lambda *_: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=grid,
-        in_specs=[qo_spec] + kv_specs, out_specs=qo_spec,
+        in_specs=[qo_spec] + kv_specs, out_specs=o_spec,
         scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32),
                         pltpu.VMEM((H, D), jnp.float32)])
     if groups > 1:
         kernel = functools.partial(_grouped_decode_attn_kernel, scale=scale,
                                    chunk=chunk, quantized=k_scale is not None,
-                                   groups=groups, window=window)
+                                   groups=groups, window=window,
+                                   has_sink=sink is not None)
     else:
         kernel = functools.partial(_decode_attn_kernel, scale=scale,
                                    chunk=chunk, quantized=k_scale is not None,
                                    work_list=len(grid) == 1)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(q.shape[:2] + (D,), jnp.float32),
         interpret=interpret, name=name,
     )(*prefetch, q, *kv_args)
 
@@ -1076,7 +1176,7 @@ def quantize_kv(x: jax.Array):
 
 
 def _dense_decode_attention(q, k, v, pos, scale, k_scale, v_scale,
-                            window=None):
+                            window=None, sink=None):
     """Reference-math route (short caches / off-TPU): same masked-softmax
     formulation as the kernel, ordinary XLA ops. Quantized caches
     dequantize up front — numerically the kernel's contract, but the f32
@@ -1084,7 +1184,8 @@ def _dense_decode_attention(q, k, v, pos, scale, k_scale, v_scale,
     small anyway. ``window`` = (window, ring): the rows are a RING's,
     gathered in the ring's order — entry e holds the newest page ``a <=
     pos // bs`` with ``a % ring == e`` — and the rows of ``(pos - window,
-    pos]`` are live."""
+    pos]`` are live. v may be narrower than k; ``sink`` [H]: a logit a
+    head in the denominator alone."""
     if k_scale is not None:
         k = k.astype(jnp.float32) * k_scale[..., None]
         v = v.astype(jnp.float32) * v_scale[..., None]
@@ -1105,8 +1206,13 @@ def _dense_decode_attention(q, k, v, pos, scale, k_scale, v_scale,
                  & (j > pos[:, None] - window[0]))[:, None, :]
     s = jnp.where(valid, s, _NEG)
     m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        b = sink.astype(jnp.float32)[None, :, None]
+        m = jnp.maximum(m, b)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
+    if sink is not None:
+        l = l + jnp.exp(b - m)
     return jnp.einsum("bhj,bjhd->bhd", p / l, v.astype(jnp.float32))
 
 
@@ -1272,6 +1378,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
                            work=None, window: Optional[int] = None,
+                           sink: Optional[jax.Array] = None,
                            route: Optional[str] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Single-token attention read through a block table — the paged twin
@@ -1306,7 +1413,12 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     grouped body (one KV head a query head is not asked of it) and its
     custom call is NAMED ``paged_window_attention``, as are its counters:
     what reads the trace counts a full read's bytes by every live row and
-    this one's by the window's."""
+    this one's by the window's.
+
+    ``v_pool`` may hold rows NARROWER than ``k_pool``'s ([P, bs, Hkv, Dv]:
+    o is [B, H, Dv]) and ``sink`` [H] gives every query head a logit that
+    joins its softmax's denominator and no value — both the grouped body's
+    (and the dense route's); a call with neither is the program it was."""
     B, NB = tables.shape
     P, bs, H, D = k_pool.shape
     Hq = q.shape[1]
@@ -1327,13 +1439,18 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         vs = None if v_scale is None else gather_pages(v_scale, tables)
         return _dense_decode_attention(
             q, k, v, pos, scale_v, ks, vs,
-            None if window is None else (window, NB))
+            None if window is None else (window, NB), sink)
     if route != "kernel":
         raise ValueError(f"unknown paged_decode_attention route {route!r}")
     G = Hq // H
+    Dv = v_pool.shape[3]
     if window is not None and G == 1:
         raise ValueError("paged_decode_attention: the windowed read is the "
                          "grouped body's (fewer KV heads than query heads)")
+    if (sink is not None or Dv != D) and (G == 1 or k_scale is not None):
+        raise ValueError("paged_decode_attention: a sink, or values "
+                         "narrower than keys, is the grouped body's over "
+                         "unquantised pools")
     if work is None:
         work = paged_work_list(tables, pos, bs, window)
     *work, n_work = work
@@ -1348,7 +1465,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         (*work, pos.astype(jnp.int32)), q, k_pool, v_pool, k_scale, v_scale,
         qo_spec, page_spec, sc_spec, grid=(n_work[0],), scale=scale_v,
         chunk=bs, interpret=_interpret(interpret), name=name, groups=G,
-        window=window)
+        window=window, sink=sink, v_spec=None if Dv == D else pl.BlockSpec(
+            (1, bs, H, Dv), lambda i, slot, page, *_: (page[i], 0, 0, 0)))
 
 
 # ---------------------------------------------------------------------------
@@ -3189,21 +3307,26 @@ def selected_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _decode_attention_bytes(*, batch, read, n_heads, d_head, layers=1,
                             kv_dtype=None, itemsize=2, steps=1,
-                            kv_heads=None):
+                            kv_heads=None, d_value=None):
     """HBM bytes of ``steps`` decode_attention dispatches: k+v live cache
     rows stream once per step (int8 rows read 1 byte/element plus one f32
     scale per (row, head) — the quantized-KV numerics contract,
     docs/design/kernels.md). ``kv_heads``: the heads a cache row holds
     where they are fewer than the query heads (grouped-query attention);
-    the read is charged those."""
-    row = (kv_heads or n_heads) * (d_head + 4 if kv_dtype == "int8"
-                                   else d_head * itemsize)
+    the read is charged those. ``d_value``: a value row's width where it
+    is not a key row's ``d_head``; k and v are charged each its own."""
+    heads = kv_heads or n_heads
+    if d_value is not None:
+        return float(batch * read * heads * (d_head + d_value) * itemsize
+                     * layers * steps)
+    row = heads * (d_head + 4 if kv_dtype == "int8" else d_head * itemsize)
     return 2.0 * batch * read * row * layers * steps
 
 
 def _paged_decode_attention_bytes(*, pages, page_block, n_heads, d_head,
                                   batch=1, layers=1, kv_dtype=None,
-                                  itemsize=2, steps=1, kv_heads=None):
+                                  itemsize=2, steps=1, kv_heads=None,
+                                  d_value=None):
     """HBM bytes of paged reads: ``pages`` pages of ``page_block`` rows,
     k and v. The decode kernel streams exactly the pages it walks (one
     program each: ``PagePool.run_segment`` passes that count, summed over
@@ -3213,7 +3336,7 @@ def _paged_decode_attention_bytes(*, pages, page_block, n_heads, d_head,
                                    n_heads=n_heads, d_head=d_head,
                                    layers=layers, kv_dtype=kv_dtype,
                                    itemsize=itemsize, steps=steps,
-                                   kv_heads=kv_heads)
+                                   kv_heads=kv_heads, d_value=d_value)
 
 
 def _paged_prefill_attention_bytes(*, batch, pages, page_block, n_heads,
@@ -3234,11 +3357,14 @@ def _paged_prefill_attention_bytes(*, batch, pages, page_block, n_heads,
 
 
 def _flash_window_attention_bytes(*, positions, n_heads, kv_heads, d_head,
-                                  itemsize=2):
+                                  itemsize=2, d_value=None):
     """HBM bytes of banded flash forwards over ``positions`` (position,
     layer) pairs, padding included: q and o of the query heads, k and v of
-    the KV heads, once each (a group's programs share a K/V block)."""
-    return 2.0 * positions * (n_heads + kv_heads) * d_head * itemsize
+    the KV heads, once each (a group's programs share a K/V block); o and
+    v ``d_value`` wide where that is not ``d_head``."""
+    return float(positions * (n_heads + kv_heads)
+                 * (d_head + (d_head if d_value is None else d_value))
+                 * itemsize)
 
 
 def _paged_latent_attention_bytes(*, pages, page_block, row, itemsize=2):

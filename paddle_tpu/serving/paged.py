@@ -382,6 +382,10 @@ class PagePool:
         self._read_kernel = model.paged_read_kernel
         self._read_geom = model.paged_read_geometry(params, kv_dtype)
         self._read_layers = model.paged_read_layers
+        # ... and the rings' read's, the layer KIND's own (MimoV2LM's
+        # sliding layers hold other heads than its global ones)
+        self._ring_geom = model.paged_read_geometry(
+            params, kv_dtype, kind="window") if self.ring else None
         # one page of every GROWING array in HBM bytes — the prefix index's
         # reuse-ledger credit unit — and of every ringed one
         def page_bytes(ringed):
@@ -708,6 +712,15 @@ class PagePool:
         obs.count("serving.adopted_total")
 
     # -- jitted programs ---------------------------------------------------
+    @property
+    def _tail(self):
+        """(ring, page_block) where the model's admission hands the pool a
+        windowed row's last ``ring`` pages alone (``admits_window_tails``),
+        else None: what ``prefill(tail=)`` is told and ``_page_write``
+        reads its source pages by."""
+        return (self.ring, self.bs) if self.ring \
+            and self.model.admits_window_tails else None
+
     def _put_fn(self):
         """:meth:`adopt_slot`'s write of shipped pages ``rows`` [n,
         page_block, *shape] at pool pages ``at`` [n]: a program like the
@@ -735,9 +748,13 @@ class PagePool:
         ``ring`` pages of a row's context into its ring — a page at a time
         where it lies (:func:`_write_pages`: the pools stay in the order
         they arrive in and the decode kernels read, through the walk's
-        loop too). What holds no prompt is not written."""
+        loop too). What holds no prompt is not written. From a model that
+        ``admits_window_tails``, ``new`` holds of a windowed row its last
+        ``ring`` pages alone, [R, ring x page_block, *shape] — the pages up
+        to the one with the row's last token — and never the row."""
         bs, ring, ringed = self.bs, self.ring, self._ring_names
         n_ring = min(nbp, ring)
+        tails = self._tail is not None
 
         def write(ring_tables, pages, pools, idx, n, new):
             R = idx.shape[0]
@@ -748,9 +765,12 @@ class PagePool:
                 top = jnp.maximum(n - 1, 0) // bs
                 a = top[:, None] - (n_ring - 1) + jnp.arange(n_ring)
                 rung = _listed(
-                    (a >= 0) & (n > 0)[:, None], jnp.clip(a, 0, nbp - 1),
+                    (a >= 0) & (n > 0)[:, None],
+                    jnp.broadcast_to(ring - n_ring + jnp.arange(n_ring),
+                                     a.shape) if tails
+                    else jnp.clip(a, 0, nbp - 1),
                     jnp.take_along_axis(ring_tables[idx], a % ring, axis=1))
-            cells = {nm: jnp.pad(
+            cells = {nm: rows if tails and nm in ringed else jnp.pad(
                 rows, ((0, 0), (0, nbp * bs - rows.shape[1]))
                 + ((0, 0),) * (rows.ndim - 2)) for nm, rows in new.items()}
             # an array at a time: one loop over all the arrays of a kind
@@ -773,6 +793,7 @@ class PagePool:
             tpp, in_place = nbp * bs, self._in_place
             write = self._page_write(nbp)
             pages_in_place = bool(self.ring) or model.admits_in_place
+            tail = self._tail
 
             def admit(params, state, prompts, lens, pages, *ring_tables):
                 # the pool's three ways to take an admission's rows
@@ -787,7 +808,7 @@ class PagePool:
                     write=functools.partial(
                         write, ring_tables[0] if ring_tables else None,
                         pages) if pages_in_place else None,
-                    slot_state=slot_state if in_place else None)
+                    slot_state=slot_state if in_place else None, tail=tail)
                 first = jnp.argmax(last, axis=-1).astype(prompts.dtype)
                 if pages_in_place:
                     # ... and a model with rings (or one that says
@@ -1224,7 +1245,7 @@ class PagePool:
              + 1).sum())
         obs.count("kernels.bytes_total", obs.roofline.kernel_cost(
             "paged_window_attention", pages=walked, page_block=self.bs,
-            **self._read_geom) or 0.0, kernel="paged_window_attention")
+            **self._ring_geom) or 0.0, kernel="paged_window_attention")
         rows = {"window_rows": int(np.minimum(at[live] + 1,
                                               self.window).sum()),
                 "full_rows": int((at[live] + 1).sum())}

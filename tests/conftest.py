@@ -57,6 +57,31 @@ def paged_model_and_params():
     return model, params
 
 
+#: tests whose assertion counts the benchmark AS IT WAS when they were
+#: written, in files of the benchmark's own (``BENCHMARK.json`` ``paths``)
+#: that only a ``benchmark`` PR may edit: they fail from the PR that adds
+#: the next cell on, and STRICTLY — the entry has to go with the lines that
+#: count. Every other line of such a test is asserted again, of the same
+#: cell and with no count of the day, where the reason names.
+_COUNTS_THE_BENCHMARK_OF_ITS_DAY = {
+    "test_chipbench_keye_vl2.py::"
+    "test_cell_is_found_by_name_with_its_mode_traffic_and_readers":
+        "PR 41's test ends with `len(workloads) == 8 and len(configs) == 7` "
+        "and its own cell last; PR 47 added the ninth cell and may not edit "
+        "the file (tests/chipbench_tests/test_chipbench_mimo_v2.py "
+        "test_the_cells_before_this_one_are_found_as_they_were asserts "
+        "every other line of it; PERF.md section 7 asks a benchmark PR to "
+        "drop the two lines and this entry)",
+}
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        for tail, why in _COUNTS_THE_BENCHMARK_OF_ITS_DAY.items():
+            if item.nodeid.endswith(tail):
+                item.add_marker(pytest.mark.xfail(reason=why, strict=True))
+
+
 _MP_CPU_PROBE = None
 
 _MP_PROBE_SRC = r"""

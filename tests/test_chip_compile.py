@@ -548,6 +548,79 @@ def test_keye_programs_keep_the_pools_in_place(one_chip, S, program,
     assert mem.temp_size_in_bytes < 2 ** 30
 
 
+# -- MimoV2LM at the mimo-ep16-serve-agentctx cell's shapes ------------------
+
+def _mimo_programs(S, program, layers):
+    """The cell's admit program at its one prompt bucket (49,152) or its
+    segment program, lowered for the described chip over the first
+    ``layers`` layers of the configuration: (compiled, the pools' avals)."""
+    import json
+    from chipbench import weights_mimo_v2
+    from paddle_tpu.serving.paged import PagePool
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "chipbench/configs/mimo-v2-flash-ep16-11l.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=layers)
+    model, shapes = weights_mimo_v2.model_and_shapes(cfg)
+    params = jax.tree_util.tree_map(lambda s: S(s.shape, s.dtype), shapes)
+    pool = PagePool.__new__(PagePool)
+    pool.model, pool.kv_dtype, pool.bs, pool.segment = model, None, 64, 32
+    pool.window, pool.ring, pool._in_place = 128, 4, False
+    pool._slot_rows, pool._fns = [], {}
+    rows = model.cache_rows({"embed": {"w": jnp.zeros((1,), jnp.bfloat16)}})
+    pool._ring_names = {r.name for r in rows if r.window}
+    pools = {r.name: S(((33 if r.window else 6273), 64)
+                       + tuple(r.held or r.shape), r.dtype) for r in rows}
+    i32 = jnp.int32
+    if program == "admit":
+        compiled = pool._admit_fn(49152, 768)._jitted.lower(
+            params, (pools, {}), S((8, 49152), i32), S((8,), i32),
+            S((8, 768), i32), S((8, 4), i32)).compile()
+    else:
+        compiled = pool._seg_fn(784)._jitted.lower(
+            params, (pools, {}), S((8, 784), i32), S((8,), i32),
+            S((8,), i32), S((8,), jnp.bool_), S((), i32),
+            S((8, 4), i32)).compile()
+    return compiled, pools
+
+
+@pytest.mark.parametrize("program", ["admit", "segment"])
+def test_mimo_programs_keep_the_pools_in_place(S, program, monkeypatch):
+    """The cell's admit program at its one prompt bucket (49,152: a row
+    walked 2,048 positions at a time, a sliding layer's last pages alone
+    handed on) and its segment program, whole, at layers 0-5 of 11 (both
+    kinds of layer, the dense FFN and the experts): no pool array is copied
+    — keys stated 192 wide and held 256, values 128, 4 or 8 heads by kind,
+    pages and rings —, every pool is aliased, the segment's steps are one
+    loop, and what a 49,152-token row expands is a block's. All 11 layers,
+    off this suite (PERF.md section 6, PR 47)."""
+    monkeypatch.setattr(pk, "_interpret", lambda interpret: False)
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    compiled, pools = _mimo_programs(S, program, 6)
+    text = compiled.as_text()
+    # the pages that grow (the global layers') are copied nowhere; a RING
+    # array is 33 pages (8.6 MB a key array), and the compiler stages so
+    # small an array through VMEM inside the step loop — into memory space
+    # 1 and back, never into another order
+    grown = {nm: a for nm, a in pools.items() if a.shape[0] == 6273}
+    assert len(grown) == 4
+    assert not _pool_copies(text, grown, [(4, 192)])
+    for copy in _pool_copies(text, pools, [(8, 192)]):
+        assert "S(1)" in copy and "{3,2,1,0:" in copy, copy
+    for name in (("flash_attention_fwd", "flash_window_attention_fwd")
+                 if program == "admit" else
+                 ("paged_decode_attention", "paged_window_attention")):
+        assert re.search(rf"%{name}[.\d]* = ", text), name
+    if program == "segment":
+        assert text.count(" while(") == 1
+    mem = compiled.memory_analysis()
+    held = sum(int(np.prod(a.shape)) * 2 for a in pools.values())
+    assert mem.alias_size_in_bytes == held
+    # (1.56 GiB here, 2.22 at all 11 layers; a whole 49,152-token row of
+    # ONE layer's q alone would be 1.2 GB)
+    assert mem.temp_size_in_bytes < 2 * 2 ** 30
+
+
 def test_flash_attention_compiles_at_latent_head_width(S):
     """Prefill of the latent-attention model expands k and v and runs the
     flash kernel at head width 192 (128 + 64 rotary; v is 192 as well): 1.5

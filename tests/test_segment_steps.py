@@ -22,6 +22,7 @@ from paddle_tpu.serving import ServingEngine
 
 import test_afmoe
 import test_keye_vl2
+import test_mimo_v2
 from test_serving_account import _deepseek, _lfm2, _nemotron_h
 
 SEGMENT, MAX_LEN, PROMPT = 32, 128, 11
@@ -44,6 +45,7 @@ MODELS = {
     "nemotron_h": lambda: _initialised(_nemotron_h(MAX_LEN)),
     "afmoe": lambda: test_afmoe.build(),
     "keye_vl2": lambda: test_keye_vl2.build()[:2],
+    "mimo_v2": lambda: test_mimo_v2.build(),
 }
 
 
